@@ -267,7 +267,7 @@ class TrainConfig:
     obs_dir: str = ""  # where metrics.jsonl / metrics.csv / heartbeat.json land
     obs_sinks: str = "jsonl"  # comma list of jsonl | csv | tracker
     obs_heartbeat: bool = True  # write heartbeat.json at report cadence
-    obs_chip_hint: str = ""  # chip gen for MFU peak ("v5e", ...); "" = env/default
+    obs_chip_hint: str = ""  # chip for the MFU peak ("v5e", ...); "" = the device's kind
     obs_strict_schema: bool = False  # raise (don't just log) on schema violations
 
     # logging

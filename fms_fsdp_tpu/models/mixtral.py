@@ -333,21 +333,6 @@ def _use_expert_a2a(
     ep = int(mesh.shape[AXIS_EXPERT])
     if ep <= 1:
         return False
-    from fms_fsdp_tpu.parallel.compat import has_new_shard_map
-
-    if not has_new_shard_map():
-        import warnings
-
-        warnings.warn(
-            "this jax version's legacy shard_map cannot express the"
-            " partial-manual (expert-axis-only) a2a dispatch — its auto-"
-            "subgroup partial manual mode hard-crashes the XLA SPMD"
-            " partitioner. Falling back to the GSPMD dispatch (correct,"
-            " ~E/top_k x the minimal expert-exchange traffic). Upgrade to"
-            " jax >= 0.8 for the explicit EP all-to-all.",
-            stacklevel=3,
-        )
-        return False
     if cfg.num_experts % ep != 0:
         import warnings
 
@@ -437,9 +422,7 @@ def _moe_ffn_dispatch_a2a(
         )  # (E, B, C, D)
         return _combine_from_buffer(out, dest, top_w, S)
 
-    from fms_fsdp_tpu.parallel.compat import shard_map as _shard_map
-
-    y = _shard_map(
+    y = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(

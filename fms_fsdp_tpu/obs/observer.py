@@ -47,8 +47,12 @@ class Observer:
         quantized_reduce: Optional[str] = None,
         restarts: int = 0,
         restart_downtime_s: float = 0.0,
+        device: Optional[Dict] = None,
     ):
         self.registry = MetricRegistry()
+        # the device every record of this run was measured on (v16
+        # fields): {"platform", "kind", "count"} as jax reports them
+        self.device = dict(device or {})
         # the kernel-tuning mode this run's step was built under (v3
         # schema field); resolved tiles arrive via the registry
         # (tune.lookup.attach_registry) as kernel.tune.* extras
@@ -283,6 +287,9 @@ class Observer:
                 if memory_allocated_bytes is None
                 else int(memory_allocated_bytes)
             ),
+            "device_platform": self.device.get("platform"),
+            "device_kind": self.device.get("kind"),
+            "device_count": self.device.get("count"),
             "extra": extras,
         }
         # non-finite scalars become null: a NaN loss (fully-poisoned
@@ -382,7 +389,10 @@ def build_observer(
     restarts = int(ledger.get("restarts", 0) or 0)
     restart_downtime_s = float(ledger.get("restart_downtime_s", 0.0) or 0.0)
 
+    from fms_fsdp_tpu.utils.flops import device_info
+
     obs = Observer(
+        device=device_info(),
         sinks=sinks,
         heartbeat=heartbeat,
         flops_per_token=flops,

@@ -17,7 +17,7 @@ import json
 import numbers
 from typing import Any, Dict, List
 
-SCHEMA_VERSION = 15
+SCHEMA_VERSION = 16
 
 # name -> (type, required)
 SCHEMA_FIELDS = {
@@ -129,8 +129,8 @@ SCHEMA_FIELDS = {
     # (draft tokens per verify step; 0 = non-speculative),
     # ``prefill_chunks`` (cumulative chunked-prefill slices advanced;
     # 0 = whole-prompt prefill) and ``paged_kernel_impl`` (0 =
-    # reference gather, 1 = single-page paged-attention kernel v1
-    # path, 2 = kernel v2 engaged — multi-page DMA and/or native
+    # reference gather, 1 = paged-attention kernel with one full-width
+    # page per cell, 2 = kernel with multi-page cells and/or native
     # quantized page reads).
     "serving": ("map", False),
     # v11: serving-fleet accounting (docs/serving.md "Fleet
@@ -180,6 +180,15 @@ SCHEMA_FIELDS = {
     "quantized_reduce": ("str", False),
     "memory_reserved_bytes": ("int", False),
     "memory_allocated_bytes": ("int", False),
+    # v16: the device the record was measured on, as jax reports it
+    # (``jax.devices()[0].platform`` / ``.device_kind`` /
+    # ``len(jax.devices())``) — a throughput or utilization figure is
+    # only readable beside the hardware that produced it, and a CPU
+    # record must never pass for a chip's. Filled by build_observer;
+    # null on a hand-built Observer given no device.
+    "device_platform": ("str", False),
+    "device_kind": ("str", False),
+    "device_count": ("int", False),
     "extra": ("map", False),
 }
 
@@ -245,6 +254,9 @@ SCHEMA_DIGESTS = {
     # drain_migrations (streaming state-transfer transport +
     # drain-and-migrate preemption); the field set itself is unchanged
     15: "72f5816eded0eb4caa3a834f60eb0dc10db1a31772699bf81af6c0c40665b38a",
+    # v16: + device_platform / device_kind / device_count (the device
+    # the record was measured on, as jax reports it)
+    16: "d10795827bb448b3bf4e2d4cbd05193ce67ef19ffbc7916d66f2f50d58ba8c77",
 }
 
 

@@ -15,11 +15,12 @@ All functions take q:(B, S, Nq, H), k/v:(B, S, Nkv, H) with Nq % Nkv == 0
 (GQA: 64/8 heads at 70B per ref:config_utils.py:26-34).
 """
 
-import functools
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
+
+from fms_fsdp_tpu.ops import flash_attention as _fa
 
 
 def xla_attention(q, k, v, *, causal: bool = True, scale: Optional[float] = None):
@@ -48,15 +49,6 @@ def xla_attention(q, k, v, *, causal: bool = True, scale: Optional[float] = None
     return out.reshape(b, sq, nq, h)
 
 
-try:  # Pallas/Mosaic may be absent on non-TPU jaxlib builds
-    from fms_fsdp_tpu.ops import flash_attention as _fa
-
-    HAS_PALLAS_FLASH = True
-except ImportError:
-    _fa = None
-    HAS_PALLAS_FLASH = False
-
-
 def configure_flash_variant(variant) -> None:
     """Apply TrainConfig.flash_kernel_variant before the step is traced
     (a trace-time env read was the old mechanism — cached jits would keep
@@ -67,8 +59,7 @@ def configure_flash_variant(variant) -> None:
     from its own config: None restores the import-time default
     (FLASH_KERNEL_VARIANT env, else auto) rather than inheriting a
     forcing left by an earlier build in the same process."""
-    if HAS_PALLAS_FLASH:
-        _fa.set_kernel_variant(variant)
+    _fa.set_kernel_variant(variant)
 
 
 def _flash_sharded(q, k, v, causal, mesh):
@@ -82,7 +73,6 @@ def _flash_sharded(q, k, v, causal, mesh):
     compile with "Mosaic kernels cannot be automatically partitioned"
     (caught by scripts/aot_lower_kernels.py against a v5e topology — the
     CPU multichip dryruns resolve impl='auto' to XLA and never see it)."""
-    from fms_fsdp_tpu.parallel.compat import shard_map
     from jax.sharding import PartitionSpec as P
 
     from fms_fsdp_tpu.ops.pallas_mode import interpret_default
@@ -104,7 +94,7 @@ def _flash_sharded(q, k, v, causal, mesh):
             ql, kl, vl, causal=causal, interpret=interpret
         )
 
-    return shard_map(
+    return jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(spec_q, spec_kv, spec_kv),
@@ -129,16 +119,15 @@ def attention(q, k, v, *, causal: bool = True, impl: str = "auto", mesh=None):
     passed whenever the computation is jitted over a >1-device mesh — the
     kernel then runs per-device under shard_map (see _flash_sharded)."""
     if impl == "pallas":
-        if not HAS_PALLAS_FLASH or not _fa.supports(q.shape, k.shape):
+        if not _fa.supports(q.shape, k.shape):
             raise NotImplementedError(
-                f"attention_kernel='pallas' requires Pallas support, a "
+                f"attention_kernel='pallas' requires a "
                 f"128-multiple head_dim and 256-aligned sequence lengths; "
                 f"got q{q.shape} k{k.shape}"
             )
         return _flash(q, k, v, causal, mesh)
     if (
         impl == "auto"
-        and HAS_PALLAS_FLASH
         and jax.default_backend() == "tpu"
         and _fa.supports(q.shape, k.shape)
     ):

@@ -32,7 +32,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from fms_fsdp_tpu.obs.scopes import scoped
-from fms_fsdp_tpu.parallel.compat import tpu_compiler_params
 
 NEG_INF = -1e30
 LOG2E = 1.4426950408889634  # log2(e)
@@ -168,7 +167,7 @@ def _flash_fwd(q, k, v, scale, causal, block_q, block_k, interpret,
         ],
         # every grid cell is independent (no scratch carried between
         # steps): telling Mosaic lets it pipeline/partition freely
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel")
         ),
         interpret=interpret,
@@ -302,7 +301,7 @@ def _flash_fwd_kvgrid(q, k, v, scale, causal, block_q, block_k, interpret):
             pltpu.VMEM((block_q, 1), jnp.float32),  # running denominator
         ],
         # state carries across the ki sweep; outer three dims independent
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,
@@ -469,7 +468,7 @@ def _flash_dq_kvgrid(
         out_specs=pl.BlockSpec((1, 1, block_q, head), qmap),
         out_shape=jax.ShapeDtypeStruct(q.shape, out_dtype or q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, head), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,
@@ -628,7 +627,7 @@ def flash_dq(
         ],
         out_specs=pl.BlockSpec((1, 1, block_q, head), lambda b, h, i: (b, h, i, 0)),
         out_shape=jax.ShapeDtypeStruct(q.shape, out_dtype or q.dtype),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel")
         ),
         interpret=interpret,
@@ -707,7 +706,7 @@ def flash_dkv(q, k, v, dout, lse, delta, *, scale, causal, block_q, block_k, int
         ],
         # dk/dv accumulate in scratch across the (g, qi) sweep — those two
         # dims must run in order; the outer three are independent
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=(
                 "parallel",
                 "parallel",

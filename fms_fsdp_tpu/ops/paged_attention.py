@@ -20,26 +20,27 @@ Two implementations, one contract:
   (the serve allocator points unwritten table slots at a pristine zero
   page), the reference path is **bit-identical** to dense decode — the
   correctness anchor tier-1 pins on CPU.
-- ``_paged_decode_kernel``: the Pallas kernel — grid (batch, kv-head,
-  page); the page table rides as scalar prefetch so each cell's k/v
-  block is DMA'd straight from its pool page (no contiguous copy ever
-  materializes), with the FlashAttention-2 online softmax accumulated in
-  VMEM scratch across the page walk. Pages past a row's length run no
-  compute (pl.when) and fetch no data (the index map clamps onto the
-  last live page — a repeat fetch Mosaic elides), which is what makes
-  the ragged batch one kernel call instead of B.
+- ``_paged_decode_kernel``: the Pallas kernel — grid (batch, kv block);
+  the page table rides as scalar prefetch so each of a cell's pages is
+  DMA'd straight from its pool page by a BlockSpec index map (no
+  contiguous copy ever materializes), with the FlashAttention-2 online
+  softmax accumulated in VMEM scratch across the block walk. A cell
+  holds every kv head of its pages — a block may not take one head out
+  of the (Nkv, H) minor tile on a TPU — and attends all query heads
+  against them in one pass, masking other heads' columns. Blocks past a
+  row's length run no compute (pl.when) and fetch no data (the index
+  map clamps onto the last live page — a repeat fetch the pipeline
+  elides), which is what makes the ragged batch one kernel call instead
+  of B.
 
 Tile resolution (page_size at allocator build, block_kv per call) goes
 through the tuning table (fms_fsdp_tpu/tune/lookup.py::
-resolve_paged_decode) like every other kernel. v2 lifts the two v1
-constraints: ``block_kv`` may be any multiple of ``page_size`` (the
-kernel walks ``block_kv // page_size`` pool pages per grid step,
-fetched by manual DMA into a VMEM block since pages are not contiguous
-in the pool), and int8/fp8-quantized pools are read natively — the
-per-page scale blocks ride the same DMA and the dequantize
-(``kv_dequantize``: ``(q * scale) -> compute dtype``) happens in VMEM
-right before the dot, so quantized serving no longer falls back to the
-reference gather.
+resolve_paged_decode) like every other kernel. ``block_kv`` may be any
+multiple of ``page_size`` (the cell fetches ``block_kv // page_size``
+pool pages), and int8/fp8-quantized pools are read natively — the
+per-page scale blocks are fetched beside the pages and applied to the
+scores and probabilities in VMEM, so quantized serving does not fall
+back to the reference gather.
 """
 
 import functools
@@ -50,7 +51,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from fms_fsdp_tpu.ops.pallas_mode import interpret_default
-from fms_fsdp_tpu.parallel.compat import tpu_compiler_params
 
 NEG_INF = -1e30
 LOG2E = 1.4426950408889634  # log2(e)
@@ -129,21 +129,40 @@ def paged_attention_reference(q, k_pages, v_pages, page_table, seq_lens):
 def _paged_decode_kernel(
     lens_ref,  # scalar prefetch: (B,) int32 query positions
     table_ref,  # scalar prefetch: (B, maxp) int32 page table
-    q_ref,  # (1, 1, group, H)
-    k_ref,  # (1, page_size, 1, H) — one pool page for this kv head
-    v_ref,
-    o_ref,  # (1, 1, group, H)
-    acc_ref,  # VMEM (group, H) fp32
-    m_ref,  # VMEM (group, 1) fp32 running max (base 2)
-    l_ref,  # VMEM (group, 1) fp32 running denominator
-    *,
+    q_ref,  # (1, Nq, H)
+    *rest,  # ppb k pages, ppb v pages (, ppb k scales, ppb v scales); o; scratch
     page_size,
+    pages_per_block,
+    nkv,
     scale,
+    quantized,
 ):
+    """One (batch row, kv block) grid cell: ``pages_per_block`` pool pages,
+    every kv head at once.
+
+    A page arrives as its (page_size * Nkv, H) row-major view — row
+    ``t * Nkv + h`` is token t of kv head h — because a cell may not take
+    one head out of the (Nkv, H) minor tile (Mosaic refuses the slice).
+    All Nq query heads multiply against all of those rows in one MXU pass
+    and the columns belonging to another kv head are masked with the
+    out-of-range positions, so each query row's softmax runs over exactly
+    its own head's tokens. No per-head loop, no strided load.
+    """
+    ppb = pages_per_block
+    k_refs, v_refs = rest[:ppb], rest[ppb : 2 * ppb]
+    rest = rest[2 * ppb :]
+    if quantized:
+        ks_refs, vs_refs = rest[:ppb], rest[ppb : 2 * ppb]
+        rest = rest[2 * ppb :]
+    o_ref, acc_ref, m_ref, l_ref = rest
+
     b = pl.program_id(0)
-    j = pl.program_id(2)
-    num_pages = pl.num_programs(2)
+    j = pl.program_id(1)
     pos = lens_ref[b]  # query position; attends to cache idx <= pos
+    block = ppb * page_size
+    nq = q_ref.shape[1]
+    group = nq // nkv
+    cols = page_size * nkv
 
     @pl.when(j == 0)
     def _():
@@ -151,234 +170,81 @@ def _paged_decode_kernel(
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    # pages holding no position <= pos run no compute (and fetched no
-    # data: the index map clamped them onto the last live page)
-    run = j * page_size <= pos
-
-    @pl.when(run)
+    # blocks holding no position <= pos run no compute (and fetched no
+    # data: the index maps clamped them onto the last live page)
+    @pl.when(j * block <= pos)
     def _():
         # scale + change of base folded into q; exp2 replaces exp in the
         # online softmax (same trick as ops/flash_attention.py)
-        q = (q_ref[0, 0] * (scale * LOG2E)).astype(q_ref.dtype)  # (G, H)
-        k = k_ref[0, :, 0, :]  # (ps, H)
-        v = v_ref[0, :, 0, :]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )  # (G, ps), base-2 domain
-        kpos = j * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, s.shape, 1
-        )
-        s = jnp.where(kpos <= pos, s, NEG_INF)
-        m = m_ref[...]
-        m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
-        p = jnp.exp2(s - m_new)
-        alpha = jnp.exp2(m - m_new)
-        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
-        pv = jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        acc_ref[...] = acc_ref[...] * alpha + pv
-        m_ref[...] = m_new
+        q = (q_ref[0] * (scale * LOG2E)).astype(q_ref.dtype)  # (Nq, H)
+        col = jax.lax.broadcasted_iota(jnp.int32, (nq, cols), 1)
+        row = jax.lax.broadcasted_iota(jnp.int32, (nq, cols), 0)
+        tok = col // nkv
+        first_q = (col % nkv) * group  # first query head of the column's kv head
+        own_head = (row >= first_q) & (row < first_q + group)
+        m, l, acc = m_ref[...], l_ref[...], acc_ref[...]
+        for i in range(ppb):
+            k = k_refs[i][0]  # (ps*Nkv, H), storage dtype
+            v = v_refs[i][0]
+            if quantized:
+                # int8/fp8 values are exact in the compute dtype; the
+                # per-row absmax scales fold into the score columns and
+                # the probabilities instead of a (ps*Nkv, H) dequantize
+                k = k.astype(q.dtype)
+                v = v.astype(q.dtype)
+            s = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )  # (Nq, ps*Nkv), base-2 domain
+            if quantized:
+                s = s * ks_refs[i][0]
+            kpos = (j * ppb + i) * page_size + tok
+            s = jnp.where(own_head & (kpos <= pos), s, NEG_INF)
+            m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+            p = jnp.exp2(s - m_new)
+            alpha = jnp.exp2(m - m_new)
+            l = l * alpha + jnp.sum(p, axis=1, keepdims=True)
+            if quantized:
+                p = p * vs_refs[i][0]
+            pv = jax.lax.dot_general(
+                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+            acc = acc * alpha + pv
+            m = m_new
+        m_ref[...], l_ref[...], acc_ref[...] = m, l, acc
 
-    @pl.when(j == num_pages - 1)
+    @pl.when(j == pl.num_programs(1) - 1)
     def _():
         l = l_ref[...]
-        # a row that attended nothing (an idle batch slot) has l == 0;
+        # a row that attended nothing (a negative position) has l == 0;
         # emit zeros, not 0/0 NaN — its output is discarded either way
         # but NaN would trip downstream finiteness guards
         safe = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0, 0] = (acc_ref[...] / safe).astype(o_ref.dtype)
-
-
-def _paged_decode_kernel_v2(
-    lens_ref,  # scalar prefetch: (B,) int32 query positions
-    table_ref,  # scalar prefetch: (B, maxp) int32 page table
-    q_ref,  # (1, 1, group, H)
-    *rest,  # [k, v(, k_scale, v_scale)] HBM refs; o_ref; scratch
-    page_size,
-    pages_per_block,
-    maxp,
-    scale,
-    quantized,
-    compute_dtype,
-):
-    """v2 body: ``pages_per_block`` pool pages per grid cell, fetched by
-    manual DMA (pages are scattered through the pool, so no BlockSpec
-    index map can describe the block); optional per-page scale blocks
-    ride the same DMA and dequantize in VMEM. Online-softmax math is
-    identical to the v1 body above, over a ``block_kv``-wide tile."""
-    if quantized:
-        (k_hbm, v_hbm, ks_hbm, vs_hbm, o_ref,
-         k_buf, v_buf, ks_buf, vs_buf, sem, acc_ref, m_ref, l_ref) = rest
-    else:
-        (k_hbm, v_hbm, o_ref, k_buf, v_buf, sem,
-         acc_ref, m_ref, l_ref) = rest
-        ks_hbm = vs_hbm = ks_buf = vs_buf = None
-
-    b = pl.program_id(0)
-    h = pl.program_id(1)
-    j = pl.program_id(2)
-    nblocks = pl.num_programs(2)
-    pos = lens_ref[b]
-    block = pages_per_block * page_size
-
-    @pl.when(j == 0)
-    def _():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-
-    run = j * block <= pos
-
-    @pl.when(run)
-    def _():
-        # fetch the block's pages; a ragged tail block re-fetches the
-        # last table slot for its out-of-range pages — those positions
-        # sit past max_seq and the kpos mask below zeroes them
-        copies = []
-        for i in range(pages_per_block):
-            slot = jnp.minimum(j * pages_per_block + i, maxp - 1)
-            pid = table_ref[b, slot]
-            dst = pl.ds(i * page_size, page_size)
-            pairs = [(k_hbm, k_buf, 0), (v_hbm, v_buf, 1)]
-            if quantized:
-                pairs += [(ks_hbm, ks_buf, 2), (vs_hbm, vs_buf, 3)]
-            for src, buf, s_i in pairs:
-                cp = pltpu.make_async_copy(
-                    src.at[pid, :, h], buf.at[dst], sem.at[s_i, i]
-                )
-                cp.start()
-                copies.append(cp)
-        for cp in copies:
-            cp.wait()
-
-        q = (q_ref[0, 0] * (scale * LOG2E)).astype(q_ref.dtype)  # (G, H)
-        k = k_buf[...]  # (block, H), storage dtype
-        v = v_buf[...]
-        if quantized:
-            # kv_dequantize in VMEM: absmax scale per stored row
-            k = (k.astype(jnp.float32) * ks_buf[...]).astype(compute_dtype)
-            v = (v.astype(jnp.float32) * vs_buf[...]).astype(compute_dtype)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )  # (G, block), base-2 domain
-        kpos = j * block + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(kpos <= pos, s, NEG_INF)
-        m = m_ref[...]
-        m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
-        p = jnp.exp2(s - m_new)
-        alpha = jnp.exp2(m - m_new)
-        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
-        pv = jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        acc_ref[...] = acc_ref[...] * alpha + pv
-        m_ref[...] = m_new
-
-    @pl.when(j == nblocks - 1)
-    def _():
-        l = l_ref[...]
-        safe = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0, 0] = (acc_ref[...] / safe).astype(o_ref.dtype)
-
-
-def _paged_kernel_v2_call(
-    q, k_pages, v_pages, page_table, seq_lens, k_scales, v_scales,
-    block_kv, compute_dtype, interpret
-):
-    b, nq, hd = q.shape
-    _, page_size, nkv, _ = k_pages.shape
-    maxp = page_table.shape[1]
-    group = nq // nkv
-    ppb = block_kv // page_size
-    nblocks = -(-maxp // ppb)
-    quantized = k_scales is not None
-    qg = q.reshape(b, nkv, group, hd)
-
-    any_spec = pl.BlockSpec(memory_space=pltpu.ANY)
-    in_specs = [
-        pl.BlockSpec((1, 1, group, hd), lambda b_, h_, j_, *_: (b_, h_, 0, 0)),
-        any_spec,
-        any_spec,
-    ]
-    operands = [qg, k_pages, v_pages]
-    n_streams = 2
-    scratch = [
-        pltpu.VMEM((ppb * page_size, hd), k_pages.dtype),
-        pltpu.VMEM((ppb * page_size, hd), v_pages.dtype),
-    ]
-    if quantized:
-        in_specs += [any_spec, any_spec]
-        operands += [k_scales, v_scales]
-        n_streams = 4
-        scratch += [
-            pltpu.VMEM((ppb * page_size, 1), k_scales.dtype),
-            pltpu.VMEM((ppb * page_size, 1), v_scales.dtype),
-        ]
-    scratch += [
-        pltpu.SemaphoreType.DMA((n_streams, ppb)),
-        pltpu.VMEM((group, hd), jnp.float32),
-        pltpu.VMEM((group, 1), jnp.float32),
-        pltpu.VMEM((group, 1), jnp.float32),
-    ]
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(b, nkv, nblocks),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec(
-            (1, 1, group, hd), lambda b_, h_, j_, *_: (b_, h_, 0, 0)
-        ),
-        scratch_shapes=scratch,
-    )
-    out = pl.pallas_call(
-        functools.partial(
-            _paged_decode_kernel_v2,
-            page_size=page_size,
-            pages_per_block=ppb,
-            maxp=maxp,
-            scale=hd**-0.5,
-            quantized=quantized,
-            compute_dtype=compute_dtype,
-        ),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, nkv, group, hd), q.dtype),
-        compiler_params=tpu_compiler_params(
-            dimension_semantics=("parallel", "parallel", "arbitrary")
-        ),
-        interpret=interpret,
-    )(seq_lens.astype(jnp.int32), page_table.astype(jnp.int32), *operands)
-    return out.reshape(b, nq * hd)
+        o_ref[0] = (acc_ref[...] / safe).astype(o_ref.dtype)
 
 
 def paged_attention_kernel(
     q, k_pages, v_pages, page_table, seq_lens, *,
-    k_scales=None, v_scales=None, block_kv=None, compute_dtype=None,
-    interpret=None,
+    k_scales=None, v_scales=None, block_kv=None, interpret=None,
 ):
     """Pallas ragged paged-attention decode; contract of
     :func:`paged_attention_reference` (same shapes, same masking rule).
 
-    Grid (B, Nkv, ceil(maxp / pages_per_block)): the page table and row
-    positions ride as scalar prefetch. With ``block_kv == page_size``
-    and full-width pools, each cell's (1, ps, 1, H) k/v block is fetched
-    straight from pool page ``page_table[b, j]`` via the BlockSpec index
-    map (the v1 single-page path, unchanged). With ``block_kv`` a larger
-    multiple of ``page_size``, or quantized pools carrying
-    ``k_scales``/``v_scales`` (per-row absmax, see ops/quant.py), the v2
-    body fetches the block's pages by manual DMA and dequantizes in
-    VMEM. Online-softmax state lives in VMEM scratch across the block
-    walk (the ``arbitrary`` grid dim).
+    Grid (B, ceil(maxp / pages_per_block)); the page table and row
+    positions ride as scalar prefetch, and each of a cell's
+    ``block_kv // page_size`` pages is its own BlockSpec operand whose
+    index map reads the pool page out of the table — so every fetch goes
+    through the Pallas pipeline (double-buffered, repeat fetches of a
+    clamped dead page elided) and no contiguous copy of a sequence ever
+    materializes. Quantized pools carry ``k_scales``/``v_scales``
+    (per-row absmax, see ops/quant.py), fetched the same way.
+    Online-softmax state lives in VMEM scratch across the block walk
+    (the ``arbitrary`` grid dim).
     """
     b, nq, hd = q.shape
     num_pool_pages, page_size, nkv, _ = k_pages.shape
     maxp = page_table.shape[1]
-    group = nq // nkv
-    scale = hd**-0.5
     if interpret is None:
         interpret = interpret_default()
     if block_kv is None:
@@ -390,85 +256,64 @@ def paged_attention_kernel(
         )
     if (k_scales is None) != (v_scales is None):
         raise ValueError("k_scales and v_scales must be passed together")
-    if k_scales is not None or block_kv != page_size:
-        return _paged_kernel_v2_call(
-            q, k_pages, v_pages, page_table, seq_lens, k_scales, v_scales,
-            block_kv, compute_dtype or q.dtype, interpret
-        )
+    quantized = k_scales is not None
+    ppb = block_kv // page_size
+    cols = page_size * nkv
 
-    qg = q.reshape(b, nkv, group, hd)
+    def page_map(i):
+        def index_map(b_, j_, lens, table):
+            # clamp dead slots onto the row's last live page (repeat fetch)
+            last = jnp.maximum(lens[b_], 0) // page_size
+            return (table[b_, jnp.minimum(j_ * ppb + i, last)], 0, 0)
 
-    def kv_map(b_, h_, j_, lens, table):
-        # clamp dead cells onto the row's last live page (repeat fetch)
-        last = jnp.maximum(lens[b_], 0) // page_size
-        return (table[b_, jnp.minimum(j_, last)], 0, h_, 0)
+        return index_map
+
+    def row_map(b_, j_, *_):
+        return (b_, 0, 0)
+
+    def page_specs(rows, width):
+        return [
+            pl.BlockSpec((1, rows, width), page_map(i)) for i in range(ppb)
+        ]
+
+    # (P, ps, Nkv, H) -> (P, ps*Nkv, H): the trailing block dims equal the
+    # array's, which is what the TPU lowering requires of a block that
+    # is not (8, 128)-aligned
+    operands = [k_pages.reshape(num_pool_pages, cols, hd)] * ppb
+    operands += [v_pages.reshape(num_pool_pages, cols, hd)] * ppb
+    in_specs = [pl.BlockSpec((1, nq, hd), row_map)]
+    in_specs += page_specs(cols, hd) * 2
+    if quantized:
+        operands += [k_scales.reshape(num_pool_pages, 1, cols)] * ppb
+        operands += [v_scales.reshape(num_pool_pages, 1, cols)] * ppb
+        in_specs += page_specs(1, cols) * 2
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(b, nkv, maxp),
-        in_specs=[
-            pl.BlockSpec((1, 1, group, hd), lambda b_, h_, j_, *_: (b_, h_, 0, 0)),
-            pl.BlockSpec((1, page_size, 1, hd), kv_map),
-            pl.BlockSpec((1, page_size, 1, hd), kv_map),
-        ],
-        out_specs=pl.BlockSpec(
-            (1, 1, group, hd), lambda b_, h_, j_, *_: (b_, h_, 0, 0)
-        ),
+        grid=(b, -(-maxp // ppb)),
+        in_specs=in_specs,
+        out_specs=pl.BlockSpec((1, nq, hd), row_map),
         scratch_shapes=[
-            pltpu.VMEM((group, hd), jnp.float32),
-            pltpu.VMEM((group, 1), jnp.float32),
-            pltpu.VMEM((group, 1), jnp.float32),
+            pltpu.VMEM((nq, hd), jnp.float32),
+            pltpu.VMEM((nq, 1), jnp.float32),
+            pltpu.VMEM((nq, 1), jnp.float32),
         ],
     )
     out = pl.pallas_call(
         functools.partial(
-            _paged_decode_kernel, page_size=page_size, scale=scale
+            _paged_decode_kernel,
+            page_size=page_size,
+            pages_per_block=ppb,
+            nkv=nkv,
+            scale=hd**-0.5,
+            quantized=quantized,
         ),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, nkv, group, hd), q.dtype),
-        # scratch carries across the page walk; batch/head dims independent
-        compiler_params=tpu_compiler_params(
-            dimension_semantics=("parallel", "parallel", "arbitrary")
+        out_shape=jax.ShapeDtypeStruct((b, nq, hd), q.dtype),
+        # scratch carries across the block walk; batch rows independent
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")
         ),
         interpret=interpret,
-    )(seq_lens.astype(jnp.int32), page_table.astype(jnp.int32), qg,
-      k_pages, v_pages)
+    )(seq_lens.astype(jnp.int32), page_table.astype(jnp.int32), q, *operands)
     return out.reshape(b, nq * hd)
-
-
-# ---------------------------------------------------------------------------
-# dispatch
-# ---------------------------------------------------------------------------
-
-
-def paged_attention(
-    q, k_pages, v_pages, page_table, seq_lens, *, impl="auto",
-    k_scales=None, v_scales=None, block_kv=None, compute_dtype=None,
-    interpret=None,
-):
-    """Ragged paged-attention decode: q (B, Nq, H) against paged k/v
-    pools -> (B, Nq*H). ``impl``:
-
-    - "reference": gather + dense attend — bit-identical to the dense
-      decode path (the tier-1 parity anchor). Quantized pools must be
-      dequantized by the caller (serve/decode.py does) — the scale
-      arguments are a kernel-path contract;
-    - "kernel": the Pallas kernel (interpret mode on CPU) — v2 reads
-      quantized pools natively when scales are passed, and walks
-      ``block_kv // page_size`` pages per grid cell;
-    - "auto": kernel on TPU backends, reference elsewhere — CPU serving
-      and tests keep dense bit-parity by default.
-    """
-    if impl == "auto":
-        impl = "reference" if jax.default_backend() != "tpu" else "kernel"
-    if impl == "reference":
-        return paged_attention_reference(
-            q, k_pages, v_pages, page_table, seq_lens
-        )
-    if impl == "kernel":
-        return paged_attention_kernel(
-            q, k_pages, v_pages, page_table, seq_lens,
-            k_scales=k_scales, v_scales=v_scales, block_kv=block_kv,
-            compute_dtype=compute_dtype, interpret=interpret,
-        )
-    raise ValueError(f"unknown paged attention impl: {impl!r}")

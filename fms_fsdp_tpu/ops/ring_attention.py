@@ -34,7 +34,6 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax import lax
-from fms_fsdp_tpu.parallel.compat import shard_map  # >=0.8 surface on any jax
 from jax.sharding import PartitionSpec as P
 
 from fms_fsdp_tpu.ops.flash_attention import (
@@ -209,7 +208,7 @@ def ring_attention(q, k, v, mesh, *, causal: bool = True, scale=None):
     lse_spec = P(spec_q[0], AXIS_CONTEXT, spec_q[2], None)
 
     @functools.partial(
-        shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(spec_q, spec_kv, spec_kv),
         out_specs=(spec_q, lse_spec),
@@ -265,7 +264,7 @@ def ring_attention(q, k, v, mesh, *, causal: bool = True, scale=None):
         return acc.astype(q.dtype), lse
 
     @functools.partial(
-        shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(spec_q, spec_kv, spec_kv, spec_q, lse_spec, spec_q),
         out_specs=(spec_q, spec_kv, spec_kv),

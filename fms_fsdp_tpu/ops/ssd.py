@@ -31,7 +31,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from fms_fsdp_tpu.obs.scopes import scoped
-from fms_fsdp_tpu.parallel.compat import tpu_compiler_params
 
 from fms_fsdp_tpu.ops.flash_attention import NEG_INF
 
@@ -60,7 +59,7 @@ def _fused_kernel(
     across the chunk sweep — the round-2 design ran one pallas_call per
     chunk under ``lax.scan`` and paid a head-major relayout of every
     operand per chunk plus the scan/dispatch overhead; measured 2x
-    slower than the XLA einsums (BENCH_SSD.json r2). Fusing the scan
+    slower than the XLA einsums (round 2). Fusing the scan
     into the grid removes both, and the (L, L) decay/score product still
     never leaves VMEM.
 
@@ -201,7 +200,7 @@ def _ssd_core_pallas_fwd(x, dtf, a, Bm, Cm, L, interpret):
             pltpu.VMEM((L, L), jnp.float32),  # shared C@B^T per (b,g,chunk)
             pltpu.VMEM((R, N, P), jnp.float32),  # per-head carried state
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             # state/cb scratch carry across (chunk, head) — sequential;
             # batch/group cells are independent
             dimension_semantics=("parallel", "parallel", "arbitrary", "arbitrary")
@@ -338,13 +337,12 @@ def ssd_scan(
 
     # "auto" resolves to the XLA formulation until the fused kernel is
     # re-measured on chip (the r2 per-chunk kernel measured 2x slower
-    # than the einsums — BENCH_SSD.json; the fused whole-sequence kernel
-    # above removes the per-chunk relayouts + scan overhead it paid).
-    # The fused kernel's v5e lowering is machine-validated every change
-    # (scripts/aot_lower_kernels.py -> AOT_LOWER.json, fwd+bwd), so the
-    # r2 "never lowered" failure class cannot recur silently; the
-    # on-chip perf race that would flip this default is
-    # chip_evidence.sh step 3.
+    # than the einsums; the fused whole-sequence kernel above removes
+    # the per-chunk relayouts + scan overhead it paid). The fused
+    # kernel's v5e lowering is compiled on every test run
+    # (tests/test_aot_compile.py, fwd+bwd), so the r2 "never lowered"
+    # failure class cannot recur silently; the on-chip race that would
+    # flip this default has not been run (ROADMAP D3(a)).
     mode = "xla" if kernel == "auto" else kernel
 
     if mode == "pallas":
@@ -352,7 +350,6 @@ def ssd_scan(
 
         interpret = interpret_default()
         if mesh is not None and mesh.size > 1:
-            from fms_fsdp_tpu.parallel.compat import shard_map
             from jax.sharding import PartitionSpec as P_
 
             from fms_fsdp_tpu.parallel.mesh import AXIS_TENSOR, DATA_AXES
@@ -380,7 +377,7 @@ def ssd_scan(
             def body(xl, dtl, al, Bl, Cl):
                 return _ssd_core_pallas(xl, dtl, al, Bl, Cl, L, interpret)
 
-            y = shard_map(
+            y = jax.shard_map(
                 body,
                 mesh=mesh,
                 in_specs=(s_x, s_dt, s_dt, s_bc, s_bc),
@@ -455,7 +452,6 @@ def ssd_scan_cp(
     ``ssd_scan`` but "pallas" does not apply here (and "auto" resolves
     to XLA on the single-device path too, by chip measurement).
     """
-    from fms_fsdp_tpu.parallel.compat import shard_map  # >=0.8 surface on any jax
     from fms_fsdp_tpu.parallel.mesh import AXIS_CONTEXT, DATA_AXES
     from fms_fsdp_tpu.parallel.sharding import resolve_spec
     from jax.sharding import PartitionSpec as P
@@ -496,7 +492,7 @@ def ssd_scan_cp(
     )
 
     @functools.partial(
-        shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(spec_x, spec_dt, P(None), spec_bc, spec_bc),
         out_specs=spec_x,
@@ -572,7 +568,7 @@ def causal_conv1d(x, weight, bias=None, activation: str = "silu"):
     Expressed as W shifted fused multiply-adds instead of a grouped
     ``lax.conv``: XLA lowers a feature_group_count==C conv terribly on TPU
     (~29ms fwd+bwd per mamba layer at 9.8b shapes vs a few ms for the
-    shifts — BENCH_SSD.json for measured numbers). The pad stays in the
+    shifts, round-2 chip runs). The pad stays in the
     input dtype — materializing it in fp32 doubles the HBM traffic and
     measured ~2x slower; the per-slice upcast fuses into the multiply-add
     loop."""

@@ -53,9 +53,9 @@ def paged_decode_step(
     PagedKVCache.pools dict (leading L dim per leaf). Returns
     (logits (B, V), embeds (B, D), pools) — the paged analog of
     ``decode_step``'s (logits, embeds, cache). Under the kernel impl,
-    quantized pools are read natively (the v2 kernel dequantizes from
-    the scale pools in VMEM) and ``block_kv`` sets the pages-per-cell
-    fetch width.
+    quantized pools are read natively (the kernel applies the scale
+    pools in VMEM) and ``block_kv`` sets the pages-per-cell fetch
+    width.
     """
     params = jax.tree.map(lambda a: a.astype(compute_dtype), params)
     b = tokens.shape[0]
@@ -82,7 +82,6 @@ def paged_decode_step(
                 k_scales=layer_pools.get("k_scale"),
                 v_scales=layer_pools.get("v_scale"),
                 block_kv=block_kv,
-                compute_dtype=compute_dtype,
                 interpret=interpret,
             )[:, None]
         if quantized:

@@ -859,10 +859,10 @@ class ServingEngine:
             ),
             "handoff_bytes": float(self._handoff_bytes),
             "handoff_s": float(self._handoff_wall),
-            # v14: speculative serving + chunked prefill + the paged
-            # attention kernel generation actually engaged (0 =
-            # reference gather, 1 = single-page kernel v1 path, 2 =
-            # kernel v2 — multi-page DMA and/or native quantized reads)
+            # v14: speculative serving + chunked prefill + how paged
+            # attention decoded (0 = reference gather, 1 = kernel with
+            # one full-width page per cell, 2 = kernel with multi-page
+            # cells and/or native quantized reads)
             "spec_accept_rate": (
                 self._spec_accept_total / self._spec_draft_total
                 if self._spec_draft_total

@@ -82,8 +82,8 @@ class LlamaAdapter(FamilyAdapter):
         impl = scfg.attn_impl
         if impl == "auto":
             impl = "reference" if jax.default_backend() != "tpu" else "kernel"
-        # v2 kernel reads quantized pools natively (in-VMEM dequantize
-        # from the scale pools) — no reference fallback on the TPU path
+        # the kernel reads quantized pools natively (scales applied in
+        # VMEM) — no reference fallback on the TPU path
         self.attn_impl = impl
 
         self._prefill_cache: Dict = {}

@@ -95,6 +95,10 @@ def _stdin_reader(q: Queue) -> None:
 def build_engine(args):
     """Heavy imports live here: the module stays importable (for the
     arg parser) without jax."""
+    from fms_fsdp_tpu.utils.compile_cache import configure_compile_cache
+
+    # a relaunched replica must not pay the full compile again
+    configure_compile_cache()
     import jax
 
     from fms_fsdp_tpu.serve.engine import ServeConfig, ServingEngine

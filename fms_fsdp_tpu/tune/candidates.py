@@ -25,10 +25,10 @@ document:
 - fused CE (ops/fused_ce.py): an XLA scan, not a Pallas kernel — the
   constraint is the fp32 (chunk, V) logits tile (one live in fwd, two in
   bwd: p and d_logits), budgeted against HBM headroom rather than VMEM.
-- paged decode (ops/paged_attention.py): per grid cell one (block_kv, H)
-  k and v page block (double-buffered), the (group, H) q/o blocks, and
-  the fp32 online-softmax scratch — O(block) residency like the kvgrid
-  family, plus the scalar-prefetched page table in SMEM.
+- paged decode (ops/paged_attention.py): per grid cell one
+  (block_kv * Nkv, H) k and v block (double-buffered), the (Nq, H) q/o
+  blocks, and the fp32 online-softmax scratch — O(block) residency like
+  the kvgrid family, plus the scalar-prefetched page table in SMEM.
 """
 
 from typing import Dict, List, Optional
@@ -368,29 +368,31 @@ def paged_decode_sig(batch: int, nq: int, nkv: int, head: int,
 
 def paged_decode_vmem_bytes(sig: Dict[str, int], dtype: str,
                             page_size: int, block_kv: int) -> int:
-    """Per-core residency of one (batch, kv-head) cell of the decode
-    kernel: k+v blocks of ``block_kv`` positions (double-buffered — the
-    next page's DMA runs behind the current page's compute), the
-    (group, H) q/o blocks, the fp32 online-softmax scratch, and the
-    row's page-table slice in SMEM (4 bytes per page, counted for
-    honesty though it never threatens the budget)."""
+    """Per-core residency of one (batch row, kv block) cell of the
+    decode kernel: k+v blocks of ``block_kv`` positions across every kv
+    head (double-buffered — the next block's DMA runs behind the current
+    block's compute), the (Nq, H) q/o blocks, the fp32 online-softmax
+    scratch, one page's fp32 (Nq, page_size * Nkv) score and
+    probability tiles, and the row's page-table slice in SMEM (4 bytes
+    per page, counted for honesty though it never threatens the
+    budget)."""
     db = dtype_bytes(dtype)
-    h = sig["head"]
-    group = max(1, sig["nq"] // max(1, sig["nkv"]))
-    kv = 2 * block_kv * h * db * _DB
-    q_o = 2 * group * h * db * _DB
-    scratch = group * h * 4 + 2 * group * 4  # fp32 acc + m/l
+    h, nq, nkv = sig["head"], sig["nq"], max(1, sig["nkv"])
+    kv = 2 * block_kv * nkv * h * db * _DB
+    q_o = 2 * nq * h * db * _DB
+    scratch = nq * h * 4 + 2 * nq * 4  # fp32 acc + m/l
+    scores = 2 * nq * page_size * nkv * 4
     table = 4 * (sig["max_seq"] // max(1, page_size))
-    return kv + q_o + scratch + table
+    return kv + q_o + scratch + scores + table
 
 
 def paged_decode_candidates(sig: Dict[str, int], dtype: str,
                             chip: str) -> List[Dict]:
-    """Legal (page_size, block_kv) tiles under the VMEM budget. The v2
-    kernel walks ``block_kv // page_size`` pool pages per grid step
-    (manual-DMA fetch, the RPA paper's layout), so enumeration covers
-    block_kv multiples of page_size — more positions per cell amortize
-    the per-step grid overhead at the price of a wider VMEM block."""
+    """Legal (page_size, block_kv) tiles under the VMEM budget. The
+    kernel fetches ``block_kv // page_size`` pool pages per grid step,
+    so enumeration covers block_kv multiples of page_size — more
+    positions per cell amortize the per-step grid overhead at the price
+    of a wider VMEM block."""
     budget = vmem_budget(chip)
     out = []
     for ps in _PAGE_SIZE_CHOICES:

@@ -152,14 +152,11 @@ def chip_kind() -> str:
         kind = jax.devices()[0].device_kind.lower()
     except Exception:  # chipless AOT hosts: no addressable devices
         return "unknown"
-    if "v5 lite" in kind or "v5e" in kind:
-        return "v5e"
-    if "v5p" in kind or "v5" in kind:
-        return "v5p"
-    if "v6 lite" in kind or "v6e" in kind:
-        return "v6e"
-    if "v4" in kind:
-        return "v4"
+    from fms_fsdp_tpu.utils.flops import chip_from_device_kind
+
+    chip = chip_from_device_kind(kind)
+    if chip is not None:
+        return chip
     return kind.replace(" ", "_")
 
 

@@ -15,10 +15,10 @@ Both run the full 7-layer stateful pipeline exactly as
 main_training_llama assembles it and report tokens/sec pulled on the
 host against per-chip device demand.
 
-Device demand reference points (BENCH_r02): llama3_194m_4k consumes
-~65k tok/s/chip, the 7B-shaped row ~30k tok/s/chip; an 8-chip host
-therefore needs ~0.5M tok/s at the 194m rate. Pass/fail bar per
-VERDICT item 8: host throughput >= 2x device demand per host.
+Device demand reference points (round-2 chip runs, records deleted in
+PR 21): llama3_194m_4k consumes ~65k tok/s/chip, the 7B-shaped row ~30k
+tok/s/chip; an 8-chip host therefore needs ~0.5M tok/s at the 194m
+rate. Pass/fail bar: host throughput >= 2x device demand per host.
 """
 
 import json

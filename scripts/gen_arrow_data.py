@@ -1,5 +1,5 @@
-"""Generate a learnable REAL arrow corpus on disk for the evidence
-eval leg (chip_evidence.sh step 4) — the same generator the e2e tests
+"""Generate a learnable REAL arrow corpus on disk for the train ->
+eval_ppl leg — the same generator the e2e tests
 use (fms_fsdp_tpu/data/synth.py), scaled up, so EVAL.json exercises
 arrow streaming -> training -> falling perplexity through the
 production entry points instead of the in-memory dummy stream.

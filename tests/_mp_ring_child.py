@@ -201,14 +201,10 @@ def main():
     np.testing.assert_allclose(
         np.asarray(shard.data), ref_moe[shard.index], atol=3e-5
     )
-    # the explicit a2a path (not the GSPMD fallback) took this config —
-    # except on legacy jax, where partial-manual shard_map is gated off
-    # and the GSPMD fallback (numerics already asserted above) is correct
+    # the explicit a2a path (not the GSPMD fallback) took this config
     from fms_fsdp_tpu.models.mixtral import _use_expert_a2a
-    from fms_fsdp_tpu.parallel.compat import has_new_shard_map
 
-    if has_new_shard_map():
-        assert _use_expert_a2a(cfg, emesh, toks.shape[0])
+    assert _use_expert_a2a(cfg, emesh, toks.shape[0])
 
     print("RING_OPS_OK", flush=True)
 

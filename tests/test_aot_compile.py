@@ -1,0 +1,136 @@
+"""Deviceless compiles of the main path's kernels for a described TPU v5e.
+
+Interpret mode hides what the chip's compiler refuses (a slice not
+aligned to the tiling, a block that takes one head out of the minor
+tile, too much VMEM), so every kernel the trainer and the server run
+on the chip is compiled here at its real geometry against a v5e:2x2
+topology description — no chip needed, about two seconds each. Nothing
+executes: a pass says the kernel compiles, not that it is right or fast.
+"""
+
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else libtpu logs to /tmp
+# describing a topology takes no chip: do not queue behind another
+# process's libtpu lock file
+os.environ.setdefault("ALLOW_MULTIPLE_LIBTPU_LOAD", "1")
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # noqa: BLE001 — no libtpu on this host
+        pytest.skip(f"cannot describe a v5e topology here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(autouse=True)
+def _no_compile_cache():
+    """A deviceless TPU executable is written to the persistent cache
+    but cannot be read back without a chip; keep it out."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+def _sds(sharding, shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _compile(fn, *shapes):
+    compiled = jax.jit(fn).lower(*shapes).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    return compiled
+
+
+@pytest.mark.parametrize("nq,nkv", [(32, 32), (8, 2)])
+def test_flash_fwd_bwd_compiles(v5e, nq, nkv):
+    from fms_fsdp_tpu.ops.flash_attention import flash_attention
+
+    def loss(q, k, v):
+        out = flash_attention(q, k, v, causal=True, interpret=False)
+        return jnp.sum(out.astype(jnp.float32))
+
+    q = _sds(v5e, (1, 4096, nq, 128), jnp.bfloat16)
+    kv = _sds(v5e, (1, 4096, nkv, 128), jnp.bfloat16)
+    _compile(jax.value_and_grad(loss, argnums=(0, 1, 2)), q, kv, kv)
+
+
+# llama3_1.8b decode geometry under ServeConfig(max_batch=8,
+# max_seq_len=2048): 16 query / 8 kv heads of 128
+_B, _NQ, _NKV, _HD, _MAX_SEQ = 8, 16, 8, 128, 2048
+
+
+@pytest.mark.parametrize(
+    "page_size,pages_per_block,store",
+    [
+        (16, 1, jnp.bfloat16),
+        (16, 4, jnp.bfloat16),
+        (128, 1, jnp.bfloat16),
+        (128, 4, jnp.bfloat16),
+        (16, 1, jnp.int8),
+        (16, 4, jnp.int8),
+    ],
+)
+def test_paged_attention_compiles(v5e, page_size, pages_per_block, store):
+    from fms_fsdp_tpu.ops.paged_attention import paged_attention_kernel
+
+    maxp = _MAX_SEQ // page_size
+    pool = _B * maxp + 2
+
+    pages = _sds(v5e, (pool, page_size, _NKV, _HD), store)
+    args = [
+        _sds(v5e, (_B, _NQ, _HD), jnp.bfloat16),
+        pages,
+        pages,
+        _sds(v5e, (_B, maxp), jnp.int32),
+        _sds(v5e, (_B,), jnp.int32),
+    ]
+    if store == jnp.int8:
+        args += [_sds(v5e, (pool, page_size, _NKV, 1), jnp.float32)] * 2
+
+    def fn(q, k, v, table, lens, k_scales=None, v_scales=None):
+        return paged_attention_kernel(
+            q, k, v, table, lens, k_scales=k_scales, v_scales=v_scales,
+            block_kv=pages_per_block * page_size, interpret=False,
+        )
+
+    _compile(fn, *args)
+
+
+def test_ssd_fused_fwd_bwd_compiles(v5e, monkeypatch):
+    from fms_fsdp_tpu.ops import pallas_mode
+    from fms_fsdp_tpu.ops.ssd import ssd_scan
+
+    monkeypatch.setattr(pallas_mode, "interpret_default", lambda: False)
+    # mamba_9.8b head geometry: 128 heads x 64, d_state 128, one group
+    b, s, heads, p, g, n = 1, 4096, 128, 64, 1, 128
+
+    def loss(x, dt, A, Bm, Cm, D):
+        y = ssd_scan(x, dt, A, Bm, Cm, D, kernel="pallas")
+        return jnp.sum(y.astype(jnp.float32))
+
+    _compile(
+        jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4, 5)),
+        _sds(v5e, (b, s, heads, p), jnp.bfloat16),
+        _sds(v5e, (b, s, heads), jnp.float32),
+        _sds(v5e, (heads,), jnp.float32),
+        _sds(v5e, (b, s, g, n), jnp.bfloat16),
+        _sds(v5e, (b, s, g, n), jnp.bfloat16),
+        _sds(v5e, (heads,), jnp.float32),
+    )

@@ -4,16 +4,15 @@ Runs the real parent->probe->row-subprocess pipeline at tiny CPU shapes
 (BENCH_SMOKE) over the headline row and its bf16 sibling (BENCH_ROWS)
 and asserts the schema the judge reads: the bf16 number and the MFU
 convention string ride in the SAME top-level object as the int8
-headline (VERDICT r4 weak #8 — a lone int8 headline vs a bf16 baseline
-invites an apples-to-oranges reading).
+headline (a lone int8 headline vs a bf16 baseline invites an
+apples-to-oranges reading). This is the one mode in which bench.py runs
+off a TPU; it reports step times and no MFU.
 """
 
 import json
 import os
 import subprocess
 import sys
-
-import pytest
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -26,8 +25,6 @@ def test_bench_smoke_schema():
         BENCH_ROWS="0,1",
         BENCH_PROBE_TIMEOUT_S="300",
         BENCH_ROW_TIMEOUT_S="300",
-        # strict mode must NOT trip on a clean (non-degraded) run
-        BENCH_STRICT="1",
     )
     proc = subprocess.run(
         [sys.executable, os.path.join(REPO, "bench.py")],
@@ -55,21 +52,22 @@ def test_bench_smoke_schema():
     assert "bf16 peak" in out["mfu_convention"]
 
     # both selected rows actually ran (no error entries at tiny shapes);
-    # MFU rounds to 0.0000 at smoke shapes on a loaded host, so the
-    # ran-at-all signals are throughput and step time
+    # the ran-at-all signals are throughput and step time. Off a TPU
+    # there is no peak: a CPU rate is never written as a share of a chip
     assert len(out["rows"]) == 2, out["rows"]
     for row in out["rows"]:
         assert "error" not in row, row
         assert row["tokens_per_sec_per_chip"] > 0
         assert row["step_time_s"] > 0
+        assert row["mfu"] is None and row["hfu"] is None
         # tuned-vs-default is a per-row first-class output: every row
         # states its tuning mode and the kernel tiles it resolved
         assert row["kernel_tuning"] in ("auto", "off"), row
         assert isinstance(row["tuning"], dict), row
 
-    # a measured run is never degraded
+    # a run whose rows ran is never degraded
     assert not out.get("degraded"), out
-    assert out["bf16_mfu"] is not None and out["bf16_vs_baseline"] is not None
+    assert out["bf16_mfu"] is None and out["bf16_vs_baseline"] is None
     # the fp8 sibling fields always ride at top level (null when the
     # fp8 row is outside the BENCH_ROWS selection, as here)
     assert "fp8_mfu" in out and "fp8_vs_baseline" in out
@@ -90,45 +88,3 @@ def test_fp8_sibling_located_structurally():
     assert kw.pop("quant") in ("fp8", "fp8_dgrad")
     head.pop("quant")
     assert kw == head
-
-
-@pytest.mark.slow
-def test_bench_fallback_tier_measures_on_cpu_host():
-    """The acceptance contract: `python bench.py` on a CPU-only host
-    (TPU probe unavailable) emits a MEASURED headline — an explicit
-    fallback_backend tier with a bf16-vs-int8-vs-fp8 relative number
-    and real rows, never vs_baseline: null with empty rows — and
-    BENCH_STRICT accepts it (degraded: false)."""
-    env = dict(os.environ)
-    env.pop("BENCH_FORCE_CPU", None)
-    env.pop("BENCH_SMOKE", None)
-    env.update(
-        JAX_PLATFORMS="cpu",  # the probe answers, as a cpu backend
-        BENCH_STRICT="1",
-        BENCH_FALLBACK_STEPS="2",
-        BENCH_FALLBACK_SEQ="256",
-    )
-    proc = subprocess.run(
-        [sys.executable, os.path.join(REPO, "bench.py")],
-        stdout=subprocess.PIPE,
-        stderr=subprocess.STDOUT,
-        text=True,
-        timeout=1800,
-        env=env,
-        cwd=REPO,
-    )
-    assert proc.returncode == 0, proc.stdout[-2000:]
-    line = [
-        ln for ln in proc.stdout.splitlines() if ln.startswith("{")
-    ][-1]
-    out = json.loads(line)
-    assert out["degraded"] is False
-    assert out["fallback_backend"] == "cpu"
-    assert "probe_error" in out
-    # a real relative number: bf16 vs int8 vs fp8 all measured
-    rel = out["quant_relative"]
-    assert rel["int8"] > 0 and rel["fp8"] > 0
-    assert out["value"] == rel["int8"]
-    assert out["rows"] and all("error" not in r for r in out["rows"])
-    quants = {r["quant"] for r in out["rows"]}
-    assert quants == {"none", "int8", "fp8"}

@@ -1,9 +1,8 @@
-"""CPU smoke of the chip-evidence eval leg (scripts/chip_evidence.sh
-step 4): a REAL arrow corpus through the production data pipeline ->
-training entry -> native eval_ppl, asserting perplexity actually falls
-vs the fresh-init model on the same stream. This is the
-arrow-streaming -> training -> quality connection at tiny scale
-(VERDICT r4 #4); the chip script runs the same legs scaled up."""
+"""CPU smoke of the train -> eval leg: a REAL arrow corpus through the
+production data pipeline -> training entry -> native eval_ppl,
+asserting perplexity actually falls vs the fresh-init model on the same
+stream. This is the arrow-streaming -> training -> quality connection
+at tiny scale."""
 
 import pytest
 

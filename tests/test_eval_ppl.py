@@ -1,5 +1,5 @@
-"""eval_ppl entry coverage: the train -> checkpoint -> native-eval leg
-that chip_evidence.sh runs (VERDICT r3 item 8's else-branch). Validates
+"""eval_ppl entry coverage: the train -> checkpoint -> native-eval
+leg. Validates
 the params-only sharded load against a checkpoint the TRAINING ENTRY
 actually wrote, and that a trained model scores better than random
 init on the deterministic dummy stream."""

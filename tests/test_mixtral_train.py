@@ -13,7 +13,6 @@ import pytest
 
 from fms_fsdp_tpu.config import TrainConfig
 from fms_fsdp_tpu.models.configs import MixtralConfig
-from fms_fsdp_tpu.parallel.compat import has_new_shard_map
 from fms_fsdp_tpu.models.mixtral import (
     _moe_ffn_dense,
     _moe_ffn_dispatch,
@@ -43,16 +42,6 @@ TINY = dict(
     num_experts=4,
     top_k=2,
     max_expected_seq_len=64,
-)
-
-
-_needs_a2a = pytest.mark.skipif(
-    not has_new_shard_map(),
-    reason=(
-        "explicit EP all-to-all needs jax >= 0.8 partial-manual "
-        "shard_map; this jax falls back to the GSPMD dispatch "
-        "(see models/mixtral.py::_use_expert_a2a)"
-    ),
 )
 
 
@@ -152,7 +141,6 @@ def test_scatter_dispatch_matches_einsum_with_drops():
         assert float(jnp.max(jnp.abs(a - b))) < 1e-4, (a.shape,)
 
 
-@_needs_a2a
 def test_a2a_dispatch_matches_plain_dispatch():
     """The shard_map all-to-all EP path must equal the single-program
     scatter path — values, stats, and gradients — at a capacity tight
@@ -264,7 +252,6 @@ def _one_step_loss(cfg, model_cfg):
     return float(m["loss"]), shardings
 
 
-@_needs_a2a
 def test_expert_parallel_matches_ep1():
     """The same global batch gives the same loss whether experts are
     sharded over the expert axis (EP all-to-all dispatch) or not."""
@@ -277,7 +264,6 @@ def test_expert_parallel_matches_ep1():
     assert spec[1] == "expert"
 
 
-@_needs_a2a
 def test_context_parallel_moe_matches_cp1():
     """MoE + context parallelism: the routing cumsum and dispatch span
     the context-sharded sequence dim. Adding EP on top of CP must not

@@ -457,7 +457,10 @@ def test_build_observer_flops_model():
         selective_checkpointing=0.5,
     )
     obs = build_observer(cfg, rank=0, model_cfg=model_cfg)
-    assert obs.flops_per_token and obs.peak_flops
+    assert obs.flops_per_token
+    # no TPU, no peak: a CPU record carries no MFU against a chip's peak
+    assert obs.peak_flops is None
+    assert obs.device["platform"] == "cpu" and obs.device["count"] == 8
     # HFU numerator counts the recompute: strictly above the MFU one
     assert obs.hfu_flops_per_token > obs.flops_per_token
 
@@ -704,10 +707,15 @@ def test_e2e_metrics_jsonl_with_injected_skip(tmp_path, capsys):
     for rec in records:
         assert validate_record(rec) == [], rec
         for field in (
-            "loss", "tokens_per_sec_per_chip", "mfu",
+            "loss", "tokens_per_sec_per_chip",
             "data_wait_frac", "goodput",
         ):
             assert rec[field] is not None
+        # v16: every record names its device; off a TPU there is no peak
+        # to divide by, so MFU is null rather than a share of a v5e
+        assert rec["device_platform"] == "cpu"
+        assert rec["device_kind"] and rec["device_count"] == 8
+        assert rec["mfu"] is None and rec["hfu"] is None
     # the injected NaN batch (device step counter 2 -> trainer step 3,
     # the second report window) is folded into that window's accounting
     assert records[0]["skipped_steps_window"] == 0

@@ -376,7 +376,7 @@ def test_kernel_v2_quantized_native_matches_dequantized(wire, block_kv):
     )
     ker = paged_attention_kernel(
         q, kq, vq, table, lens, k_scales=ks, v_scales=vs,
-        block_kv=block_kv, compute_dtype=jnp.float32, interpret=True,
+        block_kv=block_kv, interpret=True,
     )
     assert jnp.allclose(ref, ker, atol=1e-5), float(jnp.abs(ref - ker).max())
 

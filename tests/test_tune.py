@@ -807,18 +807,16 @@ def test_autotune_script_dry_run_subprocess():
 
 
 # ---------------------------------------------------------------------------
-# bench degraded-probe contract
+# bench contract: no measurement exits non-zero
 # ---------------------------------------------------------------------------
 
 
 @pytest.mark.slow
-def test_bench_probe_timeout_is_degraded_and_strict_fails():
+def test_bench_probe_timeout_is_degraded_and_fails():
     env = dict(os.environ)
     env.update(
         BENCH_FORCE_CPU="1",
         BENCH_PROBE_TIMEOUT_S="0.05",  # guaranteed probe timeout
-        BENCH_STRICT="1",
-        BENCH_FALLBACK="0",  # bare degraded record (no measured tier)
     )
     proc = subprocess.run(
         [sys.executable, os.path.join(REPO, "bench.py")],
@@ -830,12 +828,13 @@ def test_bench_probe_timeout_is_degraded_and_strict_fails():
     assert out["degraded"] is True
     assert out["vs_baseline"] is None  # never 0.0 for an unmeasured run
     assert "error" in out
-    assert proc.returncode != 0  # BENCH_STRICT: degraded exits nonzero
+    assert proc.returncode != 0  # nothing measured: never exit 0
 
 
-def test_bench_degraded_record_shape():
+def test_bench_degraded_record_shape(capsys):
     """Unit-level: the degraded record never carries a numeric
-    vs_baseline, and _finish exits nonzero only under BENCH_STRICT."""
+    vs_baseline, and _finish exits non-zero on it — there is no mode
+    in which an unmeasured run ends 0."""
     sys.path.insert(0, REPO)
     try:
         import bench
@@ -844,13 +843,31 @@ def test_bench_degraded_record_shape():
     rec = bench._degraded_result("v5e", "backend probe failed: timeout")
     assert rec["degraded"] is True and rec["vs_baseline"] is None
     assert rec["rows"] == []
-    old = os.environ.pop("BENCH_STRICT", None)
+    with pytest.raises(SystemExit) as exc:
+        bench._finish(dict(rec))
+    assert exc.value.code != 0
+    assert json.loads(capsys.readouterr().out)["degraded"] is True
+    bench._finish({"value": 0.5, "rows": [{}]})  # measured: returns
+
+
+def test_bench_without_tpu_measures_nothing(monkeypatch, capsys):
+    """A healthy probe on a non-TPU backend, outside the tests' explicit
+    plumbing mode, runs no row: degraded record, non-zero exit."""
+    sys.path.insert(0, REPO)
     try:
-        bench._finish(dict(rec))  # no strict: prints, returns
-        os.environ["BENCH_STRICT"] = "1"
-        with pytest.raises(SystemExit):
-            bench._finish(dict(rec))
+        import bench
     finally:
-        os.environ.pop("BENCH_STRICT", None)
-        if old is not None:
-            os.environ["BENCH_STRICT"] = old
+        sys.path.pop(0)
+    monkeypatch.delenv("BENCH_SMOKE", raising=False)
+    monkeypatch.setattr(
+        bench, "_probe_backend", lambda: (8, "cpu", "cpu", None)
+    )
+    monkeypatch.setattr(
+        bench, "_run_subprocess",
+        lambda *a, **k: pytest.fail("a row ran without a TPU"),
+    )
+    with pytest.raises(SystemExit) as exc:
+        bench.main()
+    assert exc.value.code != 0
+    out = json.loads(capsys.readouterr().out)
+    assert out["degraded"] is True and "not tpu" in out["error"]
