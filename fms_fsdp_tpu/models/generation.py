@@ -21,6 +21,7 @@ from jax import lax
 
 from fms_fsdp_tpu.models.configs import LlamaConfig
 from fms_fsdp_tpu.models.llama import llama_forward
+from fms_fsdp_tpu.obs.scopes import scoped
 from fms_fsdp_tpu.ops.norms import rms_norm
 from fms_fsdp_tpu.ops.paged_attention import gqa_attend
 from fms_fsdp_tpu.ops.rope import apply_rotary, rope_table
@@ -42,38 +43,51 @@ def prefill(
     The cache holds max_seq_len positions; positions >= len(prompt) are
     zeros until decode writes them.
     """
-    params = jax.tree.map(lambda a: a.astype(compute_dtype), params)
+    with jax.named_scope("params_cast"):
+        params = jax.tree.map(lambda a: a.astype(compute_dtype), params)
     b, s = tokens.shape
     hd, nkv = cfg.head_dim, cfg.n_kv_heads
     nlayers = params["layers"]["wq"].shape[0]
 
-    cos, sin = rope_table(max_seq_len, hd, cfg.rope_theta)
-    x = params["embedding"][tokens]
+    with jax.named_scope("rope"):
+        cos, sin = rope_table(max_seq_len, hd, cfg.rope_theta)
+    with jax.named_scope("embed"):
+        x = params["embedding"][tokens]
 
     def body(x, layer):
-        h = rms_norm(x, layer["attn_norm"], cfg.norm_eps)
-        q = (h @ layer["wq"]).reshape(b, s, cfg.nheads, hd)
-        k = (h @ layer["wk"]).reshape(b, s, nkv, hd)
-        v = (h @ layer["wv"]).reshape(b, s, nkv, hd)
-        q = apply_rotary(q, cos, sin)
-        k = apply_rotary(k, cos, sin)
+        with jax.named_scope("qkv"):
+            h = rms_norm(x, layer["attn_norm"], cfg.norm_eps)
+            q = (h @ layer["wq"]).reshape(b, s, cfg.nheads, hd)
+            k = (h @ layer["wk"]).reshape(b, s, nkv, hd)
+            v = (h @ layer["wv"]).reshape(b, s, nkv, hd)
+            q = apply_rotary(q, cos, sin)
+            k = apply_rotary(k, cos, sin)
         from fms_fsdp_tpu.ops.attention import attention
 
-        o = attention(q, k, v, causal=True, impl="xla")
-        x = x + o.reshape(b, s, cfg.nheads * hd) @ layer["wo"]
-        h2 = rms_norm(x, layer["ffn_norm"], cfg.norm_eps)
-        ffn = (jax.nn.silu(h2 @ layer["w1"]) * (h2 @ layer["w3"])) @ layer["w2"]
+        with jax.named_scope("attn"):
+            o = attention(q, k, v, causal=True, impl="xla")
+        with jax.named_scope("attn_out"):
+            x = x + o.reshape(b, s, cfg.nheads * hd) @ layer["wo"]
+        with jax.named_scope("ffn"):
+            h2 = rms_norm(x, layer["ffn_norm"], cfg.norm_eps)
+            ffn = (
+                jax.nn.silu(h2 @ layer["w1"]) * (h2 @ layer["w3"])
+            ) @ layer["w2"]
+            x = x + ffn
         # cache entries padded out to max_seq_len
         pad = [(0, 0), (0, max_seq_len - s), (0, 0), (0, 0)]
-        return x + ffn, (jnp.pad(k, pad), jnp.pad(v, pad))
+        return x, (jnp.pad(k, pad), jnp.pad(v, pad))
 
-    x, (k_cache, v_cache) = lax.scan(body, x, params["layers"])
-    embeds = rms_norm(x, params["norm"], cfg.norm_eps)
-    src = embeds if full_logits else embeds[:, -1:]
-    logits = src @ params["lm_head"]
+    with jax.named_scope("layers"):
+        x, (k_cache, v_cache) = lax.scan(body, x, params["layers"])
+    with jax.named_scope("lm_head"):
+        embeds = rms_norm(x, params["norm"], cfg.norm_eps)
+        src = embeds if full_logits else embeds[:, -1:]
+        logits = src @ params["lm_head"]
     return logits, embeds, {"k": k_cache, "v": v_cache}
 
 
+@scoped("qkv")
 def decode_layer_qkv(x, layer, cfg: LlamaConfig, cos, sin, positions):
     """Pre-attention half of one decode layer: norm -> q/k/v projections
     -> rotary at ``positions``. Shared by the dense decode path below and
@@ -93,40 +107,50 @@ def decode_layer_qkv(x, layer, cfg: LlamaConfig, cos, sin, positions):
 def decode_layer_out(x, layer, cfg: LlamaConfig, o):
     """Post-attention half of one decode layer: residual + SwiGLU FFN.
     Shared with the paged decode path (see decode_layer_qkv)."""
-    x = x + o @ layer["wo"]
-    h2 = rms_norm(x, layer["ffn_norm"], cfg.norm_eps)
-    ffn = (jax.nn.silu(h2 @ layer["w1"]) * (h2 @ layer["w3"])) @ layer["w2"]
-    return x + ffn
+    with jax.named_scope("attn_out"):
+        x = x + o @ layer["wo"]
+    with jax.named_scope("ffn"):
+        h2 = rms_norm(x, layer["ffn_norm"], cfg.norm_eps)
+        ffn = (
+            jax.nn.silu(h2 @ layer["w1"]) * (h2 @ layer["w3"])
+        ) @ layer["w2"]
+        return x + ffn
 
 
 def decode_chunk(params, cache, tokens, pos, cfg: LlamaConfig, compute_dtype=jnp.bfloat16):
     """Cached decode of m tokens at positions pos..pos+m-1 in one forward
     (the verification step of speculative decoding; decode_step is the
     m=1 case). Returns (logits (B, m, V), embeds (B, m, D), cache)."""
-    params = jax.tree.map(lambda a: a.astype(compute_dtype), params)
+    with jax.named_scope("params_cast"):
+        params = jax.tree.map(lambda a: a.astype(compute_dtype), params)
     b, m = tokens.shape
     hd = cfg.head_dim
     max_seq = cache["k"].shape[2]
 
-    cos, sin = rope_table(max_seq, hd, cfg.rope_theta)
+    with jax.named_scope("rope"):
+        cos, sin = rope_table(max_seq, hd, cfg.rope_theta)
     positions = pos + jnp.arange(m, dtype=jnp.int32)[None, :]  # (1, m)
     positions = jnp.broadcast_to(positions, (b, m))
-    x = params["embedding"][tokens]
+    with jax.named_scope("embed"):
+        x = params["embedding"][tokens]
 
     def body(x, inp):
         layer, k_cache, v_cache = inp
         q, k, v = decode_layer_qkv(x, layer, cfg, cos, sin, positions)
-        k_cache = lax.dynamic_update_slice(k_cache, k, (0, pos, 0, 0))
-        v_cache = lax.dynamic_update_slice(v_cache, v, (0, pos, 0, 0))
+        with jax.named_scope("kv_write"):
+            k_cache = lax.dynamic_update_slice(k_cache, k, (0, pos, 0, 0))
+            v_cache = lax.dynamic_update_slice(v_cache, v, (0, pos, 0, 0))
         # q position pos+i sees cache entries <= pos+i
         o = gqa_attend(q, k_cache, v_cache, positions)
         return decode_layer_out(x, layer, cfg, o), (k_cache, v_cache)
 
-    x, (k_cache, v_cache) = lax.scan(
-        body, x, (params["layers"], cache["k"], cache["v"])
-    )
-    embeds = rms_norm(x, params["norm"], cfg.norm_eps)
-    logits = embeds @ params["lm_head"]
+    with jax.named_scope("layers"):
+        x, (k_cache, v_cache) = lax.scan(
+            body, x, (params["layers"], cache["k"], cache["v"])
+        )
+    with jax.named_scope("lm_head"):
+        embeds = rms_norm(x, params["norm"], cfg.norm_eps)
+        logits = embeds @ params["lm_head"]
     return logits, embeds, {"k": k_cache, "v": v_cache}
 
 
@@ -140,6 +164,7 @@ def decode_step(params, cache, token, pos, cfg: LlamaConfig, compute_dtype=jnp.b
     return logits[:, 0], embeds[:, 0], cache
 
 
+@scoped("sample")
 def sample_token(logits, key, temperature, top_k, do_sample):
     """Greedy argmax or temperature / top-k sampling of one token per
     row. Public: the serving engine (fms_fsdp_tpu/serve/engine.py) uses

@@ -500,23 +500,32 @@ def _moe_token(h, lp, cfg: MixtralConfig, moe_impl: str = "dense"):
         return _moe_ffn_dense(h, lp, cfg)[0]
     assert moe_impl == "routed", f"unknown decode moe_impl {moe_impl!r}"
     top_idx, top_w, _ = _router(h, lp["gate"], cfg)  # (B, m, K)
-    w1 = lp["w1"][top_idx]  # (B, m, K, D, H)
-    w3 = lp["w3"][top_idx]
-    w2 = lp["w2"][top_idx]  # (B, m, K, H, D)
-    hidden = jax.nn.silu(
-        jnp.einsum("bmd,bmkdh->bmkh", h, w1)
-    ) * jnp.einsum("bmd,bmkdh->bmkh", h, w3)
-    out = jnp.einsum("bmkh,bmkhd->bmkd", hidden, w2)
-    return jnp.einsum("bmkd,bmk->bmd", out, top_w.astype(h.dtype))
+    with jax.named_scope("moe_gather"):
+        w1 = lp["w1"][top_idx]  # (B, m, K, D, H)
+        w3 = lp["w3"][top_idx]
+        w2 = lp["w2"][top_idx]  # (B, m, K, H, D)
+    with jax.named_scope("moe_experts"):
+        hidden = jax.nn.silu(
+            jnp.einsum("bmd,bmkdh->bmkh", h, w1)
+        ) * jnp.einsum("bmd,bmkdh->bmkh", h, w3)
+        out = jnp.einsum("bmkh,bmkhd->bmkd", hidden, w2)
+    with jax.named_scope("moe_combine"):
+        return jnp.einsum("bmkd,bmk->bmd", out, top_w.astype(h.dtype))
 
 
 def _mixtral_decode_layer_out(x, layer, cfg: MixtralConfig, o, moe_impl: str):
     """Post-attention half of one decode layer: residual + routed MoE.
     Shared by the dense-cache reference walk and the paged decode step so
     the two cannot drift (the llama decode_layer_out analog)."""
-    x = x + o @ layer["wo"]
-    h2 = rms_norm(x, layer["ffn_norm"], cfg.norm_eps)
-    return x + _moe_token(h2, layer, cfg, moe_impl)
+    with jax.named_scope("attn_out"):
+        x = x + o @ layer["wo"]
+    # the norm that feeds the router and the experts counts with the
+    # router, the residual add with the combine
+    with jax.named_scope("moe_router"):
+        h2 = rms_norm(x, layer["ffn_norm"], cfg.norm_eps)
+    moe = _moe_token(h2, layer, cfg, moe_impl)
+    with jax.named_scope("moe_combine"):
+        return x + moe
 
 
 def mixtral_prefill(
@@ -531,35 +540,43 @@ def mixtral_prefill(
     models/generation.py::prefill (same cache layout (L, B, S_max, Nkv,
     H), zeros beyond the written prefix), with the FFN as the dense-mix
     MoE. Returns (logits, embeds, {"k", "v"} cache)."""
-    params = jax.tree.map(lambda a: a.astype(compute_dtype), params)
+    with jax.named_scope("params_cast"):
+        params = jax.tree.map(lambda a: a.astype(compute_dtype), params)
     b, s = tokens.shape
     hd, nkv = cfg.head_dim, cfg.n_kv_heads
 
-    cos, sin = rope_table(max_seq_len, hd, cfg.rope_theta)
-    x = params["embedding"][tokens]
+    with jax.named_scope("rope"):
+        cos, sin = rope_table(max_seq_len, hd, cfg.rope_theta)
+    with jax.named_scope("embed"):
+        x = params["embedding"][tokens]
 
     def body(x, layer):
-        h = rms_norm(x, layer["attn_norm"], cfg.norm_eps)
-        q = (h @ layer["wq"]).reshape(b, s, cfg.nheads, hd)
-        k = (h @ layer["wk"]).reshape(b, s, nkv, hd)
-        v = (h @ layer["wv"]).reshape(b, s, nkv, hd)
+        from fms_fsdp_tpu.ops.attention import attention
         from fms_fsdp_tpu.ops.rope import apply_rotary
 
-        q = apply_rotary(q, cos, sin)
-        k = apply_rotary(k, cos, sin)
-        from fms_fsdp_tpu.ops.attention import attention
-
-        o = attention(q, k, v, causal=True, impl="xla")
-        x = x + o.reshape(b, s, cfg.nheads * hd) @ layer["wo"]
-        h2 = rms_norm(x, layer["ffn_norm"], cfg.norm_eps)
-        x = x + _moe_ffn_dense(h2, layer, cfg)[0]
+        with jax.named_scope("qkv"):
+            h = rms_norm(x, layer["attn_norm"], cfg.norm_eps)
+            q = (h @ layer["wq"]).reshape(b, s, cfg.nheads, hd)
+            k = (h @ layer["wk"]).reshape(b, s, nkv, hd)
+            v = (h @ layer["wv"]).reshape(b, s, nkv, hd)
+            q = apply_rotary(q, cos, sin)
+            k = apply_rotary(k, cos, sin)
+        with jax.named_scope("attn"):
+            o = attention(q, k, v, causal=True, impl="xla")
+        with jax.named_scope("attn_out"):
+            x = x + o.reshape(b, s, cfg.nheads * hd) @ layer["wo"]
+        with jax.named_scope("moe_dense"):
+            h2 = rms_norm(x, layer["ffn_norm"], cfg.norm_eps)
+            x = x + _moe_ffn_dense(h2, layer, cfg)[0]
         pad = [(0, 0), (0, max_seq_len - s), (0, 0), (0, 0)]
         return x, (jnp.pad(k, pad), jnp.pad(v, pad))
 
-    x, (k_cache, v_cache) = lax.scan(body, x, params["layers"])
-    embeds = rms_norm(x, params["norm"], cfg.norm_eps)
-    src = embeds if full_logits else embeds[:, -1:]
-    logits = src @ params["lm_head"]
+    with jax.named_scope("layers"):
+        x, (k_cache, v_cache) = lax.scan(body, x, params["layers"])
+    with jax.named_scope("lm_head"):
+        embeds = rms_norm(x, params["norm"], cfg.norm_eps)
+        src = embeds if full_logits else embeds[:, -1:]
+        logits = src @ params["lm_head"]
     return logits, embeds, {"k": k_cache, "v": v_cache}
 
 
@@ -578,31 +595,37 @@ def mixtral_decode_step(
     from fms_fsdp_tpu.models.generation import decode_layer_qkv
     from fms_fsdp_tpu.ops.paged_attention import gqa_attend
 
-    params = jax.tree.map(lambda a: a.astype(compute_dtype), params)
+    with jax.named_scope("params_cast"):
+        params = jax.tree.map(lambda a: a.astype(compute_dtype), params)
     b, m = token.shape
     max_seq = cache["k"].shape[2]
-    cos, sin = rope_table(max_seq, cfg.head_dim, cfg.rope_theta)
+    with jax.named_scope("rope"):
+        cos, sin = rope_table(max_seq, cfg.head_dim, cfg.rope_theta)
     positions = jnp.broadcast_to(
         pos + jnp.arange(m, dtype=jnp.int32)[None, :], (b, m)
     )
-    x = params["embedding"][token]
+    with jax.named_scope("embed"):
+        x = params["embedding"][token]
 
     def body(x, inp):
         layer, k_cache, v_cache = inp
         q, k, v = decode_layer_qkv(x, layer, cfg, cos, sin, positions)
-        k_cache = lax.dynamic_update_slice(k_cache, k, (0, pos, 0, 0))
-        v_cache = lax.dynamic_update_slice(v_cache, v, (0, pos, 0, 0))
+        with jax.named_scope("kv_write"):
+            k_cache = lax.dynamic_update_slice(k_cache, k, (0, pos, 0, 0))
+            v_cache = lax.dynamic_update_slice(v_cache, v, (0, pos, 0, 0))
         o = gqa_attend(q, k_cache, v_cache, positions)
         return (
             _mixtral_decode_layer_out(x, layer, cfg, o, moe_impl),
             (k_cache, v_cache),
         )
 
-    x, (k_cache, v_cache) = lax.scan(
-        body, x, (params["layers"], cache["k"], cache["v"])
-    )
-    embeds = rms_norm(x, params["norm"], cfg.norm_eps)
-    logits = embeds @ params["lm_head"]
+    with jax.named_scope("layers"):
+        x, (k_cache, v_cache) = lax.scan(
+            body, x, (params["layers"], cache["k"], cache["v"])
+        )
+    with jax.named_scope("lm_head"):
+        embeds = rms_norm(x, params["norm"], cfg.norm_eps)
+        logits = embeds @ params["lm_head"]
     return logits[:, 0], {"k": k_cache, "v": v_cache}
 
 
@@ -625,24 +648,29 @@ def mixtral_paged_decode_step(
     from fms_fsdp_tpu.models.generation import decode_layer_qkv
     from fms_fsdp_tpu.ops.paged_attention import gather_pages, gqa_attend
 
-    params = jax.tree.map(lambda a: a.astype(compute_dtype), params)
+    with jax.named_scope("params_cast"):
+        params = jax.tree.map(lambda a: a.astype(compute_dtype), params)
     b = tokens.shape[0]
     max_seq = page_table.shape[1] * page_size
-    cos, sin = rope_table(max_seq, cfg.head_dim, cfg.rope_theta)
+    with jax.named_scope("rope"):
+        cos, sin = rope_table(max_seq, cfg.head_dim, cfg.rope_theta)
     positions = seq_lens[:, None].astype(jnp.int32)
-    x = params["embedding"][tokens[:, None]]
+    with jax.named_scope("embed"):
+        x = params["embedding"][tokens[:, None]]
 
-    rows = jnp.arange(b)
-    page_ids = page_table[rows, seq_lens // page_size]
-    slots = seq_lens % page_size
+    with jax.named_scope("kv_write"):  # each row's write target
+        rows = jnp.arange(b)
+        page_ids = page_table[rows, seq_lens // page_size]
+        slots = seq_lens % page_size
 
     def body(x, inp):
         layer, layer_pools = inp
         q, k, v = decode_layer_qkv(x, layer, cfg, cos, sin, positions)
-        layer_pools = {
-            "k": layer_pools["k"].at[page_ids, slots].set(k[:, 0]),
-            "v": layer_pools["v"].at[page_ids, slots].set(v[:, 0]),
-        }
+        with jax.named_scope("kv_write"):
+            layer_pools = {
+                "k": layer_pools["k"].at[page_ids, slots].set(k[:, 0]),
+                "v": layer_pools["v"].at[page_ids, slots].set(v[:, 0]),
+            }
         o = gqa_attend(
             q,
             gather_pages(layer_pools["k"], page_table),
@@ -651,9 +679,11 @@ def mixtral_paged_decode_step(
         )
         return _mixtral_decode_layer_out(x, layer, cfg, o, moe_impl), layer_pools
 
-    x, pools = lax.scan(body, x, (params["layers"], pools))
-    embeds = rms_norm(x, params["norm"], cfg.norm_eps)
-    logits = embeds @ params["lm_head"]
+    with jax.named_scope("layers"):
+        x, pools = lax.scan(body, x, (params["layers"], pools))
+    with jax.named_scope("lm_head"):
+        embeds = rms_norm(x, params["norm"], cfg.norm_eps)
+        logits = embeds @ params["lm_head"]
     return logits[:, 0], pools
 
 

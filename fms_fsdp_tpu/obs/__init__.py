@@ -17,6 +17,9 @@ package gives every run a machine-readable record (docs/observability.md):
 - sinks (:mod:`~fms_fsdp_tpu.obs.sinks`) — schema-versioned JSONL, CSV
   summary, and an adapter wrapping the legacy wandb/aim tracker so
   ``get_tracker`` becomes one sink among several;
+- :func:`~fms_fsdp_tpu.obs.spans.span` — the serving engine's host
+  spans (``serve/*``), written into a ``jax.profiler`` session when one
+  runs and costing one flag check when none does;
 - :class:`~fms_fsdp_tpu.obs.observer.Observer` — the facade the train
   loops drive; built from config by
   :func:`~fms_fsdp_tpu.obs.observer.build_observer`.
@@ -39,6 +42,7 @@ from fms_fsdp_tpu.obs.sinks import (
     JSONLSink,
     TrackerSink,
 )
+from fms_fsdp_tpu.obs.spans import span
 from fms_fsdp_tpu.obs.timing import GoodputTracker, PhaseTimer
 
 __all__ = [
@@ -54,4 +58,5 @@ __all__ = [
     "Heartbeat",
     "PhaseTimer",
     "GoodputTracker",
+    "span",
 ]

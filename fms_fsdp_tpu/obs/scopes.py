@@ -1,17 +1,70 @@
-"""``jax.named_scope`` decorator for trace attribution.
+"""``jax.named_scope`` names, and the way back from a device event to one.
 
-Profiler traces (utils/train_utils.py::WindowedProfiler) are only as
-useful as their op names; a scan-of-blocks model otherwise shows up as
-one undifferentiated ``while`` region. ``scoped("name")`` wraps a
-trace-time function so every op it emits lands under ``name`` in the
-XPlane tree — zero runtime cost (named_scope only affects tracing
-metadata), safe inside jit/scan/remat, and a no-op for code paths that
-never run under a profiler.
+A scan-of-blocks model shows in a profiler trace as one undifferentiated
+``while`` region of fusions whose names (``fusion.13.remat4``) change
+with every compile. ``scoped("name")`` (or ``jax.named_scope`` directly)
+puts ``name`` into the ``op_name`` metadata of every operation traced
+under it: zero runtime cost (metadata only, the compiled arithmetic and
+its instruction names do not change), safe inside jit/scan/remat.
+
+On this jax the scope does **not** reach the trace by itself: a device
+event of the ``.xplane.pb`` is named by its instruction's HLO text and
+carries no ``op_name``. It is in the compiled executable's HLO text,
+though, as ``metadata={op_name="jit(_step)/while/body/.../moe_gather/
+gather"}`` on every instruction, under the same instruction names
+(``%fusion.300``) that the trace's events carry. ``scope_table`` reads
+that text into ``{instruction name: scope}``; joining a trace's device
+events with it by name gives each event its scope
+(benchmark/program_trace.py does so for the decode program;
+docs/observability.md "Profiler trace attribution"). The trace's
+``/host:metadata`` plane carries the HLO proto of each executed module
+too, but it is the proto of the executable that was *loaded*: the
+persistent compile cache leaves metadata out of its key and may load one
+that an older tree compiled, with that tree's names. So compile afresh,
+with the cache off, for a table of this tree's scopes.
 """
 
 import functools
+import re
+from typing import Dict, Iterable
 
 import jax
+
+# the scopes of one serving decode step, in program order
+# (models/generation.py, ops/paged_attention.py, models/mixtral.py,
+# serve/decode.py, the adapters' jitted ``_step``). ``layers`` is around
+# the layer scan: what lies under it and under no inner scope is the
+# scan's own slicing of the stacked layer arrays
+DECODE_SCOPES = (
+    "params_cast",
+    "rope",
+    "embed",
+    "layers",
+    "qkv",
+    "kv_write",
+    "kv_gather",
+    "attn",
+    "attn_out",
+    "ffn",
+    "moe_router",
+    "moe_gather",
+    "moe_experts",
+    "moe_combine",
+    "moe_dense",
+    "lm_head",
+    "sample",
+)
+
+_COMPUTATION = re.compile(r"^(?:ENTRY\s+)?%?([\w.\-]+)\s.*\{\s*$")
+_INSTRUCTION = re.compile(r"^\s+(?:ROOT\s+)?%?([\w.\-]+) = ")
+_NAME = re.compile(r"%([\w.\-]+)")
+_OP_NAME = re.compile(r"metadata=\{[^}]*?op_name=\"([^\"]*)\"")
+# the computations an instruction runs: a loop's body and condition, a
+# fusion's or a call's computation, a conditional's branches
+_CALLED = re.compile(
+    r"\b(?:body|condition|calls|to_apply|true_computation|"
+    r"false_computation)=%?([\w.\-]+)|branch_computations=\{([^}]*)\}"
+)
 
 
 def scoped(name: str):
@@ -27,3 +80,61 @@ def scoped(name: str):
         return wrapped
 
     return deco
+
+
+def scope_table(
+    hlo_text: str, names: Iterable[str] = DECODE_SCOPES
+) -> Dict[str, str]:
+    """``{instruction name: scope}`` of an optimized HLO module's text
+    (``jitted.lower(...).compile().as_text()``), for every instruction.
+
+    An instruction's scope is the innermost component of its ``op_name``
+    path that is one of ``names``. An instruction with no ``op_name`` at
+    all (the compiler made it while expanding another: the loop of slices
+    that a gather becomes, the copies that put the slices together) takes
+    the scope of what it consumes: the first of its operands that has one
+    of its own or by this same rule. Failing that, and where an
+    ``op_name`` names no scope, it takes the scope of the instruction
+    that runs its computation: the ``while`` whose body it is in, the
+    fusion, the call. ``""`` where nothing gives one. A fusion carries the
+    ``op_name`` of its root, so a fusion that the compiler built across
+    two scopes counts wholly under its root's. Instruction names are
+    unique in a module; those inside fused computations are listed too
+    and do no harm, the trace has no events for them.
+    """
+    names = frozenset(names)
+    # local: the scope an instruction has of itself or of what it consumes
+    # (a computation's text lists operands before their users)
+    local, home, caller = {}, {}, {}  # caller: by computation
+    computation = None
+    for line in hlo_text.splitlines():
+        m = _INSTRUCTION.match(line)
+        if m is None:
+            c = _COMPUTATION.match(line)
+            if c is not None:
+                computation = c.group(1)
+            continue
+        name = m.group(1)
+        op = _OP_NAME.search(line)
+        scope = next(
+            (p for p in reversed(op.group(1).split("/")) if p in names), ""
+        ) if op else ""
+        if op is None:
+            scope = next(
+                (local[o] for o in _NAME.findall(line, m.end())
+                 if local.get(o) and home[o] == computation), "")
+        local[name], home[name] = scope, computation
+        for one, several in _CALLED.findall(line):
+            for called in [one] if one else several.split(","):
+                caller[called.strip().lstrip("%")] = name
+
+    def scope_of(name):
+        seen = set()
+        while name is not None and name not in seen:
+            if local[name]:
+                return local[name]
+            seen.add(name)
+            name = caller.get(home[name])
+        return ""
+
+    return {name: scope_of(name) for name in local}

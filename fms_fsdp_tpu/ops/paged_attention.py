@@ -50,6 +50,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from fms_fsdp_tpu.obs.scopes import scoped
 from fms_fsdp_tpu.ops.pallas_mode import interpret_default
 
 NEG_INF = -1e30
@@ -61,6 +62,7 @@ LOG2E = 1.4426950408889634  # log2(e)
 # ---------------------------------------------------------------------------
 
 
+@scoped("attn")
 def gqa_attend(q, k_cache, v_cache, positions):
     """Grouped-query attention of m query positions against a cache.
 
@@ -94,6 +96,7 @@ def gqa_attend(q, k_cache, v_cache, positions):
 # ---------------------------------------------------------------------------
 
 
+@scoped("kv_gather")
 def gather_pages(pages, page_table):
     """pages (P, ps, Nkv, H) + page_table (B, maxp) -> (B, maxp*ps, Nkv, H).
 

@@ -57,44 +57,50 @@ def paged_decode_step(
     pools in VMEM) and ``block_kv`` sets the pages-per-cell fetch
     width.
     """
-    params = jax.tree.map(lambda a: a.astype(compute_dtype), params)
+    with jax.named_scope("params_cast"):
+        params = jax.tree.map(lambda a: a.astype(compute_dtype), params)
     b = tokens.shape[0]
     hd = cfg.head_dim
     max_seq = page_table.shape[1] * page_size
-    cos, sin = rope_table(max_seq, hd, cfg.rope_theta)
+    with jax.named_scope("rope"):
+        cos, sin = rope_table(max_seq, hd, cfg.rope_theta)
     positions = seq_lens[:, None].astype(jnp.int32)  # (B, 1)
-    x = params["embedding"][tokens[:, None]]  # (B, 1, D)
+    with jax.named_scope("embed"):
+        x = params["embedding"][tokens[:, None]]  # (B, 1, D)
 
-    rows = jnp.arange(b)
-    page_ids = page_table[rows, seq_lens // page_size]  # (B,)
-    slots = seq_lens % page_size
+    with jax.named_scope("kv_write"):  # each row's write target
+        rows = jnp.arange(b)
+        page_ids = page_table[rows, seq_lens // page_size]  # (B,)
+        slots = seq_lens % page_size
 
     quantized = quant != "none"
 
     def attend(q, layer_pools):
         if attn_impl == "kernel":
-            return paged_attention_kernel(
-                q[:, 0],
-                layer_pools["k"],
-                layer_pools["v"],
-                page_table,
-                seq_lens,
-                k_scales=layer_pools.get("k_scale"),
-                v_scales=layer_pools.get("v_scale"),
-                block_kv=block_kv,
-                interpret=interpret,
-            )[:, None]
+            with jax.named_scope("attn"):
+                return paged_attention_kernel(
+                    q[:, 0],
+                    layer_pools["k"],
+                    layer_pools["v"],
+                    page_table,
+                    seq_lens,
+                    k_scales=layer_pools.get("k_scale"),
+                    v_scales=layer_pools.get("v_scale"),
+                    block_kv=block_kv,
+                    interpret=interpret,
+                )[:, None]
         if quantized:
-            k = kv_dequantize(
-                gather_pages(layer_pools["k"], page_table),
-                gather_pages(layer_pools["k_scale"], page_table),
-                compute_dtype,
-            )
-            v = kv_dequantize(
-                gather_pages(layer_pools["v"], page_table),
-                gather_pages(layer_pools["v_scale"], page_table),
-                compute_dtype,
-            )
+            with jax.named_scope("kv_gather"):
+                k = kv_dequantize(
+                    gather_pages(layer_pools["k"], page_table),
+                    gather_pages(layer_pools["k_scale"], page_table),
+                    compute_dtype,
+                )
+                v = kv_dequantize(
+                    gather_pages(layer_pools["v"], page_table),
+                    gather_pages(layer_pools["v_scale"], page_table),
+                    compute_dtype,
+                )
         else:
             k = gather_pages(layer_pools["k"], page_table)
             v = gather_pages(layer_pools["v"], page_table)
@@ -106,26 +112,33 @@ def paged_decode_step(
         # scatter this step's k/v to each row's (page, slot) target —
         # idle rows' tables point every slot at the scratch page, so
         # their write lands where no live sequence reads
-        if quantized:
-            qk, sk = kv_quantize(k[:, 0], quant)
-            qv, sv = kv_quantize(v[:, 0], quant)
-            layer_pools = {
-                "k": layer_pools["k"].at[page_ids, slots].set(qk),
-                "v": layer_pools["v"].at[page_ids, slots].set(qv),
-                "k_scale": layer_pools["k_scale"].at[page_ids, slots].set(sk),
-                "v_scale": layer_pools["v_scale"].at[page_ids, slots].set(sv),
-            }
-        else:
-            layer_pools = {
-                "k": layer_pools["k"].at[page_ids, slots].set(k[:, 0]),
-                "v": layer_pools["v"].at[page_ids, slots].set(v[:, 0]),
-            }
+        with jax.named_scope("kv_write"):
+            if quantized:
+                qk, sk = kv_quantize(k[:, 0], quant)
+                qv, sv = kv_quantize(v[:, 0], quant)
+                layer_pools = {
+                    "k": layer_pools["k"].at[page_ids, slots].set(qk),
+                    "v": layer_pools["v"].at[page_ids, slots].set(qv),
+                    "k_scale": layer_pools["k_scale"]
+                    .at[page_ids, slots]
+                    .set(sk),
+                    "v_scale": layer_pools["v_scale"]
+                    .at[page_ids, slots]
+                    .set(sv),
+                }
+            else:
+                layer_pools = {
+                    "k": layer_pools["k"].at[page_ids, slots].set(k[:, 0]),
+                    "v": layer_pools["v"].at[page_ids, slots].set(v[:, 0]),
+                }
         o = attend(q, layer_pools)
         return decode_layer_out(x, layer, cfg, o), layer_pools
 
-    x, pools = lax.scan(body, x, (params["layers"], pools))
-    embeds = rms_norm(x, params["norm"], cfg.norm_eps)
-    logits = embeds @ params["lm_head"]
+    with jax.named_scope("layers"):
+        x, pools = lax.scan(body, x, (params["layers"], pools))
+    with jax.named_scope("lm_head"):
+        embeds = rms_norm(x, params["norm"], cfg.norm_eps)
+        logits = embeds @ params["lm_head"]
     return logits[:, 0], embeds[:, 0], pools
 
 
@@ -162,35 +175,40 @@ def paged_verify_step(
     decode kernel is specialized to m=1 queries); the quantized round
     trip matches paged_decode_step's reference branch.
     """
-    params = jax.tree.map(lambda a: a.astype(compute_dtype), params)
+    with jax.named_scope("params_cast"):
+        params = jax.tree.map(lambda a: a.astype(compute_dtype), params)
     b, m = tokens.shape
     hd = cfg.head_dim
     max_seq = page_table.shape[1] * page_size
-    cos, sin = rope_table(max_seq, hd, cfg.rope_theta)
+    with jax.named_scope("rope"):
+        cos, sin = rope_table(max_seq, hd, cfg.rope_theta)
     positions = (
         seq_lens[:, None] + jnp.arange(m, dtype=jnp.int32)[None, :]
     ).astype(jnp.int32)  # (B, m)
-    x = params["embedding"][tokens]  # (B, m, D)
+    with jax.named_scope("embed"):
+        x = params["embedding"][tokens]  # (B, m, D)
 
-    page_ids = page_table[
-        jnp.arange(b)[:, None], positions // page_size
-    ]  # (B, m)
-    slots = positions % page_size
+    with jax.named_scope("kv_write"):  # each token's write target
+        page_ids = page_table[
+            jnp.arange(b)[:, None], positions // page_size
+        ]  # (B, m)
+        slots = positions % page_size
 
     quantized = quant != "none"
 
     def attend(q, layer_pools):
         if quantized:
-            k = kv_dequantize(
-                gather_pages(layer_pools["k"], page_table),
-                gather_pages(layer_pools["k_scale"], page_table),
-                compute_dtype,
-            )
-            v = kv_dequantize(
-                gather_pages(layer_pools["v"], page_table),
-                gather_pages(layer_pools["v_scale"], page_table),
-                compute_dtype,
-            )
+            with jax.named_scope("kv_gather"):
+                k = kv_dequantize(
+                    gather_pages(layer_pools["k"], page_table),
+                    gather_pages(layer_pools["k_scale"], page_table),
+                    compute_dtype,
+                )
+                v = kv_dequantize(
+                    gather_pages(layer_pools["v"], page_table),
+                    gather_pages(layer_pools["v_scale"], page_table),
+                    compute_dtype,
+                )
         else:
             k = gather_pages(layer_pools["k"], page_table)
             v = gather_pages(layer_pools["v"], page_table)
@@ -199,24 +217,31 @@ def paged_verify_step(
     def body(x, inp):
         layer, layer_pools = inp
         q, k, v = decode_layer_qkv(x, layer, cfg, cos, sin, positions)
-        if quantized:
-            qk, sk = kv_quantize(k, quant)
-            qv, sv = kv_quantize(v, quant)
-            layer_pools = {
-                "k": layer_pools["k"].at[page_ids, slots].set(qk),
-                "v": layer_pools["v"].at[page_ids, slots].set(qv),
-                "k_scale": layer_pools["k_scale"].at[page_ids, slots].set(sk),
-                "v_scale": layer_pools["v_scale"].at[page_ids, slots].set(sv),
-            }
-        else:
-            layer_pools = {
-                "k": layer_pools["k"].at[page_ids, slots].set(k),
-                "v": layer_pools["v"].at[page_ids, slots].set(v),
-            }
+        with jax.named_scope("kv_write"):
+            if quantized:
+                qk, sk = kv_quantize(k, quant)
+                qv, sv = kv_quantize(v, quant)
+                layer_pools = {
+                    "k": layer_pools["k"].at[page_ids, slots].set(qk),
+                    "v": layer_pools["v"].at[page_ids, slots].set(qv),
+                    "k_scale": layer_pools["k_scale"]
+                    .at[page_ids, slots]
+                    .set(sk),
+                    "v_scale": layer_pools["v_scale"]
+                    .at[page_ids, slots]
+                    .set(sv),
+                }
+            else:
+                layer_pools = {
+                    "k": layer_pools["k"].at[page_ids, slots].set(k),
+                    "v": layer_pools["v"].at[page_ids, slots].set(v),
+                }
         o = attend(q, layer_pools)
         return decode_layer_out(x, layer, cfg, o), layer_pools
 
-    x, pools = lax.scan(body, x, (params["layers"], pools))
-    embeds = rms_norm(x, params["norm"], cfg.norm_eps)
-    logits = embeds @ params["lm_head"]
+    with jax.named_scope("layers"):
+        x, pools = lax.scan(body, x, (params["layers"], pools))
+    with jax.named_scope("lm_head"):
+        embeds = rms_norm(x, params["norm"], cfg.norm_eps)
+        logits = embeds @ params["lm_head"]
     return logits, embeds, pools

@@ -51,6 +51,7 @@ import numpy as np
 
 from fms_fsdp_tpu.models.generation import sample_token
 from fms_fsdp_tpu.obs.registry import MetricRegistry
+from fms_fsdp_tpu.obs.spans import done, span
 from fms_fsdp_tpu.serve.families import FAMILY_CODES, resolve_adapter
 from fms_fsdp_tpu.serve.scheduler import (
     REJECT_DEADLINE_UNMEETABLE,
@@ -272,6 +273,12 @@ class ServingEngine:
         asserts: these validate USER input and must survive python -O —
         an accepted never-fits request would head-of-line-block the
         FIFO queue forever."""
+        with span("submit", prompt_tokens=len(prompt)):
+            req = self._submit(prompt, max_new_tokens, deadline_s)
+            done("submit", rid=req.rid, rejected=0)
+        return req
+
+    def _submit(self, prompt, max_new_tokens, deadline_s) -> Request:
         deadline = None if deadline_s is None else self.clock() + deadline_s
         # a speculative verify step writes up to spec_draft_tokens
         # positions past the committed length before the accept rule
@@ -351,6 +358,7 @@ class ServingEngine:
 
     def _reject(self, reason: str, msg: str):
         self.registry.counter(f"serve.requests_rejected.{reason}").add()
+        done("submit", rejected=1, reason=reason)
         raise RequestRejected(reason, msg)
 
     def submit_handoff(
@@ -371,6 +379,12 @@ class ServingEngine:
         ValueError) on malformed or geometry-mismatched bytes and
         :class:`RequestRejected` on admission failure, same contract as
         :meth:`submit`."""
+        with span("submit", handoff_bytes=len(data)):
+            req = self._submit_handoff(data, max_new_tokens, deadline_s)
+            done("submit", rid=req.rid, rejected=0)
+        return req
+
+    def _submit_handoff(self, data, max_new_tokens, deadline_s) -> Request:
         from fms_fsdp_tpu.serve.disagg import unpack_handoff
 
         if not self.adapter.supports_handoff:
@@ -435,6 +449,29 @@ class ServingEngine:
     # -- prefill -----------------------------------------------------------
 
     def _prefill_request(self, req: Request, slot: int) -> None:
+        """One admission's device work, under the ``prefill`` span: a
+        handoff import (no prefill program: ``padded_tokens`` 0), the
+        staging of a chunked prefill, or the whole-prompt prefill."""
+        handoff = req.handoff_in is not None
+        p = len(req.prompt if handoff else req.resume_prompt())
+        padded = 0 if handoff else self.adapter._padded_len(
+            p, self.serve_cfg.prefill_bucket
+        )
+        built = self.adapter.prefill_programs_built
+        with span(
+            "prefill",
+            step=self.iterations,
+            rid=req.rid,
+            prompt_tokens=p,
+            padded_tokens=padded,
+        ):
+            self._prefill_admitted(req, slot)
+        self.registry.counter("serve.prefill_padded_tokens").add(padded)
+        self.registry.counter("serve.prefill_programs_built").add(
+            self.adapter.prefill_programs_built - built
+        )
+
+    def _prefill_admitted(self, req: Request, slot: int) -> None:
         if req.handoff_in is not None:
             self._import_handoff(req, slot)
             return
@@ -460,16 +497,17 @@ class ServingEngine:
         """Shared tail of whole-prompt and chunked prefill: sample the
         first token from the last real prompt position's logits row,
         record TTFT, promote the stream into the decode batch."""
-        self._key, sub = jax.random.split(self._key)
-        tok = int(
-            sample_token(
-                row[None],
-                sub,
-                self.serve_cfg.temperature,
-                self.serve_cfg.top_k,
-                self.serve_cfg.do_sample,
-            )[0]
-        )
+        with span("prefill.sample", step=self.iterations, rid=req.rid):
+            self._key, sub = jax.random.split(self._key)
+            tok = int(
+                sample_token(
+                    row[None],
+                    sub,
+                    self.serve_cfg.temperature,
+                    self.serve_cfg.top_k,
+                    self.serve_cfg.do_sample,
+                )[0]
+            )
         now = self.clock()
         if req.first_token_time is None:
             req.first_token_time = now
@@ -591,19 +629,66 @@ class ServingEngine:
     def step(self) -> List[Request]:
         """One continuous-batching iteration: expire, admit (+prefill),
         one ragged decode step, harvest finishes. Returns the requests
-        that finished during this iteration."""
-        now = self.clock()
+        that finished during this iteration.
+
+        Every phase runs under a host span (obs/spans.py: ``serve/step``
+        and its children, each carrying ``step=<iterations>``), which
+        costs a flag check while no profiler session runs."""
         self.iterations += 1
-        for r in self.scheduler.expire_queued(now):
+        it = self.iterations
+        reg = self.registry
+        reg.counter("serve.steps").add()
+        with span(
+            "step",
+            step=it,
+            queued=self.scheduler.queue_depth(),
+            busy=sum(r is not None for r in self._slots),
+        ):
+            with span("expire", step=it):
+                expired = self._expire(self.clock())
+                done("expire", step=it, expired=expired)
+            with span("admit", step=it):
+                admitted = self._admit()
+                done("admit", step=it, admitted=admitted)
+            chunks = self._advance_chunks(it)
+            if admitted or chunks:
+                reg.counter("serve.steps_with_prefill").add()
+            with span("grow", step=it):
+                evicted = self._grow()
+                done("grow", step=it, evicted=evicted)
+            self._decode(it)
+            with span("publish", step=it):
+                reg.gauge("serve.queue_depth").set(
+                    self.scheduler.queue_depth()
+                )
+                reg.gauge("serve.kv_pages_in_use").set(
+                    self.adapter.pages_in_use
+                )
+                if self._decode_wall > 0:
+                    reg.gauge("serve.tokens_per_s").set(
+                        self._decode_tokens / self._decode_wall
+                    )
+        out, self._finished_buf = self._finished_buf, []
+        return out
+
+    def _expire(self, now: float) -> int:
+        """Deadline expiry at the step boundary -> requests expired."""
+        queued = self.scheduler.expire_queued(now)
+        for r in queued:
             self.registry.counter("serve.requests_expired").add()
-        # in-flight deadline expiry at the step boundary: a running
-        # request past its deadline frees its slot and pages NOW —
-        # decoding tokens nobody can use any more starves streams that
-        # can still meet theirs
+        # in flight too: a running request past its deadline frees its
+        # slot and pages NOW — decoding tokens nobody can use any more
+        # starves streams that can still meet theirs
         running = [r for r in self._slots if r is not None]
-        for r in self.scheduler.expire_inflight(running, now):
+        inflight = self.scheduler.expire_inflight(running, now)
+        for r in inflight:
             self._release_slot(r, self._slots.index(r))
             self.registry.counter("serve.requests_expired_inflight").add()
+        return len(queued) + len(inflight)
+
+    def _admit(self) -> int:
+        """The admission loop -> requests admitted (each prefilled, or
+        staged for chunked prefill, or imported from a handoff)."""
 
         def can_fit(req: Request) -> bool:
             if req.handoff_in is not None:
@@ -623,6 +708,7 @@ class ServingEngine:
         # when two requests each fit alone but not together. Slots are
         # recounted live too: a request that finishes inside its own
         # prefill releases its slot immediately.
+        admitted = 0
         for _ in range(0 if self._draining else
                        self.serve_cfg.max_prefill_per_step):
             if self._slots.count(None) <= 0:
@@ -630,29 +716,48 @@ class ServingEngine:
             got = self.scheduler.admit(1, can_fit)
             if not got:
                 break
-            slot = self._slots.index(None)
-            self._prefill_request(got[0], slot)
+            req = got[0]
+            if req.evictions == 0:
+                self.registry.hist("serve.queue_wait_s").record(
+                    req.admit_time - req.submit_time
+                )
+            admitted += 1
+            self._prefill_request(req, self._slots.index(None))
+        return admitted
 
-        # advance each staged chunked prefill by ONE chunk, interleaved
-        # with the decode below: the chunk advance does not consume the
-        # admit budget, so short requests keep admitting (and every
-        # running stream keeps decoding) while a long prompt streams in
+    def _advance_chunks(self, it: int) -> int:
+        """Advance each staged chunked prefill by ONE chunk, interleaved
+        with the decode that follows: the chunk advance does not consume
+        the admit budget, so short requests keep admitting (and every
+        running stream keeps decoding) while a long prompt streams in.
+        -> chunks advanced."""
+        chunks = 0
         for rid in list(self._chunking):
             req, slot = self._chunking[rid]
-            row = self.adapter.prefill_chunk(rid)
-            self._prefill_chunks += 1
-            self.registry.counter("serve.prefill_chunks").add()
-            if row is not None:
-                del self._chunking[rid]
-                self._complete_prefill(
-                    req, slot, row, len(req.resume_prompt())
-                )
+            built = self.adapter.prefill_programs_built
+            with span("prefill_chunk", step=it, rid=rid):
+                row = self.adapter.prefill_chunk(rid)
+                chunks += 1
+                self._prefill_chunks += 1
+                self.registry.counter("serve.prefill_chunks").add()
+                if row is not None:
+                    del self._chunking[rid]
+                    self._complete_prefill(
+                        req, slot, row, len(req.resume_prompt())
+                    )
+            self.registry.counter("serve.prefill_programs_built").add(
+                self.adapter.prefill_programs_built - built
+            )
+        return chunks
 
-        # token-granular state growth; evict (LIFO) when the pool is
-        # dry. Constant-state families (mamba slab) always grow free —
-        # the loop never spins for them. Speculative streams reserve
-        # draft headroom: the verify step writes spec_draft_tokens
-        # positions past the committed length before rollback.
+    def _grow(self) -> int:
+        """Token-granular state growth; evict (LIFO) when the pool is
+        dry. Constant-state families (mamba slab) always grow free — the
+        loop never spins for them. Speculative streams reserve draft
+        headroom: the verify step writes spec_draft_tokens positions
+        past the committed length before rollback. -> requests evicted.
+        """
+        evicted = 0
         draft = self.adapter.spec_draft_tokens
         for slot, req in enumerate(self._slots):
             if req is None or req.rid in self._chunking:
@@ -662,9 +767,14 @@ class ServingEngine:
                 victim = self.scheduler.evict_victim(self._admit_order)
                 assert victim is not None, "no victim but pool exhausted"
                 self._evict(victim)
+                evicted += 1
                 if victim is req:
                     break
+        return evicted
 
+    def _decode(self, it: int) -> None:
+        """One decode step (plain or speculative) over the live slots,
+        and the commit of its tokens."""
         slot_rids = [
             r.rid if r is not None and r.rid not in self._chunking else None
             for r in self._slots
@@ -674,60 +784,65 @@ class ServingEngine:
             for slot, r in enumerate(self._slots)
             if r is not None and r.rid not in self._chunking
         ]
-        if active and self.adapter.speculative:
+        if not active:
+            return
+        reg = self.registry
+        uploads = self.adapter.page_table_uploads
+        finished = len(self._finished_buf)
+        with span(
+            "decode",
+            step=it,
+            live=len(active),
+            kv_tokens=int(sum(self._lens[slot] for slot, _ in active)),
+        ):
             t0 = self.clock()
-            emit, counts, logits = self.adapter.decode_spec(
-                slot_rids, self._lens, self._tokens
-            )
+            if self.adapter.speculative:
+                emit, counts, logits = self.adapter.decode_spec(
+                    slot_rids, self._lens, self._tokens
+                )
+            else:
+                self._key, sub = jax.random.split(self._key)
+                toks, logits = self.adapter.decode(
+                    slot_rids, self._lens, self._tokens, sub
+                )
             self.last_logits = logits
             self._decode_wall += self.clock() - t0
-            for slot, req in active:
-                self._spec_draft_total += draft
-                self._spec_accept_total += int(counts[slot]) - 1
-                # commit the accepted prefix token-by-token: eos and
-                # max_new checks run per token, so truncation matches
-                # the non-speculative stream exactly
-                for j in range(int(counts[slot])):
-                    self._lens[slot] += 1
-                    tok = int(emit[slot, j])
-                    req.generated.append(tok)
-                    self._tokens[slot] = tok
-                    self._decode_tokens += 1
-                    self.registry.counter("serve.decode_tokens").add()
-                    if self._finish_if_done(req, slot):
-                        break
-        elif active:
-            t0 = self.clock()
-            self._key, sub = jax.random.split(self._key)
-            toks, logits = self.adapter.decode(
-                slot_rids,
-                self._lens,
-                self._tokens,
-                sub,
-            )
-            self.last_logits = logits
-            self._decode_wall += self.clock() - t0
-            self._decode_tokens += len(active)
-            self.registry.counter("serve.decode_tokens").add(len(active))
-            for slot, req in active:
-                self._lens[slot] += 1
-                tok = int(toks[slot])
-                req.generated.append(tok)
-                self._tokens[slot] = tok
-                self._finish_if_done(req, slot)
-
-        self.registry.gauge("serve.queue_depth").set(
-            self.scheduler.queue_depth()
+            with span("decode.commit", step=it):
+                if self.adapter.speculative:
+                    draft = self.adapter.spec_draft_tokens
+                    for slot, req in active:
+                        self._spec_draft_total += draft
+                        self._spec_accept_total += int(counts[slot]) - 1
+                        # commit the accepted prefix token-by-token: eos
+                        # and max_new checks run per token, so truncation
+                        # matches the non-speculative stream exactly
+                        for j in range(int(counts[slot])):
+                            self._lens[slot] += 1
+                            tok = int(emit[slot, j])
+                            req.generated.append(tok)
+                            self._tokens[slot] = tok
+                            self._decode_tokens += 1
+                            reg.counter("serve.decode_tokens").add()
+                            if self._finish_if_done(req, slot):
+                                break
+                else:
+                    self._decode_tokens += len(active)
+                    reg.counter("serve.decode_tokens").add(len(active))
+                    for slot, req in active:
+                        self._lens[slot] += 1
+                        tok = int(toks[slot])
+                        req.generated.append(tok)
+                        self._tokens[slot] = tok
+                        self._finish_if_done(req, slot)
+                done(
+                    "decode.commit",
+                    step=it,
+                    finished=len(self._finished_buf) - finished,
+                )
+        reg.counter("serve.decode_live_slots").add(len(active))
+        reg.counter("serve.page_table_uploads").add(
+            self.adapter.page_table_uploads - uploads
         )
-        self.registry.gauge("serve.kv_pages_in_use").set(
-            self.adapter.pages_in_use
-        )
-        if self._decode_wall > 0:
-            self.registry.gauge("serve.tokens_per_s").set(
-                self._decode_tokens / self._decode_wall
-            )
-        out, self._finished_buf = self._finished_buf, []
-        return out
 
     def run(self, max_steps: int = 100000) -> None:
         """Drive step() until queue and slots drain (or max_steps)."""

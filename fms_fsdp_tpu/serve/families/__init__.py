@@ -43,6 +43,7 @@ from fms_fsdp_tpu.models.configs import (
     MambaConfig,
     MixtralConfig,
 )
+from fms_fsdp_tpu.obs.spans import span
 
 # the wire encoding of a family in numeric-only maps (obs schema v12
 # "serving", BENCH_SERVING.json rows): family = FAMILY_CODES[name]
@@ -235,6 +236,11 @@ class FamilyAdapter:
     # can advance a prompt in slices through prefill_start/prefill_chunk
     # set this; the engine rejects the knob for the rest at build
     supports_chunked_prefill: bool = False
+    # what the adapter did that only it can see, counted where it
+    # happens; the engine adds each step's difference to its registry
+    # (serve.prefill_programs_built, serve.page_table_uploads)
+    prefill_programs_built: int = 0
+    page_table_uploads: int = 0
 
     def admission_error(self, prompt_len: int, max_new: int) -> Optional[str]:
         raise NotImplementedError
@@ -432,6 +438,21 @@ class FamilyAdapter:
         whose only decode state is paged KV — that grows, and is
         reported through kv pages instead)."""
         return 0
+
+    def _upload_table(self, slot_rids) -> None:
+        """The device copy of the page table (``self._table_dev``), made
+        again only when the allocator or the slots' membership changed:
+        steady-state decode re-uploads nothing. Under the
+        ``decode.table`` span; counts ``page_table_uploads``."""
+        tkey = (self.cache.table_version, tuple(slot_rids))
+        stale = tkey != self._table_key
+        with span("decode.table", uploaded=int(stale)):
+            if stale:
+                self._table_key = tkey
+                self._table_dev = self._dev(
+                    self.cache.page_table(list(slot_rids), self.max_pages)
+                )
+                self.page_table_uploads += 1
 
     def _padded_len(self, n: int, bucket: int) -> int:
         b = max(1, bucket)

@@ -17,6 +17,7 @@ import numpy as np
 
 from fms_fsdp_tpu.models.generation import decode_chunk, prefill, sample_token
 from fms_fsdp_tpu.models.speculative import speculator_propose
+from fms_fsdp_tpu.obs.spans import span
 from fms_fsdp_tpu.serve.decode import paged_decode_step, paged_verify_step
 from fms_fsdp_tpu.serve.families import FamilyAdapter
 from fms_fsdp_tpu.serve.kv_cache import RESERVED_PAGES, PagedKVCache
@@ -219,26 +220,25 @@ class LlamaAdapter(FamilyAdapter):
         self._spec_fn = jax.jit(_spec_step, donate_argnums=(2,))
 
     def decode_spec(self, slot_rids, lens, tokens):
-        tkey = (self.cache.table_version, tuple(slot_rids))
-        if tkey != self._table_key:
-            self._table_key = tkey
-            self._table_dev = self._dev(
-                self.cache.page_table(list(slot_rids), self.max_pages)
+        self._upload_table(slot_rids)
+        with span("decode.dispatch"):
+            emit, counts, logits, embeds, pools = self._spec_fn(
+                self.params,
+                self._spec_params,
+                self.cache.pools,
+                self._table_dev,
+                self._dev(lens),
+                self._dev(tokens),
+                self._dev(self._spec_embed),
             )
-        emit, counts, logits, embeds, pools = self._spec_fn(
-            self.params,
-            self._spec_params,
-            self.cache.pools,
-            self._table_dev,
-            self._dev(lens),
-            self._dev(tokens),
-            self._dev(self._spec_embed),
-        )
-        self.cache.pools = pools
-        # np.array (not asarray): prefill writes rows in place when a
-        # new stream lands in a slot, so the host copy must be writable
-        self._spec_embed = np.array(embeds)
-        return np.asarray(emit), np.asarray(counts), logits
+            self.cache.pools = pools
+        with span("decode.wait"):
+            # np.array (not asarray): prefill writes rows in place when a
+            # new stream lands in a slot, so the host copy must be
+            # writable
+            self._spec_embed = np.array(embeds)
+            emit, counts = np.asarray(emit), np.asarray(counts)
+        return emit, counts, logits
 
     # -- capacity ----------------------------------------------------------
 
@@ -279,6 +279,7 @@ class LlamaAdapter(FamilyAdapter):
         key = (p_len, s_pad, full_logits)
         fn = self._prefill_cache.get(key)
         if fn is None:
+            self.prefill_programs_built += 1
             fn = jax.jit(
                 partial(
                     prefill,
@@ -297,13 +298,19 @@ class LlamaAdapter(FamilyAdapter):
         s_pad = self.cache.pages_needed(p_pad) * self.page_size
         ok = self.cache.ensure(rid, p_pad)
         assert ok, "admission checked capacity; ensure cannot fail here"
-        toks = np.zeros((1, p_pad), np.int32)
-        toks[0, :p] = prompt
         full_logits = p_pad != p
-        logits, embeds, kv = self._get_prefill(p_pad, s_pad, full_logits)(
-            self.params, self._dev(toks)
-        )
-        self.cache.write_prompt(rid, kv["k"][:, 0], kv["v"][:, 0])
+        built = self.prefill_programs_built
+        fn = self._get_prefill(p_pad, s_pad, full_logits)
+        with span(
+            "prefill.dispatch",
+            rid=rid,
+            built=self.prefill_programs_built - built,
+        ):
+            toks = np.zeros((1, p_pad), np.int32)
+            toks[0, :p] = prompt
+            logits, embeds, kv = fn(self.params, self._dev(toks))
+        with span("prefill.write_pages", rid=rid):
+            self.cache.write_prompt(rid, kv["k"][:, 0], kv["v"][:, 0])
         if self.speculative:
             # seed the draft chain with the hidden state that produced
             # this stream's first token
@@ -321,6 +328,7 @@ class LlamaAdapter(FamilyAdapter):
         key = ("chunk", m, s_pad)
         fn = self._prefill_cache.get(key)
         if fn is None:
+            self.prefill_programs_built += 1
             fn = jax.jit(
                 partial(
                     decode_chunk,
@@ -399,7 +407,8 @@ class LlamaAdapter(FamilyAdapter):
         tail = st["s_pad"] - st["p_pad"]
         pad = ((0, 0), (0, 0), (0, tail), (0, 0), (0, 0))
         cache = {n: jnp.pad(a, pad) for n, a in st["cache"].items()}
-        self.cache.write_prompt(rid, cache["k"][:, 0], cache["v"][:, 0])
+        with span("prefill.write_pages", rid=rid):
+            self.cache.write_prompt(rid, cache["k"][:, 0], cache["v"][:, 0])
         if self.speculative:
             self._spec_embed[st["slot"]] = np.asarray(st["embed"])
         row = st["row"]
@@ -409,21 +418,19 @@ class LlamaAdapter(FamilyAdapter):
     # -- decode ------------------------------------------------------------
 
     def decode(self, slot_rids, lens, tokens, key):
-        # cached device page table, keyed on (allocator version, slot
-        # membership): steady-state decode re-uploads nothing
-        tkey = (self.cache.table_version, tuple(slot_rids))
-        if tkey != self._table_key:
-            self._table_key = tkey
-            self._table_dev = self._dev(
-                self.cache.page_table(list(slot_rids), self.max_pages)
+        self._upload_table(slot_rids)
+        # the jitted call returns before the device ends; the read of the
+        # sampled tokens is what waits for it
+        with span("decode.dispatch"):
+            toks, logits, pools = self._decode_fn(
+                self.params,
+                self.cache.pools,
+                self._table_dev,
+                self._dev(lens),
+                self._dev(tokens),
+                self._dev(key),
             )
-        toks, logits, pools = self._decode_fn(
-            self.params,
-            self.cache.pools,
-            self._table_dev,
-            self._dev(lens),
-            self._dev(tokens),
-            self._dev(key),
-        )
-        self.cache.pools = pools
-        return np.asarray(toks), logits
+            self.cache.pools = pools
+        with span("decode.wait"):
+            toks = np.asarray(toks)
+        return toks, logits

@@ -45,6 +45,7 @@ from fms_fsdp_tpu.models.mamba import (
     mamba_prefill,
     mamba_state_bytes_per_stream,
 )
+from fms_fsdp_tpu.obs.spans import span
 from fms_fsdp_tpu.serve.disagg.slab import (
     SLAB_CODEC_VERSION,
     check_slab_header,
@@ -222,6 +223,7 @@ class MambaAdapter(FamilyAdapter):
         key = (p_pad, kv_len)
         fn = self._prefill_cache.get(key)
         if fn is None:
+            self.prefill_programs_built += 1
             fn = jax.jit(
                 partial(
                     mamba_prefill,
@@ -241,49 +243,60 @@ class MambaAdapter(FamilyAdapter):
             kv_len = self.cache.pages_needed(p_pad) * self.page_size
             ok = self.cache.ensure(rid, p_pad)
             assert ok, "admission checked capacity; ensure cannot fail here"
-        toks = np.zeros((1, p_pad), np.int32)
-        toks[0, :p] = prompt
-        logits, st1, kv = self._get_prefill(p_pad, kv_len)(
-            self.params, jnp.asarray(toks), jnp.asarray([p], np.int32)
-        )
-        # land the 1-row prefill state in the stream's slab slice
-        self._state = jax.tree.map(
-            lambda s, n: s.at[slot].set(n[0]), self._state, st1
-        )
-        if self._hybrid:
-            self.cache.write_prompt(rid, kv["k"][:, 0], kv["v"][:, 0])
+        built = self.prefill_programs_built
+        fn = self._get_prefill(p_pad, kv_len)
+        with span(
+            "prefill.dispatch",
+            rid=rid,
+            built=self.prefill_programs_built - built,
+        ):
+            toks = np.zeros((1, p_pad), np.int32)
+            toks[0, :p] = prompt
+            logits, st1, kv = fn(
+                self.params, jnp.asarray(toks), jnp.asarray([p], np.int32)
+            )
+        with span("prefill.write_pages", rid=rid):
+            # land the 1-row prefill state in the stream's slab slice
+            self._state = jax.tree.map(
+                lambda s, n: s.at[slot].set(n[0]), self._state, st1
+            )
+            if self._hybrid:
+                self.cache.write_prompt(rid, kv["k"][:, 0], kv["v"][:, 0])
         # prefill already selects each row's last real position
         return logits[0]
 
     # -- decode ------------------------------------------------------------
 
     def decode(self, slot_rids, lens, tokens, key):
+        # the jitted call returns before the device ends; the read of the
+        # sampled tokens is what waits for it
         if not self._hybrid:
-            toks, logits, self._state = self._decode_fn(
+            with span("decode.dispatch"):
+                toks, logits, self._state = self._decode_fn(
+                    self.params,
+                    self._state,
+                    jnp.asarray(lens),
+                    jnp.asarray(tokens),
+                    key,
+                )
+            with span("decode.wait"):
+                toks = np.asarray(toks)
+            return toks, logits
+        self._upload_table(slot_rids)
+        with span("decode.dispatch"):
+            toks, logits, self._state, pools = self._decode_fn(
                 self.params,
                 self._state,
+                self.cache.pools,
+                self._table_dev,
                 jnp.asarray(lens),
                 jnp.asarray(tokens),
                 key,
             )
-            return np.asarray(toks), logits
-        tkey = (self.cache.table_version, tuple(slot_rids))
-        if tkey != self._table_key:
-            self._table_key = tkey
-            self._table_dev = jnp.asarray(
-                self.cache.page_table(list(slot_rids), self.max_pages)
-            )
-        toks, logits, self._state, pools = self._decode_fn(
-            self.params,
-            self._state,
-            self.cache.pools,
-            self._table_dev,
-            jnp.asarray(lens),
-            jnp.asarray(tokens),
-            key,
-        )
-        self.cache.pools = pools
-        return np.asarray(toks), logits
+            self.cache.pools = pools
+        with span("decode.wait"):
+            toks = np.asarray(toks)
+        return toks, logits
 
     # -- disaggregation: the slab codec (serve/disagg/slab.py) -------------
 

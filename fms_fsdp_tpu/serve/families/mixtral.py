@@ -27,8 +27,66 @@ from fms_fsdp_tpu.models.mixtral import (
     mixtral_paged_decode_step,
     mixtral_prefill,
 )
+from fms_fsdp_tpu.obs.spans import span
 from fms_fsdp_tpu.serve.families import FamilyAdapter
 from fms_fsdp_tpu.serve.kv_cache import RESERVED_PAGES, PagedKVCache
+
+
+def page_geometry(model_cfg, scfg):
+    """``(page_size, block_kv, tune_how, max_pages, num_pages)`` of the
+    paged cache a mixtral engine builds for these two configs: the page
+    size through the kernel-tuning table (or pinned by
+    ``scfg.page_size``), the pages one sequence can hold, the pool."""
+    from fms_fsdp_tpu.tune.lookup import resolve_paged_decode
+
+    page_size, block_kv, tune_how = resolve_paged_decode(
+        scfg.max_batch,
+        model_cfg.nheads,
+        model_cfg.n_kv_heads,
+        model_cfg.head_dim,
+        scfg.max_seq_len,
+        scfg.compute_dtype,
+        requested_page_size=scfg.page_size or None,
+    )
+    assert scfg.max_seq_len % page_size == 0, (scfg.max_seq_len, page_size)
+    max_pages = scfg.max_seq_len // page_size
+    num_pages = scfg.num_pages or (
+        scfg.max_batch * max_pages + RESERVED_PAGES
+    )
+    return page_size, block_kv, tune_how, max_pages, num_pages
+
+
+def decode_program(model_cfg, scfg, page_size: int, compute_dtype):
+    """The jitted decode step of a mixtral engine: one ragged paged step
+    over ``scfg.max_batch`` slots and the sampler, pools donated. A
+    function of the two configs alone, so that anyone can build the
+    *same* program again and read its compiled HLO (the scopes of its
+    instructions: obs/scopes.py::scope_table). The traced function keeps
+    the name ``_step``: the profiler's trace shows the program as
+    ``jit__step``, and readers find it by that name.
+
+    ``(params, pools, page_table, seq_lens, tokens, key) ->
+    (tokens (B,) int32, logits (B, V), pools)``."""
+    moe_impl = getattr(scfg, "moe_impl", "routed")
+
+    def _step(params, pools, page_table, seq_lens, tokens, key):
+        logits, pools = mixtral_paged_decode_step(
+            params,
+            pools,
+            page_table,
+            seq_lens,
+            tokens,
+            model_cfg,
+            page_size=page_size,
+            compute_dtype=compute_dtype,
+            moe_impl=moe_impl,
+        )
+        tok = sample_token(
+            logits, key, scfg.temperature, scfg.top_k, scfg.do_sample
+        )
+        return tok.astype(jnp.int32), logits, pools
+
+    return jax.jit(_step, donate_argnums=(1,))
 
 
 class MixtralAdapter(FamilyAdapter):
@@ -38,7 +96,6 @@ class MixtralAdapter(FamilyAdapter):
 
     def __init__(self, params, model_cfg, scfg, compute_dtype=None):
         from fms_fsdp_tpu.serve.engine import _DTYPES
-        from fms_fsdp_tpu.tune.lookup import resolve_paged_decode
 
         self.params = params
         self.model_cfg = model_cfg
@@ -80,23 +137,14 @@ class MixtralAdapter(FamilyAdapter):
         params = self.params
 
         nlayers = int(params["layers"]["wq"].shape[0])
-        page_size, self.block_kv, self.tune_how = resolve_paged_decode(
-            scfg.max_batch,
-            cfg.nheads,
-            cfg.n_kv_heads,
-            cfg.head_dim,
-            scfg.max_seq_len,
-            scfg.compute_dtype,
-            requested_page_size=scfg.page_size or None,
-        )
-        assert scfg.max_seq_len % page_size == 0, (
-            scfg.max_seq_len, page_size
-        )
+        (
+            page_size,
+            self.block_kv,
+            self.tune_how,
+            self.max_pages,
+            num_pages,
+        ) = page_geometry(cfg, scfg)
         self.page_size = page_size
-        self.max_pages = scfg.max_seq_len // page_size
-        num_pages = scfg.num_pages or (
-            scfg.max_batch * self.max_pages + RESERVED_PAGES
-        )
         self.cache = PagedKVCache(
             nlayers,
             num_pages,
@@ -113,25 +161,9 @@ class MixtralAdapter(FamilyAdapter):
         self._prefill_cache: Dict = {}
         self._table_key = None
         self._table_dev = None
-
-        def _step(params, pools, page_table, seq_lens, tokens, key):
-            logits, pools = mixtral_paged_decode_step(
-                params,
-                pools,
-                page_table,
-                seq_lens,
-                tokens,
-                cfg,
-                page_size=page_size,
-                compute_dtype=self.compute_dtype,
-                moe_impl=moe_impl,
-            )
-            tok = sample_token(
-                logits, key, scfg.temperature, scfg.top_k, scfg.do_sample
-            )
-            return tok.astype(jnp.int32), logits, pools
-
-        self._decode_fn = jax.jit(_step, donate_argnums=(1,))
+        self._decode_fn = decode_program(
+            cfg, scfg, page_size, self.compute_dtype
+        )
 
     # -- capacity (same page math as llama) --------------------------------
 
@@ -165,6 +197,7 @@ class MixtralAdapter(FamilyAdapter):
         key = (p_len, s_pad, full_logits)
         fn = self._prefill_cache.get(key)
         if fn is None:
+            self.prefill_programs_built += 1
             fn = jax.jit(
                 partial(
                     mixtral_prefill,
@@ -183,32 +216,38 @@ class MixtralAdapter(FamilyAdapter):
         s_pad = self.cache.pages_needed(p_pad) * self.page_size
         ok = self.cache.ensure(rid, p_pad)
         assert ok, "admission checked capacity; ensure cannot fail here"
-        toks = np.zeros((1, p_pad), np.int32)
-        toks[0, :p] = prompt
         full_logits = p_pad != p
-        logits, _, kv = self._get_prefill(p_pad, s_pad, full_logits)(
-            self.params, self._dev(toks)
-        )
-        self.cache.write_prompt(rid, kv["k"][:, 0], kv["v"][:, 0])
+        built = self.prefill_programs_built
+        fn = self._get_prefill(p_pad, s_pad, full_logits)
+        with span(
+            "prefill.dispatch",
+            rid=rid,
+            built=self.prefill_programs_built - built,
+        ):
+            toks = np.zeros((1, p_pad), np.int32)
+            toks[0, :p] = prompt
+            logits, _, kv = fn(self.params, self._dev(toks))
+        with span("prefill.write_pages", rid=rid):
+            self.cache.write_prompt(rid, kv["k"][:, 0], kv["v"][:, 0])
         row = logits[0, p - 1] if full_logits else logits[0, 0]
         return np.asarray(row) if self.mesh is not None else row
 
     # -- decode ------------------------------------------------------------
 
     def decode(self, slot_rids, lens, tokens, key):
-        tkey = (self.cache.table_version, tuple(slot_rids))
-        if tkey != self._table_key:
-            self._table_key = tkey
-            self._table_dev = self._dev(
-                self.cache.page_table(list(slot_rids), self.max_pages)
+        self._upload_table(slot_rids)
+        # the jitted call returns before the device ends; the read of the
+        # sampled tokens is what waits for it
+        with span("decode.dispatch"):
+            toks, logits, pools = self._decode_fn(
+                self.params,
+                self.cache.pools,
+                self._table_dev,
+                self._dev(lens),
+                self._dev(tokens),
+                self._dev(key),
             )
-        toks, logits, pools = self._decode_fn(
-            self.params,
-            self.cache.pools,
-            self._table_dev,
-            self._dev(lens),
-            self._dev(tokens),
-            self._dev(key),
-        )
-        self.cache.pools = pools
-        return np.asarray(toks), logits
+            self.cache.pools = pools
+        with span("decode.wait"):
+            toks = np.asarray(toks)
+        return toks, logits

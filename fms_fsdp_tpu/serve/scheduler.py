@@ -90,6 +90,9 @@ class Request:
     # runtime bookkeeping (engine-owned)
     generated: List[int] = field(default_factory=list)
     submit_time: float = 0.0
+    # the instant of the latest admission (scheduler clock): queue wait
+    # is admit_time - submit_time at the first one
+    admit_time: Optional[float] = None
     first_token_time: Optional[float] = None
     finish_time: Optional[float] = None
     evictions: int = 0
@@ -219,6 +222,7 @@ class ContinuousBatchingScheduler:
                 break
             self.queue.popleft()
             head.state = RUNNING
+            head.admit_time = self.clock()
             out.append(head)
             free_slots -= 1
             self.admitted += 1
