@@ -33,6 +33,7 @@ The training path also returns the load-balancing auxiliary loss
 """
 
 import functools
+import itertools
 import math
 from typing import Any, Dict, List, Optional
 
@@ -178,22 +179,34 @@ def _moe_stats(aux, keep=None):
     return {"balance": aux, "drop_frac": drop}
 
 
+def _expert_mix(top_idx, top_w, E: int):
+    """The renormalized top-k weights as a (B, S, E) fp32 mixture over
+    all experts: exactly zero for an expert the row did not choose."""
+    return jnp.sum(
+        jax.nn.one_hot(top_idx, E, dtype=jnp.float32) * top_w[..., None],
+        axis=-2,
+    )
+
+
+def _all_experts_swiglu(h, lp):
+    """Every expert's SwiGLU of every row, over the layer's stacked
+    weights where they lie. h (B, S, D); w1/w3 (E, D, H); w2 (E, H, D).
+    Returns (B, S, E, D)."""
+    return jnp.einsum(
+        "bseh,ehd->bsed",
+        jax.nn.silu(jnp.einsum("bsd,edh->bseh", h, lp["w1"]))
+        * jnp.einsum("bsd,edh->bseh", h, lp["w3"]),
+        lp["w2"],
+    )
+
+
 @scoped("moe_dense")
 def _moe_ffn_dense(h, lp, cfg: MixtralConfig):
     """Dense-mix top-k MoE SwiGLU (every expert computes every token).
     h (B, S, D); w1/w3 (E, D, H); w2 (E, H, D)."""
     top_idx, top_w, aux = _router(h, lp["gate"], cfg)
-    E = cfg.num_experts
-    mix = jnp.sum(
-        jax.nn.one_hot(top_idx, E, dtype=jnp.float32) * top_w[..., None],
-        axis=-2,
-    )  # (B, S, E)
-    expert_out = jnp.einsum(
-        "bseh,ehd->bsed",
-        jax.nn.silu(jnp.einsum("bsd,edh->bseh", h, lp["w1"]))
-        * jnp.einsum("bsd,edh->bseh", h, lp["w3"]),
-        lp["w2"],
-    )  # (B, S, E, D)
+    mix = _expert_mix(top_idx, top_w, cfg.num_experts)  # (B, S, E)
+    expert_out = _all_experts_swiglu(h, lp)  # (B, S, E, D)
     y = jnp.einsum("bse,bsed->bsd", mix.astype(h.dtype), expert_out)
     return y, _moe_stats(aux)
 
@@ -488,10 +501,29 @@ def _moe_ffn_dispatch_einsum(
 # argument serve/decode.py documents. The FFN half routes ONE token:
 # ``moe_impl="dense"`` replays `_moe_ffn_dense` (every expert computes,
 # mixed by the renormalized top-k weights — the parity mode, exact vs the
-# dense forward); ``"routed"`` gathers only the top-k experts' weights per
-# token — O(top_k/E) of the dense FLOPs, the serving default at scale.
-# Both produce the same mixture (non-chosen experts carry exactly-zero
-# mix weights), which tests/test_serving_families.py pins.
+# dense forward); ``"routed"``, the serving default, lets only the routed
+# (row, expert) pairs contribute and reads each expert's weights once,
+# where they lie in the layer's stacked w1/w3/w2: every operand of its
+# products is the stack itself or one `dynamic_index_in_dim` of it, which
+# XLA fuses into the dot, never a gathered (B, m, K, D, H) copy. A decode
+# step is bound by the weight bytes it reads, ``min(n, E)`` expert copies
+# a layer for ``n = B * m * top_k`` routed pairs, so the loop order
+# follows that static shape (`routed_moe_form`):
+#
+# - ``"all_experts"`` (``n >= E``: nearly every expert is hit) streams
+#   every expert once over all rows, `_moe_ffn_dense`'s arithmetic;
+#   an expert a row did not choose carries an exactly-zero mix weight;
+# - ``"per_pair"`` (``n < E``: one or two live streams) runs one product
+#   per routed pair over the expert the pair names, at most E - 1 of them.
+#
+# Both produce the dense mixture, which tests/test_serving_families.py
+# pins.
+
+
+def routed_moe_form(n_pairs: int, num_experts: int) -> str:
+    """The loop order of ``moe_impl="routed"`` for ``n_pairs`` routed
+    (row, choice) pairs a step: a static fact of the program's shape."""
+    return "all_experts" if n_pairs >= num_experts else "per_pair"
 
 
 def _moe_token(h, lp, cfg: MixtralConfig, moe_impl: str = "dense"):
@@ -500,16 +532,30 @@ def _moe_token(h, lp, cfg: MixtralConfig, moe_impl: str = "dense"):
         return _moe_ffn_dense(h, lp, cfg)[0]
     assert moe_impl == "routed", f"unknown decode moe_impl {moe_impl!r}"
     top_idx, top_w, _ = _router(h, lp["gate"], cfg)  # (B, m, K)
-    with jax.named_scope("moe_gather"):
-        w1 = lp["w1"][top_idx]  # (B, m, K, D, H)
-        w3 = lp["w3"][top_idx]
-        w2 = lp["w2"][top_idx]  # (B, m, K, H, D)
-    with jax.named_scope("moe_experts"):
-        hidden = jax.nn.silu(
-            jnp.einsum("bmd,bmkdh->bmkh", h, w1)
-        ) * jnp.einsum("bmd,bmkdh->bmkh", h, w3)
-        out = jnp.einsum("bmkh,bmkhd->bmkd", hidden, w2)
+    B, m, K = top_idx.shape
+    E = cfg.num_experts
+    # ``moe_gather`` is around what selects the experts' weights (the
+    # mix, or the index into the stack); streaming them is ``moe_experts``
+    if routed_moe_form(B * m * K, E) == "all_experts":
+        with jax.named_scope("moe_gather"):
+            mix = _expert_mix(top_idx, top_w, E).astype(h.dtype)
+        with jax.named_scope("moe_experts"):
+            out = _all_experts_swiglu(h, lp)  # (B, m, E, D)
+        with jax.named_scope("moe_combine"):
+            return jnp.einsum("bme,bmed->bmd", mix, out)
+    rows = h.reshape(B * m, -1)
+    ids = top_idx.reshape(B * m, K)
+    out = []
+    for r, k in itertools.product(range(B * m), range(K)):
+        with jax.named_scope("moe_gather"):
+            w1, w3, w2 = (
+                lax.dynamic_index_in_dim(lp[w], ids[r, k], 0, keepdims=False)
+                for w in ("w1", "w3", "w2")
+            )
+        with jax.named_scope("moe_experts"):
+            out.append((jax.nn.silu(rows[r] @ w1) * (rows[r] @ w3)) @ w2)
     with jax.named_scope("moe_combine"):
+        out = jnp.stack(out).reshape(B, m, K, -1)
         return jnp.einsum("bmkd,bmk->bmd", out, top_w.astype(h.dtype))
 
 

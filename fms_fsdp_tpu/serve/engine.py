@@ -174,6 +174,10 @@ class ServingEngine:
             params, model_cfg, scfg, self.compute_dtype
         )
         self.family = self.adapter.family
+        # one fact per engine, not a rate: set where the program is built
+        self.registry.gauge("serve.moe_expert_reads_per_layer").set(
+            self.adapter.moe_expert_reads_per_layer
+        )
         if scfg.role != "unified" and not self.adapter.supports_handoff:
             raise ValueError(
                 f"role={scfg.role!r} needs page handoff, which the "

@@ -241,6 +241,9 @@ class FamilyAdapter:
     # (serve.prefill_programs_built, serve.page_table_uploads)
     prefill_programs_built: int = 0
     page_table_uploads: int = 0
+    # expert weight copies one decode step reads in each layer (the gauge
+    # serve.moe_expert_reads_per_layer); 0 for a family with no experts
+    moe_expert_reads_per_layer: int = 0
 
     def admission_error(self, prompt_len: int, max_new: int) -> Optional[str]:
         raise NotImplementedError

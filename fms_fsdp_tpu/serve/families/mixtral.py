@@ -3,16 +3,26 @@
 The attention half is llama's paged path over mixtral's GQA shapes —
 same PagedKVCache, same page accounting, same zero-page bit-parity
 argument. The FFN half routes each decoded token through its top-k
-experts (models/mixtral.py::_moe_token): ``moe_impl="routed"`` gathers
-just the chosen experts' weights (the serving default — O(top_k/E) of
-the dense FLOPs), ``"dense"`` replays the training-path dense mix
-bit-for-bit. Both compute the same mixture: non-chosen experts carry
-exactly-zero mix weights and two-term fp32 addition is commutative, but
-the gathered per-token einsum lowers to a different dot-general than
-the dense all-experts matmul, so routed sits one ulp (~1e-10) off dense
-rather than bitwise on it. tests/test_serving_families.py pins both
-facts: dense decode == jitted dense forward walk bit-for-bit, routed ==
-dense token-for-token with single-ulp logits.
+experts (models/mixtral.py::_moe_token). ``moe_impl="routed"``, the
+serving default, reads each expert's weights once where they lie in the
+layer's stacked w1/w3/w2 and lets only the routed (row, expert) pairs
+contribute; ``"dense"`` replays the training-path dense mix bit-for-bit.
+A decode step is bound by the expert bytes it reads, ``min(n, E)``
+expert copies a layer for ``n = max_batch * top_k`` routed pairs, so the
+routed program takes one of two loop orders by that static shape
+(``routed_moe_form``): ``"all_experts"`` streams every expert once over
+all rows with an exactly-zero mix weight where a row did not choose it
+(``n >= E``), ``"per_pair"`` runs one product per routed pair over the
+expert it names (``n < E``, one or two live streams). The adapter says
+which it built: ``moe_form``, the ``moe_form`` field of every
+``serve/decode.dispatch`` span, and the gauge
+``serve.moe_expert_reads_per_layer``. All compute the same mixture:
+``"all_experts"`` is the dense arithmetic under other scopes,
+``"per_pair"`` lowers to other dot-generals than the all-experts matmul
+and so sits one ulp (~1e-10) off dense rather than bitwise on it.
+tests/test_serving_families.py pins these facts: dense decode == jitted
+dense forward walk bit-for-bit, routed == dense token-for-token with
+single-ulp logits in both forms.
 """
 
 from functools import partial
@@ -26,6 +36,7 @@ from fms_fsdp_tpu.models.generation import sample_token
 from fms_fsdp_tpu.models.mixtral import (
     mixtral_paged_decode_step,
     mixtral_prefill,
+    routed_moe_form,
 )
 from fms_fsdp_tpu.obs.spans import span
 from fms_fsdp_tpu.serve.families import FamilyAdapter
@@ -105,10 +116,18 @@ class MixtralAdapter(FamilyAdapter):
         if moe_impl not in ("routed", "dense"):
             raise ValueError(
                 f"unknown moe_impl {moe_impl!r}: mixtral decode supports "
-                "'routed' (top-k gather) or 'dense' (training-path full "
-                "mixture, the strict bit-parity mode)"
+                "'routed' (only the routed experts contribute, each "
+                "expert's weights read once in place) or 'dense' "
+                "(training-path full mixture, the strict bit-parity mode)"
             )
         cfg = model_cfg
+        # which loop the decode program runs over the experts, and the
+        # expert copies it reads in each layer: static facts of the
+        # program's shape, known where the program is built
+        pairs, E = scfg.max_batch * cfg.top_k, cfg.num_experts
+        routed = moe_impl == "routed"
+        self.moe_form = routed_moe_form(pairs, E) if routed else "dense"
+        self.moe_expert_reads_per_layer = min(pairs, E) if routed else E
 
         if scfg.attn_impl == "kernel":
             raise ValueError(
@@ -238,7 +257,7 @@ class MixtralAdapter(FamilyAdapter):
         self._upload_table(slot_rids)
         # the jitted call returns before the device ends; the read of the
         # sampled tokens is what waits for it
-        with span("decode.dispatch"):
+        with span("decode.dispatch", moe_form=self.moe_form):
             toks, logits, pools = self._decode_fn(
                 self.params,
                 self.cache.pools,

@@ -179,6 +179,16 @@ def test_counts_come_back_as_the_events_stats(traced):
     assert rejected.stats["reason"] == "too_large"
 
 
+def test_decode_dispatch_names_the_moe_form(traced):
+    """Which loop the routed decode program runs over the experts is a
+    static fact of the engine; every ``decode.dispatch`` span carries it
+    (2 slots x top-2 against 4 experts: every expert is streamed)."""
+    engine, _, spans = traced
+    assert engine.adapter.moe_form == "all_experts"
+    assert {s.stats["moe_form"] for s in named(spans, "decode.dispatch")} == {
+        "all_experts"}
+
+
 def test_every_span_of_a_step_carries_its_step(traced):
     engine, _, spans = traced
     steps = named(spans, "step")

@@ -10,8 +10,8 @@ One engine, three families. The anchors, per the PR-17 contract:
   in tests/test_serving.py and is untouched;
 - Mamba decode-state bytes constant in generated length (the slab),
   pinned while llama's kv pages grow;
-- Mixtral routed decode == dense-mix decode (top-k gather is a FLOPs
-  knob, not a numerics knob);
+- Mixtral routed decode == dense-mix decode in both of its loop orders
+  (routing is a bytes-and-FLOPs knob, not a numerics knob);
 - pool pressure: eviction + recompute-on-resume per family, with the
   mamba slab slice zeroed on release;
 - checkpoint→family resolution errors are actionable.
@@ -332,47 +332,98 @@ def test_llama_and_mixtral_report_zero_slab(mixtral_params):
 # ---------------------------------------------------------------------------
 
 
-def test_mixtral_routed_equals_dense_mix(mixtral_params):
-    """The top-k gather computes the dense mixture: non-chosen experts
-    carry exactly-zero mix weights and fp32 addition of the two chosen
-    terms is commutative. The gathered per-token einsum lowers to a
-    different dot-general than the all-experts matmul, so routed sits
-    one ulp off dense (measured 2.3e-10) rather than bitwise on it —
-    pin that ceiling tightly. The token-level _moe_token dense path
-    must replay the training FFN (_moe_ffn_dense) bit-for-bit: that is
-    the bridge the engine's bitwise anchor stands on."""
-    from fms_fsdp_tpu.models.mixtral import _moe_ffn_dense
+# (experts, rows B, positions m) -> the loop order the routed program
+# takes for n = B * m * top_k routed pairs against E experts
+ROUTED_SHAPES = [
+    (4, 2, 3, "all_experts"),  # n 12 >= 4
+    (4, 2, 1, "all_experts"),  # n 4 == E: the edge streams every expert
+    (8, 4, 1, "all_experts"),  # n 8 == E
+    (4, 1, 1, "per_pair"),  # n 2 < 4: one live stream
+    (8, 1, 3, "per_pair"),  # n 6 < 8: a three-position verify chunk
+    (8, 3, 1, "per_pair"),  # n 6 < 8
+]
 
+
+@pytest.mark.parametrize("experts,B,m,form", ROUTED_SHAPES)
+def test_mixtral_routed_equals_dense_mix(experts, B, m, form):
+    """Routed computes the dense mixture in both loop orders: only the
+    routed (row, expert) pairs contribute, non-chosen experts carry
+    exactly-zero mix weights and fp32 addition of the two chosen terms
+    is commutative. ``per_pair`` lowers to other dot-generals than the
+    all-experts matmul, so it sits one ulp off dense (measured 2.3e-10)
+    rather than bitwise on it — pin that ceiling tightly for both. The
+    token-level _moe_token dense path must replay the training FFN
+    (_moe_ffn_dense) bit-for-bit: that is the bridge the engine's
+    bitwise anchor stands on."""
+    from fms_fsdp_tpu.models.mixtral import _moe_ffn_dense, routed_moe_form
+
+    cfg = dataclasses.replace(TINY_MIXTRAL, num_experts=experts)
+    assert routed_moe_form(B * m * cfg.top_k, experts) == form
     lp = jax.tree.map(
         lambda a: a[0].astype(jnp.float32),
-        mixtral_params["layers"],
+        init_mixtral_params(jax.random.PRNGKey(2), cfg)["layers"],
     )
-    h = jax.random.normal(jax.random.PRNGKey(3), (2, 3, 64), jnp.float32)
-    dense = np.asarray(_moe_token(h, lp, TINY_MIXTRAL, "dense"))
-    routed = np.asarray(_moe_token(h, lp, TINY_MIXTRAL, "routed"))
-    train = np.asarray(_moe_ffn_dense(h, lp, TINY_MIXTRAL)[0])
+    h = jax.random.normal(jax.random.PRNGKey(3), (B, m, 64), jnp.float32)
+    dense = np.asarray(_moe_token(h, lp, cfg, "dense"))
+    routed = np.asarray(_moe_token(h, lp, cfg, "routed"))
+    train = np.asarray(_moe_ffn_dense(h, lp, cfg)[0])
     assert (dense == train).all()
     assert np.abs(routed - dense).max() < 1e-8
+    assert np.abs(dense).max() > 1e-3  # the mixture is not all zeros
 
 
-def test_mixtral_routed_engine_matches_dense_engine(mixtral_params):
+# max_batch -> (moe_form, expert copies a layer reads) at E = 4, top_k = 2
+ROUTED_ENGINES = {1: ("per_pair", 2), 2: ("all_experts", 4),
+                  4: ("all_experts", 4)}
+
+
+@pytest.mark.parametrize("max_batch", sorted(ROUTED_ENGINES))
+def test_mixtral_routed_engine_matches_dense_engine(mixtral_params, max_batch):
     """Same streams end-to-end: the routed serving default generates
-    exactly the dense-mix engine's tokens, with per-step logits inside
-    the single-ulp routing envelope."""
+    exactly the dense-mix engine's tokens in both loop orders, with
+    per-step logits inside the single-ulp routing envelope."""
     prompt, max_new = [5, 9, 2, 7], 6
-    routed = _engine(mixtral_params, TINY_MIXTRAL)
+    routed = _engine(mixtral_params, TINY_MIXTRAL, max_batch=max_batch)
     assert routed.adapter.moe_impl == "routed"  # serving default
+    assert routed.adapter.moe_form == ROUTED_ENGINES[max_batch][0]
     r1 = routed.submit(prompt, max_new)
     lg_routed = _run_capturing(routed, [r1])
 
-    dense = _engine(mixtral_params, TINY_MIXTRAL, moe_impl="dense")
+    dense = _engine(
+        mixtral_params, TINY_MIXTRAL, max_batch=max_batch, moe_impl="dense"
+    )
     r2 = dense.submit(prompt, max_new)
     lg_dense = _run_capturing(dense, [r2])
 
     assert r1.generated == r2.generated
+    assert len(lg_routed) == len(lg_dense) == max_new - 1
     for a, b in zip(lg_routed, lg_dense):
         assert np.abs(a[0] - b[0]).max() < 1e-6
         assert a[0].argmax() == b[0].argmax()
+
+
+@pytest.mark.parametrize(
+    "kw,form,reads",
+    [({"max_batch": b}, *ROUTED_ENGINES[b]) for b in sorted(ROUTED_ENGINES)]
+    + [({"max_batch": 1, "moe_impl": "dense"}, "dense", 4)],
+)
+def test_mixtral_engine_says_which_moe_form_it_built(
+    mixtral_params, kw, form, reads
+):
+    """One fact per engine, set where the decode program is built: the
+    adapter's ``moe_form`` (also a field of every serve/decode.dispatch
+    span, tests/test_serve_spans.py) and the gauge of the expert copies a
+    decode step reads in each layer, ``min(n, E)``."""
+    eng = _engine(mixtral_params, TINY_MIXTRAL, **kw)
+    assert eng.adapter.moe_form == form
+    assert eng.adapter.moe_expert_reads_per_layer == reads
+    snap = eng.registry.snapshot(clear_windows=False)
+    assert snap["serve.moe_expert_reads_per_layer"] == float(reads)
+
+
+def test_families_without_experts_read_none(mamba_params):
+    eng = _engine(mamba_params, TINY_MAMBA)
+    assert eng.registry.snapshot()["serve.moe_expert_reads_per_layer"] == 0.0
 
 
 # ---------------------------------------------------------------------------
