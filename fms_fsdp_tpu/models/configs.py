@@ -8,7 +8,9 @@ max_expected_seq_len, rope_theta, vocab size.
 
 ``MambaConfig`` mirrors the mamba_9.8b dict config
 (ref:fms_fsdp/utils/config_utils.py:162-185): Mamba2 layers with a few
-interleaved attention layers, RMSNorm, residual in fp32.
+interleaved attention layers, RMSNorm, residual in fp32. With
+``ssm_layer="Mamba1"`` the same stack carries the Mamba-1 selective-scan
+mixer of the Jamba hybrids (models/mamba.py).
 
 ``MixtralConfig`` covers the sparse-MoE Llama family the reference touches
 only as a frozen speculator base (ref:speculator/train_speculator_utils.py:
@@ -99,13 +101,33 @@ class MambaConfig:
     pad_vocab_size_multiple: int = 16
     tie_embeddings: bool = False
     norm_eps: float = 1e-5
-    # Mamba2 layer hyperparameters (mamba_ssm defaults)
+    # mixer hyperparameters (mamba_ssm defaults); headdim, ngroups and
+    # chunk_size are Mamba2's alone
     d_state: int = 128
     d_conv: int = 4
     expand: int = 2
     headdim: int = 64
     ngroups: int = 1
     chunk_size: int = 256
+    # Mamba1 alone: width of the low-rank dt projection; 0 -> the
+    # mamba_ssm "auto", ceil(d_model / 16)
+    dt_rank: int = 0
+
+    def __post_init__(self):
+        if self.ssm_layer not in ("Mamba1", "Mamba2"):
+            raise ValueError(
+                f"unknown ssm_layer {self.ssm_layer!r}: the hybrid stack "
+                f"has a 'Mamba1' (selective scan) and a 'Mamba2' (SSD) "
+                f"mixer"
+            )
+
+    @property
+    def mamba1(self) -> bool:
+        return self.ssm_layer == "Mamba1"
+
+    @property
+    def dt_rank_(self) -> int:
+        return self.dt_rank or -(-self.d_model // 16)
 
     @property
     def padded_vocab_size(self) -> int:
@@ -123,15 +145,30 @@ class MambaConfig:
     def n_params(self) -> int:
         """Exact parameter count of the hybrid stack (see models/mamba.py)."""
         d = self.d_model
-        conv_dim = self.d_inner + 2 * self.ngroups * self.d_state
-        in_proj = 2 * self.d_inner + 2 * self.ngroups * self.d_state + self.nheads
-        per_mamba = (
-            d * in_proj
-            + conv_dim * (self.d_conv + 1)  # conv weight + bias
-            + 3 * self.nheads  # dt_bias, A_log, D
-            + self.d_inner  # gated norm
-            + self.d_inner * d  # out_proj
-        )
+        if self.mamba1:
+            di, N, R = self.d_inner, self.d_state, self.dt_rank_
+            per_mamba = (
+                d * 2 * di  # in_proj (u | z)
+                + di * (self.d_conv + 1)  # conv weight + bias
+                + di * (R + 2 * N)  # x_proj
+                + R + 2 * N  # norms on dt, B and C
+                + R * di + di  # dt_proj + bias
+                + di * N + di  # A_log, D
+                + di * d  # out_proj
+            )
+        else:
+            conv_dim = self.d_inner + 2 * self.ngroups * self.d_state
+            in_proj = (
+                2 * self.d_inner + 2 * self.ngroups * self.d_state
+                + self.nheads
+            )
+            per_mamba = (
+                d * in_proj
+                + conv_dim * (self.d_conv + 1)  # conv weight + bias
+                + 3 * self.nheads  # dt_bias, A_log, D
+                + self.d_inner  # gated norm
+                + self.d_inner * d  # out_proj
+            )
         a = self.attn_cfg
         per_attn = d * a.head_dim * (a.num_heads * 2 + a.num_heads_kv * 2)
         per_mlp = 3 * d * self.d_intermediate + d if self.d_intermediate else 0
@@ -141,9 +178,48 @@ class MambaConfig:
             + n_attn * per_attn
             + self.n_layer * (per_mlp + d)  # mlp (+norm2) and mixer norm
             + d  # final norm
-            + 2 * self.padded_vocab_size * d
+            # embedding, and the head unless it is the embedding
+            + (1 if self.tie_embeddings else 2) * self.padded_vocab_size * d
         )
         return int(total)
+
+
+def jamba_config(d: dict) -> MambaConfig:
+    """A published Jamba ``config.json`` (``model_type: jamba``) as the
+    hybrid stack's config: Mamba-1 mixers, attention with no positional
+    embedding on layers ``i % attn_layer_period == attn_layer_offset``,
+    a dense MLP after every mixer, tied head. Only dense checkpoints
+    (``num_experts == 1``): the stack has no expert layer."""
+    if d.get("num_experts", 1) != 1:
+        raise ValueError(
+            f"jamba config has num_experts={d['num_experts']}: the hybrid "
+            f"stack's feed-forward is a dense MLP (ROADMAP A3)"
+        )
+    n = d["num_hidden_layers"]
+    nq = d["num_attention_heads"]
+    return MambaConfig(
+        d_model=d["hidden_size"],
+        d_intermediate=d["intermediate_size"],
+        n_layer=n,
+        vocab_size=d["vocab_size"],
+        ssm_layer="Mamba1",
+        attn_layer_idx=tuple(
+            i for i in range(n)
+            if i % d["attn_layer_period"] == d["attn_layer_offset"]
+        ),
+        attn_cfg=MambaAttnConfig(
+            head_dim=d["hidden_size"] // nq,
+            num_heads=nq,
+            num_heads_kv=d["num_key_value_heads"],
+            rotary_emb_dim=0,
+        ),
+        tie_embeddings=d.get("tie_word_embeddings", False),
+        norm_eps=d["rms_norm_eps"],
+        d_state=d["mamba_d_state"],
+        d_conv=d["mamba_d_conv"],
+        expand=d["mamba_expand"],
+        dt_rank=d["mamba_dt_rank"],
+    )
 
 
 @dataclass(frozen=True)

@@ -1,27 +1,44 @@
-"""Mamba2 hybrid LM, TPU-native.
+"""Hybrid state-space LM, TPU-native: Mamba-2 or Mamba-1 mixers with
+interleaved attention layers.
 
 Replaces the reference's external `mamba_ssm` dependency
 (ref:main_training_mamba.py:8-13, MambaConfig dict at
 ref:config_utils.py:162-185): a stack of pre-norm blocks where each block
 is  residual + mixer(norm(residual)), then residual + mlp(norm2(residual))
-(when d_intermediate > 0), with
+(when d_intermediate > 0). One stack, the mixer chosen per layer:
 
-- mixer = Mamba2 on most layers: fused in_proj -> (z | xBC | dt), depthwise
-  causal conv1d with silu over xBC, softplus dt with learned bias,
-  negative-exponential A per head, chunked SSD selective scan (ops/ssd.py),
-  gated RMSNorm (norm(y * silu(z))), out_proj;
-- mixer = causal MHA on `attn_layer_idx` layers (9/18/27 for mamba_9.8b)
-  with GQA 32/8 heads, head_dim 128, partial rotary over the first 64 dims
-  (ref attn_cfg, config_utils.py:170-179);
+- ``ssm_layer="Mamba2"`` (Bamba, mamba_9.8b): fused in_proj ->
+  (z | xBC | dt), depthwise causal conv1d with silu over xBC, softplus dt
+  with learned bias, negative-exponential A per head, chunked SSD scan
+  (ops/ssd.py), gated RMSNorm (norm(y * silu(z))), out_proj;
+- ``ssm_layer="Mamba1"`` (the Jamba hybrids): in_proj (u's matrix and
+  the gate's, stacked) -> u, z; conv1d
+  with silu over u alone, x_proj -> (dt | B | C) of widths (dt_rank,
+  d_state, d_state) with an RMSNorm on each, dt_proj with bias and
+  softplus, A of shape (d_inner, d_state), the selective scan of
+  ops/selective_scan.py (a decay per channel and state), a plain gate
+  (y * silu(z), no norm), out_proj;
+- causal attention on `attn_layer_idx` layers, GQA down to one KV head,
+  rotary over the first ``rotary_emb_dim`` dims of each head, over the
+  whole head, or none at all (``rotary_emb_dim=0``: Jamba's attention has
+  no positional embedding; the state-space layers carry the order);
 - swiglu MLP (d_intermediate) after every mixer;
-- fp32 residual stream (`residual_in_fp32`), RMSNorm everywhere, untied
-  embeddings with vocab padded to pad_vocab_size_multiple.
+- fp32 residual stream (`residual_in_fp32`), RMSNorm everywhere, vocab
+  padded to pad_vocab_size_multiple; the head is a matrix of its own or,
+  with ``tie_embeddings``, the embedding (the tree then has no
+  ``lm_head`` leaf).
 
 Layers are heterogeneous, so the stack runs as an unrolled loop (not
 lax.scan); params live in a per-layer list pytree.
+
+Serving: recurrent decode from a constant-size slab for both mixers. A
+Mamba-1 prompt is prefilled as a sequence (each layer over the whole
+padded prompt, the final state and conv tail handed to the slab); a
+Mamba-2 prompt still scans the decode step over positions (ROADMAP A6).
 """
 
 import functools
+import math
 from typing import Any, Dict, List, Optional
 
 import jax
@@ -35,6 +52,12 @@ from fms_fsdp_tpu.ops.attention import attention
 from fms_fsdp_tpu.ops.norms import rms_norm
 from fms_fsdp_tpu.ops.quant import matmul as qmatmul
 from fms_fsdp_tpu.ops.rope import apply_rotary, rope_table
+from fms_fsdp_tpu.ops.selective_scan import (
+    freeze_past,
+    selective_scan,
+    selective_scan_reference,
+    selective_scan_step,
+)
 from fms_fsdp_tpu.ops.ssd import causal_conv1d, ssd_scan
 from fms_fsdp_tpu.parallel.mesh import AXIS_CONTEXT, AXIS_FSDP, AXIS_TENSOR, DATA_AXES
 
@@ -66,7 +89,9 @@ def init_mamba_params(key, cfg: MambaConfig, dtype=jnp.float32) -> Params:
             dtype
         )
 
-    keys = iter(jax.random.split(key, 8 * cfg.n_layer + 4))
+    keys = iter(
+        jax.random.split(key, (10 if cfg.mamba1 else 8) * cfg.n_layer + 4)
+    )
 
     def mamba_mixer():
         # dt bias: softplus^-1 of dt ~ LogUniform[1e-3, 1e-1] (mamba2 init)
@@ -87,6 +112,33 @@ def init_mamba_params(key, cfg: MambaConfig, dtype=jnp.float32) -> Params:
             "out_proj": tn(next(keys), (cfg.d_inner, d), out_std),
         }
 
+    def mamba1_mixer():
+        di, N, R = cfg.d_inner, cfg.d_state, cfg.dt_rank_
+        # dt bias as above, per channel; A[c, n] = n + 1 (the S4D-real
+        # init of mamba_ssm's Mamba-1)
+        u = jax.random.uniform(next(keys), (di,), jnp.float32)
+        dt = jnp.exp(u * (jnp.log(0.1) - jnp.log(1e-3)) + jnp.log(1e-3))
+        dt = jnp.clip(dt, 1e-4)
+        A = jnp.broadcast_to(jnp.arange(1, N + 1, dtype=jnp.float32), (di, N))
+        return {
+            # u's matrix, then the gate's, stacked: each product reads
+            # its own matrix and no fused product's output is split
+            "in_proj": tn(next(keys), (2, d, di), std),
+            "conv_w": tn(next(keys), (di, cfg.d_conv), std * 10),
+            "conv_b": jnp.zeros((di,), dtype),
+            "x_proj": tn(next(keys), (di, R + 2 * N), std),
+            "dt_norm": jnp.ones((R,), dtype),
+            "B_norm": jnp.ones((N,), dtype),
+            "C_norm": jnp.ones((N,), dtype),
+            "dt_proj": tn(next(keys), (R, di), R**-0.5),
+            "dt_bias": (dt + jnp.log(-jnp.expm1(-dt))).astype(dtype),
+            "A_log": jnp.log(A).astype(dtype),
+            "D": jnp.ones((di,), dtype),
+            "out_proj": tn(next(keys), (di, d), out_std),
+        }
+
+    ssm_mixer = mamba1_mixer if cfg.mamba1 else mamba_mixer
+
     def attn_mixer():
         a = cfg.attn_cfg
         hd = a.head_dim
@@ -101,7 +153,7 @@ def init_mamba_params(key, cfg: MambaConfig, dtype=jnp.float32) -> Params:
     for i in range(cfg.n_layer):
         layer = {
             "norm": jnp.ones((d,), dtype),
-            "mixer": attn_mixer() if i in cfg.attn_layer_idx else mamba_mixer(),
+            "mixer": attn_mixer() if i in cfg.attn_layer_idx else ssm_mixer(),
         }
         if cfg.d_intermediate > 0:
             layer["norm2"] = jnp.ones((d,), dtype)
@@ -112,12 +164,14 @@ def init_mamba_params(key, cfg: MambaConfig, dtype=jnp.float32) -> Params:
             }
         layers.append(layer)
 
-    return {
+    params = {
         "embedding": tn(next(keys), (v, d), std),
         "layers": layers,
         "norm_f": jnp.ones((d,), dtype),
-        "lm_head": tn(next(keys), (d, v), std),
     }
+    if not cfg.tie_embeddings:
+        params["lm_head"] = tn(next(keys), (d, v), std)
+    return params
 
 
 # ---------------------------------------------------------------------------
@@ -175,6 +229,72 @@ def _mamba_mixer(x, p: Params, cfg: MambaConfig, mesh, kernel="auto", quant="non
     return _constrain(out, P(DATA_AXES, AXIS_CONTEXT, None), mesh)
 
 
+def _mamba1_scan_inputs(u, p: Params, cfg: MambaConfig):
+    """x_proj, the three norms, dt_proj with bias and softplus, A: what
+    the scan reads besides ``u``. u (..., d_inner) post-conv. Returns
+    (dt (..., d_inner) fp32, A (N, d_inner) fp32, B, C (..., N))."""
+    N, R = cfg.d_state, cfg.dt_rank_
+    dbc = u @ p["x_proj"]
+    dt_r = rms_norm(dbc[..., :R], p["dt_norm"], cfg.norm_eps)
+    Bm = rms_norm(dbc[..., R : R + N], p["B_norm"], cfg.norm_eps)
+    Cm = rms_norm(dbc[..., R + N :], p["C_norm"], cfg.norm_eps)
+    dt = jax.nn.softplus(
+        jnp.dot(dt_r, p["dt_proj"], preferred_element_type=jnp.float32)
+        + p["dt_bias"].astype(jnp.float32)
+    )
+    A = -jnp.exp(p["A_log"].astype(jnp.float32)).T
+    return dt, A, Bm, Cm
+
+
+def _mamba1_mixer(
+    x, p: Params, cfg: MambaConfig, mesh=None, quant="none", *,
+    lengths=None, scan=selective_scan_reference,
+):
+    """x (B, S, D) compute dtype -> (out (B, S, D), slab) through a
+    Mamba-1 mixer, the scan started from a zero state. With ``lengths``
+    (B,) a row's state freezes at its length, and ``slab`` is what the
+    recurrent decode step goes on from: {"conv": the last d_conv-1
+    pre-conv inputs before that position, "ssd": the state there}.
+    ``scan`` is the sequence form of ops/selective_scan.py to run: the
+    differentiable ``lax.scan`` one unless the caller (prefill) asks for
+    the one that fits the platform."""
+    B, S, _ = x.shape
+    di, N, K = cfg.d_inner, cfg.d_state, cfg.d_conv
+    with jax.named_scope("ssm_in_proj"):
+        u_pre, z = (
+            _constrain(
+                qmatmul(x, p["in_proj"][i], quant=quant),
+                P(DATA_AXES, AXIS_CONTEXT, AXIS_TENSOR), mesh,
+            )
+            for i in range(2)
+        )
+    with jax.named_scope("ssm_conv"):
+        u = causal_conv1d(u_pre, p["conv_w"], p["conv_b"], activation="silu")
+    with jax.named_scope("ssm_params"):
+        dt, A, Bm, Cm = _mamba1_scan_inputs(u, p, cfg)
+        if lengths is not None:
+            dt = freeze_past(dt, lengths)
+    with jax.named_scope("ssm_scan"):
+        y, h = scan(
+            u.astype(jnp.float32), dt, A, Bm.astype(jnp.float32),
+            Cm.astype(jnp.float32), p["D"].astype(jnp.float32),
+            jnp.zeros((B, N, di), jnp.float32),
+        )
+    with jax.named_scope("ssm_gate_out"):
+        out = qmatmul(
+            y.astype(x.dtype) * jax.nn.silu(z), p["out_proj"], quant=quant
+        )
+        out = _constrain(out, P(DATA_AXES, AXIS_CONTEXT, None), mesh)
+    if lengths is None:
+        return out, None
+    with jax.named_scope("ssm_conv"):
+        padded = jnp.pad(u_pre, ((0, 0), (K - 1, 0), (0, 0)))
+        tail = jax.vmap(
+            lambda row, n: lax.dynamic_slice_in_dim(row, n, K - 1, axis=0)
+        )(padded, lengths)
+    return out, {"conv": tail, "ssd": h}
+
+
 @scoped("attn_mixer")
 def _attn_mixer(x, p: Params, cfg: MambaConfig, cos, sin, attn_impl, mesh, quant="none"):
     B, S, d = x.shape
@@ -217,6 +337,15 @@ def _mlp(x, p: Params, mesh, quant="none"):
     )
 
 
+@scoped("lm_head")
+def _head(x, params: Params):
+    """Logits of final-norm hidden states: the head's own matrix, or the
+    embedding where the tree has no ``lm_head`` (``tie_embeddings``)."""
+    if "lm_head" in params:
+        return x @ params["lm_head"]
+    return jnp.einsum("...d,vd->...v", x, params["embedding"])
+
+
 def mamba_forward(
     params: Params,
     tokens,
@@ -252,6 +381,8 @@ def mamba_forward(
             out = _attn_mixer(
                 h, layer["mixer"], cfg, cos, sin, attn_impl, mesh, quant=quant
             )
+        elif cfg.mamba1:
+            out, _ = _mamba1_mixer(h, layer["mixer"], cfg, mesh, quant=quant)
         else:
             out = _mamba_mixer(
                 h, layer["mixer"], cfg, mesh, kernel=mamba_kernel, quant=quant
@@ -275,7 +406,7 @@ def mamba_forward(
     x = rms_norm(residual.astype(compute_dtype), params["norm_f"], cfg.norm_eps)
     if return_hidden:
         return x
-    logits = x @ params["lm_head"]
+    logits = _head(x, params)
     return _constrain(logits, P(DATA_AXES, AXIS_CONTEXT, AXIS_TENSOR), mesh)
 
 
@@ -297,43 +428,60 @@ def mamba_forward(
 # serve-side decode).
 
 
+def slab_shapes(cfg: MambaConfig):
+    """One stream's slab in one mamba layer: (conv window shape, state
+    shape), by the mixer kind."""
+    if cfg.mamba1:
+        return (cfg.d_conv - 1, cfg.d_inner), (cfg.d_state, cfg.d_inner)
+    return (
+        (cfg.d_conv - 1, _conv_dim(cfg)),
+        (cfg.nheads, cfg.headdim, cfg.d_state),
+    )
+
+
 def init_mamba_decode_state(
     cfg: MambaConfig, batch: int, compute_dtype=jnp.float32
 ) -> List[Params]:
     """Per-layer recurrent decode state for ``batch`` slots.
 
-    Mamba layers: {"conv": (B, d_conv-1, conv_dim) compute dtype — the
-    sliding window of pre-conv xBC inputs; "ssd": (B, H, headdim,
-    d_state) fp32 — the carried SSD state}. Attention layers of hybrid
-    configs hold no slab here ({}): their kv lives in the caller's
-    paged pool."""
-    state: List[Params] = []
-    for i in range(cfg.n_layer):
-        if i in cfg.attn_layer_idx:
-            state.append({})
-        else:
-            state.append(
-                {
-                    "conv": jnp.zeros(
-                        (batch, cfg.d_conv - 1, _conv_dim(cfg)), compute_dtype
-                    ),
-                    "ssd": jnp.zeros(
-                        (batch, cfg.nheads, cfg.headdim, cfg.d_state),
-                        jnp.float32,
-                    ),
-                }
-            )
-    return state
+    Mamba layers: {"conv": (B, d_conv-1, conv width) compute dtype — the
+    sliding window of pre-conv inputs; "ssd": the carried fp32 state}.
+    The shapes follow the mixer (``slab_shapes``): Mamba-2 convolves xBC
+    and carries (H, headdim, d_state); Mamba-1 convolves u alone and
+    carries (d_state, d_inner), channels minor (ops/selective_scan.py
+    says why). Attention layers of hybrid configs hold no slab here
+    ({}): their kv lives in the caller's paged pool."""
+    conv, ssd = slab_shapes(cfg)
+    return [
+        {} if i in cfg.attn_layer_idx else {
+            "conv": jnp.zeros((batch,) + conv, compute_dtype),
+            "ssd": jnp.zeros((batch,) + ssd, jnp.float32),
+        }
+        for i in range(cfg.n_layer)
+    ]
 
 
 def mamba_state_bytes_per_stream(cfg: MambaConfig, compute_dtype=jnp.float32) -> int:
     """Slab bytes one decode stream holds — constant in generated length
     (the constant-memory claim a tier-1 test pins)."""
-    itemsize = jnp.dtype(compute_dtype).itemsize
+    conv, ssd = slab_shapes(cfg)
     n_mamba = cfg.n_layer - len(cfg.attn_layer_idx)
-    conv = (cfg.d_conv - 1) * _conv_dim(cfg) * itemsize
-    ssd = cfg.nheads * cfg.headdim * cfg.d_state * 4  # fp32
-    return n_mamba * (conv + ssd)
+    return n_mamba * (
+        math.prod(conv) * jnp.dtype(compute_dtype).itemsize
+        + math.prod(ssd) * 4  # fp32
+    )
+
+
+def _conv_step(window, w, b):
+    """Position t of ``causal_conv1d`` from the window of the last d_conv
+    inputs (B, d_conv, C), the current one last: the same ascending-w
+    fp32 FMA sum, bias and silu. Returns fp32."""
+    wf = w.astype(jnp.float32)
+    out = sum(
+        window[:, k].astype(jnp.float32) * wf[None, :, k]
+        for k in range(w.shape[-1])
+    )
+    return jax.nn.silu(out + b.astype(jnp.float32)[None, :])
 
 
 def _mamba_mixer_step(x, st: Params, p: Params, cfg: MambaConfig):
@@ -355,13 +503,7 @@ def _mamba_mixer_step(x, st: Params, p: Params, cfg: MambaConfig):
     # causal conv over the window of the last d_conv inputs (current
     # token included) — the position-t row of causal_conv1d's output
     window = jnp.concatenate([st["conv"], xBC_in[:, None, :]], axis=1)
-    wf = p["conv_w"].astype(jnp.float32)
-    xBC = sum(
-        window[:, w].astype(jnp.float32) * wf[None, :, w]
-        for w in range(cfg.d_conv)
-    )
-    xBC = xBC + p["conv_b"].astype(jnp.float32)[None, :]
-    xBC = jax.nn.silu(xBC).astype(x.dtype)
+    xBC = _conv_step(window, p["conv_w"], p["conv_b"]).astype(x.dtype)
 
     xs = xBC[..., :d_inner].reshape(B, H, Pd)
     Bm = xBC[..., d_inner : d_inner + G * N].reshape(B, G, N)
@@ -388,6 +530,28 @@ def _mamba_mixer_step(x, st: Params, p: Params, cfg: MambaConfig):
     return out, {"conv": window[:, 1:], "ssd": h_ssd}
 
 
+def _mamba1_mixer_step(x, st: Params, p: Params, cfg: MambaConfig):
+    """One token through a Mamba-1 mixer. x (B, D) post-norm hidden; st
+    the layer's {"conv", "ssd"} slab. Returns (out (B, D), new st): the
+    single-position case of ``_mamba1_mixer``."""
+    di = cfg.d_inner
+    with jax.named_scope("ssm_in_proj"):
+        u_pre, z = x @ p["in_proj"][0], x @ p["in_proj"][1]
+    with jax.named_scope("ssm_conv"):
+        window = jnp.concatenate([st["conv"], u_pre[:, None, :]], axis=1)
+        u = _conv_step(window, p["conv_w"], p["conv_b"]).astype(x.dtype)
+    with jax.named_scope("ssm_params"):
+        dt, A, Bm, Cm = _mamba1_scan_inputs(u, p, cfg)
+    with jax.named_scope("ssm_scan"):
+        y, h = selective_scan_step(
+            u.astype(jnp.float32), dt, A, Bm.astype(jnp.float32),
+            Cm.astype(jnp.float32), p["D"].astype(jnp.float32), st["ssd"],
+        )
+    with jax.named_scope("ssm_gate_out"):
+        out = (y.astype(x.dtype) * jax.nn.silu(z)) @ p["out_proj"]
+    return out, {"conv": window[:, 1:], "ssd": h}
+
+
 def _attn_qkv_step(h, p: Params, a, cos, sin, positions):
     """Projections + partial rotary for one decode position of a hybrid
     attn mixer. h (B, 1, D) post-norm; positions (B, 1) int32. Returns
@@ -411,6 +575,16 @@ def _attn_qkv_step(h, p: Params, a, cos, sin, positions):
     return q, k, v
 
 
+@scoped("norm")
+def _block_norm(residual, w, cfg: MambaConfig, compute_dtype):
+    return rms_norm(residual.astype(compute_dtype), w, cfg.norm_eps)
+
+
+@scoped("norm")
+def _add(residual, out):
+    return residual + out.astype(jnp.float32)
+
+
 def _stack_step(params: Params, x_t, cfg: MambaConfig, states, attn_cb):
     """One token through the whole (heterogeneous) layer stack.
 
@@ -420,25 +594,22 @@ def _stack_step(params: Params, x_t, cfg: MambaConfig, states, attn_cb):
     fp32, new per-layer states)."""
     compute_dtype = x_t.dtype
     residual = x_t.astype(jnp.float32)
+    mixer_step = _mamba1_mixer_step if cfg.mamba1 else _mamba_mixer_step
     new_states = []
     attn_j = 0
     for i, layer in enumerate(params["layers"]):
-        h = rms_norm(residual.astype(compute_dtype), layer["norm"], cfg.norm_eps)
+        h = _block_norm(residual, layer["norm"], cfg, compute_dtype)
         if i in cfg.attn_layer_idx:
             out = attn_cb(attn_j, h[:, None], layer["mixer"])
             attn_j += 1
             new_states.append(states[i])
         else:
-            out, st = _mamba_mixer_step(h, states[i], layer["mixer"], cfg)
+            out, st = mixer_step(h, states[i], layer["mixer"], cfg)
             new_states.append(st)
-        residual = residual + out.astype(jnp.float32)
+        residual = _add(residual, out)
         if "mlp" in layer:
-            h2 = rms_norm(
-                residual.astype(compute_dtype), layer["norm2"], cfg.norm_eps
-            )
-            residual = residual + _mlp(h2, layer["mlp"], None).astype(
-                jnp.float32
-            )
+            h2 = _block_norm(residual, layer["norm2"], cfg, compute_dtype)
+            residual = _add(residual, _mlp(h2, layer["mlp"], None))
     return residual, new_states
 
 
@@ -450,8 +621,11 @@ def mamba_prefill(
     *,
     compute_dtype=jnp.float32,
     kv_len: int = 0,
+    attn_impl: str = "auto",
 ):
-    """Prompt prefill by scanning the recurrent step over positions.
+    """Prompt prefill: a Mamba-1 stack takes the prompt as a sequence
+    (``_prefill_sequence``; ``attn_impl`` is its attention's), a Mamba-2
+    stack scans the recurrent step over positions, as follows.
 
     tokens (B, S_pad) int32, lengths (B,) int32 actual prompt lengths
     (<= S_pad; state freezes per-row past its length, so bucketed
@@ -463,7 +637,12 @@ def mamba_prefill(
     exact ops of the recurrent decode step, prefill state equals the
     state a token-by-token decode of the prompt would carry, bit for
     bit."""
-    params = jax.tree.map(lambda a: a.astype(compute_dtype), params)
+    with jax.named_scope("params_cast"):
+        params = jax.tree.map(lambda a: a.astype(compute_dtype), params)
+    if cfg.mamba1:
+        return _prefill_sequence(
+            params, tokens, lengths, cfg, compute_dtype, kv_len, attn_impl
+        )
     B, S_pad = tokens.shape
     a = cfg.attn_cfg
     n_attn = len(cfg.attn_layer_idx)
@@ -529,8 +708,77 @@ def mamba_prefill(
         (jnp.arange(S_pad, dtype=jnp.int32), jnp.moveaxis(tokens, 0, 1)),
     )
     x = rms_norm(last_res.astype(compute_dtype), params["norm_f"], cfg.norm_eps)
-    logits = x @ params["lm_head"]
+    logits = _head(x, params)
     return logits, states, (kv if n_attn else None)
+
+
+def _prefill_sequence(
+    params: Params, tokens, lengths, cfg: MambaConfig, compute_dtype,
+    kv_len: int, attn_impl: str,
+):
+    """``mamba_prefill`` for a Mamba-1 stack, each layer over the whole
+    padded prompt: one product for in_proj, the conv, the selective scan
+    in the form that fits the platform with each row's state frozen at
+    its length, causal attention within the prompt. Same results as the
+    per-position form: last real position's logits, the slab, and K/V
+    (zero past each row's length) for the pages. A padded position costs
+    what a real one does, and touches nothing that is kept."""
+    B, S = tokens.shape
+    a = cfg.attn_cfg
+    kv_len = kv_len or S
+    assert kv_len >= S, (kv_len, S)
+    live = jnp.arange(S, dtype=jnp.int32)[None, :] < lengths[:, None]
+    cos = sin = None
+    if a.rotary_emb_dim:
+        cos, sin = rope_table(S, a.rotary_emb_dim, 10000.0)
+    with jax.named_scope("embed"):
+        residual = params["embedding"][tokens].astype(jnp.float32)
+    states, ks, vs = [], [], []
+    for i, layer in enumerate(params["layers"]):
+        h = _block_norm(residual, layer["norm"], cfg, compute_dtype)
+        if i in cfg.attn_layer_idx:
+            out, k, v = _attn_prefill(
+                h, layer["mixer"], a, cos, sin, live, kv_len, attn_impl
+            )
+            ks.append(k)
+            vs.append(v)
+            states.append({})
+        else:
+            out, st = _mamba1_mixer(
+                h, layer["mixer"], cfg, lengths=lengths, scan=selective_scan
+            )
+            states.append(st)
+        residual = _add(residual, out)
+        if "mlp" in layer:
+            h2 = _block_norm(residual, layer["norm2"], cfg, compute_dtype)
+            residual = _add(residual, _mlp(h2, layer["mlp"], None))
+    last = jnp.take_along_axis(
+        residual, (lengths - 1)[:, None, None], axis=1
+    )[:, 0]
+    x = _block_norm(last, params["norm_f"], cfg, compute_dtype)
+    kv = {"k": jnp.stack(ks), "v": jnp.stack(vs)} if ks else None
+    return _head(x, params), states, kv
+
+
+def _attn_prefill(h, p: Params, a, cos, sin, live, kv_len, attn_impl):
+    """A hybrid attention layer over a whole padded prompt. h (B, S, D);
+    ``live`` (B, S) marks real positions. Returns (out (B, S, D), k, v
+    (B, kv_len, nkv, hd) with zeros past each row's length: the
+    zero-beyond-prompt discipline of the pages)."""
+    B, S, _ = h.shape
+    with jax.named_scope("qkv"):
+        positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
+        q, k, v = _attn_qkv_step(h, p, a, cos, sin, positions)
+    with jax.named_scope("attn"):
+        o = attention(q, k, v, causal=a.causal, impl=attn_impl)
+    with jax.named_scope("attn_out"):
+        out = o.reshape(B, S, a.num_heads * a.head_dim) @ p["wo"]
+    with jax.named_scope("kv_write"):
+        pad = ((0, 0), (0, kv_len - S), (0, 0), (0, 0))
+        keep = live[:, :, None, None]
+        k = jnp.pad(jnp.where(keep, k, 0), pad)
+        v = jnp.pad(jnp.where(keep, v, 0), pad)
+    return out, k, v
 
 
 def mamba_decode_step(
@@ -555,10 +803,12 @@ def mamba_decode_step(
     serve/decode.py does for llama; pure-Mamba configs pass ``{}`` /
     ``None`` and touch no cache at all. Returns (logits (B, V), state,
     kv_pools)."""
-    params = jax.tree.map(lambda a: a.astype(compute_dtype), params)
+    with jax.named_scope("params_cast"):
+        params = jax.tree.map(lambda a: a.astype(compute_dtype), params)
     B = tokens.shape[0]
     a = cfg.attn_cfg
-    x_t = params["embedding"][tokens]
+    with jax.named_scope("embed"):
+        x_t = params["embedding"][tokens]
 
     if cfg.attn_layer_idx:
         from fms_fsdp_tpu.ops.paged_attention import gather_pages, gqa_attend
@@ -572,18 +822,19 @@ def mamba_decode_step(
         new_pools = {"k": [], "v": []}
 
         def attn_cb(j, h, mixer):
-            q, k, v = _attn_qkv_step(h, mixer, a, cos, sin, positions)
-            k_pool = kv_pools["k"][j].at[page_ids, slots].set(k[:, 0])
-            v_pool = kv_pools["v"][j].at[page_ids, slots].set(v[:, 0])
+            with jax.named_scope("qkv"):
+                q, k, v = _attn_qkv_step(h, mixer, a, cos, sin, positions)
+            with jax.named_scope("kv_write"):
+                k_pool = kv_pools["k"][j].at[page_ids, slots].set(k[:, 0])
+                v_pool = kv_pools["v"][j].at[page_ids, slots].set(v[:, 0])
             new_pools["k"].append(k_pool)
             new_pools["v"].append(v_pool)
-            o = gqa_attend(
-                q,
-                gather_pages(k_pool, page_table),
-                gather_pages(v_pool, page_table),
-                positions,
-            )
-            return o[:, 0] @ mixer["wo"]
+            k_seq = gather_pages(k_pool, page_table)
+            v_seq = gather_pages(v_pool, page_table)
+            with jax.named_scope("attn"):
+                o = gqa_attend(q, k_seq, v_seq, positions)
+            with jax.named_scope("attn_out"):
+                return o[:, 0] @ mixer["wo"]
 
     else:
         new_pools = None
@@ -592,13 +843,14 @@ def mamba_decode_step(
             raise AssertionError("attn layer in a config without attn_layer_idx")
 
     residual, state = _stack_step(params, x_t, cfg, state, attn_cb)
-    x = rms_norm(residual.astype(compute_dtype), params["norm_f"], cfg.norm_eps)
-    logits = x @ params["lm_head"]
+    x = _block_norm(residual, params["norm_f"], cfg, compute_dtype)
+    logits = _head(x, params)
     if cfg.attn_layer_idx:
-        kv_pools = {
-            "k": jnp.stack(new_pools["k"]),
-            "v": jnp.stack(new_pools["v"]),
-        }
+        with jax.named_scope("kv_write"):
+            kv_pools = {
+                "k": jnp.stack(new_pools["k"]),
+                "v": jnp.stack(new_pools["v"]),
+            }
     return logits, state, kv_pools
 
 
@@ -622,6 +874,24 @@ def mamba_param_specs(cfg: MambaConfig) -> Params:
             "out_proj": P(AXIS_TENSOR, AXIS_FSDP),
         }
 
+    def mamba1_mixer():
+        return {
+            "in_proj": P(None, AXIS_FSDP, AXIS_TENSOR),
+            "conv_w": P(AXIS_FSDP, None),
+            "conv_b": P(AXIS_FSDP),
+            "x_proj": P(AXIS_TENSOR, None),
+            "dt_norm": P(None),
+            "B_norm": P(None),
+            "C_norm": P(None),
+            "dt_proj": P(None, AXIS_TENSOR),
+            "dt_bias": P(None),
+            "A_log": P(AXIS_FSDP, None),
+            "D": P(None),
+            "out_proj": P(AXIS_TENSOR, AXIS_FSDP),
+        }
+
+    ssm_mixer = mamba1_mixer if cfg.mamba1 else mamba_mixer
+
     def attn_mixer():
         return {
             "wq": P(AXIS_FSDP, AXIS_TENSOR),
@@ -634,7 +904,7 @@ def mamba_param_specs(cfg: MambaConfig) -> Params:
     for i in range(cfg.n_layer):
         layer = {
             "norm": P(None),
-            "mixer": attn_mixer() if i in cfg.attn_layer_idx else mamba_mixer(),
+            "mixer": attn_mixer() if i in cfg.attn_layer_idx else ssm_mixer(),
         }
         if cfg.d_intermediate > 0:
             layer["norm2"] = P(None)
@@ -645,11 +915,13 @@ def mamba_param_specs(cfg: MambaConfig) -> Params:
             }
         layers.append(layer)
 
-    return {
+    specs = {
         "embedding": P(AXIS_TENSOR, AXIS_FSDP),
         "layers": layers,
         "norm_f": P(None),
-        "lm_head": P(AXIS_FSDP, AXIS_TENSOR),
     }
+    if not cfg.tie_embeddings:
+        specs["lm_head"] = P(AXIS_FSDP, AXIS_TENSOR)
+    return specs
 
 

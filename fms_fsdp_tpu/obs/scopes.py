@@ -55,6 +55,30 @@ DECODE_SCOPES = (
     "sample",
 )
 
+# the scopes of a hybrid engine's two programs (models/mamba.py,
+# serve/families/mamba.py: ``jit__step`` and ``jit__prefill_<tokens>``),
+# in program order: the Mamba-1 mixer's five, the attention layers', and
+# what every layer shares. Nothing of a Mamba-1 step lies under none
+HYBRID_SCOPES = (
+    "params_cast",
+    "embed",
+    "norm",
+    "ssm_in_proj",
+    "ssm_conv",
+    "ssm_params",
+    "ssm_scan",
+    "ssm_gate_out",
+    "qkv",
+    "kv_write",
+    "kv_gather",
+    "attn",
+    "attn_out",
+    "mlp",
+    "lm_head",
+    "sample",
+)
+SSM_SCOPES = tuple(s for s in HYBRID_SCOPES if s.startswith("ssm_"))
+
 _COMPUTATION = re.compile(r"^(?:ENTRY\s+)?%?([\w.\-]+)\s.*\{\s*$")
 _INSTRUCTION = re.compile(r"^\s+(?:ROOT\s+)?%?([\w.\-]+) = ")
 _NAME = re.compile(r"%([\w.\-]+)")

@@ -178,6 +178,10 @@ class ServingEngine:
         self.registry.gauge("serve.moe_expert_reads_per_layer").set(
             self.adapter.moe_expert_reads_per_layer
         )
+        self.registry.gauge("serve.ssm_layers").set(self.adapter.ssm_layers)
+        self.registry.gauge("serve.ssm_state_bytes_per_stream").set(
+            self.adapter.state_bytes_per_stream
+        )
         if scfg.role != "unified" and not self.adapter.supports_handoff:
             raise ValueError(
                 f"role={scfg.role!r} needs page handoff, which the "
@@ -462,6 +466,7 @@ class ServingEngine:
             p, self.serve_cfg.prefill_bucket
         )
         built = self.adapter.prefill_programs_built
+        wrote = self.adapter.prefill_state_writes
         with span(
             "prefill",
             step=self.iterations,
@@ -473,6 +478,9 @@ class ServingEngine:
         self.registry.counter("serve.prefill_padded_tokens").add(padded)
         self.registry.counter("serve.prefill_programs_built").add(
             self.adapter.prefill_programs_built - built
+        )
+        self.registry.counter("serve.prefill_state_writes").add(
+            self.adapter.prefill_state_writes - wrote
         )
 
     def _prefill_admitted(self, req: Request, slot: int) -> None:

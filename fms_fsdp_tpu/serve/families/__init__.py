@@ -14,10 +14,11 @@ family          decode-state per stream
                 PR-11 path, ragged paged-attention kernel and all —
                 untouched, still the engine's bit-parity anchor)
 ``mamba``       fixed-size recurrent slab: per mamba layer a conv
-                window (d_conv-1, conv_dim) + fp32 SSD state (H,
-                headdim, d_state) — constant bytes regardless of
-                generated length; hybrid configs' attn layers ride
-                paged KV pages like llama
+                window (d_conv-1, conv_dim) + fp32 state, (H, headdim,
+                d_state) for a Mamba-2 mixer and (d_state, d_inner)
+                for a Mamba-1 mixer (the Jamba hybrids) — constant
+                bytes regardless of generated length; hybrid configs'
+                attn layers ride paged KV pages like llama
 ``mixtral``     paged KV pages for attention + nothing for the MoE:
                 expert routing is stateless per token (top-k gather
                 of expert weights at decode)
@@ -74,12 +75,20 @@ def load_model_config(d: dict):
 
     An explicit ``"family"`` key wins; otherwise the family is inferred
     from architecture-distinguishing keys (``d_model`` -> mamba,
-    ``num_experts`` -> mixtral, else llama). This is the single
+    ``num_experts`` -> mixtral, else llama). A published Jamba
+    ``config.json`` (``"model_type": "jamba"``, or ``"family": "jamba"``)
+    resolves to the mamba family through its own key mapping. This is the single
     resolution point replica.py and the engine share — the two can no
     longer diverge on model construction (the PR-11 bug this replaces:
     replica.py:71 hardwired its own ``init_llama_params`` copy)."""
     d = dict(d)
     family = d.pop("family", None)
+    if family == "jamba" or d.get("model_type") == "jamba":
+        # a published Jamba config.json: the mamba family's hybrid
+        # stack with Mamba-1 mixers (models/configs.py::jamba_config)
+        from fms_fsdp_tpu.models.configs import jamba_config
+
+        return jamba_config(d)
     if family is None:
         if "d_model" in d or "n_layer" in d:
             family = "mamba"
@@ -244,6 +253,11 @@ class FamilyAdapter:
     # expert weight copies one decode step reads in each layer (the gauge
     # serve.moe_expert_reads_per_layer); 0 for a family with no experts
     moe_expert_reads_per_layer: int = 0
+    # layers that keep a recurrent slab slice per stream (the gauge
+    # serve.ssm_layers), and the prefills that wrote one
+    # (serve.prefill_state_writes); 0 for a family with no such state
+    ssm_layers: int = 0
+    prefill_state_writes: int = 0
 
     def admission_error(self, prompt_len: int, max_new: int) -> Optional[str]:
         raise NotImplementedError

@@ -2,11 +2,21 @@
 
 A stream's decode state is a fixed-size slab (models/mamba.py::
 init_mamba_decode_state): per mamba layer the conv window plus the fp32
-SSD state. No paging, no growth — ``grow`` is always True and the slab
+carried state, shaped by the mixer kind (``slab_shapes``): Mamba-2
+``(d_conv-1, d_inner + 2*G*N)`` and ``(H, headdim, d_state)``, Mamba-1
+(the Jamba hybrids) ``(d_conv-1, d_inner)`` and ``(d_state, d_inner)``.
+No paging, no growth — ``grow`` is always True and the slab
 bytes a stream holds (``state_bytes_per_stream``) are constant in
 generated length, which is the family's headline property
 (tests/test_serving_families.py pins it against llama's growing
 ``kv_pages_in_use``).
+
+Prefill follows the mixer too (models/mamba.py::mamba_prefill): a
+Mamba-1 prompt goes through each layer as a sequence and hands over the
+final state, a Mamba-2 prompt scans the decode step over its positions.
+Both programs are functions of the two configs alone
+(``decode_program``, ``prefill_program``), so anyone can build them
+again and read their compiled HLO.
 
 Hybrid configs (attn_layer_idx non-empty) ride the existing PagedKVCache
 for their attention layers — page accounting, LIFO eviction and
@@ -30,7 +40,6 @@ siblings at zero recompute cost (docs/serving.md "Streaming transport
 & drain").
 """
 
-from functools import partial
 from typing import Dict, Optional
 
 import jax
@@ -39,12 +48,13 @@ import numpy as np
 
 from fms_fsdp_tpu.models.generation import sample_token
 from fms_fsdp_tpu.models.mamba import (
-    _conv_dim,
     init_mamba_decode_state,
     mamba_decode_step,
     mamba_prefill,
     mamba_state_bytes_per_stream,
+    slab_shapes,
 )
+from fms_fsdp_tpu.obs.scopes import scoped
 from fms_fsdp_tpu.obs.spans import span
 from fms_fsdp_tpu.serve.disagg.slab import (
     SLAB_CODEC_VERSION,
@@ -54,6 +64,91 @@ from fms_fsdp_tpu.serve.disagg.slab import (
 )
 from fms_fsdp_tpu.serve.families import FamilyAdapter
 from fms_fsdp_tpu.serve.kv_cache import RESERVED_PAGES, PagedKVCache
+
+
+def page_geometry(model_cfg, scfg):
+    """``(page_size, max_pages, num_pages)`` of the paged cache a hybrid
+    engine builds for its attention layers. No tuning-table entry for
+    the hybrid attention shape yet: 16 matches the table's common
+    resolution and keeps max_seq_len divisible in every test config."""
+    page_size = scfg.page_size or 16
+    assert scfg.max_seq_len % page_size == 0, (scfg.max_seq_len, page_size)
+    max_pages = scfg.max_seq_len // page_size
+    num_pages = scfg.num_pages or (scfg.max_batch * max_pages + RESERVED_PAGES)
+    return page_size, max_pages, num_pages
+
+
+@scoped("ssm_scan")
+def _mask_state(new, old, live):
+    """The slab after a step: the new rows where ``live``, the old rows
+    elsewhere (the end of the state's update, so under its scope)."""
+    return jax.tree.map(
+        lambda n, o: jnp.where(
+            live.reshape((o.shape[0],) + (1,) * (n.ndim - 1)), n, o
+        ),
+        new,
+        old,
+    )
+
+
+def decode_program(model_cfg, scfg, page_size: int, compute_dtype):
+    """The jitted decode step of a mamba engine: one recurrent step over
+    ``scfg.max_batch`` slots and the sampler, slab (and pools) donated.
+    A function of the two configs alone (families/mixtral.py::
+    decode_program says why); the traced function keeps the name
+    ``_step``, so the profiler shows the program as ``jit__step``.
+
+    Hybrid: ``(params, state, pools, page_table, seq_lens, tokens, key)
+    -> (tokens (B,) int32, logits (B, V), state, pools)``; without
+    attention layers the pools and the table drop out of both."""
+    cfg = model_cfg
+
+    def sample(logits, key):
+        tok = sample_token(
+            logits, key, scfg.temperature, scfg.top_k, scfg.do_sample
+        )
+        return tok.astype(jnp.int32)
+
+    if cfg.attn_layer_idx:
+
+        def _step(params, state, pools, page_table, seq_lens, tokens, key):
+            logits, new_state, pools = mamba_decode_step(
+                params, state, pools, page_table, seq_lens, tokens,
+                cfg, page_size=page_size, compute_dtype=compute_dtype,
+            )
+            # idle rows (lens 0 — a prompt is never empty) must not
+            # smear garbage into released, zeroed slab slices
+            state = _mask_state(new_state, state, seq_lens > 0)
+            return sample(logits, key), logits, state, pools
+
+        return jax.jit(_step, donate_argnums=(1, 2))
+
+    def _step(params, state, seq_lens, tokens, key):
+        logits, new_state, _ = mamba_decode_step(
+            params, state, None, None, seq_lens, tokens,
+            cfg, compute_dtype=compute_dtype,
+        )
+        state = _mask_state(new_state, state, seq_lens > 0)
+        return sample(logits, key), logits, state
+
+    return jax.jit(_step, donate_argnums=(1,))
+
+
+def prefill_program(model_cfg, scfg, p_pad: int, kv_len: int, compute_dtype):
+    """The jitted prefill of one padded prompt length: ``(params, tokens
+    (1, p_pad), lengths (1,)) -> (logits (1, V), slab rows, kv)``. The
+    traced function is named by the length, so the profiler shows each
+    shape's program under its own name, ``jit__prefill_<p_pad>``."""
+    attn_impl = "auto" if scfg.attn_impl == "auto" else "xla"
+
+    def _prefill(params, tokens, lengths):
+        return mamba_prefill(
+            params, tokens, lengths, model_cfg,
+            compute_dtype=compute_dtype, kv_len=kv_len, attn_impl=attn_impl,
+        )
+
+    _prefill.__name__ = f"_prefill_{p_pad}"
+    return jax.jit(_prefill)
 
 
 class MambaAdapter(FamilyAdapter):
@@ -102,16 +197,8 @@ class MambaAdapter(FamilyAdapter):
 
         if self._hybrid:
             a = cfg.attn_cfg
-            # default page size: no tuning-table entry for the hybrid
-            # attn shape yet — 16 matches the table's common resolution
-            # and keeps max_seq_len divisible in every test config
-            self.page_size = scfg.page_size or 16
-            assert scfg.max_seq_len % self.page_size == 0, (
-                scfg.max_seq_len, self.page_size
-            )
-            self.max_pages = scfg.max_seq_len // self.page_size
-            num_pages = scfg.num_pages or (
-                scfg.max_batch * self.max_pages + RESERVED_PAGES
+            self.page_size, self.max_pages, num_pages = page_geometry(
+                cfg, scfg
             )
             self.cache = PagedKVCache(
                 len(cfg.attn_layer_idx),
@@ -134,50 +221,27 @@ class MambaAdapter(FamilyAdapter):
         self._table_key = None
         self._table_dev = None
 
-        def _mask_state(new, old, live):
+        self._decode_fn = decode_program(
+            cfg, scfg, self.page_size, self.compute_dtype
+        )
+        self.ssm_layers = cfg.n_layer - len(cfg.attn_layer_idx)
+
+        # one stream's rows into its slot of the slab (and zeros, on
+        # release): jitted with the slab donated, so a write moves the
+        # rows and not the slab
+        def _write_slot(state, rows, slot):
             return jax.tree.map(
-                lambda n, o: jnp.where(
-                    live.reshape((o.shape[0],) + (1,) * (n.ndim - 1)), n, o
+                lambda s, r: jax.lax.dynamic_update_index_in_dim(
+                    s, r.astype(s.dtype), slot, 0
                 ),
-                new,
-                old,
+                state,
+                rows,
             )
 
-        if self._hybrid:
-            page_size = self.page_size
-
-            def _step(params, state, pools, page_table, seq_lens, tokens,
-                      key):
-                logits, new_state, pools = mamba_decode_step(
-                    params, state, pools, page_table, seq_lens, tokens,
-                    cfg, page_size=page_size,
-                    compute_dtype=self.compute_dtype,
-                )
-                # idle rows (lens 0 — a prompt is never empty) must not
-                # smear garbage into released, zeroed slab slices
-                state = _mask_state(new_state, state, seq_lens > 0)
-                tok = sample_token(
-                    logits, key, scfg.temperature, scfg.top_k,
-                    scfg.do_sample,
-                )
-                return tok.astype(jnp.int32), logits, state, pools
-
-            self._decode_fn = jax.jit(_step, donate_argnums=(1, 2))
-        else:
-
-            def _step(params, state, seq_lens, tokens, key):
-                logits, new_state, _ = mamba_decode_step(
-                    params, state, None, None, seq_lens, tokens,
-                    cfg, compute_dtype=self.compute_dtype,
-                )
-                state = _mask_state(new_state, state, seq_lens > 0)
-                tok = sample_token(
-                    logits, key, scfg.temperature, scfg.top_k,
-                    scfg.do_sample,
-                )
-                return tok.astype(jnp.int32), logits, state
-
-            self._decode_fn = jax.jit(_step, donate_argnums=(1,))
+        self._write_slot = jax.jit(_write_slot, donate_argnums=(0,))
+        self._zero_rows = jax.tree.map(
+            lambda s: jnp.zeros((1,) + s.shape[1:], s.dtype), self._state
+        )
 
     # -- capacity ----------------------------------------------------------
 
@@ -211,8 +275,8 @@ class MambaAdapter(FamilyAdapter):
     def release(self, rid: int, slot: int) -> None:
         # zero the slab slice: an idle slot must hold no residue of the
         # evicted stream (and the decode step's live-mask keeps it zero)
-        self._state = jax.tree.map(
-            lambda s: s.at[slot].set(0), self._state
+        self._state = self._write_slot(
+            self._state, self._zero_rows, np.int32(slot)
         )
         if self._hybrid:
             self.cache.free(rid)
@@ -224,13 +288,8 @@ class MambaAdapter(FamilyAdapter):
         fn = self._prefill_cache.get(key)
         if fn is None:
             self.prefill_programs_built += 1
-            fn = jax.jit(
-                partial(
-                    mamba_prefill,
-                    cfg=self.model_cfg,
-                    compute_dtype=self.compute_dtype,
-                    kv_len=kv_len,
-                )
+            fn = prefill_program(
+                self.model_cfg, self.scfg, p_pad, kv_len, self.compute_dtype
             )
             self._prefill_cache[key] = fn
         return fn
@@ -255,12 +314,14 @@ class MambaAdapter(FamilyAdapter):
             logits, st1, kv = fn(
                 self.params, jnp.asarray(toks), jnp.asarray([p], np.int32)
             )
-        with span("prefill.write_pages", rid=rid):
+        with span("prefill.write_state", rid=rid):
             # land the 1-row prefill state in the stream's slab slice
-            self._state = jax.tree.map(
-                lambda s, n: s.at[slot].set(n[0]), self._state, st1
+            self._state = self._write_slot(
+                self._state, st1, np.int32(slot)
             )
-            if self._hybrid:
+            self.prefill_state_writes += 1
+        if self._hybrid:
+            with span("prefill.write_pages", rid=rid):
                 self.cache.write_prompt(rid, kv["k"][:, 0], kv["v"][:, 0])
         # prefill already selects each row's last real position
         return logits[0]
@@ -310,10 +371,9 @@ class MambaAdapter(FamilyAdapter):
             "compute_dtype": jnp.dtype(self.compute_dtype).name,
             "n_layer": int(cfg.n_layer),
             "attn_layers": sorted(int(i) for i in cfg.attn_layer_idx),
-            "conv_shape": [int(cfg.d_conv - 1), int(_conv_dim(cfg))],
-            "ssd_shape": [
-                int(cfg.nheads), int(cfg.headdim), int(cfg.d_state)
-            ],
+            "ssm_layer": cfg.ssm_layer,
+            "conv_shape": [int(d) for d in slab_shapes(cfg)[0]],
+            "ssd_shape": [int(d) for d in slab_shapes(cfg)[1]],
         }
         if self._hybrid:
             geo.update(
@@ -410,8 +470,8 @@ class MambaAdapter(FamilyAdapter):
             # pre-import value
             if self._hybrid:
                 self.cache.free(rid)
-            self._state = jax.tree.map(
-                lambda s: s.at[slot].set(0), self._state
+            self._state = self._write_slot(
+                self._state, self._zero_rows, np.int32(slot)
             )
             raise HandoffError(
                 f"slab import failed after allocation (pages freed, "
@@ -426,6 +486,13 @@ class MambaAdapter(FamilyAdapter):
         return mamba_state_bytes_per_stream(
             self.model_cfg, self.compute_dtype
         )
+
+    @property
+    def slab(self):
+        """The whole slab: list over layers of {"conv", "ssd"} with a
+        leading slot axis ({} for hybrid attn layers). Donated into every
+        decode step, so a reference kept across one goes stale."""
+        return self._state
 
     def slab_slice(self, slot: int):
         """The slot's slab (debug/tests): list over layers of {"conv",

@@ -353,7 +353,9 @@ def make_train_step(
         if fused:
             from fms_fsdp_tpu.ops.fused_ce import fused_linear_cross_entropy
 
-            w = params["lm_head"].astype(policy.compute_dtype)
+            # a tied head (MambaConfig.tie_embeddings) is the embedding
+            w = params["lm_head"] if "lm_head" in params else params["embedding"].T
+            w = w.astype(policy.compute_dtype)
             return fused_linear_cross_entropy(out, w, labels, chunk) + aux, stats
         return cross_entropy_loss(out, labels) + aux, stats
 
