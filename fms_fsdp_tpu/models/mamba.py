@@ -32,9 +32,12 @@ Layers are heterogeneous, so the stack runs as an unrolled loop (not
 lax.scan); params live in a per-layer list pytree.
 
 Serving: recurrent decode from a constant-size slab for both mixers. A
-Mamba-1 prompt is prefilled as a sequence (each layer over the whole
-padded prompt, the final state and conv tail handed to the slab); a
-Mamba-2 prompt still scans the decode step over positions (ROADMAP A6).
+Mamba-1 prompt is prefilled as a sequence, a chunk of ``PREFILL_CHUNK``
+positions at a time through the whole stack, in a loop inside the one
+program that stops at the prompt's length (``_prefill_sequence``: the
+scan's state, the conv's last inputs and the K/V written so far go from
+chunk to chunk; the last chunk's state is handed to the slab); a Mamba-2
+prompt still scans the decode step over positions (ROADMAP A6).
 """
 
 import functools
@@ -48,12 +51,13 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from fms_fsdp_tpu.models.configs import MambaConfig
 from fms_fsdp_tpu.obs.scopes import scoped
-from fms_fsdp_tpu.ops.attention import attention
+from fms_fsdp_tpu.ops.attention import attention, chunk_attention
 from fms_fsdp_tpu.ops.norms import rms_norm
 from fms_fsdp_tpu.ops.quant import matmul as qmatmul
 from fms_fsdp_tpu.ops.rope import apply_rotary, rope_table
 from fms_fsdp_tpu.ops.selective_scan import (
     freeze_past,
+    largest_divisor,
     selective_scan,
     selective_scan_reference,
     selective_scan_step,
@@ -248,18 +252,21 @@ def _mamba1_scan_inputs(u, p: Params, cfg: MambaConfig):
 
 def _mamba1_mixer(
     x, p: Params, cfg: MambaConfig, mesh=None, quant="none", *,
-    lengths=None, scan=selective_scan_reference,
+    lengths=None, scan=selective_scan_reference, carry=None,
 ):
     """x (B, S, D) compute dtype -> (out (B, S, D), slab) through a
-    Mamba-1 mixer, the scan started from a zero state. With ``lengths``
-    (B,) a row's state freezes at its length, and ``slab`` is what the
-    recurrent decode step goes on from: {"conv": the last d_conv-1
-    pre-conv inputs before that position, "ssd": the state there}.
+    Mamba-1 mixer. With ``lengths`` (B,) a row's state freezes at its
+    length, and ``slab`` is what the recurrent decode step goes on from:
+    {"conv": the last d_conv-1 pre-conv inputs before that position,
+    "ssd": the state there}. ``carry`` is such a slab to go on from (the
+    sequence is then the continuation of the one that left it); without
+    it the scan starts from a zero state and the conv from zero inputs.
     ``scan`` is the sequence form of ops/selective_scan.py to run: the
     differentiable ``lax.scan`` one unless the caller (prefill) asks for
     the one that fits the platform."""
     B, S, _ = x.shape
     di, N, K = cfg.d_inner, cfg.d_state, cfg.d_conv
+    before = None if carry is None else carry["conv"]
     with jax.named_scope("ssm_in_proj"):
         u_pre, z = (
             _constrain(
@@ -269,7 +276,9 @@ def _mamba1_mixer(
             for i in range(2)
         )
     with jax.named_scope("ssm_conv"):
-        u = causal_conv1d(u_pre, p["conv_w"], p["conv_b"], activation="silu")
+        u = causal_conv1d(
+            u_pre, p["conv_w"], p["conv_b"], activation="silu", init=before
+        )
     with jax.named_scope("ssm_params"):
         dt, A, Bm, Cm = _mamba1_scan_inputs(u, p, cfg)
         if lengths is not None:
@@ -278,7 +287,8 @@ def _mamba1_mixer(
         y, h = scan(
             u.astype(jnp.float32), dt, A, Bm.astype(jnp.float32),
             Cm.astype(jnp.float32), p["D"].astype(jnp.float32),
-            jnp.zeros((B, N, di), jnp.float32),
+            jnp.zeros((B, N, di), jnp.float32) if carry is None
+            else carry["ssd"],
         )
     with jax.named_scope("ssm_gate_out"):
         out = qmatmul(
@@ -288,7 +298,9 @@ def _mamba1_mixer(
     if lengths is None:
         return out, None
     with jax.named_scope("ssm_conv"):
-        padded = jnp.pad(u_pre, ((0, 0), (K - 1, 0), (0, 0)))
+        if before is None:
+            before = jnp.zeros((B, K - 1, di), u_pre.dtype)
+        padded = jnp.concatenate([before.astype(u_pre.dtype), u_pre], 1)
         tail = jax.vmap(
             lambda row, n: lax.dynamic_slice_in_dim(row, n, K - 1, axis=0)
         )(padded, lengths)
@@ -712,73 +724,132 @@ def mamba_prefill(
     return logits, states, (kv if n_attn else None)
 
 
+# positions one trip of the sequence prefill's loop takes through the
+# stack. A chunk's products do C operations a weight byte (the v5e's
+# ridge is 240) and every chunk reads every weight once; timed on the
+# chip at 256, 512 and 1024 (PERF.md, PR 28). A constant of the program:
+# no option selects it.
+PREFILL_CHUNK = 512
+
+
+def prefill_chunk(p_pad: int) -> int:
+    """The chunk of a prompt padded to ``p_pad``: the largest divisor of
+    ``p_pad`` up to ``PREFILL_CHUNK``, so that chunks tile the bucket."""
+    return largest_divisor(p_pad, PREFILL_CHUNK)
+
+
+def prefill_positions(cfg: MambaConfig, p: int, p_pad: int) -> int:
+    """Positions that ``mamba_prefill`` computes for a prompt of ``p``
+    tokens padded to ``p_pad``: whole chunks up to the prompt's end for
+    a Mamba-1 stack, the whole bucket for a Mamba-2 one."""
+    if not cfg.mamba1:
+        return p_pad
+    c = prefill_chunk(p_pad)
+    return -(-p // c) * c
+
+
 def _prefill_sequence(
     params: Params, tokens, lengths, cfg: MambaConfig, compute_dtype,
     kv_len: int, attn_impl: str,
 ):
-    """``mamba_prefill`` for a Mamba-1 stack, each layer over the whole
-    padded prompt: one product for in_proj, the conv, the selective scan
-    in the form that fits the platform with each row's state frozen at
-    its length, causal attention within the prompt. Same results as the
-    per-position form: last real position's logits, the slab, and K/V
-    (zero past each row's length) for the pages. A padded position costs
-    what a real one does, and touches nothing that is kept."""
+    """``mamba_prefill`` for a Mamba-1 stack: the prompt as a sequence,
+    ``prefill_chunk`` positions at a time through every layer, in one
+    loop whose trip count is read from ``lengths`` on the device, so the
+    chunks past the longest row's end are never computed. From chunk to
+    chunk go, per Mamba layer, the slab (the scan's float32 state and
+    the conv's last inputs, each row's frozen at its length), the K/V
+    written so far, and each row's residual at its last real position.
+    Same results as the per-position form: that position's logits, the
+    slab, and K/V (zero past each row's length) for the pages."""
     B, S = tokens.shape
     a = cfg.attn_cfg
+    c = prefill_chunk(S)
     kv_len = kv_len or S
     assert kv_len >= S, (kv_len, S)
-    live = jnp.arange(S, dtype=jnp.int32)[None, :] < lengths[:, None]
+    assert a.causal, "a chunk cannot attend to the chunks after it"
     cos = sin = None
     if a.rotary_emb_dim:
         cos, sin = rope_table(S, a.rotary_emb_dim, 10000.0)
-    with jax.named_scope("embed"):
-        residual = params["embedding"][tokens].astype(jnp.float32)
-    states, ks, vs = [], [], []
-    for i, layer in enumerate(params["layers"]):
-        h = _block_norm(residual, layer["norm"], cfg, compute_dtype)
-        if i in cfg.attn_layer_idx:
-            out, k, v = _attn_prefill(
-                h, layer["mixer"], a, cos, sin, live, kv_len, attn_impl
-            )
-            ks.append(k)
-            vs.append(v)
-            states.append({})
-        else:
-            out, st = _mamba1_mixer(
-                h, layer["mixer"], cfg, lengths=lengths, scan=selective_scan
-            )
-            states.append(st)
-        residual = _add(residual, out)
-        if "mlp" in layer:
-            h2 = _block_norm(residual, layer["norm2"], cfg, compute_dtype)
-            residual = _add(residual, _mlp(h2, layer["mlp"], None))
-    last = jnp.take_along_axis(
-        residual, (lengths - 1)[:, None, None], axis=1
-    )[:, 0]
+    n_attn = len(cfg.attn_layer_idx)
+    kv_shape = (B, kv_len, a.num_heads_kv, a.head_dim)
+
+    def chunk(j, carry):
+        states, ks, vs, last = carry
+        start = j * c
+        ahead = lengths - start  # of each row, from this chunk's start on
+        live = jnp.arange(c, dtype=jnp.int32)[None, :] < ahead[:, None]
+        with jax.named_scope("embed"):
+            toks = lax.dynamic_slice_in_dim(tokens, start, c, axis=1)
+            residual = params["embedding"][toks].astype(jnp.float32)
+        states, ks, vs, attn_j = list(states), list(ks), list(vs), 0
+        for i, layer in enumerate(params["layers"]):
+            h = _block_norm(residual, layer["norm"], cfg, compute_dtype)
+            if i in cfg.attn_layer_idx:
+                out, ks[attn_j], vs[attn_j] = _attn_prefill(
+                    h, layer["mixer"], a, cos, sin, live,
+                    ks[attn_j], vs[attn_j], start, attn_impl,
+                )
+                attn_j += 1
+            else:
+                out, states[i] = _mamba1_mixer(
+                    h, layer["mixer"], cfg, lengths=jnp.clip(ahead, 0, c),
+                    scan=selective_scan, carry=states[i],
+                )
+            residual = _add(residual, out)
+            if "mlp" in layer:
+                h2 = _block_norm(residual, layer["norm2"], cfg, compute_dtype)
+                residual = _add(residual, _mlp(h2, layer["mlp"], None))
+        # the head reads a row's last real position alone
+        at = ahead - 1
+        row = jnp.take_along_axis(
+            residual, jnp.clip(at, 0, c - 1)[:, None, None], axis=1
+        )[:, 0]
+        last = jnp.where(((at >= 0) & (at < c))[:, None], row, last)
+        return states, ks, vs, last
+
+    states, ks, vs, last = lax.fori_loop(
+        0,
+        (jnp.max(lengths) + c - 1) // c,
+        chunk,
+        (
+            init_mamba_decode_state(cfg, B, compute_dtype),
+            [jnp.zeros(kv_shape, compute_dtype)] * n_attn,
+            [jnp.zeros(kv_shape, compute_dtype)] * n_attn,
+            jnp.zeros((B, cfg.d_model), jnp.float32),
+        ),
+    )
     x = _block_norm(last, params["norm_f"], cfg, compute_dtype)
-    kv = {"k": jnp.stack(ks), "v": jnp.stack(vs)} if ks else None
+    kv = {"k": jnp.stack(ks), "v": jnp.stack(vs)} if n_attn else None
     return _head(x, params), states, kv
 
 
-def _attn_prefill(h, p: Params, a, cos, sin, live, kv_len, attn_impl):
-    """A hybrid attention layer over a whole padded prompt. h (B, S, D);
-    ``live`` (B, S) marks real positions. Returns (out (B, S, D), k, v
-    (B, kv_len, nkv, hd) with zeros past each row's length: the
+def _attn_prefill(h, p: Params, a, cos, sin, live, k_buf, v_buf, start,
+                  attn_impl):
+    """A hybrid attention layer over one chunk of a padded prompt. h
+    (B, c, D) at positions ``start`` to ``start + c``; ``live`` (B, c)
+    marks real positions; k_buf, v_buf (B, kv_len, nkv, hd) hold the
+    chunks before this one. Returns (out (B, c, D), k_buf, v_buf with
+    this chunk written: zeros at positions that are not real, the
     zero-beyond-prompt discipline of the pages)."""
-    B, S, _ = h.shape
+    B, c, _ = h.shape
     with jax.named_scope("qkv"):
-        positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
+        positions = jnp.broadcast_to(
+            start + jnp.arange(c, dtype=jnp.int32), (B, c)
+        )
         q, k, v = _attn_qkv_step(h, p, a, cos, sin, positions)
-    with jax.named_scope("attn"):
-        o = attention(q, k, v, causal=a.causal, impl=attn_impl)
-    with jax.named_scope("attn_out"):
-        out = o.reshape(B, S, a.num_heads * a.head_dim) @ p["wo"]
     with jax.named_scope("kv_write"):
-        pad = ((0, 0), (0, kv_len - S), (0, 0), (0, 0))
         keep = live[:, :, None, None]
-        k = jnp.pad(jnp.where(keep, k, 0), pad)
-        v = jnp.pad(jnp.where(keep, v, 0), pad)
-    return out, k, v
+        k_buf = lax.dynamic_update_slice_in_dim(
+            k_buf, jnp.where(keep, k, 0), start, axis=1
+        )
+        v_buf = lax.dynamic_update_slice_in_dim(
+            v_buf, jnp.where(keep, v, 0), start, axis=1
+        )
+    with jax.named_scope("attn"):
+        o = chunk_attention(q, k_buf, v_buf, start, impl=attn_impl)
+    with jax.named_scope("attn_out"):
+        out = o.reshape(B, c, a.num_heads * a.head_dim) @ p["wo"]
+    return out, k_buf, v_buf
 
 
 def mamba_decode_step(

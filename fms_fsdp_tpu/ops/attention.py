@@ -13,12 +13,17 @@ implementations behind one dispatcher:
 
 All functions take q:(B, S, Nq, H), k/v:(B, S, Nkv, H) with Nq % Nkv == 0
 (GQA: 64/8 heads at 70B per ref:config_utils.py:26-34).
+
+``chunk_attention`` is the same attention for a prompt taken a chunk at a
+time (the hybrid's looped prefill): the queries of one chunk against a
+cache that holds the chunks before it.
 """
 
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 
 from fms_fsdp_tpu.ops import flash_attention as _fa
 
@@ -133,3 +138,46 @@ def attention(q, k, v, *, causal: bool = True, impl: str = "auto", mesh=None):
     ):
         return _flash(q, k, v, causal, mesh)
     return xla_attention(q, k, v, causal=causal)
+
+
+def chunk_attention(q, k_cache, v_cache, start, *, impl: str = "auto"):
+    """Causal attention of the ``c`` queries at positions ``start`` to
+    ``start + c`` against a cache written up to there.
+
+    q (B, c, Nq, H); k_cache/v_cache (B, L, Nkv, H), L at least
+    ``start + c``; ``start`` a multiple of ``c``, traced or not. The
+    chunk's own block of keys goes under the causal mask, each earlier
+    block is seen
+    whole, and the partials merge exactly through their log-sum-exp, as
+    ring attention merges a device's (ops/ring_attention.py). Each
+    partial is the flash kernel where ``attention`` would take it and an
+    einsum over one (c, c) score block elsewhere. The cache from
+    ``start + c`` on is never read, so the cost follows the positions
+    computed so far and not the cache's length. Returns (B, c, Nq, H)."""
+    from fms_fsdp_tpu.ops.pallas_mode import interpret_default
+    from fms_fsdp_tpu.ops.ring_attention import einsum_partial, merge_partial
+
+    b, c, _, h = q.shape
+    scale = h**-0.5
+    flash = _fa.supports(q.shape, (b, c) + k_cache.shape[2:]) and (
+        impl == "pallas" or (impl == "auto" and jax.default_backend() == "tpu")
+    )
+
+    def partial_at(at, diag):
+        k = lax.dynamic_slice_in_dim(k_cache, at, c, axis=1)
+        v = lax.dynamic_slice_in_dim(v_cache, at, c, axis=1)
+        if flash:
+            return _fa.flash_attention(
+                q, k, v, causal=diag, scale=scale, return_lse=True,
+                interpret=interpret_default(),
+            )
+        return einsum_partial(q, k, v, diag, scale)
+
+    o, lse = partial_at(start, True)
+    o, _ = lax.fori_loop(
+        0,
+        start // c,
+        lambda i, carry: merge_partial(carry, *partial_at(i * c, False)),
+        (o.astype(jnp.float32), lse),
+    )
+    return o.astype(q.dtype)

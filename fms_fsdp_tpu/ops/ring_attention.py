@@ -67,7 +67,7 @@ def _scores(q, k, causal, scale):
     return qg, s
 
 
-def _einsum_partial(q, k, v, causal, scale):
+def einsum_partial(q, k, v, causal, scale):
     """Small-shape fallback: (o_norm, lse) via a materialized score matrix."""
     b, sq, nq, h = q.shape
     _, s = _scores(q, k, causal, scale)
@@ -82,6 +82,18 @@ def _einsum_partial(q, k, v, causal, scale):
     o = jnp.moveaxis(o, 3, 1).reshape(b, sq, nq, h)
     lse = jnp.moveaxis(lse, 3, 1).reshape(b, sq, nq, 1)
     return o, lse
+
+
+def merge_partial(carry, o, lse):
+    """(running fp32 output, running lse) with one more partial over a
+    disjoint kv set merged in, exactly:  lse' = logaddexp(lse_a, lse_b),
+    o' = o_a exp(lse_a - lse') + o_b exp(lse_b - lse')."""
+    acc, lse_run = carry
+    lse_new = jnp.logaddexp(lse_run, lse)
+    # fully-masked-so-far rows: keep weights finite
+    w_run = jnp.exp(jnp.maximum(lse_run - lse_new, NEG_INF))
+    w_new = jnp.exp(jnp.maximum(lse - lse_new, NEG_INF))
+    return acc * w_run + o.astype(jnp.float32) * w_new, lse_new
 
 
 def _einsum_partial_grads(q, k, v, do, lse, delta, causal, scale):
@@ -177,7 +189,7 @@ def ring_attention(q, k, v, mesh, *, causal: bool = True, scale=None):
                 interpret=interpret,
                 return_lse=True,
             )
-        return _einsum_partial(q_loc, k_cur, v_cur, diag, scale)
+        return einsum_partial(q_loc, k_cur, v_cur, diag, scale)
 
     def partial_grads(qpack, k_cur, v_cur, diag: bool):
         if use_flash:
@@ -218,25 +230,17 @@ def ring_attention(q, k, v, mesh, *, causal: bool = True, scale=None):
         idx = lax.axis_index(AXIS_CONTEXT)
         b, s_loc, nq, h = q.shape
 
-        def merge(carry, o, lse):
-            acc, lse_run = carry
-            lse_new = jnp.logaddexp(lse_run, lse)
-            # fully-masked-so-far rows: keep weights finite
-            w_run = jnp.exp(jnp.maximum(lse_run - lse_new, NEG_INF))
-            w_new = jnp.exp(jnp.maximum(lse - lse_new, NEG_INF))
-            return acc * w_run + o.astype(jnp.float32) * w_new, lse_new
-
         def body(step, carry):
             acc, lse_run, k_cur, v_cur = carry
             src = (idx - step) % cp  # global chunk currently held
 
             def diag(_):
                 o, lse = partial_fn(q, k_cur, v_cur, True)
-                return merge((acc, lse_run), o, lse)
+                return merge_partial((acc, lse_run), o, lse)
 
             def visible(_):
                 o, lse = partial_fn(q, k_cur, v_cur, False)
-                return merge((acc, lse_run), o, lse)
+                return merge_partial((acc, lse_run), o, lse)
 
             def masked(_):
                 return acc, lse_run

@@ -129,8 +129,10 @@ def kernel_supports(channels: int) -> bool:
     return rows % SUBLANES == 0 or rows < SUBLANES
 
 
-def _chunk(S: int) -> int:
-    return max(t for t in range(1, min(S, MAX_CHUNK) + 1) if S % t == 0)
+def largest_divisor(n: int, cap: int) -> int:
+    """The largest divisor of ``n`` that is at most ``cap``: the chunk
+    that tiles ``n`` positions."""
+    return max(t for t in range(1, min(n, cap) + 1) if n % t == 0)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -140,7 +142,7 @@ def selective_scan_kernel(u, dt, A, B, C, D, h0, interpret=False):
     N = A.shape[0]
     R = Cn // LANES
     Rb = SUBLANES if R % SUBLANES == 0 else R
-    T = _chunk(S)
+    T = largest_divisor(S, MAX_CHUNK)
     f32 = jnp.float32
 
     def tiles(x):  # (..., Cn) -> (..., R, 128)

@@ -561,9 +561,11 @@ def ssd_scan_reference(x, dt, A, Bm, Cm, D=None):
 
 
 @scoped("causal_conv1d")
-def causal_conv1d(x, weight, bias=None, activation: str = "silu"):
+def causal_conv1d(x, weight, bias=None, activation: str = "silu", init=None):
     """Depthwise causal conv over (B, S, C) with kernel (C, W), the
-    mamba_ssm causal_conv1d equivalent.
+    mamba_ssm causal_conv1d equivalent. ``init`` (B, W-1, C) is the
+    inputs that came before ``x`` (a sequence taken up where an earlier
+    call left off); without it they are zeros.
 
     Expressed as W shifted fused multiply-adds instead of a grouped
     ``lax.conv``: XLA lowers a feature_group_count==C conv terribly on TPU
@@ -575,7 +577,10 @@ def causal_conv1d(x, weight, bias=None, activation: str = "silu"):
     B, S, Cch = x.shape
     W = weight.shape[-1]
     wf = weight.astype(jnp.float32)
-    xt = jnp.pad(x, ((0, 0), (W - 1, 0), (0, 0)))
+    if init is None:
+        xt = jnp.pad(x, ((0, 0), (W - 1, 0), (0, 0)))
+    else:
+        xt = jnp.concatenate([init.astype(x.dtype), x], axis=1)
     out = sum(
         lax.dynamic_slice_in_dim(xt, w, S, axis=1).astype(jnp.float32)
         * wf[None, None, :, w]

@@ -467,6 +467,7 @@ class ServingEngine:
         )
         built = self.adapter.prefill_programs_built
         wrote = self.adapter.prefill_state_writes
+        ran = self.adapter.prefill_computed_tokens
         with span(
             "prefill",
             step=self.iterations,
@@ -475,7 +476,10 @@ class ServingEngine:
             padded_tokens=padded,
         ):
             self._prefill_admitted(req, slot)
+            ran = self.adapter.prefill_computed_tokens - ran
+            done("prefill", rid=req.rid, computed_tokens=ran)
         self.registry.counter("serve.prefill_padded_tokens").add(padded)
+        self.registry.counter("serve.prefill_computed_tokens").add(ran)
         self.registry.counter("serve.prefill_programs_built").add(
             self.adapter.prefill_programs_built - built
         )

@@ -258,6 +258,10 @@ class FamilyAdapter:
     # (serve.prefill_state_writes); 0 for a family with no such state
     ssm_layers: int = 0
     prefill_state_writes: int = 0
+    # positions the prefill programs computed (serve.prefill_computed_
+    # tokens): the padded tokens, but for a prefill that stops at the
+    # prompt's length inside its bucket (mamba.py's Mamba-1 loop)
+    prefill_computed_tokens: int = 0
 
     def admission_error(self, prompt_len: int, max_new: int) -> Optional[str]:
         raise NotImplementedError

@@ -309,6 +309,7 @@ class LlamaAdapter(FamilyAdapter):
             toks = np.zeros((1, p_pad), np.int32)
             toks[0, :p] = prompt
             logits, embeds, kv = fn(self.params, self._dev(toks))
+            self.prefill_computed_tokens += p_pad
         with span("prefill.write_pages", rid=rid):
             self.cache.write_prompt(rid, kv["k"][:, 0], kv["v"][:, 0])
         if self.speculative:
@@ -355,6 +356,7 @@ class LlamaAdapter(FamilyAdapter):
         assert ok, "admission checked capacity; ensure cannot fail here"
         toks = np.zeros((1, p_pad), np.int32)
         toks[0, :p] = prompt
+        self.prefill_computed_tokens += p_pad  # the chunks cover the bucket
         nlayers = int(self.params["layers"]["wq"].shape[0])
         # mini-cache length p_pad, NOT s_pad: whole-prompt prefill's
         # attention reduces over exactly p_pad key positions, and

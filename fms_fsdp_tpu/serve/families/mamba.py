@@ -12,8 +12,11 @@ generated length, which is the family's headline property
 ``kv_pages_in_use``).
 
 Prefill follows the mixer too (models/mamba.py::mamba_prefill): a
-Mamba-1 prompt goes through each layer as a sequence and hands over the
-final state, a Mamba-2 prompt scans the decode step over its positions.
+Mamba-1 prompt goes through the stack as a sequence, a chunk of
+positions at a time in a loop inside its bucket's program that stops at
+the prompt's length (the positions it ran are counted in
+``prefill_computed_tokens``), and hands over the last chunk's state; a
+Mamba-2 prompt scans the decode step over its positions.
 Both programs are functions of the two configs alone
 (``decode_program``, ``prefill_program``), so anyone can build them
 again and read their compiled HLO.
@@ -52,6 +55,7 @@ from fms_fsdp_tpu.models.mamba import (
     mamba_decode_step,
     mamba_prefill,
     mamba_state_bytes_per_stream,
+    prefill_positions,
     slab_shapes,
 )
 from fms_fsdp_tpu.obs.scopes import scoped
@@ -313,6 +317,9 @@ class MambaAdapter(FamilyAdapter):
             toks[0, :p] = prompt
             logits, st1, kv = fn(
                 self.params, jnp.asarray(toks), jnp.asarray([p], np.int32)
+            )
+            self.prefill_computed_tokens += prefill_positions(
+                self.model_cfg, p, p_pad
             )
         with span("prefill.write_state", rid=rid):
             # land the 1-row prefill state in the stream's slab slice

@@ -246,6 +246,7 @@ class MixtralAdapter(FamilyAdapter):
             toks = np.zeros((1, p_pad), np.int32)
             toks[0, :p] = prompt
             logits, _, kv = fn(self.params, self._dev(toks))
+            self.prefill_computed_tokens += p_pad
         with span("prefill.write_pages", rid=rid):
             self.cache.write_prompt(rid, kv["k"][:, 0], kv["v"][:, 0])
         row = logits[0, p - 1] if full_logits else logits[0, 0]

@@ -7,6 +7,7 @@ served position.
 """
 
 import dataclasses
+import re
 
 import jax
 import jax.numpy as jnp
@@ -60,10 +61,10 @@ def _ref_logits(tree32, tokens):
 
 
 def _engine(tree, dtype="float32", **kw):
-    scfg = ServeConfig(
-        max_batch=2, max_seq_len=64, compute_dtype=dtype,
-        attn_impl="reference", prefill_bucket=BUCKET,
-        max_prefill_per_step=2, **kw)
+    scfg = ServeConfig(**{
+        "max_batch": 2, "max_seq_len": 64, "compute_dtype": dtype,
+        "attn_impl": "reference", "prefill_bucket": BUCKET,
+        "max_prefill_per_step": 2, **kw})
     return ServingEngine(tree, CFG, scfg)
 
 
@@ -139,13 +140,15 @@ def test_attention_has_no_rope(params):
     h = jax.random.normal(jax.random.PRNGKey(3), (1, 16, 64))
     mixer = params["layers"][3]["mixer"]
     live = jnp.ones((1, 16), bool)
+    buf = jnp.zeros((1, 16, 1, 16))  # one chunk, nothing before it
     out, _, _ = M._attn_prefill(
-        h, mixer, CFG.attn_cfg, None, None, live, 16, "xla")
+        h, mixer, CFG.attn_cfg, None, None, live, buf, buf, 0, "xla")
     want = reference.attention_mixer(h, mixer, TINY)
     assert _gaps(np.asarray(out), np.asarray(want)) < 1e-5
     roped = dataclasses.replace(CFG.attn_cfg, rotary_emb_dim=16)
     cos, sin = M.rope_table(16, 16, 10000.0)
-    rot, _, _ = M._attn_prefill(h, mixer, roped, cos, sin, live, 16, "xla")
+    rot, _, _ = M._attn_prefill(
+        h, mixer, roped, cos, sin, live, buf, buf, 0, "xla")
     assert _gaps(np.asarray(rot), np.asarray(want)) > 1e-2
 
 
@@ -214,6 +217,193 @@ def test_prefill_through_the_kernel_equals_the_scan_form(params, monkeypatch):
     assert len(calls) == 5  # one per Mamba layer
     for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
         np.testing.assert_allclose(a, b, rtol=2e-5, atol=2e-5)
+
+
+# ---------------------------------------------------------------------------
+# the looped prefill: a chunk at a time, stopped at the prompt's length
+# ---------------------------------------------------------------------------
+
+C, P_PAD = 4, 16  # four chunks to a bucket
+
+
+def _prefill_rows(params, rows, chunk, monkeypatch, **kw):
+    """``mamba_prefill`` of ``rows`` (token lists) padded to P_PAD, with
+    the loop's chunk set to ``chunk``: P_PAD makes it one chunk, which is
+    the whole-sequence form."""
+    monkeypatch.setattr(M, "PREFILL_CHUNK", chunk)
+    assert M.prefill_chunk(P_PAD) == chunk
+    toks = np.zeros((len(rows), P_PAD), np.int32)
+    for i, r in enumerate(rows):
+        toks[i, : len(r)] = r
+    kw = {"compute_dtype": jnp.float32, "kv_len": P_PAD, "attn_impl": "xla",
+          **kw}
+    return M.mamba_prefill(
+        params, jnp.asarray(toks),
+        jnp.asarray([len(r) for r in rows], jnp.int32), CFG, **kw)
+
+
+def _decode_token_by_token(params, row):
+    """The prompt through the recurrent decode step, one position at a
+    time, its K/V in one page of P_PAD slots: -> (last logits, slab,
+    {"k", "v"} (n_attn, P_PAD, nkv, hd))."""
+    step = jax.jit(lambda st, pools, t, tok: M.mamba_decode_step(
+        params, st, pools, jnp.asarray([[1]], jnp.int32), t, tok, CFG,
+        page_size=P_PAD, compute_dtype=jnp.float32))
+    state = M.init_mamba_decode_state(CFG, 1, jnp.float32)
+    a = CFG.attn_cfg
+    pools = {k: jnp.zeros((1, 2, P_PAD, a.num_heads_kv, a.head_dim))
+             for k in ("k", "v")}
+    for t, tok in enumerate(row):
+        logits, state, pools = step(
+            state, pools, jnp.asarray([t], jnp.int32),
+            jnp.asarray([tok], jnp.int32))
+    return logits[0], state, {k: v[:, 1] for k, v in pools.items()}
+
+
+@pytest.mark.parametrize("lengths", [
+    (1,), (C - 1,), (C,), (C + 1,), (P_PAD - 1,), (P_PAD,),
+    (P_PAD - 1, C + 1),  # two ragged rows in one call
+])
+def test_looped_prefill_is_the_whole_sequence_and_the_decode_step(
+        params, lengths, monkeypatch):
+    rng = np.random.default_rng(sum(lengths))
+    rows = [rng.integers(1, 512, size=n).tolist() for n in lengths]
+    logits, slab, kv = _prefill_rows(params, rows, C, monkeypatch)
+    whole = _prefill_rows(params, rows, P_PAD, monkeypatch)
+    # against the whole-sequence form: the scan and the conv walk the
+    # same positions in the same order; products and the attention's sum
+    # differ by their tiling
+    for got, want in zip(jax.tree.leaves((logits, slab, kv)),
+                         jax.tree.leaves(whole)):
+        np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
+    for r, (row, n) in enumerate(zip(rows, lengths)):
+        # K/V are exactly zero from the length on
+        assert not np.asarray(kv["k"][:, r, n:]).any()
+        assert not np.asarray(kv["v"][:, r, n:]).any()
+        want_logits, want_slab, want_kv = _decode_token_by_token(params, row)
+        np.testing.assert_allclose(
+            logits[r], want_logits, rtol=1e-4, atol=1e-4)
+        for got, want in zip(slab, want_slab):
+            for part in got:  # conv, ssd; {} for the attention layer
+                np.testing.assert_allclose(
+                    got[part][r], want[part][0], rtol=2e-5, atol=2e-5)
+        for part in ("k", "v"):
+            np.testing.assert_allclose(
+                kv[part][:, r], want_kv[part], rtol=2e-5, atol=2e-5)
+
+
+def test_kernel_carries_its_state_across_a_chunk_edge(params, monkeypatch):
+    """The Pallas scan (interpret mode here) started from the state the
+    chunk before it left, two chunks of 8 to a bucket of 16, one row
+    ending inside the second: what the scan form gives in one chunk."""
+    rng = np.random.default_rng(5)
+    rows = [rng.integers(1, 512, size=n).tolist() for n in (P_PAD, 11)]
+    want = _prefill_rows(params, rows, P_PAD, monkeypatch)
+    carried = []
+
+    def kernel(*args):
+        carried.append(args[-1])  # h0
+        return ss.selective_scan_kernel(*args, interpret=True)
+
+    monkeypatch.setattr(M, "selective_scan", kernel)
+    got = _prefill_rows(params, rows, 8, monkeypatch)
+    assert len(carried) == 5  # the loop's body is traced once
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(a, b, rtol=2e-5, atol=2e-5)
+
+
+def test_chunk_attention_through_the_flash_kernel_merges_exactly():
+    """The form the chip runs: the chunk's own block under the causal
+    mask and every earlier block whole, both through the flash kernel
+    (interpret mode here), merged through the log-sum-exp; against
+    causal attention over the whole sequence."""
+    from fms_fsdp_tpu.ops.attention import chunk_attention, xla_attention
+
+    c, L = 256, 768
+    ks = jax.random.split(jax.random.PRNGKey(11), 3)
+    q = jax.random.normal(ks[0], (1, L, 4, 128))
+    k = jax.random.normal(ks[1], (1, L, 1, 128))
+    v = jax.random.normal(ks[2], (1, L, 1, 128))
+    want = xla_attention(q, k, v, causal=True)
+    for start in (0, 256, 512):
+        # the cache beyond the chunk holds what a later chunk would write:
+        # it must not be read
+        cache_k = k.at[:, start + c:].set(1e4)
+        cache_v = v.at[:, start + c:].set(1e4)
+        for impl in ("pallas", "xla"):
+            got = jax.jit(chunk_attention, static_argnames="impl")(
+                q[:, start: start + c], cache_k, cache_v,
+                jnp.int32(start), impl=impl)
+            np.testing.assert_allclose(
+                got, want[:, start: start + c], rtol=2e-5, atol=2e-5)
+
+
+def _entry_computation(text):
+    """The lines of an optimized HLO module's ENTRY computation."""
+    lines = text.splitlines()
+    start = next(i for i, l in enumerate(lines) if l.startswith("ENTRY"))
+    end = next(i for i in range(start, len(lines)) if lines[i] == "}")
+    return lines[start + 1: end]
+
+
+def test_largest_program_is_one_loop_over_chunks(monkeypatch):
+    """The compiled prefill program of the longest bucket: one ``while``
+    at its top, over the chunks, with every layer's work inside it, and
+    nowhere an array of the whole bucket's positions by a model width."""
+    from fms_fsdp_tpu.serve.families.mamba import prefill_program
+
+    monkeypatch.setattr(M, "PREFILL_CHUNK", 8)
+    p_pad = 48  # no width of the model
+    scfg = ServeConfig(
+        max_batch=2, max_seq_len=96, compute_dtype="float32",
+        attn_impl="reference", prefill_bucket=p_pad)
+    shapes = jax.eval_shape(lambda: _params())
+    text = prefill_program(CFG, scfg, p_pad, p_pad, jnp.float32).lower(
+        shapes, jax.ShapeDtypeStruct((1, p_pad), jnp.int32),
+        jax.ShapeDtypeStruct((1,), jnp.int32)).compile().as_text()
+    assert sum(" while(" in l for l in _entry_computation(text)) == 1
+    # (positions, d_model), (positions, d_inner), (positions, d_intermediate)
+    for width in (CFG.d_model, CFG.d_inner, CFG.d_intermediate):
+        assert not re.search(rf"\[(\d+,)*{p_pad},{width}\]", text), width
+    # the chunk's are there
+    assert re.search(rf"\[1,8,{CFG.d_inner}\]", text)
+
+
+def test_computed_tokens_are_whole_chunks_up_to_the_prompts_end(
+        params, monkeypatch, tmp_path):
+    """``serve.prefill_computed_tokens`` and the ``prefill.done`` span's
+    ``computed_tokens``: ceil(p / C) * C, never above the padded
+    tokens."""
+    from jax.profiler import ProfileData
+    import glob
+
+    monkeypatch.setattr(M, "PREFILL_CHUNK", C)
+    eng = _engine(params, prefill_bucket=P_PAD)
+    lengths = (1, C, C + 1, P_PAD - 1, P_PAD, P_PAD + 3)
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    with jax.profiler.trace(str(tmp_path), profiler_options=opts):
+        for n in lengths:
+            eng.submit(list(range(1, n + 1)), 2)
+        eng.run()
+    want = [-(-n // C) * C for n in lengths]
+    reg = eng.registry
+    assert reg.counter("serve.prefill_computed_tokens").value == sum(want)
+    padded = reg.counter("serve.prefill_padded_tokens").value
+    assert padded == sum(-(-n // P_PAD) * P_PAD for n in lengths)
+    assert sum(want) < padded
+    (path,) = glob.glob(str(tmp_path / "plugins/profile/*/*.xplane.pb"))
+    events = {
+        name: [dict(e.stats) for plane in ProfileData.from_file(path).planes
+               for line in plane.lines for e in line.events
+               if e.name == "serve/" + name]
+        for name in ("prefill", "prefill.done")}
+    by_rid = {e["rid"]: e for e in events["prefill"]}
+    assert len(events["prefill.done"]) == len(lengths)
+    for e in events["prefill.done"]:
+        mine = by_rid[e["rid"]]
+        p = mine["prompt_tokens"]
+        assert e["computed_tokens"] == -(-p // C) * C <= mine["padded_tokens"]
 
 
 # ---------------------------------------------------------------------------

@@ -68,6 +68,7 @@ PARENT = {
     "admit.done": "admit",
     "grow.done": "grow",
     "decode.commit.done": "decode.commit",
+    "prefill.done": "prefill",
 }
 # the chunked path (llama only) adds one name
 CHUNKED_PARENT = {"prefill_chunk": "step", "prefill.sample": "prefill_chunk",
@@ -165,6 +166,17 @@ def test_chunked_prefill_spans(traced_chunked, name):
     assert all("rid" in s.stats for s in inside)
 
 
+def test_a_prefill_that_does_not_stop_short_computes_its_bucket(
+        traced, traced_chunked):
+    """``serve.prefill_computed_tokens`` equals the padded tokens for
+    the families whose prefill covers the bucket, staged in chunks
+    (llama) or whole (mixtral); the hybrid's is in tests/test_jamba.py."""
+    for engine, _, _ in (traced, traced_chunked):
+        reg = engine.registry
+        assert reg.counter("serve.prefill_computed_tokens").value == (
+            reg.counter("serve.prefill_padded_tokens").value) > 0
+
+
 def test_counts_come_back_as_the_events_stats(traced):
     """The keyword counts of a span are the event's stats on this jax
     (they are not left in its name): what benchmark/program_trace.py
@@ -212,7 +224,8 @@ def test_the_spans_of_a_request_share_its_rid(traced):
     for pf in prefills:
         kids = [s for s in spans if s is not pf and s.inside(pf)]
         assert {s.name for s in kids} == {
-            "prefill.dispatch", "prefill.write_pages", "prefill.sample"}
+            "prefill.dispatch", "prefill.write_pages", "prefill.sample",
+            "prefill.done"}
         assert {s.stats["rid"] for s in kids} == {pf.stats["rid"]}
     accepted = [s.stats["rid"] for s in named(spans, "submit.done")
                 if not s.stats["rejected"]]
@@ -234,6 +247,9 @@ COUNTERS = {
         s.stats["padded_tokens"] for s in named(sp, "prefill")),
     "serve.prefill_tokens": lambda sp: sum(
         s.stats["prompt_tokens"] for s in named(sp, "prefill")),
+    # an adapter whose prefill does not stop short computes its bucket
+    "serve.prefill_computed_tokens": lambda sp: sum(
+        s.stats["computed_tokens"] for s in named(sp, "prefill.done")),
     "serve.prefill_programs_built": lambda sp: sum(
         s.stats["built"] for s in named(sp, "prefill.dispatch")),
     "serve.page_table_uploads": lambda sp: sum(
@@ -265,6 +281,7 @@ def test_counters_read_what_happened(traced):
     engine, reqs, spans = traced
     reg = engine.registry
     assert reg.counter("serve.prefill_padded_tokens").value == 8 + 16 + 16
+    assert reg.counter("serve.prefill_computed_tokens").value == 8 + 16 + 16
     # three shapes, each prefilled once: (8, not full), (16, x), (16, x)
     assert reg.counter("serve.prefill_programs_built").value == 2
     assert reg.counter("serve.steps_with_prefill").value == 3
