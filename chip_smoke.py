@@ -119,7 +119,7 @@ def train_spec(phase, rehearse):
         # mamba_9.8b: d_model 4096, d_inner 8192, 128 heads x 64, d_state
         # 128, MLP 14336; 2 of 32 layers, the second an attention layer
         # (32 query / 8 kv heads x 128) so the hybrid's flash path runs;
-        # vocab cut to 32000 as bench.py's row does
+        # vocab cut to 32000 so that two layers fit the chip
         kw = dict(vocab_size=32000, fsdp_activation_checkpointing=True,
                   selective_checkpointing=0.5,
                   **{"MambaConfig.n_layer": 2,

@@ -128,7 +128,7 @@ def _flash_fwd(q, k, v, scale, causal, block_q, block_k, interpret,
     (_fwd_kernel_kvgrid). ``variant`` pins the family for this call
     (the tuning-table choice, resolved in flash_attention); otherwise
     FLASH_KERNEL_VARIANT / set_kernel_variant overrides the automatic
-    choice — raced on chip by scripts/bench_kernels.py."""
+    choice."""
     if _use_kvgrid(k.shape[2], variant):
         return _flash_fwd_kvgrid(
             q, k, v, scale, causal, block_q, block_k, interpret

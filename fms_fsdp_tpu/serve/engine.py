@@ -88,8 +88,7 @@ class ServeConfig:
     # chunked prefill: prompts longer than this split into chunk-sized
     # slices advanced one per engine step, interleaved with decode — a
     # long prompt no longer head-of-line-blocks every running stream's
-    # next token (the long-prompt p99-TTFT win, scripts/bench_serving).
-    # Chunked logits are bit-identical to whole-prompt prefill
+    # next token. Chunked logits are bit-identical to whole-prompt prefill
     # (decode_chunk and prefill run the same attention op-for-op over
     # the same zero-initialized cache). 0 = whole-prompt, the exact v1
     # code path
@@ -169,9 +168,10 @@ class ServingEngine:
 
         # family-specific device work (cache/slab, prefill + decode
         # jits, page accounting) — resolved from the model config, with
-        # the params tree validated against it
+        # the params tree validated against it; what happens inside the
+        # adapter it counts into this registry itself
         self.adapter = resolve_adapter(
-            params, model_cfg, scfg, self.compute_dtype
+            params, model_cfg, scfg, self.compute_dtype, self.registry
         )
         self.family = self.adapter.family
         # one fact per engine, not a rate: set where the program is built
@@ -314,11 +314,7 @@ class ServingEngine:
             # handoff stream (the estimate is pure page bytes; the
             # header adds O(prompt) ints on top)
             cache = self.adapter.cache
-            need = cache.pages_needed(
-                self.adapter._padded_len(
-                    len(prompt), self.serve_cfg.prefill_bucket
-                )
-            )
+            need = cache.pages_needed(self.adapter._padded(len(prompt)))
             page_bytes = sum(
                 int(pool.nbytes) // cache.num_pages
                 for pool in cache.pools.values()
@@ -461,13 +457,10 @@ class ServingEngine:
         handoff import (no prefill program: ``padded_tokens`` 0), the
         staging of a chunked prefill, or the whole-prompt prefill."""
         handoff = req.handoff_in is not None
-        p = len(req.prompt if handoff else req.resume_prompt())
-        padded = 0 if handoff else self.adapter._padded_len(
-            p, self.serve_cfg.prefill_bucket
-        )
-        built = self.adapter.prefill_programs_built
-        wrote = self.adapter.prefill_state_writes
-        ran = self.adapter.prefill_computed_tokens
+        prompt = req.prompt if handoff else req.resume_prompt()
+        p = len(prompt)
+        padded = 0 if handoff else self.adapter._padded(p)
+        chunk = self.serve_cfg.prefill_chunk_tokens
         with span(
             "prefill",
             step=self.iterations,
@@ -475,39 +468,24 @@ class ServingEngine:
             prompt_tokens=p,
             padded_tokens=padded,
         ):
-            self._prefill_admitted(req, slot)
-            ran = self.adapter.prefill_computed_tokens - ran
-            done("prefill", rid=req.rid, computed_tokens=ran)
+            if handoff:
+                self._import_handoff(req, slot)
+            elif chunk and p > chunk and self.adapter.supports_chunked_prefill:
+                # chunked prefill: allocate + stage now, advance one chunk
+                # per step() interleaved with decode — the slot is held but
+                # joins the decode batch only once the whole prompt is in
+                self.adapter.prefill_start(req.rid, slot, prompt)
+                self._slots[slot] = req
+                self._chunking[req.rid] = (req, slot)
+            else:
+                # the adapter allocates the stream's decode state (pages
+                # and/or slab slice), runs the family prefill and hands
+                # back the (V,) logits row of the last real prompt
+                # position; sampling stays here so every family shares one
+                # rng stream and one sampler
+                row = self.adapter.prefill(req.rid, slot, prompt)
+                self._complete_prefill(req, slot, row, p)
         self.registry.counter("serve.prefill_padded_tokens").add(padded)
-        self.registry.counter("serve.prefill_computed_tokens").add(ran)
-        self.registry.counter("serve.prefill_programs_built").add(
-            self.adapter.prefill_programs_built - built
-        )
-        self.registry.counter("serve.prefill_state_writes").add(
-            self.adapter.prefill_state_writes - wrote
-        )
-
-    def _prefill_admitted(self, req: Request, slot: int) -> None:
-        if req.handoff_in is not None:
-            self._import_handoff(req, slot)
-            return
-        prompt = req.resume_prompt()
-        p = len(prompt)
-        chunk = self.serve_cfg.prefill_chunk_tokens
-        if chunk and p > chunk and self.adapter.supports_chunked_prefill:
-            # chunked prefill: allocate + stage now, advance one chunk
-            # per step() interleaved with decode — the slot is held but
-            # joins the decode batch only once the whole prompt is in
-            self.adapter.prefill_start(req.rid, slot, prompt)
-            self._slots[slot] = req
-            self._chunking[req.rid] = (req, slot)
-            return
-        # the adapter allocates the stream's decode state (pages and/or
-        # slab slice), runs the family prefill and hands back the (V,)
-        # logits row of the last real prompt position; sampling stays
-        # here so every family shares one rng stream and one sampler
-        row = self.adapter.prefill(req.rid, slot, prompt)
-        self._complete_prefill(req, slot, row, p)
 
     def _complete_prefill(self, req: Request, slot: int, row, p: int) -> None:
         """Shared tail of whole-prompt and chunked prefill: sample the
@@ -552,6 +530,7 @@ class ServingEngine:
         from fms_fsdp_tpu.serve.disagg import HandoffError
 
         header, arrays, nbytes = req.handoff_in
+        done("prefill", rid=req.rid, computed_tokens=0)  # no program ran
         t0 = self.clock()
         try:
             ok = self.adapter.import_handoff(req.rid, slot, header, arrays)
@@ -579,8 +558,7 @@ class ServingEngine:
         self._admit_order.append(req)
         self._tokens[slot] = req.generated[-1]
         self._lens[slot] = int(header["seq_len"])
-        if self._finish_if_done(req, slot):
-            return
+        self._finish_if_done(req, slot)
 
     def _export_handoff(self, req: Request, slot: int) -> None:
         """The prefill half: gather the stream's pages, pack them with
@@ -750,7 +728,6 @@ class ServingEngine:
         chunks = 0
         for rid in list(self._chunking):
             req, slot = self._chunking[rid]
-            built = self.adapter.prefill_programs_built
             with span("prefill_chunk", step=it, rid=rid):
                 row = self.adapter.prefill_chunk(rid)
                 chunks += 1
@@ -761,9 +738,6 @@ class ServingEngine:
                     self._complete_prefill(
                         req, slot, row, len(req.resume_prompt())
                     )
-            self.registry.counter("serve.prefill_programs_built").add(
-                self.adapter.prefill_programs_built - built
-            )
         return chunks
 
     def _grow(self) -> int:
@@ -803,7 +777,6 @@ class ServingEngine:
         if not active:
             return
         reg = self.registry
-        uploads = self.adapter.page_table_uploads
         finished = len(self._finished_buf)
         with span(
             "decode",
@@ -856,9 +829,6 @@ class ServingEngine:
                     finished=len(self._finished_buf) - finished,
                 )
         reg.counter("serve.decode_live_slots").add(len(active))
-        reg.counter("serve.page_table_uploads").add(
-            self.adapter.page_table_uploads - uploads
-        )
 
     def run(self, max_steps: int = 100000) -> None:
         """Drive step() until queue and slots drain (or max_steps)."""

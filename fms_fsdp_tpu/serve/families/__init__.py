@@ -37,17 +37,21 @@ Obs note: the schema-v12 ``serving`` map is flat str->number, so the
 family travels as a numeric code (:data:`FAMILY_CODES`), not a string.
 """
 
+from functools import partial
 from typing import Optional
+
+import numpy as np
 
 from fms_fsdp_tpu.models.configs import (
     LlamaConfig,
     MambaConfig,
     MixtralConfig,
 )
-from fms_fsdp_tpu.obs.spans import span
+from fms_fsdp_tpu.obs.registry import MetricRegistry
+from fms_fsdp_tpu.obs.spans import done, span
 
 # the wire encoding of a family in numeric-only maps (obs schema v12
-# "serving", BENCH_SERVING.json rows): family = FAMILY_CODES[name]
+# "serving"): family = FAMILY_CODES[name]
 FAMILY_CODES = {"llama": 0, "mamba": 1, "mixtral": 2}
 FAMILY_NAMES = {v: k for k, v in FAMILY_CODES.items()}
 
@@ -171,48 +175,78 @@ def init_params_for(model_cfg):
     return lambda key: init_llama_params(key, model_cfg)
 
 
-def resolve_adapter(params, model_cfg, serve_cfg, compute_dtype=None):
-    """Checkpoint + config -> the family's adapter (jax imports here)."""
+def paged_geometry(scfg, nheads: int, n_kv_heads: int, head_dim: int,
+                   tuned: bool = True):
+    """``(page_size, block_kv, tune_how, max_pages, num_pages)`` of the
+    paged cache an engine builds for attention of these shapes: the page
+    size through the kernel-tuning table when ``tuned`` (llama, mixtral),
+    else 16 (the hybrid's attention shape has no table entry; 16 is the
+    table's common resolution), either way pinned by ``scfg.page_size``;
+    then the pages one sequence can hold and the pool."""
+    from fms_fsdp_tpu.serve.kv_cache import RESERVED_PAGES
+
+    if tuned:
+        from fms_fsdp_tpu.tune.lookup import resolve_paged_decode
+
+        page_size, block_kv, tune_how = resolve_paged_decode(
+            scfg.max_batch,
+            nheads,
+            n_kv_heads,
+            head_dim,
+            scfg.max_seq_len,
+            scfg.compute_dtype,
+            requested_page_size=scfg.page_size or None,
+        )
+    else:
+        page_size, block_kv, tune_how = scfg.page_size or 16, 0, "n/a"
+    assert scfg.max_seq_len % page_size == 0, (scfg.max_seq_len, page_size)
+    max_pages = scfg.max_seq_len // page_size
+    num_pages = scfg.num_pages or (
+        scfg.max_batch * max_pages + RESERVED_PAGES
+    )
+    return page_size, block_kv, tune_how, max_pages, num_pages
+
+
+def resolve_adapter(
+    params, model_cfg, serve_cfg, compute_dtype=None, registry=None
+):
+    """Checkpoint + config -> the family's adapter (jax imports here).
+    ``registry`` is where the adapter counts (the engine hands over its
+    own); with none given the adapter makes one."""
     family = family_of(model_cfg)
     check_params_family(params, family)
     if family == "mamba":
-        from fms_fsdp_tpu.serve.families.mamba import MambaAdapter
-
-        return MambaAdapter(params, model_cfg, serve_cfg, compute_dtype)
-    if family == "mixtral":
-        from fms_fsdp_tpu.serve.families.mixtral import MixtralAdapter
-
-        return MixtralAdapter(params, model_cfg, serve_cfg, compute_dtype)
-    from fms_fsdp_tpu.serve.families.llama import LlamaAdapter
-
-    return LlamaAdapter(params, model_cfg, serve_cfg, compute_dtype)
+        from fms_fsdp_tpu.serve.families.mamba import MambaAdapter as cls
+    elif family == "mixtral":
+        from fms_fsdp_tpu.serve.families.mixtral import MixtralAdapter as cls
+    else:
+        from fms_fsdp_tpu.serve.families.llama import LlamaAdapter as cls
+    return cls(params, model_cfg, serve_cfg, compute_dtype, registry)
 
 
 class FamilyAdapter:
-    """The protocol (docs/serving.md "Family adapters" has the table).
+    """The skeleton of a family's device work (docs/serving.md "Family
+    adapters" has the table). The engine owns scheduling, sampling, rng
+    and the request metrics; this class owns what every family does the
+    same way around its programs and its state, and counts what happens
+    there into the registry it was given:
 
-    The engine owns scheduling, sampling, rng and metrics; the adapter
-    owns every family-specific device interaction:
+    - ``admission_error`` / ``can_admit`` / ``grow`` / ``release``: the
+      page-capacity rule over ``self.cache`` (constant answers for a
+      family with no pages);
+    - ``prefill(rid, slot, prompt)``: pad to the bucket, allocate, look
+      the program up (``_program``), call it, land its outputs (slab
+      rows, K/V pages), hand back the (V,) logits row of the last real
+      prompt position;
+    - ``decode(slot_rids, lens, tokens, key)``: upload the page table
+      when it went stale, one jitted ragged step over all ``max_batch``
+      slots with the pools and the slab donated, read the sampled tokens
+      -> (tokens (B,) np.int32, logits (B, V)).
 
-    - ``admission_error(prompt_len, max_new)`` — worst-case capacity
-      check at submit; a message means reject (reason=too_large).
-    - ``can_admit(rid, prompt_len)`` — would a prefill of this resumed
-      prompt fit right now (pre-admission, nothing allocated)?
-    - ``prefill(rid, slot, prompt)`` — allocate the stream's state,
-      run the family prefill, write slot state; returns the (V,)
-      logits row of the last real prompt position.
-    - ``grow(rid, n_tokens)`` — make room for the next token; False
-      triggers the engine's LIFO eviction loop. Constant-state
-      families always return True.
-    - ``release(rid, slot)`` — return the stream's state (free pages /
-      zero the slab slice). Eviction, expiry and completion all land
-      here; recompute-on-resume re-prefills into whatever slot comes
-      next.
-    - ``decode(slot_rids, lens, tokens, key)`` — one jitted ragged
-      decode step over all max_batch slots; returns (sampled tokens
-      (B,) np.int32, logits (B, V)). The adapter owns donation and
-      page-table upload caching.
-    - ``pages_in_use`` / ``state_bytes_per_stream`` — obs.
+    A family sets its state (``cache``, ``_state``) and ``_decode_fn`` in
+    ``_setup`` and writes the three prefill hooks (``_prefill_key``,
+    ``_build_prefill``, ``_call_prefill``) and, where a released stream
+    leaves something behind, ``_release_state``.
 
     Disaggregation (serve/disagg/): paged families additionally set
     ``supports_handoff`` and inherit the base ``export_handoff`` /
@@ -227,84 +261,232 @@ class FamilyAdapter:
 
     family: str = "?"
     cache = None  # PagedKVCache when the family uses pages, else None
+    # the recurrent slab when the family keeps one (with ``_write_slot``,
+    # the program that lands one stream's rows in it: mamba.py)
+    _state = None
     page_size: int = 0
     max_pages: int = 0
     attn_impl: str = "none"
     block_kv: int = 0
     tune_how: str = "n/a"
     mesh = None  # the serving mesh when serve_layout is set, else None
+    _repl = None  # its replicated sharding
     supports_handoff: bool = False
     supports_layout: bool = False
     # speculative serving (ServeConfig.speculator_path): the adapter
     # flips ``speculative`` when it loaded a draft head; the engine then
-    # routes through ``decode_spec`` and budgets ``spec_draft_tokens``
+    # routes through its ``decode_spec`` and budgets ``spec_draft_tokens``
     # extra cache positions per stream for in-flight draft writes
     speculative: bool = False
     spec_draft_tokens: int = 0
-    # chunked prefill (ServeConfig.prefill_chunk_tokens): families that
-    # can advance a prompt in slices through prefill_start/prefill_chunk
-    # set this; the engine rejects the knob for the rest at build
+    # chunked prefill (ServeConfig.prefill_chunk_tokens): a family that
+    # can advance a prompt in slices has ``prefill_start(rid, slot,
+    # prompt)`` and ``prefill_chunk(rid)`` (llama.py) and sets this; the
+    # engine rejects the knob for the rest at build
     supports_chunked_prefill: bool = False
-    # what the adapter did that only it can see, counted where it
-    # happens; the engine adds each step's difference to its registry
-    # (serve.prefill_programs_built, serve.page_table_uploads)
-    prefill_programs_built: int = 0
-    page_table_uploads: int = 0
     # expert weight copies one decode step reads in each layer (the gauge
     # serve.moe_expert_reads_per_layer); 0 for a family with no experts
     moe_expert_reads_per_layer: int = 0
     # layers that keep a recurrent slab slice per stream (the gauge
-    # serve.ssm_layers), and the prefills that wrote one
-    # (serve.prefill_state_writes); 0 for a family with no such state
+    # serve.ssm_layers); 0 for a family with no such state
     ssm_layers: int = 0
-    prefill_state_writes: int = 0
-    # positions the prefill programs computed (serve.prefill_computed_
-    # tokens): the padded tokens, but for a prefill that stops at the
-    # prompt's length inside its bucket (mamba.py's Mamba-1 loop)
-    prefill_computed_tokens: int = 0
+    _pages_noun: str = "pages"  # what a rejection calls the pool's pages
+    _dispatch_fields: dict = {}  # the family's fields of decode.dispatch
+
+    def __init__(
+        self, params, model_cfg, scfg, compute_dtype=None, registry=None
+    ):
+        from fms_fsdp_tpu.serve.engine import _DTYPES
+
+        self.params = params
+        self.model_cfg = model_cfg
+        self.scfg = scfg
+        self.compute_dtype = compute_dtype or _DTYPES[scfg.compute_dtype]
+        self.registry = MetricRegistry() if registry is None else registry
+        self._prefill_cache: dict = {}
+        self._table_key = None
+        self._table_dev = None
+        self._setup()
+
+    def _setup(self) -> None:
+        """The family's refusals, state (``_init_pages``, ``_state``) and
+        ``_decode_fn``, from ``self.params``, ``model_cfg`` and ``scfg``."""
+        raise NotImplementedError
+
+    def _init_pages(
+        self, nlayers, nheads, n_kv_heads, head_dim, quant="none", tuned=True
+    ) -> None:
+        """The paged cache of ``nlayers`` attention layers of these
+        shapes (``paged_geometry``), kv-head-sharded on a serving mesh."""
+        from fms_fsdp_tpu.serve.kv_cache import PagedKVCache
+
+        (
+            self.page_size,
+            self.block_kv,
+            self.tune_how,
+            self.max_pages,
+            num_pages,
+        ) = paged_geometry(self.scfg, nheads, n_kv_heads, head_dim, tuned)
+        shape = (nlayers, num_pages, self.page_size, n_kv_heads, head_dim)
+        self.cache = PagedKVCache(
+            *shape,
+            dtype=self.compute_dtype,
+            quant=quant,
+            shardings=self._pool_shardings(shape),
+        )
+
+    # -- capacity: the page rule, once -------------------------------------
+
+    def _padded(self, n: int) -> int:
+        """``n`` rounded up to the prefill bucket."""
+        b = max(1, self.scfg.prefill_bucket)
+        return -(-n // b) * b
 
     def admission_error(self, prompt_len: int, max_new: int) -> Optional[str]:
-        raise NotImplementedError
+        """Worst-case capacity check at submit; a message means reject
+        (reason=too_large). A constant slab fits iff a slot exists."""
+        if self.cache is None:
+            return None
+        from fms_fsdp_tpu.serve.kv_cache import RESERVED_PAGES
+
+        # speculative verify writes draft tokens past the committed
+        # length before rollback — budget those cache positions too
+        worst = (
+            self._padded(prompt_len + max_new - 1)
+            + 1
+            + self.spec_draft_tokens
+        )
+        need = self.cache.pages_needed(worst)
+        total = self.cache.num_pages - RESERVED_PAGES
+        if need > total:
+            return (
+                f"request needs up to {need} {self._pages_noun} but the "
+                f"pool holds {total}; raise num_pages or shrink "
+                f"prompt/max_new_tokens"
+            )
+        return None
 
     def can_admit(self, rid: int, prompt_len: int) -> bool:
-        raise NotImplementedError
-
-    def prefill(self, rid: int, slot: int, prompt):
-        raise NotImplementedError
+        """Would a prefill of this (resumed) prompt fit right now
+        (pre-admission, nothing allocated)?"""
+        return self.cache is None or self.cache.can_ensure(
+            rid, self._padded(prompt_len) + 1
+        )
 
     def grow(self, rid: int, n_tokens: int) -> bool:
-        raise NotImplementedError
+        """Make room for the next token; False triggers the engine's
+        LIFO eviction loop. Constant-state families always grow."""
+        return self.cache is None or self.cache.ensure(rid, n_tokens)
 
     def release(self, rid: int, slot: int) -> None:
+        """Return the stream's state. Eviction, expiry and completion
+        all land here; recompute-on-resume re-prefills into whatever
+        slot comes next."""
+        self._release_state(rid, slot)
+        if self.cache is not None:
+            self.cache.free(rid)
+
+    def _release_state(self, rid: int, slot: int) -> None:
+        """What a family holds for a stream beside its pages."""
+
+    # -- prefill: the template and the family's three hooks ----------------
+
+    def _program(self, key, build):
+        """-> (the program cached under ``key``, 1 if ``build(key)`` made
+        it now else 0); counts ``serve.prefill_programs_built``."""
+        fn = self._prefill_cache.get(key)
+        if fn is not None:
+            return fn, 0
+        self.registry.counter("serve.prefill_programs_built").add()
+        fn = self._prefill_cache[key] = build(key)
+        return fn, 1
+
+    def _prefill_key(self, p: int, p_pad: int, kv_len: int):
+        """The key of the program that prefills ``p`` tokens padded to
+        ``p_pad`` into a K/V of ``kv_len`` positions."""
         raise NotImplementedError
+
+    def _build_prefill(self, key):
+        """The jitted prefill program of ``key``."""
+        raise NotImplementedError
+
+    def _call_prefill(self, fn, toks, p: int):
+        """Call ``fn`` on the padded token row ``toks`` (1, p_pad) ->
+        (logits row (V,) of position ``p - 1``, K/V ``{"k", "v"}`` for
+        the pages or None, slab rows or None, positions computed)."""
+        raise NotImplementedError
+
+    def _count_prefill(self, rid: int, computed: int) -> None:
+        """The positions the prefill programs compute for ``rid``: the
+        counter and the ``prefill.done`` marker's field."""
+        self.registry.counter("serve.prefill_computed_tokens").add(computed)
+        done("prefill", rid=rid, computed_tokens=computed)
+
+    def prefill(self, rid: int, slot: int, prompt):
+        """Allocate the stream's state, run the family's prefill, write
+        the slot's state; -> the (V,) logits row of the last real prompt
+        position."""
+        p = len(prompt)
+        p_pad = self._padded(p)
+        kv_len = 0
+        if self.cache is not None:
+            kv_len = self.cache.pages_needed(p_pad) * self.page_size
+            ok = self.cache.ensure(rid, p_pad)
+            assert ok, "admission checked capacity; ensure cannot fail here"
+        fn, built = self._program(
+            self._prefill_key(p, p_pad, kv_len), self._build_prefill
+        )
+        with span("prefill.dispatch", rid=rid, built=built):
+            toks = np.zeros((1, p_pad), np.int32)
+            toks[0, :p] = prompt
+            row, kv, rows, computed = self._call_prefill(fn, toks, p)
+        if rows is not None:
+            with span("prefill.write_state", rid=rid):
+                # land the 1-row prefill state in the stream's slab slice
+                self._state = self._write_slot(
+                    self._state, rows, np.int32(slot)
+                )
+                self.registry.counter("serve.prefill_state_writes").add()
+        if kv is not None:
+            with span("prefill.write_pages", rid=rid):
+                self.cache.write_prompt(rid, kv["k"][:, 0], kv["v"][:, 0])
+        self._count_prefill(rid, computed)
+        # on a mesh, hand the engine a host row: the engine's eager
+        # sampler mixes it with its single-device rng key, which jax
+        # refuses across device sets
+        return np.asarray(row) if self.mesh is not None else row
+
+    # -- decode: the template ----------------------------------------------
 
     def decode(self, slot_rids, lens, tokens, key):
-        raise NotImplementedError
-
-    # -- speculative decode (ServeConfig.speculator_path) ------------------
-
-    def decode_spec(self, slot_rids, lens, tokens):
-        """One draft-then-verify step over all slots: propose
-        ``spec_draft_tokens`` tokens per row, score them in one jitted
-        verify forward, commit the longest greedy-matching prefix.
-        Returns (emit (B, n+1) np.int32, counts (B,) np.int32, logits
-        (B, V) of each row's committed position) — row b's new tokens
-        are ``emit[b, :counts[b]]``."""
-        raise NotImplementedError
-
-    # -- chunked prefill (ServeConfig.prefill_chunk_tokens) ----------------
-
-    def prefill_start(self, rid: int, slot: int, prompt) -> None:
-        """Allocate the stream's state and stage ``prompt`` for
-        incremental prefill; no forward runs yet."""
-        raise NotImplementedError
-
-    def prefill_chunk(self, rid: int):
-        """Advance a staged prefill by one chunk. Returns None while
-        incomplete; on the final chunk, commits the state and returns
-        the (V,) logits row of the last real prompt position —
-        bit-identical to what whole-prompt ``prefill`` returns."""
-        raise NotImplementedError
+        """One jitted ragged decode step over all slots. The program
+        takes ``(params, [slab], [pools, page table], seq_lens, tokens,
+        key)`` and returns ``(tokens, logits, [slab], [pools])``: the
+        state a family has, donated and put back."""
+        state = []
+        if self._state is not None:
+            state.append(self._state)
+        if self.cache is not None:
+            self._upload_table(slot_rids)
+            state += [self.cache.pools, self._table_dev]
+        # the jitted call returns before the device ends; the read of the
+        # sampled tokens is what waits for it
+        with span("decode.dispatch", **self._dispatch_fields):
+            toks, logits, *state = self._decode_fn(
+                self.params,
+                *state,
+                self._dev(lens),
+                self._dev(tokens),
+                # the key is on the device: only a mesh wants it replicated
+                key if self._repl is None else self._dev(key),
+            )
+            if self._state is not None:
+                self._state = state.pop(0)
+            if self.cache is not None:
+                self.cache.pools = state.pop(0)
+        with span("decode.wait"):
+            toks = np.asarray(toks)
+        return toks, logits
 
     # -- serving layout (ServeConfig.serve_layout) -------------------------
 
@@ -342,7 +524,7 @@ class FamilyAdapter:
         (L, num_pages, page_size, Nkv, H): kv-heads over the tensor
         axis (serve_kv_pool_specs). None single-chip — the pool then
         builds exactly as before."""
-        if getattr(self, "mesh", None) is None:
+        if self.mesh is None:
             return None
         from fms_fsdp_tpu.parallel.sharding import (
             named_sharding,
@@ -370,7 +552,7 @@ class FamilyAdapter:
         import jax.numpy as jnp
 
         x = jnp.asarray(x)
-        if getattr(self, "_repl", None) is not None:
+        if self._repl is not None:
             x = jax.device_put(x, self._repl)
         return x
 
@@ -464,7 +646,7 @@ class FamilyAdapter:
         """The device copy of the page table (``self._table_dev``), made
         again only when the allocator or the slots' membership changed:
         steady-state decode re-uploads nothing. Under the
-        ``decode.table`` span; counts ``page_table_uploads``."""
+        ``decode.table`` span; counts ``serve.page_table_uploads``."""
         tkey = (self.cache.table_version, tuple(slot_rids))
         stale = tkey != self._table_key
         with span("decode.table", uploaded=int(stale)):
@@ -473,20 +655,58 @@ class FamilyAdapter:
                 self._table_dev = self._dev(
                     self.cache.page_table(list(slot_rids), self.max_pages)
                 )
-                self.page_table_uploads += 1
+                self.registry.counter("serve.page_table_uploads").add()
 
-    def _padded_len(self, n: int, bucket: int) -> int:
-        b = max(1, bucket)
-        return -(-n // b) * b
+
+class PagedAdapter(FamilyAdapter):
+    """The prefill hooks of a family whose every layer keeps K/V pages
+    and whose model prefill is ``_model_prefill(params, tokens, cfg=,
+    max_seq_len=, compute_dtype=, full_logits=) -> (logits, embeds,
+    kv)`` (llama, mixtral). A prompt that fills its bucket takes the
+    program that returns the last position's logits alone
+    (``full_logits`` False), so a bucket has up to two programs."""
+
+    _model_prefill = None
+    _draft_seed = None  # set by a speculative llama's prefill
+
+    def _prefill_key(self, p: int, p_pad: int, kv_len: int):
+        return (p_pad, kv_len, p_pad != p)
+
+    def _build_prefill(self, key):
+        import jax
+
+        _, kv_len, full_logits = key
+        return jax.jit(
+            partial(
+                self._model_prefill,
+                cfg=self.model_cfg,
+                max_seq_len=kv_len,
+                compute_dtype=self.compute_dtype,
+                full_logits=full_logits,
+            )
+        )
+
+    def _call_prefill(self, fn, toks, p: int):
+        logits, embeds, kv = fn(self.params, self._dev(toks))
+        p_pad = toks.shape[1]
+        if self.speculative:
+            # the hidden state that produced this stream's first token
+            # (llama.py::prefill seeds the draft chain with it)
+            self._draft_seed = embeds[0, p - 1]
+        # logits of the last REAL position predict the next token
+        row = logits[0, p - 1] if p_pad != p else logits[0, 0]
+        return row, kv, None, p_pad
 
 
 __all__ = [
     "FAMILY_CODES",
     "FAMILY_NAMES",
     "FamilyAdapter",
+    "PagedAdapter",
     "check_params_family",
     "family_of",
     "init_params_for",
     "load_model_config",
+    "paged_geometry",
     "resolve_adapter",
 ]

@@ -1,15 +1,15 @@
-"""Llama family adapter: the PR-11 paged-KV serving path, verbatim.
+"""Llama family adapter: the paged-KV serving path.
 
-This is a *move*, not a rewrite: the tuner-resolved page size, the
-PagedKVCache pool, the jitted prefill cache keyed on (p_len, s_pad,
-full_logits), the donated ragged decode step and the page-table upload
-cache are exactly the code that lived inline in ServingEngine — the
-existing bit-parity anchor (tests/test_serving.py) must keep holding
-over the refactor, so the ops and their order are unchanged.
+The tuner-resolved page size, the PagedKVCache pool, the prefill
+programs keyed on (p_len, s_pad, full_logits), the donated ragged decode
+step and the page-table upload cache are the skeleton's
+(serve/families/__init__.py); what is llama's own is the decode program
+over the ragged paged-attention kernel, chunked prefill and speculative
+decode. The engine's bit-parity anchor (tests/test_serving.py) holds the
+ops and their order.
 """
 
 from functools import partial
-from typing import Dict, Optional
 
 import jax
 import jax.numpy as jnp
@@ -19,80 +19,37 @@ from fms_fsdp_tpu.models.generation import decode_chunk, prefill, sample_token
 from fms_fsdp_tpu.models.speculative import speculator_propose
 from fms_fsdp_tpu.obs.spans import span
 from fms_fsdp_tpu.serve.decode import paged_decode_step, paged_verify_step
-from fms_fsdp_tpu.serve.families import FamilyAdapter
-from fms_fsdp_tpu.serve.kv_cache import RESERVED_PAGES, PagedKVCache
+from fms_fsdp_tpu.serve.families import PagedAdapter
 
 
-class LlamaAdapter(FamilyAdapter):
+class LlamaAdapter(PagedAdapter):
     family = "llama"
     supports_handoff = True
     supports_layout = True
     supports_chunked_prefill = True
+    _model_prefill = staticmethod(prefill)
 
-    def __init__(self, params, model_cfg, scfg, compute_dtype=None):
-        from fms_fsdp_tpu.serve.engine import _DTYPES
-        from fms_fsdp_tpu.tune.lookup import resolve_paged_decode
-
-        self.params = params
-        self.model_cfg = model_cfg
-        self.scfg = scfg
-        self.compute_dtype = compute_dtype or _DTYPES[scfg.compute_dtype]
+    def _setup(self) -> None:
+        cfg, scfg = self.model_cfg, self.scfg
         # serve_layout: build the serving mesh + shard params (tp over
         # heads/ffn, fsdp ZeRO-style — the train rulebook). No-op when
         # unset, keeping the single-chip bit-parity anchor byte-exact.
         self._init_layout(scfg)
-        params = self.params
-
-        nlayers = int(params["layers"]["wq"].shape[0])
-        page_size, self.block_kv, self.tune_how = resolve_paged_decode(
-            scfg.max_batch,
-            model_cfg.nheads,
-            model_cfg.n_kv_heads,
-            model_cfg.head_dim,
-            scfg.max_seq_len,
-            scfg.compute_dtype,
-            requested_page_size=scfg.page_size or None,
-        )
-        assert scfg.max_seq_len % page_size == 0, (
-            scfg.max_seq_len, page_size
-        )
-        self.page_size = page_size
-        self.max_pages = scfg.max_seq_len // page_size
-        num_pages = scfg.num_pages or (
-            scfg.max_batch * self.max_pages + RESERVED_PAGES
-        )
-        self.cache = PagedKVCache(
-            nlayers,
-            num_pages,
-            page_size,
-            model_cfg.n_kv_heads,
-            model_cfg.head_dim,
-            dtype=self.compute_dtype,
+        self._init_pages(
+            int(self.params["layers"]["wq"].shape[0]),
+            cfg.nheads,
+            cfg.n_kv_heads,
+            cfg.head_dim,
             quant=scfg.kv_quant,
-            # kv-head-sharded pools on a serving mesh; None single-chip
-            shardings=self._pool_shardings(
-                (
-                    nlayers,
-                    num_pages,
-                    page_size,
-                    model_cfg.n_kv_heads,
-                    model_cfg.head_dim,
-                )
-            ),
         )
+        page_size = self.page_size
         impl = scfg.attn_impl
         if impl == "auto":
             impl = "reference" if jax.default_backend() != "tpu" else "kernel"
         # the kernel reads quantized pools natively (scales applied in
         # VMEM) — no reference fallback on the TPU path
         self.attn_impl = impl
-
-        self._prefill_cache: Dict = {}
-        self._table_key = None
-        self._table_dev = None
-        self._chunk_state: Dict = {}  # rid -> staged incremental prefill
-
-        cfg = model_cfg
+        self._chunk_state: dict = {}  # rid -> staged incremental prefill
 
         def _step(params, pools, page_table, seq_lens, tokens, key):
             logits, _, pools = paged_decode_step(
@@ -220,6 +177,12 @@ class LlamaAdapter(FamilyAdapter):
         self._spec_fn = jax.jit(_spec_step, donate_argnums=(2,))
 
     def decode_spec(self, slot_rids, lens, tokens):
+        """One draft-then-verify step over all slots: propose
+        ``spec_draft_tokens`` tokens per row, score them in one jitted
+        verify forward, commit the longest greedy-matching prefix.
+        Returns (emit (B, n+1) np.int32, counts (B,) np.int32, logits
+        (B, V) of each row's committed position) — row b's new tokens
+        are ``emit[b, :counts[b]]``."""
         self._upload_table(slot_rids)
         with span("decode.dispatch"):
             emit, counts, logits, embeds, pools = self._spec_fn(
@@ -240,106 +203,32 @@ class LlamaAdapter(FamilyAdapter):
             emit, counts = np.asarray(emit), np.asarray(counts)
         return emit, counts, logits
 
-    # -- capacity ----------------------------------------------------------
-
-    def _padded(self, n: int) -> int:
-        return self._padded_len(n, self.scfg.prefill_bucket)
-
-    def admission_error(self, prompt_len: int, max_new: int) -> Optional[str]:
-        # speculative verify writes draft tokens past the committed
-        # length before rollback — budget those cache positions too
-        worst = (
-            self._padded(prompt_len + max_new - 1)
-            + 1
-            + self.spec_draft_tokens
-        )
-        need = self.cache.pages_needed(worst)
-        total = self.cache.num_pages - RESERVED_PAGES
-        if need > total:
-            return (
-                f"request needs up to {need} pages but the pool holds "
-                f"{total}; raise num_pages or shrink "
-                f"prompt/max_new_tokens"
-            )
-        return None
-
-    def can_admit(self, rid: int, prompt_len: int) -> bool:
-        return self.cache.can_ensure(rid, self._padded(prompt_len) + 1)
-
-    def grow(self, rid: int, n_tokens: int) -> bool:
-        return self.cache.ensure(rid, n_tokens)
-
-    def release(self, rid: int, slot: int) -> None:
+    def _release_state(self, rid: int, slot: int) -> None:
         self._chunk_state.pop(rid, None)
-        self.cache.free(rid)
 
-    # -- prefill -----------------------------------------------------------
-
-    def _get_prefill(self, p_len: int, s_pad: int, full_logits: bool):
-        key = (p_len, s_pad, full_logits)
-        fn = self._prefill_cache.get(key)
-        if fn is None:
-            self.prefill_programs_built += 1
-            fn = jax.jit(
-                partial(
-                    prefill,
-                    cfg=self.model_cfg,
-                    max_seq_len=s_pad,
-                    compute_dtype=self.compute_dtype,
-                    full_logits=full_logits,
-                )
-            )
-            self._prefill_cache[key] = fn
-        return fn
+    def _seed_draft(self, slot: int, embed) -> None:
+        """Seed the slot's draft chain with the hidden state that
+        produced its stream's first token."""
+        if self.speculative:
+            self._spec_embed[slot] = np.asarray(embed)
 
     def prefill(self, rid: int, slot: int, prompt):
-        p = len(prompt)
-        p_pad = self._padded(p)
-        s_pad = self.cache.pages_needed(p_pad) * self.page_size
-        ok = self.cache.ensure(rid, p_pad)
-        assert ok, "admission checked capacity; ensure cannot fail here"
-        full_logits = p_pad != p
-        built = self.prefill_programs_built
-        fn = self._get_prefill(p_pad, s_pad, full_logits)
-        with span(
-            "prefill.dispatch",
-            rid=rid,
-            built=self.prefill_programs_built - built,
-        ):
-            toks = np.zeros((1, p_pad), np.int32)
-            toks[0, :p] = prompt
-            logits, embeds, kv = fn(self.params, self._dev(toks))
-            self.prefill_computed_tokens += p_pad
-        with span("prefill.write_pages", rid=rid):
-            self.cache.write_prompt(rid, kv["k"][:, 0], kv["v"][:, 0])
-        if self.speculative:
-            # seed the draft chain with the hidden state that produced
-            # this stream's first token
-            self._spec_embed[slot] = np.asarray(embeds[0, p - 1])
-        # logits of the last REAL position predict the next token
-        row = logits[0, p - 1] if full_logits else logits[0, 0]
-        # on a mesh, hand the engine a host row: the engine's eager
-        # sampler mixes it with its single-device rng key, which jax
-        # refuses across device sets
-        return np.asarray(row) if self.mesh is not None else row
+        # the skeleton's, and the draft chain's seed after the page write
+        row = super().prefill(rid, slot, prompt)
+        self._seed_draft(slot, self._draft_seed)
+        return row
 
     # -- chunked prefill (ServeConfig.prefill_chunk_tokens) ----------------
 
-    def _get_chunk_fn(self, m: int, s_pad: int):
-        key = ("chunk", m, s_pad)
-        fn = self._prefill_cache.get(key)
-        if fn is None:
-            self.prefill_programs_built += 1
-            fn = jax.jit(
-                partial(
-                    decode_chunk,
-                    cfg=self.model_cfg,
-                    compute_dtype=self.compute_dtype,
-                ),
-                donate_argnums=(1,),
-            )
-            self._prefill_cache[key] = fn
-        return fn
+    def _build_chunk(self, key):
+        return jax.jit(
+            partial(
+                decode_chunk,
+                cfg=self.model_cfg,
+                compute_dtype=self.compute_dtype,
+            ),
+            donate_argnums=(1,),
+        )
 
     def prefill_start(self, rid: int, slot: int, prompt) -> None:
         """Stage ``prompt`` for incremental prefill: allocate the full
@@ -356,7 +245,7 @@ class LlamaAdapter(FamilyAdapter):
         assert ok, "admission checked capacity; ensure cannot fail here"
         toks = np.zeros((1, p_pad), np.int32)
         toks[0, :p] = prompt
-        self.prefill_computed_tokens += p_pad  # the chunks cover the bucket
+        self._count_prefill(rid, p_pad)  # the chunks cover the bucket
         nlayers = int(self.params["layers"]["wq"].shape[0])
         # mini-cache length p_pad, NOT s_pad: whole-prompt prefill's
         # attention reduces over exactly p_pad key positions, and
@@ -387,10 +276,15 @@ class LlamaAdapter(FamilyAdapter):
         }
 
     def prefill_chunk(self, rid: int):
+        """Advance a staged prefill by one chunk. Returns None while
+        incomplete; on the final chunk, commits the state and returns
+        the (V,) logits row of the last real prompt position —
+        bit-identical to what whole-prompt ``prefill`` returns."""
         st = self._chunk_state[rid]
         pos = st["pos"]
         m = min(self.scfg.prefill_chunk_tokens, st["p_pad"] - pos)
-        logits, embeds, st["cache"] = self._get_chunk_fn(m, st["p_pad"])(
+        fn, _ = self._program(("chunk", m, st["p_pad"]), self._build_chunk)
+        logits, embeds, st["cache"] = fn(
             self.params,
             st["cache"],
             self._dev(st["toks"][:, pos : pos + m]),
@@ -411,28 +305,7 @@ class LlamaAdapter(FamilyAdapter):
         cache = {n: jnp.pad(a, pad) for n, a in st["cache"].items()}
         with span("prefill.write_pages", rid=rid):
             self.cache.write_prompt(rid, cache["k"][:, 0], cache["v"][:, 0])
-        if self.speculative:
-            self._spec_embed[st["slot"]] = np.asarray(st["embed"])
+        self._seed_draft(st["slot"], st["embed"])
         row = st["row"]
         del self._chunk_state[rid]
         return np.asarray(row) if self.mesh is not None else row
-
-    # -- decode ------------------------------------------------------------
-
-    def decode(self, slot_rids, lens, tokens, key):
-        self._upload_table(slot_rids)
-        # the jitted call returns before the device ends; the read of the
-        # sampled tokens is what waits for it
-        with span("decode.dispatch"):
-            toks, logits, pools = self._decode_fn(
-                self.params,
-                self.cache.pools,
-                self._table_dev,
-                self._dev(lens),
-                self._dev(tokens),
-                self._dev(key),
-            )
-            self.cache.pools = pools
-        with span("decode.wait"):
-            toks = np.asarray(toks)
-        return toks, logits

@@ -59,26 +59,23 @@ from fms_fsdp_tpu.models.mamba import (
     slab_shapes,
 )
 from fms_fsdp_tpu.obs.scopes import scoped
-from fms_fsdp_tpu.obs.spans import span
 from fms_fsdp_tpu.serve.disagg.slab import (
     SLAB_CODEC_VERSION,
     check_slab_header,
     pack_slab_leaves,
     split_slab_leaves,
 )
-from fms_fsdp_tpu.serve.families import FamilyAdapter
-from fms_fsdp_tpu.serve.kv_cache import RESERVED_PAGES, PagedKVCache
+from fms_fsdp_tpu.serve.families import FamilyAdapter, paged_geometry
 
 
 def page_geometry(model_cfg, scfg):
     """``(page_size, max_pages, num_pages)`` of the paged cache a hybrid
-    engine builds for its attention layers. No tuning-table entry for
-    the hybrid attention shape yet: 16 matches the table's common
-    resolution and keeps max_seq_len divisible in every test config."""
-    page_size = scfg.page_size or 16
-    assert scfg.max_seq_len % page_size == 0, (scfg.max_seq_len, page_size)
-    max_pages = scfg.max_seq_len // page_size
-    num_pages = scfg.num_pages or (scfg.max_batch * max_pages + RESERVED_PAGES)
+    engine builds for its attention layers (untuned: 16 tokens a page
+    unless ``scfg.page_size`` pins it)."""
+    a = model_cfg.attn_cfg
+    page_size, _, _, max_pages, num_pages = paged_geometry(
+        scfg, a.num_heads, a.num_heads_kv, a.head_dim, tuned=False
+    )
     return page_size, max_pages, num_pages
 
 
@@ -158,15 +155,10 @@ def prefill_program(model_cfg, scfg, p_pad: int, kv_len: int, compute_dtype):
 class MambaAdapter(FamilyAdapter):
     family = "mamba"
     supports_handoff = True  # via the slab codec, not the page codec
+    _pages_noun = "attn pages"
 
-    def __init__(self, params, model_cfg, scfg, compute_dtype=None):
-        from fms_fsdp_tpu.serve.engine import _DTYPES
-
-        self.params = params
-        self.model_cfg = model_cfg
-        self.scfg = scfg
-        self.compute_dtype = compute_dtype or _DTYPES[scfg.compute_dtype]
-        cfg = model_cfg
+    def _setup(self) -> None:
+        cfg, scfg = self.model_cfg, self.scfg
         self._hybrid = bool(cfg.attn_layer_idx)
 
         if scfg.serve_layout:
@@ -189,7 +181,7 @@ class MambaAdapter(FamilyAdapter):
                 "mamba serving stores its recurrent slab unquantized and "
                 "hybrid attn pages full-width: set kv_quant='none'"
             )
-        if getattr(scfg, "speculator_path", ""):
+        if scfg.speculator_path:
             raise ValueError(
                 "mamba serving has no speculative decode path yet: the "
                 "MLPSpeculator draft/verify loop is llama-only (the "
@@ -201,19 +193,13 @@ class MambaAdapter(FamilyAdapter):
 
         if self._hybrid:
             a = cfg.attn_cfg
-            self.page_size, self.max_pages, num_pages = page_geometry(
-                cfg, scfg
-            )
-            self.cache = PagedKVCache(
+            self._init_pages(
                 len(cfg.attn_layer_idx),
-                num_pages,
-                self.page_size,
+                a.num_heads,
                 a.num_heads_kv,
                 a.head_dim,
-                dtype=self.compute_dtype,
-                quant="none",
+                tuned=False,
             )
-        self.tune_how = "n/a"
 
         # the whole fleet of slots steps as one fixed-shape batch: one
         # slab covering max_batch streams, donated through the jit so
@@ -221,10 +207,6 @@ class MambaAdapter(FamilyAdapter):
         self._state = init_mamba_decode_state(
             cfg, scfg.max_batch, self.compute_dtype
         )
-        self._prefill_cache: Dict = {}
-        self._table_key = None
-        self._table_dev = None
-
         self._decode_fn = decode_program(
             cfg, scfg, self.page_size, self.compute_dtype
         )
@@ -247,124 +229,34 @@ class MambaAdapter(FamilyAdapter):
             lambda s: jnp.zeros((1,) + s.shape[1:], s.dtype), self._state
         )
 
-    # -- capacity ----------------------------------------------------------
-
-    def _padded(self, n: int) -> int:
-        return self._padded_len(n, self.scfg.prefill_bucket)
-
-    def admission_error(self, prompt_len: int, max_new: int) -> Optional[str]:
-        if not self._hybrid:
-            return None  # constant slab: fits iff a slot exists
-        worst = self._padded(prompt_len + max_new - 1) + 1
-        need = self.cache.pages_needed(worst)
-        total = self.cache.num_pages - RESERVED_PAGES
-        if need > total:
-            return (
-                f"request needs up to {need} attn pages but the pool "
-                f"holds {total}; raise num_pages or shrink "
-                f"prompt/max_new_tokens"
-            )
-        return None
-
-    def can_admit(self, rid: int, prompt_len: int) -> bool:
-        if not self._hybrid:
-            return True
-        return self.cache.can_ensure(rid, self._padded(prompt_len) + 1)
-
-    def grow(self, rid: int, n_tokens: int) -> bool:
-        if not self._hybrid:
-            return True
-        return self.cache.ensure(rid, n_tokens)
-
-    def release(self, rid: int, slot: int) -> None:
+    def _release_state(self, rid: int, slot: int) -> None:
         # zero the slab slice: an idle slot must hold no residue of the
         # evicted stream (and the decode step's live-mask keeps it zero)
         self._state = self._write_slot(
             self._state, self._zero_rows, np.int32(slot)
         )
-        if self._hybrid:
-            self.cache.free(rid)
 
-    # -- prefill -----------------------------------------------------------
+    # -- prefill: one program a padded length, told the prompt's length ----
 
-    def _get_prefill(self, p_pad: int, kv_len: int):
-        key = (p_pad, kv_len)
-        fn = self._prefill_cache.get(key)
-        if fn is None:
-            self.prefill_programs_built += 1
-            fn = prefill_program(
-                self.model_cfg, self.scfg, p_pad, kv_len, self.compute_dtype
-            )
-            self._prefill_cache[key] = fn
-        return fn
+    def _prefill_key(self, p: int, p_pad: int, kv_len: int):
+        return (p_pad, kv_len)
 
-    def prefill(self, rid: int, slot: int, prompt):
-        p = len(prompt)
-        p_pad = self._padded(p)
-        kv_len = 0
-        if self._hybrid:
-            kv_len = self.cache.pages_needed(p_pad) * self.page_size
-            ok = self.cache.ensure(rid, p_pad)
-            assert ok, "admission checked capacity; ensure cannot fail here"
-        built = self.prefill_programs_built
-        fn = self._get_prefill(p_pad, kv_len)
-        with span(
-            "prefill.dispatch",
-            rid=rid,
-            built=self.prefill_programs_built - built,
-        ):
-            toks = np.zeros((1, p_pad), np.int32)
-            toks[0, :p] = prompt
-            logits, st1, kv = fn(
-                self.params, jnp.asarray(toks), jnp.asarray([p], np.int32)
-            )
-            self.prefill_computed_tokens += prefill_positions(
-                self.model_cfg, p, p_pad
-            )
-        with span("prefill.write_state", rid=rid):
-            # land the 1-row prefill state in the stream's slab slice
-            self._state = self._write_slot(
-                self._state, st1, np.int32(slot)
-            )
-            self.prefill_state_writes += 1
-        if self._hybrid:
-            with span("prefill.write_pages", rid=rid):
-                self.cache.write_prompt(rid, kv["k"][:, 0], kv["v"][:, 0])
+    def _build_prefill(self, key):
+        return prefill_program(
+            self.model_cfg, self.scfg, *key, self.compute_dtype
+        )
+
+    def _call_prefill(self, fn, toks, p: int):
+        logits, rows, kv = fn(
+            self.params, jnp.asarray(toks), jnp.asarray([p], np.int32)
+        )
         # prefill already selects each row's last real position
-        return logits[0]
-
-    # -- decode ------------------------------------------------------------
-
-    def decode(self, slot_rids, lens, tokens, key):
-        # the jitted call returns before the device ends; the read of the
-        # sampled tokens is what waits for it
-        if not self._hybrid:
-            with span("decode.dispatch"):
-                toks, logits, self._state = self._decode_fn(
-                    self.params,
-                    self._state,
-                    jnp.asarray(lens),
-                    jnp.asarray(tokens),
-                    key,
-                )
-            with span("decode.wait"):
-                toks = np.asarray(toks)
-            return toks, logits
-        self._upload_table(slot_rids)
-        with span("decode.dispatch"):
-            toks, logits, self._state, pools = self._decode_fn(
-                self.params,
-                self._state,
-                self.cache.pools,
-                self._table_dev,
-                jnp.asarray(lens),
-                jnp.asarray(tokens),
-                key,
-            )
-            self.cache.pools = pools
-        with span("decode.wait"):
-            toks = np.asarray(toks)
-        return toks, logits
+        return (
+            logits[0],
+            kv if self._hybrid else None,
+            rows,
+            prefill_positions(self.model_cfg, p, toks.shape[1]),
+        )
 
     # -- disaggregation: the slab codec (serve/disagg/slab.py) -------------
 

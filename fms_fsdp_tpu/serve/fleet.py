@@ -104,8 +104,7 @@ serve/scheduler.py with per-reason counters).
 Proof: scripts/chaos_soak_serving.py kills AND stalls replicas
 mid-stream under seeded load and asserts zero dropped requests, greedy
 token-parity vs an unfaulted fleet, and measured availability < 1.0
-(docs/serving.md "Fleet resilience"; BENCH_SERVING.json
-``fleet-under-churn``).
+(docs/serving.md "Fleet resilience").
 
 This module imports no jax: the router is pure orchestration and must
 stay importable in thin supervisor processes (and the

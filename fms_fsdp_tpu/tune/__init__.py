@@ -7,7 +7,7 @@ Three layers (docs/performance.md "Autotuning"):
   statically against a per-chip VMEM budget (the same residency math the
   kernels document; no device, no timing);
 - :mod:`table` — the schema-versioned JSON tuning table committed
-  in-repo (KERNEL_TUNING.json, like AOT_LOWER.json), keyed by
+  in-repo (KERNEL_TUNING.json), keyed by
   (kernel, shape signature, dtype, chip kind);
 - :mod:`lookup` — trace-time resolution wired into
   ops/{flash_attention,ssd,fused_ce} and the serving engine's paged

@@ -18,8 +18,8 @@ operator pins ``chip=`` explicitly.
 
 Chosen configs are recorded as ``kernel.tune.*`` gauges/counters once a
 MetricRegistry is attached (main_training wires the Observer's registry
-in), and :func:`choices` exposes them to bench.py for the
-tuned-vs-default column.
+in), and :func:`choices` exposes them to scripts/autotune_kernels.py
+and the tests.
 """
 
 import logging

@@ -1,7 +1,7 @@
 """The schema-versioned JSON tuning table.
 
-One document, committed in-repo at KERNEL_TUNING.json (like
-AOT_LOWER.json), holds every tuned entry:
+One document, committed in-repo at KERNEL_TUNING.json, holds every
+tuned entry:
 
     {
       "schema_version": 1,

@@ -14,10 +14,10 @@ every test run; this sweep adds the whole-step and multi-device targets.
 What it cannot do: execute. Numerics, runtime hangs, and performance
 still need silicon (chip_smoke.py is the quickest such run).
 
-Robustness contract mirrors bench.py: the parent never imports jax;
-every target runs as ``--target N`` in its own subprocess under a
+Robustness contract: the parent never imports jax; every target runs as ``--target N`` in its own subprocess under a
 watchdog, so one Mosaic crash or hang yields a JSON error/timeout entry
-instead of killing the sweep. Results land in AOT_LOWER.json.
+instead of killing the sweep. Results land in AOT_LOWER.json (written
+where it is run; not committed).
 
 Run: python scripts/aot_lower_kernels.py            # full sweep
      python scripts/aot_lower_kernels.py --target 0 # one target (child)
@@ -354,8 +354,8 @@ TARGETS = [
             selective_checkpointing=1,
         ),
     ),
-    # the 32k single-chip long-context bench row exactly as bench.py
-    # runs it: kv-streamed flash + full AC + chunked fused CE
+    # the 32k single-chip long-context step: kv-streamed flash + full AC
+    # + chunked fused CE
     (
         "train_llama194m_32k_kvgrid_fusedce",
         lambda: _compile_train_step(

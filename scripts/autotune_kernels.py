@@ -8,7 +8,7 @@ survivors on the attached chip (fwd+bwd, proper warmup and
 tuning table the trace-time lookup reads
 (fms_fsdp_tpu/tune/{table,lookup}.py).
 
-Robustness contract mirrors bench.py / aot_lower_kernels.py: the parent
+Robustness contract mirrors aot_lower_kernels.py: the parent
 never imports jax; every candidate times in its own ``--measure``
 subprocess under a watchdog, so one Mosaic hang or OOM yields an error
 entry instead of killing the sweep. Measured entries replace
@@ -52,8 +52,8 @@ CANDIDATE_TIMEOUT_S = int(os.environ.get("AUTOTUNE_CANDIDATE_TIMEOUT_S", "420"))
 STEPS = int(os.environ.get("AUTOTUNE_STEPS", "10"))
 REPS = int(os.environ.get("AUTOTUNE_REPS", "3"))
 
-# The sweep suite: every distinct kernel signature the bench rows
-# (bench.py ROWS) trace, in the training dtype. Keyed exactly as the
+# The sweep suite: the kernel signatures of the training and serving
+# shapes the table answers, in the training dtype. Keyed exactly as the
 # trace-time lookup keys them, so a sweep win is a guaranteed exact hit.
 SUITE = [
     # flash: llama2_7b headline (32q/32kv heads, head 128, seq 4096)
@@ -89,7 +89,7 @@ SUITE = [
     ("fused_ce", {"d_model": 4096, "vocab": 32000}, "bfloat16"),
     ("fused_ce", {"d_model": 1024, "vocab": 128256}, "bfloat16"),
     # paged decode (serving): 7B-shaped GQA decode batch and the
-    # high-throughput small-model shape bench_serving.py drives
+    # high-throughput small-model shape
     ("paged_decode",
      {"batch": 8, "nq": 32, "nkv": 8, "head": 128, "max_seq": 4096},
      "bfloat16"),

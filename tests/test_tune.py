@@ -1,7 +1,7 @@
 """Kernel autotuner: table round trip, lookup fallback chain, VMEM cost
 model vs the kernels' own residency math, config/env precedence, CPU
-determinism, bit-identical "off" behavior, the _pick_block degradation
-signal, and the bench degraded-probe contract."""
+determinism, bit-identical "off" behavior and the _pick_block
+degradation signal."""
 
 import json
 import os
@@ -804,70 +804,3 @@ def test_autotune_script_dry_run_subprocess():
     assert doc["mode"] == "dry_run"
     assert doc.get("table_violations") == []
     assert all(s["legal_candidates"] > 0 for s in doc["suite"])
-
-
-# ---------------------------------------------------------------------------
-# bench contract: no measurement exits non-zero
-# ---------------------------------------------------------------------------
-
-
-@pytest.mark.slow
-def test_bench_probe_timeout_is_degraded_and_fails():
-    env = dict(os.environ)
-    env.update(
-        BENCH_FORCE_CPU="1",
-        BENCH_PROBE_TIMEOUT_S="0.05",  # guaranteed probe timeout
-    )
-    proc = subprocess.run(
-        [sys.executable, os.path.join(REPO, "bench.py")],
-        stdout=subprocess.PIPE, stderr=subprocess.STDOUT, text=True,
-        timeout=300, env=env, cwd=REPO,
-    )
-    line = [ln for ln in proc.stdout.splitlines() if ln.startswith("{")][-1]
-    out = json.loads(line)
-    assert out["degraded"] is True
-    assert out["vs_baseline"] is None  # never 0.0 for an unmeasured run
-    assert "error" in out
-    assert proc.returncode != 0  # nothing measured: never exit 0
-
-
-def test_bench_degraded_record_shape(capsys):
-    """Unit-level: the degraded record never carries a numeric
-    vs_baseline, and _finish exits non-zero on it — there is no mode
-    in which an unmeasured run ends 0."""
-    sys.path.insert(0, REPO)
-    try:
-        import bench
-    finally:
-        sys.path.pop(0)
-    rec = bench._degraded_result("v5e", "backend probe failed: timeout")
-    assert rec["degraded"] is True and rec["vs_baseline"] is None
-    assert rec["rows"] == []
-    with pytest.raises(SystemExit) as exc:
-        bench._finish(dict(rec))
-    assert exc.value.code != 0
-    assert json.loads(capsys.readouterr().out)["degraded"] is True
-    bench._finish({"value": 0.5, "rows": [{}]})  # measured: returns
-
-
-def test_bench_without_tpu_measures_nothing(monkeypatch, capsys):
-    """A healthy probe on a non-TPU backend, outside the tests' explicit
-    plumbing mode, runs no row: degraded record, non-zero exit."""
-    sys.path.insert(0, REPO)
-    try:
-        import bench
-    finally:
-        sys.path.pop(0)
-    monkeypatch.delenv("BENCH_SMOKE", raising=False)
-    monkeypatch.setattr(
-        bench, "_probe_backend", lambda: (8, "cpu", "cpu", None)
-    )
-    monkeypatch.setattr(
-        bench, "_run_subprocess",
-        lambda *a, **k: pytest.fail("a row ran without a TPU"),
-    )
-    with pytest.raises(SystemExit) as exc:
-        bench.main()
-    assert exc.value.code != 0
-    out = json.loads(capsys.readouterr().out)
-    assert out["degraded"] is True and "not tpu" in out["error"]
