@@ -463,7 +463,11 @@ def _serve_phase(phase, args, workdir, meter):
         """Submit a wave, run it dry; returns (requests, first decode
         step's logits row of the first stream)."""
         reqs = [engine.submit(p, new_tokens) for p in batch]
-        engine.step()  # admits + prefills stream 0, then decodes it once
+        # the first step admits and prefills stream 0 and dispatches its
+        # first decode step; the second one commits it
+        engine.last_logits = None
+        while engine.last_logits is None:
+            engine.step()
         first = np.asarray(engine.last_logits[0], np.float32)
         engine.run()
         return reqs, first

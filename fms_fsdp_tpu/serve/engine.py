@@ -39,11 +39,22 @@ PR 19 raw-speed additions, both parity-preserving: chunked prefill
 with decode, and speculative serving (``speculator_path``) commits
 multiple greedy tokens per verify step through the family adapter's
 ``decode_spec``.
+
+Since PR 30 the plain decode loop keeps one step in flight: ``step()``
+dispatches decode step n+1 from step n's token output, which stays on
+the device, and only then reads step n's tokens and commits them, so the
+host's work between two steps runs while the device computes. Lengths
+advance at dispatch, tokens become visible at the commit; whatever takes
+a stream away or reads its tokens outside the commit first collects the
+step in flight (``_collect``). docs/serving.md "The decode loop" has the
+order and what it means for an end-of-sequence token. The speculative
+path stays dispatch-then-collect inside one ``step()``: how many tokens
+a stream gains there is known only from the result.
 """
 
 import time
 from dataclasses import dataclass
-from typing import Callable, Dict, List, Optional, Sequence
+from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -54,6 +65,7 @@ from fms_fsdp_tpu.obs.registry import MetricRegistry
 from fms_fsdp_tpu.obs.spans import done, span
 from fms_fsdp_tpu.serve.families import FAMILY_CODES, resolve_adapter
 from fms_fsdp_tpu.serve.scheduler import (
+    FINISHED,
     REJECT_DEADLINE_UNMEETABLE,
     REJECT_OVERLOADED,
     REJECT_TOO_LARGE,
@@ -67,6 +79,16 @@ _DTYPES = {
     "float32": jnp.float32,
     "float16": jnp.float16,
 }
+
+
+@dataclass
+class _InFlight:
+    """A decode step that was dispatched and whose tokens the host has
+    not read yet."""
+
+    toks: object  # (B,) int32, on the device
+    logits: object  # (B, V), on the device
+    streams: List[Tuple[int, Request]]  # (slot, request) it decodes
 
 
 @dataclass(frozen=True)
@@ -223,16 +245,29 @@ class ServingEngine:
         self._admit_order: List[Request] = []
         self._tokens = np.zeros((scfg.max_batch,), np.int32)
         self._lens = np.zeros((scfg.max_batch,), np.int32)
+        # the plain decode loop's token row lives on the device, in the
+        # adapter (a step's output is the next step's input); ``_tokens``
+        # holds what the host knows, and ``_fresh`` marks the slots whose
+        # host value the next dispatch must write into the row (a new
+        # stream's first token). The speculative path reads ``_tokens``
+        # alone.
+        self._fresh = np.zeros((scfg.max_batch,), bool)
+        self._inflight: Optional[_InFlight] = None
         self._key = jax.random.PRNGKey(seed)
         self._decode_tokens = 0
         self._prefill_tokens = 0
+        # host seconds inside the decode phase: a step's dispatch plus
+        # what is left of it to wait for at its collect (device time that
+        # runs behind other host work is not in it)
         self._decode_wall = 0.0
         self._finished_buf: List[Request] = []
         # handoff imports that failed typed AFTER admission (the
         # adapter freed its allocations): the replica loop drains these
         # via take_failed() and rejects them back to the router
         self._failed_buf: List[Request] = []
-        self.last_logits = None  # (B, V) of the last decode step (debug)
+        # (B, V) of the last decode step whose tokens were committed: the
+        # collected step's, not the one in flight (debug)
+        self.last_logits = None
         self.iterations = 0  # engine step() count (health + fault ctx)
         self._draining = False
         # disaggregation accounting (obs schema v13 serving map)
@@ -512,6 +547,7 @@ class ServingEngine:
         self._slots[slot] = req
         self._admit_order.append(req)
         self._tokens[slot] = tok
+        self._fresh[slot] = True
         self._lens[slot] = p
         if self._finish_if_done(req, slot, now=now):
             return
@@ -557,6 +593,7 @@ class ServingEngine:
         self._slots[slot] = req
         self._admit_order.append(req)
         self._tokens[slot] = req.generated[-1]
+        self._fresh[slot] = True
         self._lens[slot] = int(header["seq_len"])
         self._finish_if_done(req, slot)
 
@@ -597,7 +634,8 @@ class ServingEngine:
         if not done:
             return False
         self.scheduler.mark_finished(req, now=now)
-        self._release_slot(req, slot)
+        if self._slots[slot] is req:  # else released at its last dispatch
+            self._release_slot(req, slot)
         self._finished_buf.append(req)
         self.registry.counter("serve.requests_completed").add()
         self.registry.hist("serve.request_latency_s").record(req.latency)
@@ -610,6 +648,7 @@ class ServingEngine:
         if req in self._admit_order:
             self._admit_order.remove(req)
         self._tokens[slot] = 0
+        self._fresh[slot] = False
         self._lens[slot] = 0
 
     def _evict(self, victim: Request) -> None:
@@ -622,8 +661,11 @@ class ServingEngine:
 
     def step(self) -> List[Request]:
         """One continuous-batching iteration: expire, admit (+prefill),
-        one ragged decode step, harvest finishes. Returns the requests
-        that finished during this iteration.
+        dispatch one ragged decode step, then collect and commit the
+        step dispatched by the previous iteration. Returns the requests
+        that finished during this iteration. The first ``step()`` of an
+        idle engine therefore returns before its decode step's tokens
+        are visible; the next one brings them.
 
         Every phase runs under a host span (obs/spans.py: ``serve/step``
         and its children, each carrying ``step=<iterations>``), which
@@ -674,6 +716,10 @@ class ServingEngine:
         # slot and pages NOW — decoding tokens nobody can use any more
         # starves streams that can still meet theirs
         running = [r for r in self._slots if r is not None]
+        if self.scheduler.past_deadline(running, now) and self._collect():
+            # an expired stream keeps what the step in flight made for
+            # it, and may turn out to have ended there
+            running = [r for r in self._slots if r is not None]
         inflight = self.scheduler.expire_inflight(running, now)
         for r in inflight:
             self._release_slot(r, self._slots.index(r))
@@ -749,11 +795,18 @@ class ServingEngine:
         """
         evicted = 0
         draft = self.adapter.spec_draft_tokens
-        for slot, req in enumerate(self._slots):
+        for slot in range(len(self._slots)):
+            req = self._slots[slot]
             if req is None or req.rid in self._chunking:
                 continue
             need = int(self._lens[slot]) + 1 + draft
             while not self.adapter.grow(req.rid, need):
+                # a victim's resume prompt must hold its last token, and
+                # the commit may free pages (or end ``req``) by itself
+                if self._collect():
+                    if self._slots[slot] is not req:
+                        break
+                    continue
                 victim = self.scheduler.evict_victim(self._admit_order)
                 assert victim is not None, "no victim but pool exhausted"
                 self._evict(victim)
@@ -763,17 +816,118 @@ class ServingEngine:
         return evicted
 
     def _decode(self, it: int) -> None:
-        """One decode step (plain or speculative) over the live slots,
-        and the commit of its tokens."""
-        slot_rids = [
-            r.rid if r is not None and r.rid not in self._chunking else None
-            for r in self._slots
-        ]
-        active = [
+        """The decode phase of an iteration: dispatch a step over the
+        live slots, then collect and commit the one dispatched before it
+        (speculative: one draft-and-verify step, dispatched and
+        committed here)."""
+        if self.adapter.speculative:
+            return self._decode_spec(it)
+        active = self._active()
+        if not active and self._inflight is None:
+            return
+        with span(
+            "decode",
+            step=it,
+            live=len(active),
+            kv_tokens=int(sum(self._lens[slot] for slot, _ in active)),
+        ):
+            prev, self._inflight = self._inflight, None
+            if active:
+                self._inflight = self._dispatch(active, prev is not None)
+            if prev is not None:
+                self._commit(prev)
+
+    def _active(self) -> List[Tuple[int, Request]]:
+        """(slot, request) of the streams a decode step serves."""
+        return [
             (slot, r)
             for slot, r in enumerate(self._slots)
             if r is not None and r.rid not in self._chunking
         ]
+
+    def _slot_rids(self, active) -> List[Optional[int]]:
+        """Per slot the rid of its stream in ``active``, else None."""
+        rids: List[Optional[int]] = [None] * len(self._slots)
+        for slot, req in active:
+            rids[slot] = req.rid
+        return rids
+
+    def _dispatch(self, active, overlapped: bool) -> _InFlight:
+        """Dispatch one decode step over ``active`` without reading its
+        result, and advance the host's lengths past it: the page growth
+        and the table of the next step need them. A stream whose token
+        in flight is its ``max_new_tokens``-th gives its slot, slab row
+        and pages back now (on the device the release is ordered behind
+        the step by the slab and the pools, which every program takes
+        donated), so the next admission comes when it always came."""
+        reg = self.registry
+        t0 = self.clock()
+        self._key, sub = jax.random.split(self._key)
+        # copies: the host's arrays change before the device has run
+        toks, logits = self.adapter.decode_dispatch(
+            self._slot_rids(active),
+            self._lens.copy(),
+            self._tokens.copy(),
+            sub,
+            self._fresh.copy(),
+            in_flight=int(overlapped),
+        )
+        self._fresh[:] = False
+        self._decode_wall += self.clock() - t0
+        reg.counter("serve.decode_live_slots").add(len(active))
+        if overlapped:
+            reg.counter("serve.decode_steps_overlapped").add()
+        for slot, req in active:
+            # the cache holds the prompt and all of the stream's tokens
+            # but the newest, the one in flight counted
+            self._lens[slot] += 1
+            tokens = int(self._lens[slot]) - len(req.prompt) + 1
+            if tokens >= req.max_new_tokens:
+                self._release_slot(req, slot)
+        return _InFlight(toks, logits, active)
+
+    def _collect(self) -> bool:
+        """Wait for the decode step in flight and commit its tokens;
+        False where none is. The door for everything that takes a
+        stream away, or looks at its tokens, outside the commit."""
+        flight, self._inflight = self._inflight, None
+        if flight is None:
+            return False
+        self._commit(flight)
+        return True
+
+    def _commit(self, flight: _InFlight) -> None:
+        """Read a dispatched step's tokens (the wait for the device) and
+        hand each to its stream. A stream that ended by ``eos_token`` at
+        the commit before rode this step too: its token is dropped."""
+        it = self.iterations
+        reg = self.registry
+        finished = len(self._finished_buf)
+        t0 = self.clock()
+        toks = self.adapter.decode_collect(flight.toks)
+        self._decode_wall += self.clock() - t0
+        self.last_logits = flight.logits
+        with span("decode.commit", step=it):
+            live = [sr for sr in flight.streams if sr[1].state != FINISHED]
+            self._decode_tokens += len(live)
+            reg.counter("serve.decode_tokens").add(len(live))
+            if len(live) < len(flight.streams):
+                reg.counter("serve.decode_tokens_discarded").add(
+                    len(flight.streams) - len(live)
+                )
+            for slot, req in live:
+                req.generated.append(int(toks[slot]))
+                self._finish_if_done(req, slot)
+            done(
+                "decode.commit",
+                step=it,
+                finished=len(self._finished_buf) - finished,
+            )
+
+    def _decode_spec(self, it: int) -> None:
+        """One draft-and-verify step over the live slots and the commit
+        of its tokens, nothing left in flight."""
+        active = self._active()
         if not active:
             return
         reg = self.registry
@@ -785,44 +939,28 @@ class ServingEngine:
             kv_tokens=int(sum(self._lens[slot] for slot, _ in active)),
         ):
             t0 = self.clock()
-            if self.adapter.speculative:
-                emit, counts, logits = self.adapter.decode_spec(
-                    slot_rids, self._lens, self._tokens
-                )
-            else:
-                self._key, sub = jax.random.split(self._key)
-                toks, logits = self.adapter.decode(
-                    slot_rids, self._lens, self._tokens, sub
-                )
+            emit, counts, logits = self.adapter.decode_spec(
+                self._slot_rids(active), self._lens, self._tokens
+            )
             self.last_logits = logits
             self._decode_wall += self.clock() - t0
             with span("decode.commit", step=it):
-                if self.adapter.speculative:
-                    draft = self.adapter.spec_draft_tokens
-                    for slot, req in active:
-                        self._spec_draft_total += draft
-                        self._spec_accept_total += int(counts[slot]) - 1
-                        # commit the accepted prefix token-by-token: eos
-                        # and max_new checks run per token, so truncation
-                        # matches the non-speculative stream exactly
-                        for j in range(int(counts[slot])):
-                            self._lens[slot] += 1
-                            tok = int(emit[slot, j])
-                            req.generated.append(tok)
-                            self._tokens[slot] = tok
-                            self._decode_tokens += 1
-                            reg.counter("serve.decode_tokens").add()
-                            if self._finish_if_done(req, slot):
-                                break
-                else:
-                    self._decode_tokens += len(active)
-                    reg.counter("serve.decode_tokens").add(len(active))
-                    for slot, req in active:
+                draft = self.adapter.spec_draft_tokens
+                for slot, req in active:
+                    self._spec_draft_total += draft
+                    self._spec_accept_total += int(counts[slot]) - 1
+                    # commit the accepted prefix token-by-token: eos
+                    # and max_new checks run per token, so truncation
+                    # matches the non-speculative stream exactly
+                    for j in range(int(counts[slot])):
                         self._lens[slot] += 1
-                        tok = int(toks[slot])
+                        tok = int(emit[slot, j])
                         req.generated.append(tok)
                         self._tokens[slot] = tok
-                        self._finish_if_done(req, slot)
+                        self._decode_tokens += 1
+                        reg.counter("serve.decode_tokens").add()
+                        if self._finish_if_done(req, slot):
+                            break
                 done(
                     "decode.commit",
                     step=it,
@@ -838,8 +976,15 @@ class ServingEngine:
             self.step()
 
     def has_work(self) -> bool:
-        return bool(self.scheduler.queue) or any(
-            r is not None for r in self._slots
+        """Queued or running requests, a dispatched decode step whose
+        tokens are not committed yet, or finished requests that no
+        ``step()`` has returned yet (they ended at a collect outside one:
+        ``drain``'s, ``pack_stream``'s): keep stepping while true."""
+        return (
+            bool(self.scheduler.queue)
+            or any(r is not None for r in self._slots)
+            or self._inflight is not None
+            or bool(self._finished_buf)
         )
 
     # -- fleet hooks (docs/serving.md "Fleet resilience") ------------------
@@ -848,7 +993,11 @@ class ServingEngine:
         """Stop admitting: queued and new requests are refused, running
         streams finish. The fleet router drains a replica before a
         planned stop so in-flight work completes instead of requeueing;
-        ``drained`` flips once the slots empty."""
+        ``drained`` flips once the slots empty. The decode step in
+        flight is collected first, so the running streams hold their
+        last tokens; a stream that ended there is the next ``step()``'s
+        to return, and ``has_work()`` stays true until it has."""
+        self._collect()
         self._draining = True
 
     def take_failed(self) -> List[Request]:
@@ -878,6 +1027,7 @@ class ServingEngine:
 
         if not self.adapter.supports_handoff or self.adapter.speculative:
             return None
+        self._collect()  # the frame holds the stream's last token
         if req.rid in self._chunking or req not in self._slots:
             return None
         slot = self._slots.index(req)
@@ -900,7 +1050,12 @@ class ServingEngine:
 
     @property
     def drained(self) -> bool:
-        return self._draining and all(r is None for r in self._slots)
+        return (
+            self._draining
+            and self._inflight is None
+            and all(r is None for r in self._slots)
+            and not self._finished_buf
+        )
 
     def health(self) -> Dict[str, float]:
         """One flat liveness snapshot (the replica loop's heartbeat

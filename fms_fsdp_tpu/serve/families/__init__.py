@@ -238,10 +238,10 @@ class FamilyAdapter:
       the program up (``_program``), call it, land its outputs (slab
       rows, K/V pages), hand back the (V,) logits row of the last real
       prompt position;
-    - ``decode(slot_rids, lens, tokens, key)``: upload the page table
-      when it went stale, one jitted ragged step over all ``max_batch``
-      slots with the pools and the slab donated, read the sampled tokens
-      -> (tokens (B,) np.int32, logits (B, V)).
+    - ``decode_dispatch(slot_rids, lens, tokens, key, fresh)``: upload the
+      page table when stale, one jitted ragged step over all slots (pools,
+      slab donated) fed its predecessor's tokens on the device -> (tokens
+      (B,), logits (B, V)) unread; ``decode_collect(tokens)`` reads, waits.
 
     A family sets its state (``cache``, ``_state``) and ``_decode_fn`` in
     ``_setup`` and writes the three prefill hooks (``_prefill_key``,
@@ -458,25 +458,48 @@ class FamilyAdapter:
 
     # -- decode: the template ----------------------------------------------
 
-    def decode(self, slot_rids, lens, tokens, key):
-        """One jitted ragged decode step over all slots. The program
-        takes ``(params, [slab], [pools, page table], seq_lens, tokens,
-        key)`` and returns ``(tokens, logits, [slab], [pools])``: the
-        state a family has, donated and put back."""
+    # the token output of the last dispatched step, (B,) int32 on the
+    # device, maybe still being computed: the next step's token input
+    # wherever the engine brings no fresh token from the host
+    _toks = None
+
+    def decode_dispatch(
+        self, slot_rids, lens, tokens, key, fresh, in_flight=0
+    ):
+        """Dispatch one jitted ragged decode step over all slots and
+        return its sampled tokens (B,) int32 and logits (B, V) as device
+        arrays, unread: the call returns before the device ends. A slot's
+        token input is the host's ``tokens`` where ``fresh`` (a stream
+        prefilled since the last dispatch: its first token) and the last
+        dispatched step's own output elsewhere, which never leaves the
+        device.
+        ``in_flight`` 1: the engine dispatches this step before it read
+        the last one's tokens. The program takes ``(params, [slab],
+        [pools, page table], seq_lens, tokens, key)`` and returns
+        ``(tokens, logits, [slab], [pools])``: the state a family has,
+        donated and put back."""
+        import jax.numpy as jnp
+
+        row = self._toks
+        if row is None or fresh.any():
+            host = self._dev(tokens)
+            row = jnp.where(
+                self._dev(fresh), host, host if row is None else row
+            )
         state = []
         if self._state is not None:
             state.append(self._state)
         if self.cache is not None:
             self._upload_table(slot_rids)
             state += [self.cache.pools, self._table_dev]
-        # the jitted call returns before the device ends; the read of the
-        # sampled tokens is what waits for it
-        with span("decode.dispatch", **self._dispatch_fields):
-            toks, logits, *state = self._decode_fn(
+        with span(
+            "decode.dispatch", in_flight=in_flight, **self._dispatch_fields
+        ):
+            self._toks, logits, *state = self._decode_fn(
                 self.params,
                 *state,
                 self._dev(lens),
-                self._dev(tokens),
+                row,
                 # the key is on the device: only a mesh wants it replicated
                 key if self._repl is None else self._dev(key),
             )
@@ -484,9 +507,13 @@ class FamilyAdapter:
                 self._state = state.pop(0)
             if self.cache is not None:
                 self.cache.pools = state.pop(0)
+        return self._toks, logits
+
+    def decode_collect(self, toks):
+        """The sampled tokens of a dispatched step on the host, (B,)
+        np.int32: the read is what waits for the device."""
         with span("decode.wait"):
-            toks = np.asarray(toks)
-        return toks, logits
+            return np.asarray(toks)
 
     # -- serving layout (ServeConfig.serve_layout) -------------------------
 

@@ -184,7 +184,7 @@ class LlamaAdapter(PagedAdapter):
         (B, V) of each row's committed position) — row b's new tokens
         are ``emit[b, :counts[b]]``."""
         self._upload_table(slot_rids)
-        with span("decode.dispatch"):
+        with span("decode.dispatch", in_flight=0):
             emit, counts, logits, embeds, pools = self._spec_fn(
                 self.params,
                 self._spec_params,

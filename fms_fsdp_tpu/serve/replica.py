@@ -268,6 +268,29 @@ def serve_loop(engine, replica_idx: int, idle_sleep_s: float = 0.02,
             }
         )
 
+    def emit_finished(reqs):
+        for req in reqs:
+            ent = by_req.pop(id(req), None)
+            if ent is None:
+                continue
+            if req.handoff_out is not None:
+                # prefill role: the stream's pages + state, packed.
+                # The router journals these bytes BEFORE forwarding
+                # to a decode replica — a death on either side of a
+                # half-shipped handoff replays from the journal.
+                ship("handoff", ent[1], req.handoff_out, ttft=req.ttft)
+                continue
+            _emit(
+                {
+                    "type": "done",
+                    "rid": ent[1],
+                    "tokens": list(req.generated),
+                    # engine-side time-to-first-token (a duration,
+                    # so clock domains don't matter to the router)
+                    "ttft": req.ttft,
+                }
+            )
+
     def emit_failed():
         # handoff imports that failed typed after admission: reject
         # back so the router requeues for re-prefill (never counted
@@ -304,6 +327,12 @@ def serve_loop(engine, replica_idx: int, idle_sleep_s: float = 0.02,
             preempt_t0 = time.monotonic()
             engine.drain()
             return_queued()
+            # drain() collected the decode step in flight: a stream that
+            # ended there is done, not one to migrate, and no step()
+            # comes any more to return it
+            emit_finished(
+                [r for r, _ in by_req.values() if r.state == "finished"]
+            )
             for req in engine.live_requests():
                 ent = by_req.pop(id(req), None)
                 if ent is None:
@@ -408,29 +437,7 @@ def serve_loop(engine, replica_idx: int, idle_sleep_s: float = 0.02,
 
         # 4) step + stream completions
         if engine.has_work():
-            for req in engine.step():
-                ent = by_req.pop(id(req), None)
-                if ent is None:
-                    continue
-                if req.handoff_out is not None:
-                    # prefill role: the stream's pages + state, packed.
-                    # The router journals these bytes BEFORE forwarding
-                    # to a decode replica — a death on either side of a
-                    # half-shipped handoff replays from the journal.
-                    ship(
-                        "handoff", ent[1], req.handoff_out, ttft=req.ttft
-                    )
-                    continue
-                _emit(
-                    {
-                        "type": "done",
-                        "rid": ent[1],
-                        "tokens": list(req.generated),
-                        # engine-side time-to-first-token (a duration,
-                        # so clock domains don't matter to the router)
-                        "ttft": req.ttft,
-                    }
-                )
+            emit_finished(engine.step())
             emit_failed()
             # engine-side deadline expiries (queued or in-flight) never
             # come back from step(); the router must still terminalize

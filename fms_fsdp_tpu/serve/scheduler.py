@@ -189,15 +189,20 @@ class ContinuousBatchingScheduler:
         deadlines. The engine calls this at the step boundary and frees
         the victims' pages (``serve.requests_expired_inflight``)."""
         now = self.clock() if now is None else now
-        dead = [
-            r for r in running
-            if r.deadline is not None and now > r.deadline
-        ]
+        dead = self.past_deadline(running, now)
         for r in dead:
             r.state = EXPIRED
             r.finish_time = now
             self.expired_inflight += 1
         return dead
+
+    @staticmethod
+    def past_deadline(running: List[Request], now: float) -> List[Request]:
+        """The requests of ``running`` whose absolute deadline passed."""
+        return [
+            r for r in running
+            if r.deadline is not None and now > r.deadline
+        ]
 
     # -- admission ---------------------------------------------------------
 

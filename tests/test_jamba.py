@@ -73,23 +73,24 @@ def _serve_capturing(eng, prompts, max_new):
     served position (the prefill's, then each decode step's), read where
     the adapter hands them to the engine."""
     rows = {}
-    prefill, decode = eng.adapter.prefill, eng.adapter.decode
+    prefill, decode = eng.adapter.prefill, eng.adapter.decode_dispatch
 
     def capture_prefill(rid, slot, prompt):
         row = prefill(rid, slot, prompt)
         rows[rid] = [np.asarray(row, np.float32)]
         return row
 
-    def capture_decode(slot_rids, lens, tokens, key):
+    def capture_decode(slot_rids, lens, tokens, key, fresh, **kw):
         live = [(slot, rid) for slot, rid in enumerate(slot_rids)
                 if rid is not None and lens[slot] > 0]
-        toks, logits = decode(slot_rids, lens, tokens, key)
+        toks, logits = decode(slot_rids, lens, tokens, key, fresh, **kw)
         step = np.asarray(logits, np.float32)
         for slot, rid in live:
             rows[rid].append(step[slot])
         return toks, logits
 
-    eng.adapter.prefill, eng.adapter.decode = capture_prefill, capture_decode
+    eng.adapter.prefill = capture_prefill
+    eng.adapter.decode_dispatch = capture_decode
     reqs = [eng.submit(p, max_new) for p in prompts]
     eng.run()
     # the last decode step's logits pick a token that is never served

@@ -288,8 +288,12 @@ def test_counters_read_what_happened(traced):
     assert reg.counter("serve.decode_live_slots").value == sum(
         new - 1 for _, new in REQUESTS)
     assert sum(s.stats["admitted"] for s in named(spans, "admit.done")) == 3
-    kv = [s.stats["kv_tokens"] for s in named(spans, "decode")]
+    decodes = named(spans, "decode")
+    kv = [s.stats["kv_tokens"] for s in decodes if s.stats["live"]]
     assert kv[0] == 5 and all(k > 0 for k in kv)
+    # the last iteration dispatches nothing: it commits the step in flight
+    assert [s.stats["live"] for s in decodes].count(0) == 1
+    assert decodes[-1].stats["live"] == 0
 
 
 # -- (b) tracing off is no profiler session ----------------------------------
