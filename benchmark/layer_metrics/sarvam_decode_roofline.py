@@ -1,0 +1,25 @@
+"""The sarvam decode program's share of its memory roofline: the bytes a
+decode step must move (``costs_sarvam.sarvam_decode_bytes``: attention,
+router, shared expert, dense MLP and head once, the held experts that
+some live stream chose as their expectation, the live latent pages) over
+the HBM peak, over the median device time of the decode program. Bound:
+HBM bandwidth (819 GB/s on a v5e). Live streams and their cached tokens
+are the window's means over the steps that ran no prefill; the program
+itself steps all ``max_batch`` slots, streams every held expert and
+gathers pages up to the longest live stream."""
+
+from benchmark import costs_sarvam, trace_reduce
+from benchmark.program_scopes_sarvam import DECODE_MODULE, live_means
+
+
+def read(run):
+    if run.trace_data is None or run.peaks is None:
+        return None
+    durs = sorted(trace_reduce.module_durations_ns(
+        run.trace_data, (DECODE_MODULE,)))
+    live = live_means(run)
+    if not durs or live is None:
+        return None
+    ms = durs[len(durs) // 2] / 1e6
+    need = costs_sarvam.sarvam_decode_bytes(run.config, *live)
+    return 100.0 * need / run.peaks["hbm_bytes_per_s"] / (ms / 1e3)

@@ -267,3 +267,154 @@ class MixtralConfig:
         if include_embeddings:
             total += 2 * self.src_vocab_size * d
         return int(total)
+
+
+@dataclass(frozen=True)
+class SarvamConfig:
+    """Latent-attention (MLA) MoE family (``model_type: sarvam_mla``;
+    models/sarvam.py): every layer attends through a compressed latent
+    (``kv_lora_rank`` values and one shared rotary key a position, no
+    query compression), the first ``first_k_dense`` layers carry a dense
+    SwiGLU MLP, the rest ``num_experts`` sigmoid-routed experts of which
+    ``top_k`` serve a token, beside ``num_shared_experts`` that serve
+    every token.
+
+    ``experts_held`` = (first id, count) says which of the ``num_experts``
+    routed experts this program holds: the router keeps its published
+    width, the expert layer computes the part of the result that its own
+    experts give, and adds nothing for the others (expert parallelism's
+    share of a layer; the exchange is not in this program). ``None`` is
+    the whole model. ``src_vocab_size`` is likewise the rows held."""
+
+    src_vocab_size: int = 262144
+    emb_dim: int = 4096
+    nheads: int = 64
+    nlayers: int = 32
+    qk_nope_head_dim: int = 128
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 128
+    kv_lora_rank: int = 512
+    hidden_dim: int = 16384  # the leading dense layers' MLP
+    first_k_dense: int = 1
+    moe_hidden_dim: int = 2048  # one expert
+    num_experts: int = 128  # the router's width
+    experts_held: Optional[Tuple[int, int]] = None
+    top_k: int = 8
+    num_shared_experts: int = 1
+    routed_scaling_factor: float = 2.5
+    max_expected_seq_len: int = 131072
+    rope_theta: float = 10000.0
+    rope_factor: float = 40.0
+    rope_original_max_position: int = 4096
+    rope_beta_fast: float = 32.0
+    rope_beta_slow: float = 1.0
+    rope_mscale: float = 1.0
+    rope_mscale_all_dim: float = 1.0
+    norm_eps: float = 1e-6
+
+    def __post_init__(self):
+        first, count = self.held
+        if not (0 <= first and count >= 1
+                and first + count <= self.num_experts):
+            raise ValueError(
+                f"experts_held={self.experts_held} is no range of the "
+                f"{self.num_experts} routed experts"
+            )
+
+    @property
+    def held(self) -> Tuple[int, int]:
+        """(first id, count) of the routed experts held here."""
+        return self.experts_held or (0, self.num_experts)
+
+    @property
+    def q_head_dim(self) -> int:
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+    @property
+    def latent_dim(self) -> int:
+        """What a position leaves in the cache, a layer: the normed
+        latent and the rotated shared key."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+    @property
+    def n_moe_layers(self) -> int:
+        return self.nlayers - self.first_k_dense
+
+    def n_params(self) -> int:
+        """Parameters held here (the experts and vocabulary rows held)."""
+        d, n = self.emb_dim, self.nheads
+        attn = (
+            d * n * self.q_head_dim
+            + d * self.latent_dim
+            + self.kv_lora_rank
+            + self.kv_lora_rank * n * (self.qk_nope_head_dim + self.v_head_dim)
+            + n * self.v_head_dim * d
+            + 2 * d
+        )
+        dense = 3 * d * self.hidden_dim
+        moe = (
+            d * self.num_experts + self.num_experts
+            + 3 * d * self.moe_hidden_dim
+            * (self.held[1] + self.num_shared_experts)
+        )
+        return int(
+            self.nlayers * attn
+            + self.first_k_dense * dense
+            + self.n_moe_layers * moe
+            + d
+            + 2 * self.src_vocab_size * d
+        )
+
+
+def sarvam_config(d: dict) -> SarvamConfig:
+    """A published ``config.json`` of ``model_type: sarvam_mla`` as the
+    family's config. A file that states a chip's share of a deployment
+    gives the experts held as ``num_experts`` with the router's width
+    under ``published`` and the first held id as ``first_expert_held``
+    (benchmark/configs/sarvam-105b.1chip.json); without ``published`` the
+    model is whole. The config has no key for the scoring rule, the
+    routing groups or where ``use_qk_norm`` acts: models/sarvam.py says
+    how each is read."""
+    if d.get("q_lora_rank"):
+        raise ValueError(
+            "sarvam_mla with q_lora_rank: the family's queries are not "
+            "compressed (models/sarvam.py)"
+        )
+    rs = d.get("rope_scaling") or {}
+    if rs.get("type", "deepseek_yarn") != "deepseek_yarn":
+        raise ValueError(f"rope_scaling type {rs.get('type')!r}: the family "
+                         "has the deepseek_yarn frequencies")
+    held = d["num_experts"]
+    published = (d.get("published") or {}).get("num_experts", held)
+    return SarvamConfig(
+        src_vocab_size=d["vocab_size"],
+        emb_dim=d["hidden_size"],
+        nheads=d["num_attention_heads"],
+        nlayers=d["num_hidden_layers"],
+        qk_nope_head_dim=d["qk_nope_head_dim"],
+        qk_rope_head_dim=d["qk_rope_head_dim"],
+        v_head_dim=d["v_head_dim"],
+        kv_lora_rank=d["kv_lora_rank"],
+        hidden_dim=d["intermediate_size"],
+        first_k_dense=d.get("first_k_dense_replace", 0),
+        moe_hidden_dim=d["moe_intermediate_size"],
+        num_experts=published,
+        experts_held=(
+            (int(d.get("first_expert_held", 0)), held)
+            if held != published else None
+        ),
+        top_k=d["num_experts_per_tok"],
+        num_shared_experts=d.get("num_shared_experts", 0),
+        routed_scaling_factor=d.get("routed_scaling_factor", 1.0),
+        max_expected_seq_len=d["max_position_embeddings"],
+        rope_theta=d.get("rope_theta", 10000.0),
+        rope_factor=rs.get("factor", 1.0),
+        rope_original_max_position=rs.get(
+            "original_max_position_embeddings", d["max_position_embeddings"]
+        ),
+        rope_beta_fast=rs.get("beta_fast", 32.0),
+        rope_beta_slow=rs.get("beta_slow", 1.0),
+        rope_mscale=rs.get("mscale", 1.0),
+        rope_mscale_all_dim=rs.get("mscale_all_dim", 0.0),
+        norm_eps=d["rms_norm_eps"],
+    )

@@ -179,9 +179,14 @@ def _moe_stats(aux, keep=None):
     return {"balance": aux, "drop_frac": drop}
 
 
-def _expert_mix(top_idx, top_w, E: int):
-    """The renormalized top-k weights as a (B, S, E) fp32 mixture over
-    all experts: exactly zero for an expert the row did not choose."""
+def _expert_mix(top_idx, top_w, E: int, first: int = 0):
+    """The top-k weights as a (B, S, E) fp32 mixture over the ``E``
+    experts held, ids ``first`` to ``first + E``: exactly zero for an
+    expert the row did not choose, and a choice of an expert that is not
+    held adds to no column (the one-hot of an id out of range is all
+    zero). ``first = 0`` with every expert held is the whole layer's."""
+    if first:
+        top_idx = top_idx - first
     return jnp.sum(
         jax.nn.one_hot(top_idx, E, dtype=jnp.float32) * top_w[..., None],
         axis=-2,
@@ -189,9 +194,9 @@ def _expert_mix(top_idx, top_w, E: int):
 
 
 def _all_experts_swiglu(h, lp):
-    """Every expert's SwiGLU of every row, over the layer's stacked
-    weights where they lie. h (B, S, D); w1/w3 (E, D, H); w2 (E, H, D).
-    Returns (B, S, E, D)."""
+    """The SwiGLU of every row in every expert held, over the layer's
+    stacked weights where they lie. h (B, S, D); w1/w3 (E, D, H); w2
+    (E, H, D), E the experts held. Returns (B, S, E, D)."""
     return jnp.einsum(
         "bseh,ehd->bsed",
         jax.nn.silu(jnp.einsum("bsd,edh->bseh", h, lp["w1"]))
@@ -522,7 +527,10 @@ def _moe_ffn_dispatch_einsum(
 
 def routed_moe_form(n_pairs: int, num_experts: int) -> str:
     """The loop order of ``moe_impl="routed"`` for ``n_pairs`` routed
-    (row, choice) pairs a step: a static fact of the program's shape."""
+    (row, choice) pairs a step on the ``num_experts`` experts held: a
+    static fact of the program's shape. (A program that holds a share of
+    the experts still loops over every routed pair in the second form:
+    which of them land on its share is known on the device alone.)"""
     return "all_experts" if n_pairs >= num_experts else "per_pair"
 
 

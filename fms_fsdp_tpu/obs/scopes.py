@@ -79,6 +79,37 @@ HYBRID_SCOPES = (
 )
 SSM_SCOPES = tuple(s for s in HYBRID_SCOPES if s.startswith("ssm_"))
 
+# the scopes of a sarvam engine's two programs (models/sarvam.py,
+# serve/families/sarvam.py: ``jit__step`` and ``jit__prefill_<tokens>``),
+# in program order. ``mla_kv_down`` holds ``W_kva``, the latent's norm and
+# the rotary tables; ``mla_absorb`` the decode step's queries through
+# ``W_kvb^K`` and outputs through ``W_kvb^V``; ``mla_expand`` the
+# prefill's ``W_kvb`` over a block of the latent; ``moe_group`` the
+# prefill's sort of its (token, choice) pairs by held expert. ``layers``
+# is around the scan over the MoE layers: its own slicing of the stack
+SARVAM_SCOPES = (
+    "params_cast",
+    "embed",
+    "norm",
+    "layers",
+    "mla_q",
+    "mla_kv_down",
+    "latent_write",
+    "latent_gather",
+    "mla_absorb",
+    "mla_expand",
+    "attn",
+    "attn_out",
+    "mlp",
+    "moe_router",
+    "moe_shared",
+    "moe_group",
+    "moe_experts",
+    "moe_combine",
+    "lm_head",
+    "sample",
+)
+
 _COMPUTATION = re.compile(r"^(?:ENTRY\s+)?%?([\w.\-]+)\s.*\{\s*$")
 _INSTRUCTION = re.compile(r"^\s+(?:ROOT\s+)?%?([\w.\-]+) = ")
 _NAME = re.compile(r"%([\w.\-]+)")

@@ -320,3 +320,148 @@ def paged_attention_kernel(
         interpret=interpret,
     )(seq_lens.astype(jnp.int32), page_table.astype(jnp.int32), q, *operands)
     return out.reshape(b, nq * hd)
+
+
+# ---------------------------------------------------------------------------
+# latent (MLA) pages: one pool, the key's leading lanes are the value
+# ---------------------------------------------------------------------------
+
+
+def _latent_decode_kernel(
+    lens_ref,  # scalar prefetch: (B,) int32 query positions
+    table_ref,  # scalar prefetch: (B, maxp) int32 page table
+    layer_ref,  # scalar prefetch: (1,) int32 layer of the pool
+    q_ref,  # (1, N, W)
+    *rest,  # ppb pages (1, 1, ps, W); o (1, N, Vw); scratch
+    page_size,
+    pages_per_block,
+    value_width,
+    scale,
+):
+    """One (batch row, block of pages) grid cell of absorbed latent
+    attention: every query head against the same ``W``-wide rows, whose
+    first ``value_width`` lanes are also the value. ``_paged_decode_kernel``
+    with one kv head, one operand for keys and values, and no columns to
+    mask but the positions past the row's."""
+    ppb = pages_per_block
+    page_refs = rest[:ppb]
+    o_ref, acc_ref, m_ref, l_ref = rest[ppb:]
+    b = pl.program_id(0)
+    j = pl.program_id(1)
+    pos = lens_ref[b]
+    n = q_ref.shape[1]
+
+    @pl.when(j == 0)
+    def _():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+
+    @pl.when(j * (ppb * page_size) <= pos)
+    def _():
+        q = (q_ref[0] * (scale * LOG2E)).astype(q_ref.dtype)  # (N, W)
+        tok = jax.lax.broadcasted_iota(jnp.int32, (n, page_size), 1)
+        m, l, acc = m_ref[...], l_ref[...], acc_ref[...]
+        for i in range(ppb):
+            k = page_refs[i][0, 0]  # (ps, W)
+            s = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )  # (N, ps), base-2 domain
+            kpos = (j * ppb + i) * page_size + tok
+            s = jnp.where(kpos <= pos, s, NEG_INF)
+            m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+            p = jnp.exp2(s - m_new)
+            alpha = jnp.exp2(m - m_new)
+            l = l * alpha + jnp.sum(p, axis=1, keepdims=True)
+            acc = acc * alpha + jax.lax.dot_general(
+                p.astype(k.dtype), k[:, :value_width],
+                (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+            m = m_new
+        m_ref[...], l_ref[...], acc_ref[...] = m, l, acc
+
+    @pl.when(j == pl.num_programs(1) - 1)
+    def _():
+        l = l_ref[...]
+        safe = jnp.where(l == 0.0, 1.0, l)
+        o_ref[0] = (acc_ref[...] / safe).astype(o_ref.dtype)
+
+
+# cache positions one grid cell of the latent kernel attends
+LATENT_BLOCK_TOKENS = 512
+
+
+@scoped("attn")
+def latent_attention_kernel(
+    q, pool, layer, page_table, seq_lens, *, value_width, scale,
+    interpret=None,
+):
+    """Ragged paged decode attention over one layer of a latent pool.
+
+    q (B, N, W): a query a row and head, as wide as a pool entry; pool
+    (L, P, page_size, W), read where it lies (``layer`` a traced scalar:
+    no slice of the pool is ever made); page_table (B, maxp); row ``b``
+    sees cache positions <= seq_lens[b]. A position's value is the first
+    ``value_width`` lanes of its entry. Returns (B, N, value_width) fp32:
+    softmax(scale * q k^T) v.
+
+    Grid (B, blocks of pages); each page of a cell is its own BlockSpec
+    operand whose index map reads the pool page out of the table, dead
+    blocks clamped onto the row's last live page (a repeat fetch the
+    pipeline elides) and skipped; the running softmax lives in VMEM
+    scratch across a row's walk."""
+    b, n, w = q.shape
+    page_size = pool.shape[2]
+    maxp = page_table.shape[1]
+    if interpret is None:
+        interpret = interpret_default()
+    from fms_fsdp_tpu.ops.selective_scan import largest_divisor
+
+    ppb = largest_divisor(maxp, max(1, LATENT_BLOCK_TOKENS // page_size))
+
+    def page_map(i):
+        def index_map(b_, j_, lens, table, layer_):
+            last = jnp.maximum(lens[b_], 0) // page_size
+            return (layer_[0], table[b_, jnp.minimum(j_ * ppb + i, last)], 0, 0)
+
+        return index_map
+
+    def row_map(b_, j_, *_):
+        return (b_, 0, 0)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(b, maxp // ppb),
+        in_specs=[pl.BlockSpec((1, n, w), row_map)] + [
+            pl.BlockSpec((1, 1, page_size, w), page_map(i)) for i in range(ppb)
+        ],
+        out_specs=pl.BlockSpec((1, n, value_width), row_map),
+        scratch_shapes=[
+            pltpu.VMEM((n, value_width), jnp.float32),
+            pltpu.VMEM((n, 1), jnp.float32),
+            pltpu.VMEM((n, 1), jnp.float32),
+        ],
+    )
+    return pl.pallas_call(
+        functools.partial(
+            _latent_decode_kernel,
+            page_size=page_size,
+            pages_per_block=ppb,
+            value_width=value_width,
+            scale=scale,
+        ),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, n, value_width), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")
+        ),
+        interpret=interpret,
+    )(
+        seq_lens.astype(jnp.int32),
+        page_table.astype(jnp.int32),
+        jnp.asarray(layer, jnp.int32).reshape(1),
+        q,
+        *([pool] * ppb),
+    )

@@ -1,4 +1,4 @@
-"""Family adapters: one serving engine, three model families.
+"""Family adapters: one serving engine, four model families.
 
 The ServingEngine owns admission, continuous batching, eviction and
 metrics — none of which care what a "slot" stores. What differs per
@@ -22,6 +22,10 @@ family          decode-state per stream
 ``mixtral``     paged KV pages for attention + nothing for the MoE:
                 expert routing is stateless per token (top-k gather
                 of expert weights at decode)
+``sarvam``      paged latent pages (one pool, ``latent_dim`` values a
+                position and layer whatever the head count: MLA);
+                the held share of a sigmoid-routed expert layer and
+                its shared expert are stateless per token
 ==============  ========================================================
 
 Every adapter is parity-anchored: greedy decode through the engine is
@@ -46,18 +50,20 @@ from fms_fsdp_tpu.models.configs import (
     LlamaConfig,
     MambaConfig,
     MixtralConfig,
+    SarvamConfig,
 )
 from fms_fsdp_tpu.obs.registry import MetricRegistry
 from fms_fsdp_tpu.obs.spans import done, span
 
 # the wire encoding of a family in numeric-only maps (obs schema v12
 # "serving"): family = FAMILY_CODES[name]
-FAMILY_CODES = {"llama": 0, "mamba": 1, "mixtral": 2}
+FAMILY_CODES = {"llama": 0, "mamba": 1, "mixtral": 2, "sarvam": 3}
 FAMILY_NAMES = {v: k for k, v in FAMILY_CODES.items()}
 
 _CONFIG_FAMILIES = (
     (MambaConfig, "mamba"),
     (MixtralConfig, "mixtral"),
+    (SarvamConfig, "sarvam"),
     (LlamaConfig, "llama"),
 )
 
@@ -69,7 +75,7 @@ def family_of(model_cfg) -> str:
             return name
     raise ValueError(
         f"unknown model config type {type(model_cfg).__name__}: expected "
-        f"LlamaConfig, MambaConfig or MixtralConfig "
+        f"LlamaConfig, MambaConfig, MixtralConfig or SarvamConfig "
         f"(fms_fsdp_tpu/models/configs.py)"
     )
 
@@ -81,7 +87,9 @@ def load_model_config(d: dict):
     from architecture-distinguishing keys (``d_model`` -> mamba,
     ``num_experts`` -> mixtral, else llama). A published Jamba
     ``config.json`` (``"model_type": "jamba"``, or ``"family": "jamba"``)
-    resolves to the mamba family through its own key mapping. This is the single
+    resolves to the mamba family through its own key mapping, and a
+    published ``"model_type": "sarvam_mla"`` one (or ``"family":
+    "sarvam"``) to the sarvam family through its own. This is the single
     resolution point replica.py and the engine share — the two can no
     longer diverge on model construction (the PR-11 bug this replaces:
     replica.py:71 hardwired its own ``init_llama_params`` copy)."""
@@ -93,6 +101,10 @@ def load_model_config(d: dict):
         from fms_fsdp_tpu.models.configs import jamba_config
 
         return jamba_config(d)
+    if family == "sarvam" or d.get("model_type") == "sarvam_mla":
+        from fms_fsdp_tpu.models.configs import sarvam_config
+
+        return sarvam_config(d)
     if family is None:
         if "d_model" in d or "n_layer" in d:
             family = "mamba"
@@ -138,6 +150,8 @@ def check_params_family(params, family: str) -> None:
     layers = params.get("layers") if hasattr(params, "get") else None
     if isinstance(layers, (list, tuple)):
         actual = "mamba"
+    elif isinstance(layers, dict) and "wkv_a" in layers:
+        actual = "sarvam"  # latent attention's down-projection
     elif isinstance(layers, dict) and "gate" in layers:
         actual = "mixtral"
     elif isinstance(layers, dict) and "wq" in layers:
@@ -146,7 +160,8 @@ def check_params_family(params, family: str) -> None:
         raise ValueError(
             "params do not look like any serveable family (no "
             "recognizable 'layers' structure): expected init_llama_params"
-            " / init_mamba_params / init_mixtral_params output or a "
+            " / init_mamba_params / init_mixtral_params / "
+            "init_sarvam_params output or a "
             "checkpoint thereof"
         )
     if actual != family:
@@ -170,6 +185,10 @@ def init_params_for(model_cfg):
         from fms_fsdp_tpu.models.mixtral import init_mixtral_params
 
         return lambda key: init_mixtral_params(key, model_cfg)
+    if family == "sarvam":
+        from fms_fsdp_tpu.models.sarvam import init_sarvam_params
+
+        return lambda key: init_sarvam_params(key, model_cfg)
     from fms_fsdp_tpu.models.llama import init_llama_params
 
     return lambda key: init_llama_params(key, model_cfg)
@@ -219,6 +238,8 @@ def resolve_adapter(
         from fms_fsdp_tpu.serve.families.mamba import MambaAdapter as cls
     elif family == "mixtral":
         from fms_fsdp_tpu.serve.families.mixtral import MixtralAdapter as cls
+    elif family == "sarvam":
+        from fms_fsdp_tpu.serve.families.sarvam import SarvamAdapter as cls
     else:
         from fms_fsdp_tpu.serve.families.llama import LlamaAdapter as cls
     return cls(params, model_cfg, serve_cfg, compute_dtype, registry)
@@ -416,11 +437,12 @@ class FamilyAdapter:
         the pages or None, slab rows or None, positions computed)."""
         raise NotImplementedError
 
-    def _count_prefill(self, rid: int, computed: int) -> None:
+    def _count_prefill(self, rid: int, computed: int, **counts) -> None:
         """The positions the prefill programs compute for ``rid``: the
-        counter and the ``prefill.done`` marker's field."""
+        counter and the ``prefill.done`` marker's field, beside which a
+        family may put ``counts`` of its own."""
         self.registry.counter("serve.prefill_computed_tokens").add(computed)
-        done("prefill", rid=rid, computed_tokens=computed)
+        done("prefill", rid=rid, computed_tokens=computed, **counts)
 
     def prefill(self, rid: int, slot: int, prompt):
         """Allocate the stream's state, run the family's prefill, write
@@ -449,7 +471,9 @@ class FamilyAdapter:
                 self.registry.counter("serve.prefill_state_writes").add()
         if kv is not None:
             with span("prefill.write_pages", rid=rid):
-                self.cache.write_prompt(rid, kv["k"][:, 0], kv["v"][:, 0])
+                self.cache.write_prompt(
+                    rid, *(kv[name][:, 0] for name in self.cache.entry_shapes)
+                )
         self._count_prefill(rid, computed)
         # on a mesh, hand the engine a host row: the engine's eager
         # sampler mixes it with its single-device rng key, which jax

@@ -3,11 +3,18 @@
 The serving engine never materializes a (B, S_max, Nkv, H) cache per
 sequence — that layout wastes HBM on every request shorter than S_max
 and couples batch membership to memory layout. Instead the cache is a
-shared pool of fixed-size pages, one pool per k and v:
+shared pool of fixed-size pages, one pool for each thing a position
+leaves behind. What that is, the family declares (``pools``: name ->
+the shape of one position's entry). By default keys and values per kv
+head:
 
     pools["k"]: (L, P, page_size, Nkv, H)   P = num_pages
 
-and each sequence owns an ordered list of page ids; logical cache
+and for latent attention (models/sarvam.py) one pool and no head axis,
+``pools["latent"]: (L, P, page_size, latent_dim)``. The allocator, the
+page tables, ``write_prompt``, the page export and import and ``defrag``
+work on whatever pools were declared. Each sequence owns an ordered list
+of page ids; logical cache
 position ``t`` of a sequence lives at (pages[t // page_size],
 t % page_size). The page table handed to the decode step is the padded
 (B, max_pages) int32 matrix of those lists.
@@ -39,9 +46,11 @@ KV bytes ~2x at bf16 compute; the reference read path dequantizes only
 the gathered pages, never the pool.
 """
 
+import functools
 import heapq
 from typing import Dict, List, Optional
 
+import jax
 import jax.numpy as jnp
 
 from fms_fsdp_tpu.ops.quant import kv_quantize
@@ -53,6 +62,12 @@ RESERVED_PAGES = 2
 _QUANT_STORE_DTYPE = {"int8": jnp.int8, "fp8": jnp.float8_e4m3fn}
 
 
+@functools.partial(jax.jit, donate_argnums=(0,))
+def _write_pages(pool, ids, pages):
+    """``pages`` (L, n, page_size, *entry) into pool pages ``ids``."""
+    return pool.at[:, ids].set(pages.astype(pool.dtype))
+
+
 class PagedKVCache:
     """Device pools + the host-side page allocator."""
 
@@ -61,11 +76,12 @@ class PagedKVCache:
         n_layers: int,
         num_pages: int,
         page_size: int,
-        n_kv_heads: int,
-        head_dim: int,
+        n_kv_heads: int = 0,
+        head_dim: int = 0,
         dtype=jnp.bfloat16,
         quant: str = "none",
         shardings: Optional[Dict] = None,
+        pools: Optional[Dict[str, tuple]] = None,
     ):
         assert num_pages > RESERVED_PAGES, (
             f"num_pages={num_pages}: pages 0/1 are reserved (zero/scratch), "
@@ -81,11 +97,20 @@ class PagedKVCache:
         self.dtype = dtype
         self.quant = quant
 
+        # name -> shape of one position's entry, in write_prompt's order
+        self.entry_shapes = dict(pools) if pools else {
+            "k": (n_kv_heads, head_dim),
+            "v": (n_kv_heads, head_dim),
+        }
+        if quant != "none" and pools:
+            raise ValueError(
+                "quantized page storage is the k/v pools' wire format "
+                f"(per kv head): pools {sorted(pools)} store full-width"
+            )
         store = _QUANT_STORE_DTYPE.get(quant, dtype)
-        shape = (n_layers, num_pages, page_size, n_kv_heads, head_dim)
         self.pools = {
-            "k": jnp.zeros(shape, store),
-            "v": jnp.zeros(shape, store),
+            name: jnp.zeros((n_layers, num_pages, page_size) + tuple(e), store)
+            for name, e in self.entry_shapes.items()
         }
         if quant != "none":
             sshape = (n_layers, num_pages, page_size, n_kv_heads, 1)
@@ -95,8 +120,6 @@ class PagedKVCache:
             # serving-layout placement (leaf name -> jax Sharding):
             # pools born sharded stay sharded — every later .at[].set /
             # gather propagates the operand's sharding under GSPMD
-            import jax
-
             self.pools = {
                 name: (
                     jax.device_put(pool, shardings[name])
@@ -216,12 +239,16 @@ class PagedKVCache:
 
     # -- writes ------------------------------------------------------------
 
-    def write_prompt(self, seq_id: int, k, v):
-        """Scatter a prefilled (L, S_pad, Nkv, H) k/v pair into seq_id's
-        pages. ``S_pad`` must be a page multiple covering the prompt
-        (positions past the prompt are the prefill's zero padding, which
-        keeps page tails dense-identical). Call ``ensure`` first."""
-        L, s_pad = k.shape[0], k.shape[1]
+    def write_prompt(self, seq_id: int, *values):
+        """Scatter a prefilled prompt into seq_id's pages: one (L, S_pad,
+        *entry) array for each declared pool, in their order (``k, v``
+        for the default pools). ``S_pad`` must be a page multiple
+        covering the prompt (positions past the prompt are the prefill's
+        zero padding, which keeps page tails dense-identical). Call
+        ``ensure`` first."""
+        assert len(values) == len(self.entry_shapes), (
+            len(values), list(self.entry_shapes))
+        L, s_pad = values[0].shape[0], values[0].shape[1]
         assert s_pad % self.page_size == 0, (s_pad, self.page_size)
         n = s_pad // self.page_size
         pages = self._seq_pages.get(seq_id, [])
@@ -230,16 +257,21 @@ class PagedKVCache:
             f"{len(pages)} — call ensure() first"
         )
         ids = jnp.asarray(pages[:n], jnp.int32)
-        kp = k.reshape(L, n, self.page_size, self.n_kv_heads, self.head_dim)
-        vp = v.reshape(L, n, self.page_size, self.n_kv_heads, self.head_dim)
+        paged = {
+            name: x.reshape((L, n, self.page_size) + tuple(entry))
+            for (name, entry), x in zip(self.entry_shapes.items(), values)
+        }
         if self.quant == "none":
+            # the pool is donated: the write moves the prompt's pages and
+            # not the pool (an eager ``.at[].set`` makes a second pool
+            # first: 2.4 GB beside a 2.4 GB latent pool, PERF.md PR 31)
             self.pools = {
-                "k": self.pools["k"].at[:, ids].set(kp.astype(self.dtype)),
-                "v": self.pools["v"].at[:, ids].set(vp.astype(self.dtype)),
+                name: _write_pages(self.pools[name], ids, x)
+                for name, x in paged.items()
             }
         else:
-            qk, sk = kv_quantize(kp, self.quant)
-            qv, sv = kv_quantize(vp, self.quant)
+            qk, sk = kv_quantize(paged["k"], self.quant)
+            qv, sv = kv_quantize(paged["v"], self.quant)
             self.pools = {
                 "k": self.pools["k"].at[:, ids].set(qk),
                 "v": self.pools["v"].at[:, ids].set(qv),
@@ -251,7 +283,7 @@ class PagedKVCache:
 
     def gather_pages(self, seq_id: int) -> Dict[str, "object"]:
         """Read seq_id's pages out of the device pools as host arrays:
-        leaf name -> (L, n_pages, page_size, Nkv, H|1) ndarray in the
+        leaf name -> (L, n_pages, page_size, *entry) ndarray in the
         pool's STORAGE dtype — int8/fp8 pages come out as their 1-byte
         values plus the fp32 scale leaves, never dequantized (the
         handoff ships what the pool holds, bit for bit)."""
@@ -284,7 +316,7 @@ class PagedKVCache:
                 f"pool's {sorted(self.pools)} — kv_quant mismatch "
                 f"between replicas"
             )
-        n = int(arrays["k"].shape[1])
+        n = int(next(iter(arrays.values())).shape[1])
         for name, pool in self.pools.items():
             want = (pool.shape[0], n) + tuple(pool.shape[2:])
             got = tuple(arrays[name].shape)

@@ -278,3 +278,99 @@ def test_hybrid_prefill_of_the_longest_bucket_is_one_loop_over_chunks(
     for width in (cfg.d_model, cfg.d_inner, cfg.d_intermediate):
         assert f"[1,{p_pad},{width}]" not in text, width
     assert f"[1,{c},{cfg.d_inner}]" in text  # the chunk's are there
+
+
+def test_sarvam_programs_keep_one_latent_pool_and_no_dense_expert_array(
+    v5e, monkeypatch
+):
+    """The decode program (32 slots) and the prefill program of 16384
+    tokens of the sarvam cell at published widths, 1 + 5 layers, 32 of
+    128 experts held, bfloat16. Recorded: decode peak 13.43 GB with 0.10
+    GB of temporaries beside 10.92 GB of weights and the 2.40 GB pool
+    (2441 pages of 128 positions), which is donated, written and read by
+    the paged kernel in place (with the pool's entry at 576
+    and not 640 lanes the compiler laid it out pages-minor and the step
+    held 2.67 GB of temporaries: models/sarvam.py::pool_width); prefill
+    peak 12.55 GB with 1.51 GB of temporaries (the pool stands beside
+    it: 14.95 GB of the 16.9). Neither holds keys or values expanded for
+    a whole cache, nor an array of (tokens, experts, width)."""
+    import json
+    import re
+
+    from fms_fsdp_tpu.models.sarvam import (
+        init_sarvam_params,
+        pool_width,
+        prefill_chunk,
+    )
+    from fms_fsdp_tpu.serve.engine import ServeConfig
+    from fms_fsdp_tpu.serve.families import load_model_config
+    from fms_fsdp_tpu.serve.families.sarvam import (
+        decode_program,
+        page_geometry,
+        prefill_program,
+    )
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    here = os.path.dirname(os.path.abspath(__file__))
+    with open(os.path.join(
+            here, "..", "benchmark", "configs", "sarvam-105b.1chip.json")) as f:
+        cfg = load_model_config(json.load(f))
+    scfg = ServeConfig(
+        max_batch=32, max_seq_len=16896, num_pages=2443,
+        prefill_bucket=2048, attn_impl="auto", moe_impl="routed",
+        compute_dtype="bfloat16",
+    )
+    bf16 = jnp.bfloat16
+    params = jax.tree.map(
+        lambda a: _sds(v5e, a.shape, a.dtype),
+        jax.eval_shape(
+            lambda k: init_sarvam_params(k, cfg, bf16), jax.random.PRNGKey(0)
+        ),
+    )
+    page, max_pages, num_pages = page_geometry(cfg, scfg)
+    assert (cfg.latent_dim, pool_width(cfg)) == (576, 640)
+    assert (page, max_pages) == (128, 132)
+    pool = (cfg.nlayers, num_pages, page, 640)
+    pool_bytes = 2 * 6 * num_pages * page * 640
+    B, top = 32, 16384
+    decode = decode_program(cfg, scfg, page, bf16).lower(
+        params, {"latent": _sds(v5e, pool, bf16)},
+        _sds(v5e, (B, max_pages), jnp.int32), _sds(v5e, (B,), jnp.int32),
+        _sds(v5e, (B,), jnp.int32), _sds(v5e, (2,), jnp.uint32),
+    ).compile()
+    prefill = prefill_program(cfg, scfg, top, top, bf16).lower(
+        params, _sds(v5e, (1, top), jnp.int32), _sds(v5e, (1,), jnp.int32)
+    ).compile()
+    dm, pm = decode.memory_analysis(), prefill.memory_analysis()
+    assert dm.peak_memory_in_bytes < 13.6e9 and dm.temp_size_in_bytes < 0.3e9
+    assert pm.peak_memory_in_bytes < 12.8e9 and pm.temp_size_in_bytes < 1.8e9
+    assert pm.peak_memory_in_bytes + pool_bytes < 15.75 * 2**30
+    dtext, ptext = decode.as_text(), prefill.as_text()
+    assert dtext.startswith("HloModule jit__step,")
+    assert ptext.startswith(f"HloModule jit__prefill_{top},")
+    # the pool is one array, an argument aliased to a result, never copied
+    assert f"bf16[{','.join(map(str, pool))}]" in dtext
+    assert not re.search(
+        r"= bf16\[%s\]\S* copy\(" % ",".join(map(str, pool)), dtext)
+    # the ragged paged latent kernel, in the dense layer and the scan
+    assert dtext.count("tpu_custom_call") == 2
+    # prefill: a flash call for the chunk's own block and one in the walk
+    # over earlier blocks (dense layer, and the scan's body), and three
+    # grouped matmuls in the scan's body
+    assert ptext.count("tpu_custom_call") == 2 * 2 + 3
+    N, c = cfg.nheads, prefill_chunk(top)
+    for text in (dtext, ptext):
+        for dims in set(re.findall(r"\b(?:f32|bf16)\[([\d,]+)\]", text)):
+            s = tuple(int(d) for d in dims.split(","))
+            n = 1
+            for d in s:
+                n *= d
+            # keys or values expanded for as many positions as a stream
+            # may hold: (..., positions, heads, a head's width)
+            assert not (len(s) >= 3 and s[-2] == N and s[-1] in (128, 192, 256)
+                        and n >= scfg.max_seq_len * N * 128), s
+            # dense over experts: (tokens, experts, width)
+            assert not (len(s) >= 3 and s[-2] in (32, 128)
+                        and s[-1] in (2048, 4096) and s[-3] >= c), s
+    # the chunk's own are there: its pairs' rows, sorted by expert
+    assert f"bf16[{c * cfg.top_k},{cfg.emb_dim}]" in ptext
