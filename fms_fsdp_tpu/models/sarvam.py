@@ -99,10 +99,6 @@ PREFILL_CHUNK = 2048
 # positions that exist and not for ``max_seq_len``.
 DECODE_BLOCK_TOKENS = 2048
 
-# the flash kernel takes one head width for queries, keys and values, a
-# multiple of 128: the expanded form's 192 and 128 are padded to it
-_FLASH_WIDTH = 256
-
 LANES = 128
 
 
@@ -266,21 +262,15 @@ def _attn_partial(q, k, v, causal: bool, scale: float, flash: bool):
     """Attention of q (B, c, N, dq) over k (B, s, N, dq), v (B, s, N, dv)
     -> (normalised output (B, c, N, dv), log-sum-exp (B, c, N, 1) fp32):
     a partial that ``merge_partial`` joins with others over disjoint
-    keys. The flash kernel where ``flash`` (its one head width padded
-    with zeros, which add nothing to a score and whose value columns are
-    dropped), an einsum over the (c, s) scores elsewhere."""
-    dv = v.shape[-1]
+    keys. Where ``flash`` the flash kernel at the widths given, an earlier
+    block (not causal) in one step over its keys with no running rescale
+    (PERF.md PR 32: 2.85 -> 2.58 ms a block); else an einsum."""
     if flash:
-        def pad(t):
-            return jnp.pad(
-                t, [(0, 0)] * 3 + [(0, _FLASH_WIDTH - t.shape[-1])]
-            )
-
-        o, lse = _fa.flash_attention(
-            pad(q), pad(k), pad(v), causal=causal, scale=scale,
-            return_lse=True, interpret=interpret_default(),
+        return _fa.flash_attention(
+            q, k, v, causal=causal, scale=scale, return_lse=True,
+            block_k=None if causal else k.shape[1],
+            interpret=interpret_default(),
         )
-        return o[..., :dv], lse
     s = jnp.einsum(
         "bqnd,bsnd->bnqs", q, k, preferred_element_type=jnp.float32
     ) * scale
@@ -300,6 +290,16 @@ def _use_flash(attn_impl: str, c: int) -> bool:
         attn_impl == "pallas"
         or (attn_impl == "auto" and jax.default_backend() == "tpu")
     )
+
+
+def prefill_attn_form(cfg: SarvamConfig, attn_impl: str, p_pad: int) -> str:
+    """What ``_attn_partial`` runs in the prefill program of ``p_pad``
+    positions (``attn_form`` on ``serve/prefill.dispatch``): the kernel
+    with values narrower than keys (the published 192 and 128) or of one
+    width, or the einsum (off a TPU, and odd chunks)."""
+    if not _use_flash(attn_impl, prefill_chunk(p_pad)):
+        return "einsum"
+    return "flash" if cfg.q_head_dim == cfg.v_head_dim else "flash_two_width"
 
 
 # ---------------------------------------------------------------------------

@@ -17,10 +17,10 @@ Replaces the reference's SDPA FlashAttention-2 CUDA path
   (kv_head = q_head // group) — kv is never materialized repeated
   (70B trains at 64 q / 8 kv heads, ref:config_utils.py:26-34).
 
-The q/k/v layout inside the kernels is (B, N, S, H) with H = 128-multiple
-head dims (every reference variant has head_dim 128). Blockwise structure
-means a "context" mesh axis (ring attention) composes by walking remote kv
-blocks — see parallel/ring.py.
+The layout inside the kernels is (B, N, S, H). The training variants have
+one head width, a multiple of 128; the forward kernels also take values of
+another width than queries and keys (latent attention: 192 and 128, as they
+are). A "context" mesh axis walks remote kv blocks: ops/ring_attention.py.
 """
 
 import functools
@@ -60,7 +60,7 @@ def _causal_mask(scores, q_block, k_block, q_start, k_start):
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, block_k, causal):
     block_q = q_ref.shape[2]
-    head = q_ref.shape[3]
+    head = v_ref.shape[3]  # the accumulator's and the output's width
     seq_k = k_ref.shape[2]
     qi = pl.program_id(2)
     q_start = qi * block_q
@@ -121,20 +121,20 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, block_k, causal):
 @scoped("flash_attention_fwd")
 def _flash_fwd(q, k, v, scale, causal, block_q, block_k, interpret,
                variant=None):
-    """q: (B, Nq, Sq, H); k/v: (B, Nkv, Sk, H) -> (o, lse).
+    """q (B, Nq, Sq, H), k (B, Nkv, Sk, H), v (B, Nkv, Sk, Hv) -> (o, lse),
+    o (B, Nq, Sq, Hv) as wide as the values.
 
     Two implementations (identical math/contract): the kv-resident
     fori_loop kernel below, and the kv-streamed grid kernel
-    (_fwd_kernel_kvgrid). ``variant`` pins the family for this call
-    (the tuning-table choice, resolved in flash_attention); otherwise
-    FLASH_KERNEL_VARIANT / set_kernel_variant overrides the automatic
-    choice."""
+    (_fwd_kernel_kvgrid). ``variant`` pins the family for this call (the
+    tuning-table choice, resolved in flash_attention); otherwise
+    FLASH_KERNEL_VARIANT / set_kernel_variant overrides the automatic one."""
     if _use_kvgrid(k.shape[2], variant):
         return _flash_fwd_kvgrid(
             q, k, v, scale, causal, block_q, block_k, interpret
         )
     batch, nq, seq_q, head = q.shape
-    nkv, seq_k = k.shape[1], k.shape[2]
+    nkv, seq_k, vdim = k.shape[1], k.shape[2], v.shape[3]
     group = nq // nkv
 
     grid = (batch, nq, seq_q // block_q)
@@ -152,17 +152,17 @@ def _flash_fwd(q, k, v, scale, causal, block_q, block_k, interpret,
                 (1, 1, seq_k, head), lambda b, h, i: (b, h // group, 0, 0)
             ),
             pl.BlockSpec(
-                (1, 1, seq_k, head), lambda b, h, i: (b, h // group, 0, 0)
+                (1, 1, seq_k, vdim), lambda b, h, i: (b, h // group, 0, 0)
             ),
         ],
         out_specs=[
             pl.BlockSpec(
-                (1, 1, block_q, head), lambda b, h, i: (b, h, i, 0)
+                (1, 1, block_q, vdim), lambda b, h, i: (b, h, i, 0)
             ),
             pl.BlockSpec((1, 1, block_q, 1), lambda b, h, i: (b, h, i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct(q.shape, q.dtype),
+            jax.ShapeDtypeStruct((batch, nq, seq_q, vdim), q.dtype),
             jax.ShapeDtypeStruct((batch, nq, seq_q, 1), jnp.float32),
         ],
         # every grid cell is independent (no scratch carried between
@@ -265,7 +265,7 @@ def _fwd_kernel_kvgrid(
 def _flash_fwd_kvgrid(q, k, v, scale, causal, block_q, block_k, interpret):
     """kv-streamed variant of _flash_fwd; same contract."""
     batch, nq, seq_q, head = q.shape
-    nkv, seq_k = k.shape[1], k.shape[2]
+    nkv, seq_k, vdim = k.shape[1], k.shape[2], v.shape[3]
     group = nq // nkv
     num_kb = seq_k // block_k
 
@@ -285,18 +285,18 @@ def _flash_fwd_kvgrid(q, k, v, scale, causal, block_q, block_k, interpret):
         in_specs=[
             pl.BlockSpec((1, 1, block_q, head), lambda b, h, i, j: (b, h, i, 0)),
             pl.BlockSpec((1, 1, block_k, head), kvmap),
-            pl.BlockSpec((1, 1, block_k, head), kvmap),
+            pl.BlockSpec((1, 1, block_k, vdim), kvmap),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, block_q, head), lambda b, h, i, j: (b, h, i, 0)),
+            pl.BlockSpec((1, 1, block_q, vdim), lambda b, h, i, j: (b, h, i, 0)),
             pl.BlockSpec((1, 1, block_q, 1), lambda b, h, i, j: (b, h, i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct(q.shape, q.dtype),
+            jax.ShapeDtypeStruct((batch, nq, seq_q, vdim), q.dtype),
             jax.ShapeDtypeStruct((batch, nq, seq_q, 1), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((block_q, head), jnp.float32),  # acc
+            pltpu.VMEM((block_q, vdim), jnp.float32),  # acc
             pltpu.VMEM((block_q, 1), jnp.float32),  # running max (base 2)
             pltpu.VMEM((block_q, 1), jnp.float32),  # running denominator
         ],
@@ -481,21 +481,12 @@ def _flash_dq_kvgrid(
 
 
 def _dkv_kernel(
-    q_ref,
-    k_ref,
-    v_ref,
-    do_ref,
-    lse_ref,
-    delta_ref,
-    dk_ref,
-    dv_ref,
-    dk_acc,
-    dv_acc,
+    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,  # inputs
+    dk_ref, dv_ref,  # outputs
+    dk_acc, dv_acc,  # fp32 scratch
     *,
-    scale,
-    causal,
-    group,
-    num_qb,
+    scale, causal,
+    group, num_qb,
 ):
     """Streamed-q dk/dv: grid (b, kvh, ki, g, qi), q walked via the grid.
 
@@ -723,13 +714,22 @@ def flash_dkv(q, k, v, dout, lse, delta, *, scale, causal, block_q, block_k, int
 @scoped("flash_attention_bwd")
 def _flash_bwd(scale, causal, block_q, block_k, interpret, variant,
                residuals, dout, dlse=None):
-    """Backward for o (and optionally the lse output).
+    """Backward for o (and optionally the lse output), one head width.
 
     A differentiable lse output only shifts the per-row delta: the lse
     cotangent enters as ds_ij += p_ij * dlse_i, and ds is already
     p * (dp - delta), so delta_eff = delta - dlse — zero kernel changes.
     """
     q, k, v, o, lse = residuals
+    if v.shape[-1] != q.shape[-1]:
+        # the dq and dk/dv kernels size every block by q's width; only the
+        # forward takes two (serving's latent attention: no training path)
+        raise NotImplementedError(
+            "flash_attention backward with a value width other than the "
+            f"query/key width is not built (keys {q.shape[-1]} wide, "
+            f"values {v.shape[-1]} wide): the dq and dk/dv kernels take "
+            "one head width; only the forward kernels take two"
+        )
     delta = jnp.sum(
         o.astype(jnp.float32) * dout.astype(jnp.float32), axis=-1, keepdims=True
     )
@@ -931,11 +931,11 @@ def flash_attention(
     variant=None,
     quant=None,
 ):
-    """q: (B, S, Nq, H); k/v: (B, S, Nkv, H) -> (B, S, Nq, H).
-
-    ``block_q``/``block_k``/``variant`` default to the tuning-table
-    resolution (fms_fsdp_tpu/tune/lookup.py): exact signature match,
-    then nearest signature, then the static 512/512 defaults —
+    """q: (B, S, Nq, H); k: (B, S, Nkv, H); v: (B, S, Nkv, Hv) ->
+    (B, S, Nq, Hv). Hv = H everywhere but in latent attention's forward
+    (Hv != H has no backward). ``block_q``/``block_k``/``variant``
+    default to the tuning-table resolution (tune/lookup.py): exact
+    signature match, then nearest, then the static 512/512 defaults —
     bit-identical to the pre-tuner behavior when ``kernel_tuning="off"``
     or the table has no legal entry. Passing them explicitly pins the
     values (tests, ring attention's bwd partials). The resolution is

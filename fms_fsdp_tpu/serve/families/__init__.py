@@ -455,10 +455,10 @@ class FamilyAdapter:
             kv_len = self.cache.pages_needed(p_pad) * self.page_size
             ok = self.cache.ensure(rid, p_pad)
             assert ok, "admission checked capacity; ensure cannot fail here"
-        fn, built = self._program(
-            self._prefill_key(p, p_pad, kv_len), self._build_prefill
-        )
-        with span("prefill.dispatch", rid=rid, built=built):
+        key = self._prefill_key(p, p_pad, kv_len)
+        fn, built = self._program(key, self._build_prefill)
+        fields = self._prefill_fields(key)
+        with span("prefill.dispatch", rid=rid, built=built, **fields):
             toks = np.zeros((1, p_pad), np.int32)
             toks[0, :p] = prompt
             row, kv, rows, computed = self._call_prefill(fn, toks, p)
@@ -707,6 +707,11 @@ class FamilyAdapter:
                     self.cache.page_table(list(slot_rids), self.max_pages)
                 )
                 self.registry.counter("serve.page_table_uploads").add()
+
+    def _prefill_fields(self, key) -> dict:
+        """The family's fields of the ``prefill.dispatch`` span of the
+        program of ``key`` (what it built, like ``_dispatch_fields``)."""
+        return {}
 
 
 class PagedAdapter(FamilyAdapter):

@@ -6,9 +6,8 @@ What a position leaves behind is one ``latent_dim``-wide row a layer
 count, so the cache is **one** pool ``(L, P, page_size, pool_width)``
 with no head axis (``pool_width``: the 576 values in whole rows of 128
 lanes, 640, as the chip's tiling would lay them out anyway):
-``PagedKVCache`` with ``pools={"latent": ...}``, the
-same allocator, page tables, LIFO eviction and recompute-on-resume as
-every paged family.
+``PagedKVCache`` with ``pools={"latent": ...}``, the same allocator,
+page tables, LIFO eviction and recompute-on-resume as every paged family.
 
 Decode: one ragged paged step over ``max_batch`` slots. Each layer
 writes the position's latent to its page and attends in the absorbed
@@ -24,13 +23,13 @@ gauge ``serve.moe_expert_reads_per_layer``.
 
 Prefill: the prompt as a sequence, ``PREFILL_CHUNK`` positions at a
 time in a loop inside its bucket's program that stops at the prompt's
-length (``serve.prefill_computed_tokens`` says what ran), attention in
-the expanded form (flash kernel, keys and values made from each latent
-block as it is met), the experts routed and grouped, no pair on a held
-expert dropped. The program returns, beside the first token's logits
-and the prompt's latent for the pages, how many (token, choice) pairs
-landed on held experts: ``serve.moe_pairs_held`` beside
-``serve.moe_pairs_routed``, and both on the ``serve/prefill.done`` span.
+length (``serve.prefill_computed_tokens`` says what ran); attention in
+the expanded form, keys 192 and values 128 wide as published, made from
+each latent block as it is met (``attn_form`` on ``prefill.dispatch``
+says which form a program runs); the experts routed and grouped, no pair
+on a held expert dropped. Beside the first token's logits and the latent
+for the pages the program returns the pairs that landed on held experts:
+``serve.moe_pairs_held`` / ``_routed``, both on ``serve/prefill.done``.
 
 Not here yet (PERF.md section 7): a serving layout over chips (the
 expert layer's exchange), handoff of latent pages, quantized latent
@@ -46,6 +45,7 @@ from fms_fsdp_tpu.models.generation import sample_token
 from fms_fsdp_tpu.models.mixtral import routed_moe_form
 from fms_fsdp_tpu.models.sarvam import (
     pool_width,
+    prefill_attn_form,
     prefill_positions,
     sarvam_paged_decode_step,
     sarvam_prefill,
@@ -107,13 +107,17 @@ def decode_program(model_cfg, scfg, page_size: int, compute_dtype):
     return jax.jit(_step, donate_argnums=(1,))
 
 
+def _prefill_attn_impl(scfg) -> str:
+    """``sarvam_prefill``'s name for ``scfg.attn_impl``."""
+    return {"auto": "auto", "kernel": "pallas"}.get(scfg.attn_impl, "xla")
+
+
 def prefill_program(model_cfg, scfg, p_pad: int, kv_len: int, compute_dtype):
     """The jitted prefill of one padded prompt length: ``(params, tokens
     (1, p_pad), lengths (1,)) -> (logits (1, V), latent (L, 1, kv_len,
     pool_width), pairs on held experts)``. The traced function is named
     by the length: ``jit__prefill_<p_pad>`` in the profiler's trace."""
-    attn_impl = {"auto": "auto", "kernel": "pallas"}.get(scfg.attn_impl, "xla")
-    moe_impl = scfg.moe_impl
+    attn_impl, moe_impl = _prefill_attn_impl(scfg), scfg.moe_impl
 
     def _prefill(params, tokens, lengths):
         return sarvam_prefill(
@@ -180,6 +184,9 @@ class SarvamAdapter(FamilyAdapter):
         gauge("serve.moe_experts_held").set(held)
         gauge("serve.moe_experts_published").set(cfg.num_experts)
         gauge("serve.latent_bytes_per_token").set(self.latent_bytes_per_token)
+        # the width of the values the prefill's attention runs at: the
+        # published one, nothing is padded to the keys' width
+        gauge("serve.prefill_attn_value_width").set(cfg.v_head_dim)
         self._pairs_held = 0
 
     @property
@@ -201,6 +208,12 @@ class SarvamAdapter(FamilyAdapter):
         return prefill_program(
             self.model_cfg, self.scfg, *key, self.compute_dtype
         )
+
+    def _prefill_fields(self, key) -> dict:
+        form = prefill_attn_form(
+            self.model_cfg, _prefill_attn_impl(self.scfg), key[0]
+        )
+        return {"attn_form": form}
 
     def _call_prefill(self, fn, toks, p: int):
         logits, latent, pairs = fn(

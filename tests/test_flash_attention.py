@@ -253,3 +253,93 @@ def test_auto_kvgrid_dispatch_past_cap(monkeypatch):
         q, k, v, causal=True, block_q=128, block_k=128, interpret=True
     )
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
+
+
+# -- values of another width than queries and keys (latent attention) ---------
+
+
+def _einsum_attention(q, k, v, causal):
+    """The einsum form for any value width, GQA by repeating kv heads,
+    fp32 softmax -> (o (B, S, Nq, Hv), lse (B, S, Nq, 1))."""
+    group = q.shape[2] // k.shape[2]
+    k, v = (jnp.repeat(x, group, axis=2) for x in (k, v))
+    s = jnp.einsum(
+        "bqnd,bsnd->bnqs", q, k, preferred_element_type=jnp.float32
+    ) * q.shape[-1] ** -0.5
+    if causal:
+        s = jnp.where(jnp.tril(jnp.ones(s.shape[-2:], bool)), s, -jnp.inf)
+    lse = jax.scipy.special.logsumexp(s, axis=-1)
+    p = jnp.exp(s - lse[..., None])
+    o = jnp.einsum("bnqs,bsnd->bqnd", p, v.astype(jnp.float32))
+    return o, jnp.moveaxis(lse, 1, 2)[..., None]
+
+
+def _rand_two_widths(nq, nkv, h, hv, seed, dtype=jnp.float32):
+    q, k, _ = _rand_qkv(2, 256, nq, nkv, h, seed=seed)
+    _, _, v = _rand_qkv(2, 256, nq, nkv, hv, seed=seed + 1)
+    return q.astype(dtype), k.astype(dtype), v.astype(dtype)
+
+
+@pytest.mark.parametrize("variant", ["resident", "kvgrid"])
+@pytest.mark.parametrize("nq,nkv", [(8, 8), (8, 1)])
+@pytest.mark.parametrize("causal", [True, False])
+@pytest.mark.parametrize("h,hv", [(192, 128), (128, 256)])
+def test_flash_two_widths_match_einsum(h, hv, causal, nq, nkv, variant):
+    """Values narrower (latent attention's 192 and 128) or wider than
+    queries and keys, in both forward families: the output is as wide as
+    the values, and it and the log-sum-exp are the einsum form's."""
+    q, k, v = _rand_two_widths(nq, nkv, h, hv, seed=13)
+    ref_o, ref_lse = _einsum_attention(q, k, v, causal)
+    o, lse = flash_attention(
+        q, k, v, causal=causal, block_q=128, block_k=64, interpret=True,
+        return_lse=True, variant=variant,
+    )
+    assert o.shape == (2, 256, nq, hv) and lse.shape == (2, 256, nq, 1)
+    np.testing.assert_allclose(np.asarray(o), np.asarray(ref_o), atol=2e-5)
+    np.testing.assert_allclose(
+        np.asarray(lse), np.asarray(ref_lse), atol=2e-5
+    )
+    only_o = flash_attention(
+        q, k, v, causal=causal, block_q=128, block_k=64, interpret=True,
+        variant=variant,
+    )
+    np.testing.assert_array_equal(np.asarray(only_o), np.asarray(o))
+
+
+@pytest.mark.parametrize("variant", ["resident", "kvgrid"])
+@pytest.mark.parametrize("h,hv", [(192, 128), (128, 256)])
+def test_flash_two_widths_bf16_parity(h, hv, variant):
+    """bfloat16 inputs at the tolerance of the one-width parity test."""
+    q, k, v = _rand_two_widths(8, 2, h, hv, seed=17, dtype=jnp.bfloat16)
+    ref_o, ref_lse = _einsum_attention(q, k, v, True)
+    o, lse = flash_attention(
+        q, k, v, causal=True, block_q=128, block_k=64, interpret=True,
+        return_lse=True, variant=variant,
+    )
+    assert o.dtype == jnp.bfloat16 and lse.dtype == jnp.float32
+    np.testing.assert_allclose(
+        np.asarray(o, np.float32), np.asarray(ref_o), atol=2e-2, rtol=2e-2
+    )
+    np.testing.assert_allclose(
+        np.asarray(lse), np.asarray(ref_lse), atol=2e-2, rtol=2e-2
+    )
+
+
+@pytest.mark.parametrize("return_lse", [False, True])
+def test_flash_two_widths_have_no_backward(return_lse):
+    """The dq and dk/dv kernels take one head width: a gradient through
+    unequal widths is refused, and the message says what is not built."""
+    q, k, v = _rand_two_widths(4, 2, 192, 128, seed=19)
+
+    def loss(q, k, v):
+        out = flash_attention(
+            q, k, v, causal=True, block_q=128, block_k=128, interpret=True,
+            return_lse=return_lse,
+        )
+        return sum(jnp.sum(x) for x in jax.tree.leaves(out))
+
+    assert np.isfinite(float(loss(q, k, v)))  # the forward alone runs
+    with pytest.raises(
+        NotImplementedError, match="backward with a value width.*not built"
+    ):
+        jax.grad(loss, argnums=(0, 1, 2))(q, k, v)

@@ -303,12 +303,21 @@ def test_full_forward_agrees_with_the_reference(c):
     assert _gap(np.asarray(got), _ref_logits(tree, c, tokens)) < 1e-5
 
 
-@pytest.mark.parametrize("attn_impl", ["xla", "pallas"])
-def test_prefill_in_chunks_is_the_forward(attn_impl, monkeypatch):
+# the flash kernel at one head width (24 and 24) and with values narrower
+# than keys (24 and 16: the published model's 192 and 128 in small)
+NARROW_V = {**SHARE, "v_head_dim": 16}
+
+
+@pytest.mark.parametrize("attn_impl,c", [
+    ("xla", SHARE), ("pallas", SHARE), ("pallas", NARROW_V),
+], ids=["xla", "pallas", "pallas-two-widths"])
+def test_prefill_in_chunks_is_the_forward(attn_impl, c, monkeypatch):
     """Two ragged rows through three chunks: the last real position's
     logits, and the latent that the pages take (zero past the length).
-    ``pallas``: the flash kernel with its one head width padded."""
-    c = SHARE
+    ``pallas``: the flash kernel at the head widths as they are, nothing
+    padded to a common width of 256 and no output column sliced away."""
+    import re
+
     cfg, tree = sarvam_config(c), _tree(c)
     chunk = 256 if attn_impl == "pallas" else CHUNK
     monkeypatch.setattr(M, "PREFILL_CHUNK", chunk)
@@ -318,10 +327,23 @@ def test_prefill_in_chunks_is_the_forward(attn_impl, monkeypatch):
     toks = np.zeros((2, S), np.int32)
     for b, n in enumerate(lengths):
         toks[b, :n] = rng.integers(1, 256, size=n)
-    logits, lat, pairs = jax.jit(
+    prefill = jax.jit(
         lambda p, t, l: M.sarvam_prefill(
             p, t, l, cfg, compute_dtype=jnp.float32, kv_len=S + 16,
-            attn_impl=attn_impl))(tree, jnp.asarray(toks), jnp.asarray(lengths))
+            attn_impl=attn_impl))
+    args = (tree, jnp.asarray(toks), jnp.asarray(lengths))
+    logits, lat, pairs = prefill(*args)
+    form = M.prefill_attn_form(cfg, attn_impl, S)
+    assert form == {"xla": "einsum"}.get(
+        attn_impl, "flash" if c is SHARE else "flash_two_width")
+    if attn_impl == "pallas":
+        # queries, keys, values or an output of 4 heads 256 wide, in the
+        # model's layout or the kernel's: the parent's padded operands
+        text = prefill.lower(*args).as_text()
+        assert not re.search(r"tensor<2x(256x4|4x256)x256xf32>", text)
+        dq, dv = cfg.q_head_dim, cfg.v_head_dim
+        assert f"tensor<2x4x256x{dq}xf32>" in text
+        assert f"tensor<2x4x256x{dv}xf32>" in text
     assert lat.shape == (3, 2, S + 16, M.pool_width(cfg))
     assert float(jnp.abs(lat[..., cfg.latent_dim:]).max()) == 0.0
     for b, n in enumerate(lengths):

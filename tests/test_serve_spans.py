@@ -201,6 +201,49 @@ def test_decode_dispatch_names_the_moe_form(traced):
         "all_experts"}
 
 
+@pytest.fixture(scope="module")
+def traced_sarvam(tmp_path_factory):
+    """A tiny latent-attention MoE (values narrower than keys: 24 and 16)
+    served under a profiler session, the einsum attention of a CPU."""
+    from fms_fsdp_tpu.models.configs import SarvamConfig
+    from fms_fsdp_tpu.models.sarvam import init_sarvam_params
+
+    cfg = SarvamConfig(
+        src_vocab_size=128, emb_dim=64, nheads=4, nlayers=2, first_k_dense=1,
+        hidden_dim=128, moe_hidden_dim=32, kv_lora_rank=32,
+        qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16,
+        num_experts=4, top_k=2, max_expected_seq_len=64,
+    )
+    params = init_sarvam_params(jax.random.PRNGKey(2), cfg)
+    return cfg, params, serve(
+        params, cfg, SCFG, tmp_path_factory.mktemp("sarvam"))
+
+
+def test_prefill_dispatch_names_the_attention_form(traced_sarvam, traced):
+    """What the prefill program's attention runs is a fact of the program
+    (the backend, its chunk, the head widths): every ``prefill.dispatch``
+    span of the latent-attention family carries it, and a gauge set at
+    build holds the value width it runs at. Other families add no field."""
+    cfg, params, (engine, _, spans) = traced_sarvam
+    dispatched = named(spans, "prefill.dispatch")
+    assert len(dispatched) == len(REQUESTS)
+    assert {s.stats["attn_form"] for s in dispatched} == {"einsum"}
+    assert {s.stats["built"] for s in dispatched} == {0, 1}
+    width = engine.registry.gauge("serve.prefill_attn_value_width").value
+    assert width == cfg.v_head_dim == 16 != cfg.q_head_dim
+    # the same engine told to take the kernels: a bucket the flash blocks
+    # tile runs it with two widths, an odd one the einsum
+    kernel = ServingEngine(
+        params, cfg, ServeConfig(**{**SCFG.__dict__, "attn_impl": "kernel"}))
+    fields = kernel.adapter._prefill_fields
+    assert fields((256, 256)) == {"attn_form": "flash_two_width"}
+    assert fields((24, 24)) == {"attn_form": "einsum"}
+    assert all(
+        "attn_form" not in s.stats
+        for s in named(traced[2], "prefill.dispatch"))
+    assert traced[0].adapter._prefill_fields((16, 16, True)) == {}
+
+
 def test_every_span_of_a_step_carries_its_step(traced):
     engine, _, spans = traced
     steps = named(spans, "step")
