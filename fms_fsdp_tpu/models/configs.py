@@ -418,3 +418,212 @@ def sarvam_config(d: dict) -> SarvamConfig:
         rope_mscale_all_dim=rs.get("mscale_all_dim", 0.0),
         norm_eps=d["rms_norm_eps"],
     )
+
+
+# the kinds of layer of the window-and-full family: (attention, feed-forward)
+KEXAONE_ATTN_KINDS = ("sliding_attention", "full_attention")
+KEXAONE_MLP_KINDS = ("dense", "sparse")
+
+
+def _kexaone_kind(attn: str, mlp: str) -> str:
+    """(attention, feed-forward) -> the name of the kind's stack in the
+    tree: ``sliding_dense``, ``sliding_sparse``, ``full_dense`` or
+    ``full_sparse``."""
+    return attn.split("_")[0] + "_" + mlp
+
+
+KEXAONE_LAYER_KINDS = tuple(
+    _kexaone_kind(a, m)
+    for a in KEXAONE_ATTN_KINDS for m in KEXAONE_MLP_KINDS
+)
+
+
+@dataclass(frozen=True)
+class KExaoneConfig:
+    """Window-and-full-attention MoE family (``model_type: exaone_moe``;
+    models/kexaone.py): grouped-query attention whose layers are of two
+    kinds, ``layer_types[i]`` = ``"sliding_attention"`` (a position sees
+    itself and the ``sliding_window - 1`` before it; rotary embedding) or
+    ``"full_attention"`` (every earlier position; no positional
+    embedding), and whose feed-forward is of two kinds,
+    ``mlp_layer_types[i]`` = ``"dense"`` (a SwiGLU of ``hidden_dim``) or
+    ``"sparse"`` (``num_experts`` sigmoid-routed experts of
+    ``moe_hidden_dim``, ``top_k`` a token, beside ``num_shared_experts``
+    that serve every token). Weights of one shape whatever the attention
+    kind.
+
+    ``experts_held`` and ``src_vocab_size``: as ``SarvamConfig`` has them
+    (the routed experts and the vocabulary rows this program holds)."""
+
+    src_vocab_size: int = 153600
+    emb_dim: int = 6144
+    nheads: int = 64
+    kvheads: int = 8
+    head_dim: int = 128
+    nlayers: int = 48
+    layer_types: Tuple[str, ...] = ()
+    mlp_layer_types: Tuple[str, ...] = ()
+    sliding_window: int = 128
+    hidden_dim: int = 18432  # a dense layer's MLP
+    moe_hidden_dim: int = 2048  # one expert
+    num_experts: int = 128  # the router's width
+    experts_held: Optional[Tuple[int, int]] = None
+    top_k: int = 8
+    num_shared_experts: int = 1
+    routed_scaling_factor: float = 2.5
+    max_expected_seq_len: int = 262144
+    rope_theta: float = 1000000.0
+    norm_eps: float = 1e-5
+
+    def __post_init__(self):
+        first, count = self.held
+        if not (0 <= first and count >= 1
+                and first + count <= self.num_experts):
+            raise ValueError(
+                f"experts_held={self.experts_held} is no range of the "
+                f"{self.num_experts} routed experts"
+            )
+        for name, kinds in (("layer_types", KEXAONE_ATTN_KINDS),
+                            ("mlp_layer_types", KEXAONE_MLP_KINDS)):
+            got = getattr(self, name)
+            if len(got) != self.nlayers or set(got) - set(kinds):
+                raise ValueError(
+                    f"{name} must name one of {kinds} for each of the "
+                    f"{self.nlayers} layers, got {got}"
+                )
+        if self.nheads % self.kvheads:
+            raise ValueError(
+                f"{self.nheads} query heads do not share {self.kvheads} "
+                "kv heads evenly"
+            )
+
+    @property
+    def held(self) -> Tuple[int, int]:
+        """(first id, count) of the routed experts held here."""
+        return self.experts_held or (0, self.num_experts)
+
+    def kind(self, i: int) -> str:
+        """Layer ``i``'s kind, the name of its stack in the parameter
+        tree."""
+        return _kexaone_kind(self.layer_types[i], self.mlp_layer_types[i])
+
+    @property
+    def stacks(self):
+        """``{kind: the indices of its layers}``, kinds in the order they
+        first occur. A layer's index in its stack is its place among the
+        layers of its kind."""
+        out = {}
+        for i in range(self.nlayers):
+            out.setdefault(self.kind(i), []).append(i)
+        return out
+
+    @property
+    def window_layers(self) -> Tuple[int, ...]:
+        return tuple(
+            i for i, t in enumerate(self.layer_types)
+            if t == "sliding_attention"
+        )
+
+    @property
+    def full_layers(self) -> Tuple[int, ...]:
+        return tuple(
+            i for i, t in enumerate(self.layer_types) if t == "full_attention"
+        )
+
+    @property
+    def n_moe_layers(self) -> int:
+        return sum(t == "sparse" for t in self.mlp_layer_types)
+
+    def n_params(self) -> int:
+        """Parameters held here (the experts and vocabulary rows held)."""
+        d, hd = self.emb_dim, self.head_dim
+        attn = (
+            2 * d * self.nheads * hd + 2 * d * self.kvheads * hd
+            + 2 * hd + 2 * d
+        )
+        dense = 3 * d * self.hidden_dim
+        moe = (
+            d * self.num_experts + self.num_experts
+            + 3 * d * self.moe_hidden_dim
+            * (self.held[1] + self.num_shared_experts)
+        )
+        n_moe = self.n_moe_layers
+        return int(
+            self.nlayers * attn
+            + (self.nlayers - n_moe) * dense
+            + n_moe * moe
+            + d
+            + 2 * self.src_vocab_size * d
+        )
+
+
+def kexaone_config(d: dict) -> KExaoneConfig:
+    """A published ``config.json`` of ``model_type: exaone_moe`` as the
+    family's config. A file that states a chip's share of a deployment
+    gives the experts held as ``num_experts`` with the router's width
+    under ``published`` and the first held id as ``first_expert_held``
+    (benchmark/configs/k-exaone-236b.1chip.json), as ``sarvam_config``
+    reads them. The multi-token-prediction module
+    (``num_nextn_predict_layers``) is not built: it does not enter the
+    next-token logits, and a config that asks for it is refused by name.
+    models/kexaone.py says how the keys the config does not have are
+    read."""
+    if d.get("num_nextn_predict_layers"):
+        raise ValueError(
+            "exaone_moe with num_nextn_predict_layers="
+            f"{d['num_nextn_predict_layers']}: the multi-token-prediction "
+            "module is not built (its block is not fixed by the config); "
+            "set it to 0 to serve the trunk's next-token logits"
+        )
+    if d.get("scoring_func", "sigmoid") != "sigmoid":
+        raise ValueError(
+            f"scoring_func {d['scoring_func']!r}: the family's router "
+            "scores by sigmoid"
+        )
+    if d.get("n_group", 1) != 1 or d.get("topk_group", 1) != 1:
+        raise ValueError(
+            "exaone_moe with routing groups (n_group, topk_group != 1): "
+            "the router chooses over all experts"
+        )
+    if not d.get("norm_topk_prob", True):
+        raise ValueError(
+            "norm_topk_prob false: the family's chosen weights are normalised"
+        )
+    rp = d.get("rope_parameters") or {}
+    if rp.get("rope_type", "default") != "default":
+        raise ValueError(
+            f"rope_type {rp.get('rope_type')!r}: the family has the "
+            "default frequencies"
+        )
+    L = d["num_hidden_layers"]
+    layer_types = tuple(d["layer_types"])
+    first_dense = d.get("first_k_dense_replace", 0)
+    mlp_types = tuple(d.get("mlp_layer_types") or (
+        "dense" if i < first_dense else "sparse" for i in range(L)
+    ))
+    held = d["num_experts"]
+    published = (d.get("published") or {}).get("num_experts", held)
+    return KExaoneConfig(
+        src_vocab_size=d["vocab_size"],
+        emb_dim=d["hidden_size"],
+        nheads=d["num_attention_heads"],
+        kvheads=d["num_key_value_heads"],
+        head_dim=d["head_dim"],
+        nlayers=L,
+        layer_types=layer_types,
+        mlp_layer_types=mlp_types,
+        sliding_window=d["sliding_window"],
+        hidden_dim=d["intermediate_size"],
+        moe_hidden_dim=d["moe_intermediate_size"],
+        num_experts=published,
+        experts_held=(
+            (int(d.get("first_expert_held", 0)), held)
+            if held != published else None
+        ),
+        top_k=d["num_experts_per_tok"],
+        num_shared_experts=d.get("num_shared_experts", 0),
+        routed_scaling_factor=d.get("routed_scaling_factor", 1.0),
+        max_expected_seq_len=d["max_position_embeddings"],
+        rope_theta=float(rp.get("rope_theta", d.get("rope_theta", 10000.0))),
+        norm_eps=d["rms_norm_eps"],
+    )

@@ -1,0 +1,179 @@
+"""The held share of a sigmoid-routed expert layer: what a chip of an
+expert-parallel deployment computes of a layer, without the exchange.
+
+``s = sigmoid(W_g h)`` in float32 over all ``num_experts``; the ``top_k``
+largest of ``s + b`` are chosen (the bias chooses and does not weigh);
+``w_i = routed_scaling_factor * s_i / sum of the chosen s``; the layer
+adds ``sum over the chosen experts held here of w_i E_i(h)`` and, beside
+it, the shared expert's ``S(h)`` (whole on every chip). One copy for the
+families that route this way (models/sarvam.py, models/kexaone.py);
+under it models/mixtral.py's ``_expert_mix``, ``_all_experts_swiglu`` and
+``routed_moe_form`` over the experts held.
+
+A config gives four things: ``top_k``, ``routed_scaling_factor``,
+``num_experts`` (the router's width) and ``held`` = (first id, count) of
+the routed experts this program holds. A layer is a dict of leaves:
+``gate (D, num_experts)``, ``gate_bias (num_experts,)``, ``w1``/``w3``
+``(held, D, h)``, ``w2 (held, h, D)`` and, where there is a shared
+expert, ``shared_w1``/``shared_w3``/``shared_w2``.
+
+Three forms of the held part: over a decode step's rows the two of
+models/mixtral.py (``routed_moe_form``: every held expert streamed once,
+or one product a routed pair); over a prompt's chunk the (token, choice)
+pairs that land on held experts sorted by expert and multiplied group by
+group (``_moe_grouped``, the megablox grouped matmul), none dropped.
+"""
+
+import itertools
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+from fms_fsdp_tpu.models.mixtral import (
+    _all_experts_swiglu,
+    _expert_mix,
+    routed_moe_form,
+)
+from fms_fsdp_tpu.obs.scopes import scoped
+from fms_fsdp_tpu.ops.pallas_mode import interpret_default
+from fms_fsdp_tpu.ops.selective_scan import largest_divisor
+
+
+def _swiglu(h, w1, w3, w2):
+    return (jax.nn.silu(h @ w1) * (h @ w3)) @ w2
+
+
+@scoped("moe_router")
+def _router(h, layer, cfg):
+    """h (..., D) -> (chosen ids (..., K) int over all ``num_experts``,
+    their weights (..., K) fp32 that sum to ``routed_scaling_factor``).
+    Scores are sigmoids in fp32; the bias is added for the choice
+    alone."""
+    scores = jax.nn.sigmoid((h @ layer["gate"]).astype(jnp.float32))
+    _, idx = lax.top_k(
+        scores + layer["gate_bias"].astype(jnp.float32), cfg.top_k
+    )
+    w = jnp.take_along_axis(scores, idx, axis=-1)
+    w = cfg.routed_scaling_factor * w / jnp.sum(w, axis=-1, keepdims=True)
+    return idx, w
+
+
+@scoped("moe_shared")
+def _shared(h, layer):
+    if "shared_w1" not in layer:
+        return jnp.zeros_like(h)
+    return _swiglu(h, layer["shared_w1"], layer["shared_w3"], layer["shared_w2"])
+
+
+def _held_mixture(h, layer, cfg, idx, w):
+    """Every held expert over every row of h (B, S, D), mixed by the
+    rows' weights for them (exactly zero where a row chose another)."""
+    first, held = cfg.held
+    with jax.named_scope("moe_experts"):
+        mix = _expert_mix(idx, w, held, first).astype(h.dtype)
+        out = _all_experts_swiglu(h, layer)  # (B, S, held, D)
+    with jax.named_scope("moe_combine"):
+        return jnp.einsum("bse,bsed->bsd", mix, out)
+
+
+def _moe_dense_held(h, layer, cfg):
+    """Held experts' part of the mixture, every held expert over every
+    row (the parity form). h (B, S, D)."""
+    return _held_mixture(h, layer, cfg, *_router(h, layer, cfg))
+
+
+def _moe_token(h, layer, cfg, moe_impl: str):
+    """Held experts' part of the mixture over a decode step's rows.
+    h (B, m, D) post-ffn_norm. The two routed forms of
+    models/mixtral.py over the experts held."""
+    if moe_impl == "dense":
+        return _moe_dense_held(h, layer, cfg)
+    assert moe_impl == "routed", f"unknown decode moe_impl {moe_impl!r}"
+    idx, w = _router(h, layer, cfg)
+    B, m, K = idx.shape
+    first, held = cfg.held
+    if routed_moe_form(B * m * K, held) == "all_experts":
+        return _held_mixture(h, layer, cfg, idx, w)
+    rows = h.reshape(B * m, -1)
+    local = idx.reshape(B * m, K) - first
+    here = (local >= 0) & (local < held)
+    out = []
+    for r, k in itertools.product(range(B * m), range(K)):
+        with jax.named_scope("moe_experts"):
+            # a pair on an expert that is not held reads a held one and
+            # is weighed by exactly zero below
+            w1, w3, w2 = (
+                lax.dynamic_index_in_dim(
+                    layer[name], jnp.clip(local[r, k], 0, held - 1), 0,
+                    keepdims=False,
+                )
+                for name in ("w1", "w3", "w2")
+            )
+            out.append(_swiglu(rows[r], w1, w3, w2))
+    with jax.named_scope("moe_combine"):
+        out = jnp.stack(out).reshape(B, m, K, -1)
+        wt = jnp.where(here.reshape(B, m, K), w, 0.0).astype(h.dtype)
+        return jnp.einsum("bmkd,bmk->bmd", out, wt)
+
+
+def _gmm(x, stack, sizes, l):
+    """Rows of ``x`` (M, k), sorted by group, times their group's matrix
+    in layer ``l`` of ``stack`` (L, G, k, n): the megablox grouped
+    matmul. The kernel is handed the whole stack, seen as ``L * G``
+    groups of which only layer ``l``'s have rows, so no layer's slice of
+    it is ever copied out (a sliced operand is: 1.6 GB a layer and
+    chunk at the published widths). Its grid follows ``sum(sizes)``:
+    rows past that are not visited and hold whatever the buffer held."""
+    from jax.experimental.pallas.ops.tpu.megablox import gmm
+
+    M, k = x.shape
+    L, G, _, n = stack.shape
+    sizes = lax.dynamic_update_slice(
+        jnp.zeros((L * G,), jnp.int32), sizes, (l * G,)
+    )
+    # timed on the chip at the published widths, 4096 rows in 32 groups
+    # (PERF.md, PR 31): (512, 1024, 1024) 1.91 / 1.95 ms up / down,
+    # (256, 1024, 1024) 1.45 / 1.49, (128, 1024, 1024) 1.67 / 1.70,
+    # (256, 2048, 1024) 1.44 / 1.26
+    tiling = (largest_divisor(M, 256), min(k, 2048), min(n, 1024))
+    return gmm(
+        x, stack.reshape(L * G, k, n), sizes, preferred_element_type=x.dtype,
+        tiling=tiling, interpret=interpret_default(),
+    )
+
+
+def _moe_grouped(h, layer, cfg, experts=None, l=0):
+    """Held experts' part of the mixture over a chunk's rows h (T, D):
+    the (token, choice) pairs on held experts sorted by expert, each
+    group through its expert, no pair dropped. ``experts``: the MoE
+    layers' stacked ``w1``/``w3``/``w2`` (L, held, ...) with ``l`` the
+    layer's index in them (``layer``'s own, as a stack of one, when
+    None). Returns (y (T, D), the number of pairs that landed on held
+    experts)."""
+    if experts is None:
+        experts = {name: layer[name][None] for name in ("w1", "w3", "w2")}
+    idx, w = _router(h, layer, cfg)  # (T, K)
+    T, K = idx.shape
+    first, held = cfg.held
+    with jax.named_scope("moe_group"):
+        local = idx.reshape(T * K) - first
+        here = (local >= 0) & (local < held)
+        # pairs on experts that are not held sort behind every group
+        key = jnp.where(here, local, held)
+        order = jnp.argsort(key, stable=True)
+        sizes = jnp.bincount(key, length=held + 1)[:held].astype(jnp.int32)
+        n_here = jnp.sum(sizes)
+        xs = h[order // K]
+    with jax.named_scope("moe_experts"):
+        hid = jax.nn.silu(_gmm(xs, experts["w1"], sizes, l)) * _gmm(
+            xs, experts["w3"], sizes, l
+        )
+        out = _gmm(hid, experts["w2"], sizes, l)
+    with jax.named_scope("moe_combine"):
+        out = jnp.where(
+            jnp.arange(T * K)[:, None] < n_here, out, jnp.zeros_like(out)
+        )
+        back = out[jnp.argsort(order)].reshape(T, K, -1)
+        wt = jnp.where(here.reshape(T, K), w, 0.0).astype(h.dtype)
+        return jnp.einsum("tkd,tk->td", back, wt), n_here

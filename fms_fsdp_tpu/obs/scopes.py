@@ -110,6 +110,45 @@ SARVAM_SCOPES = (
     "sample",
 )
 
+# the scopes of a kexaone engine's two programs (models/kexaone.py,
+# serve/families/kexaone.py: ``jit__step`` and ``jit__prefill_<tokens>``),
+# in program order; the two kinds of attention layer are told apart in
+# every phase. Window layers: ``win_write`` (the ring's write in a decode
+# step; in a prefill the carry of a chunk's last ``sliding_window``
+# positions and the ring's order at the end) and ``attn_window`` (the
+# ring's attention; the windowed flash kernel over a chunk's band and the
+# carried positions' part). Full layers: ``kv_write`` (the page write; the
+# prefill's buffer write), ``kv_read`` (the reference's gather of pages;
+# nothing under the kernel, which reads them itself) and ``attn_full``
+# (the ragged paged kernel or the gathered attention; the prefill's walk
+# over the chunk's own and earlier blocks). ``qk_norm`` is the RMSNorm by
+# head of q and k, ``rope`` the window layers' rotary embedding; the
+# ``moe_*`` scopes are models/moe_held.py's, as in ``SARVAM_SCOPES``.
+# ``layers`` is around the (unrolled) stack
+KEXAONE_SCOPES = (
+    "params_cast",
+    "embed",
+    "norm",
+    "layers",
+    "qkv",
+    "qk_norm",
+    "rope",
+    "win_write",
+    "attn_window",
+    "kv_write",
+    "kv_read",
+    "attn_full",
+    "attn_out",
+    "mlp",
+    "moe_router",
+    "moe_shared",
+    "moe_group",
+    "moe_experts",
+    "moe_combine",
+    "lm_head",
+    "sample",
+)
+
 _COMPUTATION = re.compile(r"^(?:ENTRY\s+)?%?([\w.\-]+)\s.*\{\s*$")
 _INSTRUCTION = re.compile(r"^\s+(?:ROOT\s+)?%?([\w.\-]+) = ")
 _NAME = re.compile(r"%([\w.\-]+)")

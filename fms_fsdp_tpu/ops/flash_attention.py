@@ -310,6 +310,158 @@ def _flash_fwd_kvgrid(q, k, v, scale, causal, block_q, block_k, interpret):
 
 
 # ---------------------------------------------------------------------------
+# forward under a sliding window (serving's window layers; no backward)
+# ---------------------------------------------------------------------------
+
+
+def _band_blocks(i, block_q, block_k, window):
+    """(first, last) K block that the Q block ``i`` can see under the
+    causal mask and a window of ``window`` positions (a position sees
+    itself and the ``window - 1`` before it). Traced or not."""
+    q_start = i * block_q
+    first = jnp.maximum(q_start - (window - 1), 0) // block_k
+    return first, (q_start + block_q - 1) // block_k
+
+
+def _fwd_kernel_window(
+    q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
+    *, scale, window, num_kb,
+):
+    """kv-streamed forward over the band: grid (b, h, qi, j), cell ``j``
+    holding the ``j``-th K block of the Q block's band. A K block that
+    lies wholly outside the band is neither fetched (the index map never
+    names it; cells past the band's last block are clamped onto it, a
+    repeat fetch Mosaic elides) nor computed (pl.when); the band's two
+    edges are masked element by element in every cell that runs (at a
+    window no wider than a block there is no cell without an edge)."""
+    block_q = q_ref.shape[2]
+    block_k = k_ref.shape[2]
+    qi = pl.program_id(2)
+    j = pl.program_id(3)
+    q_start = qi * block_q
+    first, last = _band_blocks(qi, block_q, block_k, window)
+
+    @pl.when(j == 0)
+    def _():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+
+    @pl.when(first + j <= last)
+    def _():
+        k_start = (first + j) * block_k
+        q = (q_ref[0, 0] * (scale * LOG2E)).astype(q_ref.dtype)
+        k = k_ref[0, 0]
+        v = v_ref[0, 0]
+        s = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        )  # (BQ, BK), base-2 domain
+        back = (
+            q_start - k_start
+            + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
+            - jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
+        )  # how far the key lies behind the query
+        s = jnp.where((back >= 0) & (back < window), s, NEG_INF)
+        # a row that sees nothing of this block and nothing yet keeps
+        # m = NEG_INF and adds p = 1 a key; the first block it does see
+        # (its own position's, which every row has) wipes that: alpha = 0
+        m = m_ref[...]
+        m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.exp2(s - m_new)
+        alpha = jnp.exp2(m - m_new)
+        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
+        pv = jax.lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        acc_ref[...] = acc_ref[...] * alpha + pv
+        m_ref[...] = m_new
+
+    @pl.when(j == num_kb - 1)
+    def _():
+        l = l_ref[...]
+        o_ref[0, 0] = (acc_ref[...] / l).astype(o_ref.dtype)
+        lse_ref[0, 0] = m_ref[...] * LN2 + jnp.log(l)
+
+
+@scoped("flash_attention_fwd")
+def _flash_fwd_window(q, k, v, scale, window, block_q, block_k, interpret):
+    """q (B, Nq, S, H), k (B, Nkv, S, H), v (B, Nkv, S, Hv) -> (o, lse) of
+    causal attention in which a position sees itself and the ``window -
+    1`` before it. The work follows the band, ``S * window``, and not
+    ``S * S / 2``."""
+    batch, nq, seq, head = q.shape
+    nkv, vdim = k.shape[1], v.shape[3]
+    group = nq // nkv
+    # the widest band of any Q block, in K blocks: static
+    num_kb = max(
+        (i * block_q + block_q - 1) // block_k
+        - max(i * block_q - (window - 1), 0) // block_k + 1
+        for i in range(seq // block_q)
+    )
+
+    def kvmap(b, h, i, j):
+        first, last = _band_blocks(i, block_q, block_k, window)
+        return (b, h // group, jnp.minimum(first + j, last), 0)
+
+    return pl.pallas_call(
+        functools.partial(
+            _fwd_kernel_window, scale=scale, window=window, num_kb=num_kb
+        ),
+        grid=(batch, nq, seq // block_q, num_kb),
+        in_specs=[
+            pl.BlockSpec((1, 1, block_q, head), lambda b, h, i, j: (b, h, i, 0)),
+            pl.BlockSpec((1, 1, block_k, head), kvmap),
+            pl.BlockSpec((1, 1, block_k, vdim), kvmap),
+        ],
+        out_specs=[
+            pl.BlockSpec((1, 1, block_q, vdim), lambda b, h, i, j: (b, h, i, 0)),
+            pl.BlockSpec((1, 1, block_q, 1), lambda b, h, i, j: (b, h, i, 0)),
+        ],
+        out_shape=[
+            jax.ShapeDtypeStruct((batch, nq, seq, vdim), q.dtype),
+            jax.ShapeDtypeStruct((batch, nq, seq, 1), jnp.float32),
+        ],
+        scratch_shapes=[
+            pltpu.VMEM((block_q, vdim), jnp.float32),
+            pltpu.VMEM((block_q, 1), jnp.float32),
+            pltpu.VMEM((block_q, 1), jnp.float32),
+        ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")
+        ),
+        interpret=interpret,
+    )(q, k, v)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def _flash_window_bnsh(q, k, v, scale, window, block_q, block_k, interpret):
+    return _flash_fwd_window(
+        q, k, v, scale, window, block_q, block_k, interpret
+    )
+
+
+def _flash_window_fwd(q, k, v, scale, window, block_q, block_k, interpret):
+    out = _flash_fwd_window(
+        q, k, v, scale, window, block_q, block_k, interpret
+    )
+    return out, None
+
+
+def _flash_window_bwd(scale, window, block_q, block_k, interpret, res, g):
+    # the dq and dk/dv kernels walk every block under the diagonal; only
+    # the forward walks a band (serving's window layers: no training path)
+    raise NotImplementedError(
+        f"flash_attention backward under a sliding window (window={window}) "
+        "is not built: the dq and dk/dv kernels take no window; only the "
+        "forward kernel walks the band"
+    )
+
+
+_flash_window_bnsh.defvjp(_flash_window_fwd, _flash_window_bwd)
+
+
+# ---------------------------------------------------------------------------
 # backward: dq
 # ---------------------------------------------------------------------------
 
@@ -930,6 +1082,7 @@ def flash_attention(
     return_lse: bool = False,
     variant=None,
     quant=None,
+    window=None,
 ):
     """q: (B, S, Nq, H); k: (B, S, Nkv, H); v: (B, S, Nkv, Hv) ->
     (B, S, Nq, Hv). Hv = H everywhere but in latent attention's forward
@@ -951,7 +1104,30 @@ def flash_attention(
     With ``return_lse``, also returns the per-query logsumexp
     (B, S, Nq, 1) fp32 as a differentiable output, enabling exact
     merging of attention partials over disjoint kv sets (ring attention).
+
+    ``window`` (None: none): a position sees itself and the ``window -
+    1`` before it. Causal, queries and keys of one length, forward only:
+    a kernel of its own that fetches and computes the K blocks of each Q
+    block's band and no others (blocks of ``block_q`` x ``block_k``,
+    256 x 128 unless pinned; the tuning table has no entry for it).
     """
+    if window is not None:
+        if not causal or q.shape[1] != k.shape[1] or window < 1:
+            raise ValueError(
+                "a sliding window is causal over queries and keys of one "
+                f"length (causal={causal}, {q.shape[1]} queries, "
+                f"{k.shape[1]} keys, window={window})"
+            )
+        scale = float(scale if scale is not None else q.shape[-1] ** -0.5)
+        ot, lse = _flash_window_bnsh(
+            jnp.swapaxes(q, 1, 2), jnp.swapaxes(k, 1, 2),
+            jnp.swapaxes(v, 1, 2), scale, int(window),
+            _pick_block(q.shape[1], block_q or 256),
+            _pick_block(k.shape[1], block_k or 128), interpret,
+        )
+        if return_lse:
+            return jnp.swapaxes(ot, 1, 2), jnp.swapaxes(lse, 1, 2)
+        return jnp.swapaxes(ot, 1, 2)
     from fms_fsdp_tpu.tune.lookup import (
         record_final_flash_blocks,
         resolve_flash,

@@ -1,4 +1,4 @@
-"""Family adapters: one serving engine, four model families.
+"""Family adapters: one serving engine, five model families.
 
 The ServingEngine owns admission, continuous batching, eviction and
 metrics — none of which care what a "slot" stores. What differs per
@@ -26,6 +26,12 @@ family          decode-state per stream
                 position and layer whatever the head count: MLA);
                 the held share of a sigmoid-routed expert layer and
                 its shared expert are stateless per token
+``kexaone``     a cache of each kind of attention layer: paged KV
+                pages for the full layers alone (the only thing that
+                grows) and, for the window layers, a ring of
+                ``sliding_window`` keys and values a slot, constant
+                bytes whatever the context; the held share of the
+                expert layer is the code sarvam runs
 ==============  ========================================================
 
 Every adapter is parity-anchored: greedy decode through the engine is
@@ -47,6 +53,8 @@ from typing import Optional
 import numpy as np
 
 from fms_fsdp_tpu.models.configs import (
+    KEXAONE_LAYER_KINDS,
+    KExaoneConfig,
     LlamaConfig,
     MambaConfig,
     MixtralConfig,
@@ -57,13 +65,16 @@ from fms_fsdp_tpu.obs.spans import done, span
 
 # the wire encoding of a family in numeric-only maps (obs schema v12
 # "serving"): family = FAMILY_CODES[name]
-FAMILY_CODES = {"llama": 0, "mamba": 1, "mixtral": 2, "sarvam": 3}
+FAMILY_CODES = {
+    "llama": 0, "mamba": 1, "mixtral": 2, "sarvam": 3, "kexaone": 4,
+}
 FAMILY_NAMES = {v: k for k, v in FAMILY_CODES.items()}
 
 _CONFIG_FAMILIES = (
     (MambaConfig, "mamba"),
     (MixtralConfig, "mixtral"),
     (SarvamConfig, "sarvam"),
+    (KExaoneConfig, "kexaone"),
     (LlamaConfig, "llama"),
 )
 
@@ -75,7 +86,8 @@ def family_of(model_cfg) -> str:
             return name
     raise ValueError(
         f"unknown model config type {type(model_cfg).__name__}: expected "
-        f"LlamaConfig, MambaConfig, MixtralConfig or SarvamConfig "
+        f"LlamaConfig, MambaConfig, MixtralConfig, SarvamConfig or "
+        f"KExaoneConfig "
         f"(fms_fsdp_tpu/models/configs.py)"
     )
 
@@ -89,7 +101,9 @@ def load_model_config(d: dict):
     ``config.json`` (``"model_type": "jamba"``, or ``"family": "jamba"``)
     resolves to the mamba family through its own key mapping, and a
     published ``"model_type": "sarvam_mla"`` one (or ``"family":
-    "sarvam"``) to the sarvam family through its own. This is the single
+    "sarvam"``) to the sarvam family through its own, a published
+    ``"model_type": "exaone_moe"`` one (or ``"family": "kexaone"``) to
+    the kexaone family through its own. This is the single
     resolution point replica.py and the engine share — the two can no
     longer diverge on model construction (the PR-11 bug this replaces:
     replica.py:71 hardwired its own ``init_llama_params`` copy)."""
@@ -105,6 +119,10 @@ def load_model_config(d: dict):
         from fms_fsdp_tpu.models.configs import sarvam_config
 
         return sarvam_config(d)
+    if family == "kexaone" or d.get("model_type") == "exaone_moe":
+        from fms_fsdp_tpu.models.configs import kexaone_config
+
+        return kexaone_config(d)
     if family is None:
         if "d_model" in d or "n_layer" in d:
             family = "mamba"
@@ -148,7 +166,11 @@ def check_params_family(params, family: str) -> None:
     the checkpoint and the model config disagree — fail at build with
     the fix spelled out, not at the first prefill with a shape error."""
     layers = params.get("layers") if hasattr(params, "get") else None
-    if isinstance(layers, (list, tuple)):
+    if layers is None and hasattr(params, "get") and any(
+        kind in params for kind in KEXAONE_LAYER_KINDS
+    ):
+        actual = "kexaone"  # a stack for each kind of layer, no "layers"
+    elif isinstance(layers, (list, tuple)):
         actual = "mamba"
     elif isinstance(layers, dict) and "wkv_a" in layers:
         actual = "sarvam"  # latent attention's down-projection
@@ -161,7 +183,7 @@ def check_params_family(params, family: str) -> None:
             "params do not look like any serveable family (no "
             "recognizable 'layers' structure): expected init_llama_params"
             " / init_mamba_params / init_mixtral_params / "
-            "init_sarvam_params output or a "
+            "init_sarvam_params / init_kexaone_params output or a "
             "checkpoint thereof"
         )
     if actual != family:
@@ -189,6 +211,10 @@ def init_params_for(model_cfg):
         from fms_fsdp_tpu.models.sarvam import init_sarvam_params
 
         return lambda key: init_sarvam_params(key, model_cfg)
+    if family == "kexaone":
+        from fms_fsdp_tpu.models.kexaone import init_kexaone_params
+
+        return lambda key: init_kexaone_params(key, model_cfg)
     from fms_fsdp_tpu.models.llama import init_llama_params
 
     return lambda key: init_llama_params(key, model_cfg)
@@ -240,6 +266,8 @@ def resolve_adapter(
         from fms_fsdp_tpu.serve.families.mixtral import MixtralAdapter as cls
     elif family == "sarvam":
         from fms_fsdp_tpu.serve.families.sarvam import SarvamAdapter as cls
+    elif family == "kexaone":
+        from fms_fsdp_tpu.serve.families.kexaone import KExaoneAdapter as cls
     else:
         from fms_fsdp_tpu.serve.families.llama import LlamaAdapter as cls
     return cls(params, model_cfg, serve_cfg, compute_dtype, registry)
@@ -754,15 +782,98 @@ class PagedAdapter(FamilyAdapter):
         return row, kv, None, p_pad
 
 
+def kernel_or_reference(scfg) -> str:
+    """``"kernel"`` or ``"reference"`` for a family whose decode attention
+    has a ragged paged kernel: ``auto`` takes the kernel on a TPU and the
+    gathered form elsewhere."""
+    import jax
+
+    if scfg.attn_impl == "auto":
+        return "kernel" if jax.default_backend() == "tpu" else "reference"
+    return scfg.attn_impl
+
+
+def sequence_prefill_attn_impl(scfg) -> str:
+    """A sequence prefill's name (``auto``, ``pallas``, ``xla``) for
+    ``scfg.attn_impl``."""
+    return {"auto": "auto", "kernel": "pallas"}.get(scfg.attn_impl, "xla")
+
+
+class HeldExpertsAdapter(FamilyAdapter):
+    """What the adapters of the families that run models/moe_held.py
+    share (sarvam, kexaone): the ``moe_impl`` rule, which loop the decode
+    program runs over the held experts (``moe_form``, the gauge
+    ``serve.moe_expert_reads_per_layer``), the gauges of the share, and
+    the count of the (token, choice) pairs a prefill routed and of those
+    that landed on a held expert. ``model_cfg`` has ``top_k``, ``held``,
+    ``num_experts`` and ``n_moe_layers``; the family's ``_call_prefill``
+    leaves the prefill program's own count in ``self._pairs_held``."""
+
+    _pairs_held = 0  # on the device until the count is read
+
+    def _init_held_experts(self) -> None:
+        from fms_fsdp_tpu.models.mixtral import routed_moe_form
+
+        cfg, scfg = self.model_cfg, self.scfg
+        self.moe_impl = moe_impl = scfg.moe_impl
+        if moe_impl not in ("routed", "dense"):
+            raise ValueError(
+                f"unknown moe_impl {moe_impl!r}: {self.family} serving "
+                "supports 'routed' (decode reads each held expert once or "
+                "one a routed pair, prefill groups the pairs by held "
+                "expert) or 'dense' (every held expert over every row, "
+                "the parity mode)"
+            )
+        # which loop the decode program runs over the held experts and the
+        # expert copies it reads in each layer: facts of its shape
+        pairs, held = scfg.max_batch * cfg.top_k, cfg.held[1]
+        routed = moe_impl == "routed"
+        self.moe_form = routed_moe_form(pairs, held) if routed else "dense"
+        self.moe_expert_reads_per_layer = min(pairs, held) if routed else held
+        self._dispatch_fields = {"moe_form": self.moe_form}
+        self.registry.gauge("serve.moe_experts_held").set(held)
+        self.registry.gauge("serve.moe_experts_published").set(
+            cfg.num_experts
+        )
+
+    def _refuse(self, *knobs) -> None:
+        """``(knob, value, why)``: a set knob that this family does not
+        take is refused by name at build."""
+        for knob, value, why in knobs:
+            if value:
+                raise ValueError(
+                    f"{self.family} serving does not take {knob}={value!r}: "
+                    f"{why}"
+                )
+
+    def _count_prefill(self, rid: int, computed: int) -> None:
+        """Beside the positions computed: the (token, choice) pairs they
+        routed, and those that landed on a held expert (the program's
+        own count; reading it waits for the prefill, which the engine's
+        sampler does next anyway; the dense form weighs every pair and
+        counts none)."""
+        cfg = self.model_cfg
+        routed = computed * cfg.top_k * cfg.n_moe_layers
+        held = int(self._pairs_held) if self.moe_impl == "routed" else 0
+        self.registry.counter("serve.moe_pairs_routed").add(routed)
+        self.registry.counter("serve.moe_pairs_held").add(held)
+        super()._count_prefill(
+            rid, computed, moe_pairs_routed=routed, moe_pairs_held=held
+        )
+
+
 __all__ = [
     "FAMILY_CODES",
     "FAMILY_NAMES",
     "FamilyAdapter",
+    "HeldExpertsAdapter",
     "PagedAdapter",
     "check_params_family",
     "family_of",
     "init_params_for",
+    "kernel_or_reference",
     "load_model_config",
     "paged_geometry",
     "resolve_adapter",
+    "sequence_prefill_attn_impl",
 ]

@@ -42,7 +42,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from fms_fsdp_tpu.models.generation import sample_token
-from fms_fsdp_tpu.models.mixtral import routed_moe_form
 from fms_fsdp_tpu.models.sarvam import (
     pool_width,
     prefill_attn_form,
@@ -50,7 +49,12 @@ from fms_fsdp_tpu.models.sarvam import (
     sarvam_paged_decode_step,
     sarvam_prefill,
 )
-from fms_fsdp_tpu.serve.families import FamilyAdapter, paged_geometry
+from fms_fsdp_tpu.serve.families import (
+    HeldExpertsAdapter,
+    kernel_or_reference as resolve_attn_impl,
+    paged_geometry,
+    sequence_prefill_attn_impl as _prefill_attn_impl,
+)
 
 
 # positions a page of the latent pool holds unless ``scfg.page_size`` pins
@@ -72,14 +76,6 @@ def page_geometry(model_cfg, scfg):
         scfg, model_cfg.nheads, 1, model_cfg.latent_dim, tuned=False
     )
     return page_size, max_pages, num_pages
-
-
-def resolve_attn_impl(scfg) -> str:
-    """``"kernel"`` or ``"reference"``: ``auto`` takes the ragged paged
-    latent kernel on a TPU and the gathered form elsewhere."""
-    if scfg.attn_impl == "auto":
-        return "kernel" if jax.default_backend() == "tpu" else "reference"
-    return scfg.attn_impl
 
 
 def decode_program(model_cfg, scfg, page_size: int, compute_dtype):
@@ -107,11 +103,6 @@ def decode_program(model_cfg, scfg, page_size: int, compute_dtype):
     return jax.jit(_step, donate_argnums=(1,))
 
 
-def _prefill_attn_impl(scfg) -> str:
-    """``sarvam_prefill``'s name for ``scfg.attn_impl``."""
-    return {"auto": "auto", "kernel": "pallas"}.get(scfg.attn_impl, "xla")
-
-
 def prefill_program(model_cfg, scfg, p_pad: int, kv_len: int, compute_dtype):
     """The jitted prefill of one padded prompt length: ``(params, tokens
     (1, p_pad), lengths (1,)) -> (logits (1, V), latent (L, 1, kv_len,
@@ -130,22 +121,14 @@ def prefill_program(model_cfg, scfg, p_pad: int, kv_len: int, compute_dtype):
     return jax.jit(_prefill)
 
 
-class SarvamAdapter(FamilyAdapter):
+class SarvamAdapter(HeldExpertsAdapter):
     family = "sarvam"
     _pages_noun = "latent pages"
 
     def _setup(self) -> None:
         cfg, scfg = self.model_cfg, self.scfg
-        self.moe_impl = moe_impl = scfg.moe_impl
-        if moe_impl not in ("routed", "dense"):
-            raise ValueError(
-                f"unknown moe_impl {moe_impl!r}: sarvam serving supports "
-                "'routed' (decode reads each held expert once or one a "
-                "routed pair, prefill groups the pairs by held expert) or "
-                "'dense' (every held expert over every row, the parity "
-                "mode)"
-            )
-        for knob, value, why in (
+        self._init_held_experts()
+        self._refuse(
             ("serve_layout", scfg.serve_layout,
              "the expert layer's exchange over chips is not built: run "
              "one chip's share (SarvamConfig.experts_held)"),
@@ -153,20 +136,8 @@ class SarvamAdapter(FamilyAdapter):
              "latent pages are stored full-width"),
             ("speculator_path", scfg.speculator_path,
              "the draft/verify loop is llama-only"),
-        ):
-            if value:
-                raise ValueError(
-                    f"sarvam serving does not take {knob}={value!r}: {why}"
-                )
+        )
         self.attn_impl = resolve_attn_impl(scfg)
-        # which loop the decode program runs over the held experts and the
-        # expert copies it reads in each layer: facts of its shape
-        pairs, held = scfg.max_batch * cfg.top_k, cfg.held[1]
-        routed = moe_impl == "routed"
-        self.moe_form = routed_moe_form(pairs, held) if routed else "dense"
-        self.moe_expert_reads_per_layer = min(pairs, held) if routed else held
-        self._dispatch_fields = {"moe_form": self.moe_form}
-
         from fms_fsdp_tpu.serve.kv_cache import PagedKVCache
 
         self.page_size, self.max_pages, num_pages = page_geometry(cfg, scfg)
@@ -181,13 +152,10 @@ class SarvamAdapter(FamilyAdapter):
             cfg, scfg, self.page_size, self.compute_dtype
         )
         gauge = self.registry.gauge
-        gauge("serve.moe_experts_held").set(held)
-        gauge("serve.moe_experts_published").set(cfg.num_experts)
         gauge("serve.latent_bytes_per_token").set(self.latent_bytes_per_token)
         # the width of the values the prefill's attention runs at: the
         # published one, nothing is padded to the keys' width
         gauge("serve.prefill_attn_value_width").set(cfg.v_head_dim)
-        self._pairs_held = 0
 
     @property
     def latent_bytes_per_token(self) -> int:
@@ -225,19 +193,4 @@ class SarvamAdapter(FamilyAdapter):
             {"latent": latent},
             None,
             prefill_positions(p, toks.shape[1]),
-        )
-
-    def _count_prefill(self, rid: int, computed: int) -> None:
-        """Beside the positions computed: the (token, choice) pairs they
-        routed, and those that landed on a held expert (the program's
-        own count; reading it waits for the prefill, which the engine's
-        sampler does next anyway; the dense form weighs every pair and
-        counts none)."""
-        cfg = self.model_cfg
-        routed = computed * cfg.top_k * cfg.n_moe_layers
-        held = int(self._pairs_held) if self.moe_impl == "routed" else 0
-        self.registry.counter("serve.moe_pairs_routed").add(routed)
-        self.registry.counter("serve.moe_pairs_held").add(held)
-        super()._count_prefill(
-            rid, computed, moe_pairs_routed=routed, moe_pairs_held=held
         )

@@ -383,3 +383,117 @@ def test_sarvam_programs_keep_one_latent_pool_and_no_dense_expert_array(
                         and s[-1] in (2048, 4096) and s[-3] >= c), s
     # the chunk's own are there: its pairs' rows, sorted by expert
     assert f"bf16[{c * cfg.top_k},{cfg.emb_dim}]" in ptext
+
+
+def test_kexaone_programs_fit_beside_a_cache_of_each_kind_at_eight_layers(
+    v5e, monkeypatch
+):
+    """The decode program (32 slots) and the prefill program of 16384
+    tokens of the kexaone cell at published widths, layers 0-7 (two
+    periods LLLG, the first layer dense), 16 of 128 experts held,
+    bfloat16: the compile that decides 8 layers or 5. Recorded (PR 33):
+    decode peak 13.78 GB with 0.007 GB of temporaries beside 11.96 GB of
+    weights, the 1.60 GB pools of the **two full layers alone** and 0.10
+    GB of rings for the six window layers, all donated and written in
+    place; prefill peak 12.92 GB with 0.98 GB of temporaries, 14.6 GB
+    beside pools and rings of the 16.9 the compiler has: 8 layers are
+    kept. With the products of W_q asked for by head (the reshape in
+    front of the QK-norm's sum) the compiler laid W_q out by head first:
+    a transposed copy of 100 MB a layer in every decode step, 0.61 GB of
+    temporaries there and 2.46 GB in the prefill (16.0 GB beside the
+    pools): models/kexaone.py::_qkv ends the products before the
+    reshape. No operand is a layer's slice of a pool, no weight is
+    copied, and nothing holds keys or values for a whole context in a
+    window layer."""
+    import json
+    import re
+
+    from fms_fsdp_tpu.models.kexaone import init_kexaone_params, prefill_chunk
+    from fms_fsdp_tpu.serve.engine import ServeConfig
+    from fms_fsdp_tpu.serve.families import load_model_config
+    from fms_fsdp_tpu.serve.families.kexaone import (
+        cache_bytes,
+        decode_program,
+        page_geometry,
+        prefill_program,
+        ring_shape,
+    )
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    here = os.path.dirname(os.path.abspath(__file__))
+    with open(os.path.join(
+            here, "..", "benchmark", "configs",
+            "k-exaone-236b.1chip.json")) as f:
+        cfg = load_model_config(json.load(f))
+    with open(os.path.join(
+            here, "..", "benchmark", "workloads",
+            "k-exaone-236b.serve-mixed-over.json")) as f:
+        scfg = ServeConfig(**json.load(f)["engine"])
+    assert (scfg.max_batch, scfg.prefill_bucket) == (32, 2048)
+    assert cfg.nlayers == 8 and cfg.held == (0, 16)
+    bf16 = jnp.bfloat16
+    params = jax.tree.map(
+        lambda a: _sds(v5e, a.shape, a.dtype),
+        jax.eval_shape(
+            lambda k: init_kexaone_params(k, cfg, bf16), jax.random.PRNGKey(0)
+        ),
+    )
+    page, block_kv, max_pages, num_pages = page_geometry(cfg, scfg)
+    assert (page, block_kv) == (128, 512)
+    cost = cache_bytes(cfg, bf16)
+    assert cost == {"per_token": 8192, "per_stream": 3145728}
+    pool = (2, num_pages, page, 8, 128)  # the full layers alone
+    pool_bytes = num_pages * page * cost["per_token"]
+    ring = ring_shape(cfg, scfg)
+    assert ring == (6, 32, 128, 8, 128)  # whatever max_seq_len is
+    ring_bytes = 32 * cost["per_stream"]
+    B, top = 32, 16384
+    decode = decode_program(cfg, scfg, page, block_kv, bf16).lower(
+        params, {k: _sds(v5e, ring, bf16) for k in ("k", "v")},
+        {k: _sds(v5e, pool, bf16) for k in ("k", "v")},
+        _sds(v5e, (B, max_pages), jnp.int32), _sds(v5e, (B,), jnp.int32),
+        _sds(v5e, (B,), jnp.int32), _sds(v5e, (2,), jnp.uint32),
+    ).compile()
+    prefill = prefill_program(cfg, scfg, top, top, bf16).lower(
+        params, _sds(v5e, (1, top), jnp.int32), _sds(v5e, (1,), jnp.int32)
+    ).compile()
+    dm, pm = decode.memory_analysis(), prefill.memory_analysis()
+    hbm = 15.75 * 2**30
+    weights = cfg.n_params() * 2
+    assert dm.temp_size_in_bytes < 0.05e9
+    assert dm.peak_memory_in_bytes < weights + pool_bytes + ring_bytes + 0.2e9
+    assert dm.peak_memory_in_bytes < hbm
+    assert pm.temp_size_in_bytes < 1.1e9 < 2.46e9
+    assert pm.peak_memory_in_bytes < 13.0e9
+    assert pm.peak_memory_in_bytes + pool_bytes + ring_bytes < hbm - 1.5e9
+    dtext, ptext = decode.as_text(), prefill.as_text()
+    assert dtext.startswith("HloModule jit__step,")
+    assert ptext.startswith(f"HloModule jit__prefill_{top},")
+    # pools and rings are arguments aliased to results, never copied
+    for shape in (pool, ring):
+        dims = ",".join(map(str, shape))
+        assert f"bf16[{dims}]" in dtext
+        assert not re.search(r"= bf16\[%s\]\S* copy\(" % dims, dtext)
+    # the ragged paged kernel once a full layer; in the prefill a windowed
+    # flash call a window layer, two flash calls a full layer (the chunk's
+    # own block, the walk over earlier ones), three grouped matmuls a
+    # sparse layer
+    assert dtext.count("tpu_custom_call") == 2
+    assert ptext.count("tpu_custom_call") == 6 + 2 * 2 + 3 * 7
+    c = prefill_chunk(top)
+    for text in (dtext, ptext):
+        # no weight laid out again
+        assert not re.search(
+            r"= bf16\[(8192,6144|6144,8192|1024,6144)\]\S* copy\(", text)
+        for dims in set(re.findall(r"\b(?:f32|bf16)\[([\d,]+)\]", text)):
+            s = tuple(int(d) for d in dims.split(","))
+            n = 1
+            for d in s:
+                n *= d
+            # dense over experts: (tokens, experts, width)
+            assert not (len(s) >= 3 and s[-2] in (16, 128)
+                        and s[-1] in (2048, 6144) and s[-3] >= c), s
+    # the prompt's keys and values for the pages: the two full layers'
+    assert f"bf16[2,1,{top},8,128]" in ptext
+    assert f"bf16[6,1,{top},8,128]" not in ptext
+    assert "bf16[6,1,128,8,128]" in ptext  # the rings handed over

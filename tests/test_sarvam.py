@@ -14,6 +14,7 @@ import pytest
 from benchmark import weights
 from benchmark.reference import sarvam as reference
 from fms_fsdp_tpu.models import mixtral as X
+from fms_fsdp_tpu.models import moe_held as H
 from fms_fsdp_tpu.models import sarvam as M
 from fms_fsdp_tpu.models.configs import SarvamConfig, sarvam_config
 from fms_fsdp_tpu.ops.rope import yarn_mscale, yarn_rope_table
@@ -153,7 +154,7 @@ def test_router_is_sigmoid_biased_for_the_choice_alone_and_sums_to_the_scale():
     cfg = sarvam_config(TINY)
     layer = jax.tree.map(lambda a: a[0], _tree(TINY)["layers"])
     h = jax.random.normal(jax.random.PRNGKey(1), (1, 64, 64))
-    idx, w = M._router(h, layer, cfg)
+    idx, w = H._router(h, layer, cfg)
     np.testing.assert_allclose(np.asarray(w.sum(-1)), 2.5, rtol=1e-5)
     scores = np.asarray(jax.nn.sigmoid(h @ layer["gate"]))
     picked = np.take_along_axis(scores, np.asarray(idx), -1)
@@ -164,7 +165,7 @@ def test_router_is_sigmoid_biased_for_the_choice_alone_and_sums_to_the_scale():
     assert np.abs(scores.sum(-1) - 1).min() > 0.5
     # the bias changes who is chosen
     flat = dict(layer, gate_bias=jnp.zeros_like(layer["gate_bias"]))
-    idx0, _ = M._router(h, flat, cfg)
+    idx0, _ = H._router(h, flat, cfg)
     moved = np.mean(np.sort(np.asarray(idx), -1) != np.sort(np.asarray(idx0), -1))
     print("choices the bias moved:", moved)
     assert 0.02 < moved < 0.5
@@ -212,7 +213,7 @@ def test_absorbed_attention_equals_expanded():
 
 def _moe_layer_by_token_loop(h, layer, cfg):
     """The held experts' part, token by token and choice by choice."""
-    idx, w = M._router(h[None], layer, cfg)
+    idx, w = H._router(h[None], layer, cfg)
     idx, w = np.asarray(idx[0]), np.asarray(w[0])
     first, held = cfg.held
     out = np.zeros(h.shape, np.float64)
@@ -237,7 +238,7 @@ def test_routed_prefill_equals_the_token_loop_under_skewed_routing(c):
     h = jax.random.normal(jax.random.PRNGKey(4), (96, 64))
     y, n = jax.jit(lambda h, l: M._moe_grouped(h, l, cfg))(h, layer)
     want, pairs = _moe_layer_by_token_loop(h, layer, cfg)
-    idx, _ = M._router(h[None], layer, cfg)
+    idx, _ = H._router(h[None], layer, cfg)
     counts = np.bincount(np.asarray(idx).ravel(), minlength=16)
     assert counts[5] == 96 and counts[6] == 0  # skewed indeed
     assert int(n) == pairs
