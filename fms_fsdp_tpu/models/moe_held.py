@@ -21,7 +21,10 @@ Three forms of the held part: over a decode step's rows the two of
 models/mixtral.py (``routed_moe_form``: every held expert streamed once,
 or one product a routed pair); over a prompt's chunk the (token, choice)
 pairs that land on held experts sorted by expert and multiplied group by
-group (``_moe_grouped``, the megablox grouped matmul), none dropped.
+group (``_moe_grouped``, the megablox grouped matmul), none dropped, and
+only their rows moved: a slab of the sorted pairs at a time
+(``grouped_slab``), one trip unless the routing is skewed onto the
+experts held.
 """
 
 import itertools
@@ -117,6 +120,10 @@ def _moe_token(h, layer, cfg, moe_impl: str):
         return jnp.einsum("bmkd,bmk->bmd", out, wt)
 
 
+# rows of a grouped product's tile: a slab is a whole number of them
+_ROW_TILE = 256
+
+
 def _gmm(x, stack, sizes, l):
     """Rows of ``x`` (M, k), sorted by group, times their group's matrix
     in layer ``l`` of ``stack`` (L, G, k, n): the megablox grouped
@@ -124,7 +131,9 @@ def _gmm(x, stack, sizes, l):
     groups of which only layer ``l``'s have rows, so no layer's slice of
     it is ever copied out (a sliced operand is: 1.6 GB a layer and
     chunk at the published widths). Its grid follows ``sum(sizes)``:
-    rows past that are not visited and hold whatever the buffer held."""
+    rows past that are not visited and hold whatever the buffer held,
+    NaN included; the caller zeroes them before any product reads
+    them."""
     from jax.experimental.pallas.ops.tpu.megablox import gmm
 
     M, k = x.shape
@@ -136,11 +145,21 @@ def _gmm(x, stack, sizes, l):
     # (PERF.md, PR 31): (512, 1024, 1024) 1.91 / 1.95 ms up / down,
     # (256, 1024, 1024) 1.45 / 1.49, (128, 1024, 1024) 1.67 / 1.70,
     # (256, 2048, 1024) 1.44 / 1.26
-    tiling = (largest_divisor(M, 256), min(k, 2048), min(n, 1024))
+    tiling = (largest_divisor(M, _ROW_TILE), min(k, 2048), min(n, 1024))
     return gmm(
         x, stack.reshape(L * G, k, n), sizes, preferred_element_type=x.dtype,
         tiling=tiling, interpret=interpret_default(),
     )
+
+
+def grouped_slab(cfg, pairs: int) -> int:
+    """Rows one trip of ``_moe_grouped``'s loop takes of a chunk's
+    ``pairs`` (token, choice) pairs: one and a half times the share that
+    lands on the experts held when the router is balanced, in whole row
+    tiles, at most all of them (a layer that holds every expert takes
+    them in one trip)."""
+    want = -(-3 * pairs * cfg.held[1] // (2 * cfg.num_experts))
+    return min(pairs, -(-want // _ROW_TILE) * _ROW_TILE)
 
 
 def _moe_grouped(h, layer, cfg, experts=None, l=0):
@@ -149,31 +168,77 @@ def _moe_grouped(h, layer, cfg, experts=None, l=0):
     group through its expert, no pair dropped. ``experts``: the MoE
     layers' stacked ``w1``/``w3``/``w2`` (L, held, ...) with ``l`` the
     layer's index in them (``layer``'s own, as a stack of one, when
-    None). Returns (y (T, D), the number of pairs that landed on held
-    experts)."""
+    None).
+
+    Only the pairs that landed here are moved: the sorted pairs are
+    taken ``grouped_slab`` rows at a time, in a loop whose trip count
+    ``ceil(landed / slab)`` is read on the device (one trip unless the
+    routing is skewed onto the experts held, none when nothing landed).
+    A trip gathers its rows of ``h``, multiplies them group by group
+    with the slab's own group sizes, and adds each row's weighted result
+    to its token: one product of a (T, slab) matrix that holds each
+    pair's weight in its token's row with the slab's results, on the
+    MXU, products of the operands as they are summed in float32.
+
+    Returns (y (T, D), the number of pairs that landed on held experts,
+    the loop's trips)."""
     if experts is None:
         experts = {name: layer[name][None] for name in ("w1", "w3", "w2")}
     idx, w = _router(h, layer, cfg)  # (T, K)
     T, K = idx.shape
     first, held = cfg.held
+    slab = grouped_slab(cfg, T * K)
     with jax.named_scope("moe_group"):
         local = idx.reshape(T * K) - first
         here = (local >= 0) & (local < held)
         # pairs on experts that are not held sort behind every group
         key = jnp.where(here, local, held)
-        order = jnp.argsort(key, stable=True)
-        sizes = jnp.bincount(key, length=held + 1)[:held].astype(jnp.int32)
-        n_here = jnp.sum(sizes)
-        xs = h[order // K]
-    with jax.named_scope("moe_experts"):
-        hid = jax.nn.silu(_gmm(xs, experts["w1"], sizes, l)) * _gmm(
-            xs, experts["w3"], sizes, l
+        order = jnp.argsort(key, stable=True).astype(jnp.int32)
+        # whole slabs, so that the last one's slice starts where it says
+        order = jnp.pad(order, (0, -(T * K) % slab))
+        sizes = jnp.sum(
+            key[:, None] == jnp.arange(held), axis=0, dtype=jnp.int32
         )
-        out = _gmm(hid, experts["w2"], sizes, l)
-    with jax.named_scope("moe_combine"):
-        out = jnp.where(
-            jnp.arange(T * K)[:, None] < n_here, out, jnp.zeros_like(out)
-        )
-        back = out[jnp.argsort(order)].reshape(T, K, -1)
-        wt = jnp.where(here.reshape(T, K), w, 0.0).astype(h.dtype)
-        return jnp.einsum("tkd,tk->td", back, wt), n_here
+        ends = jnp.cumsum(sizes)
+        starts, n_here = ends - sizes, ends[-1]
+        trips = (n_here + slab - 1) // slab
+        weights = w.reshape(T * K)
+
+    def trip(s, y):
+        lo = s * slab
+        with jax.named_scope("moe_group"):
+            pairs = lax.dynamic_slice_in_dim(order, lo, slab)
+            # of each group, the rows that lie in this slab
+            mine = jnp.clip(
+                jnp.minimum(ends, lo + slab) - jnp.maximum(starts, lo), 0
+            )
+            valid = jnp.arange(slab) < n_here - lo
+            token = pairs // K
+            xs = h[token]
+        with jax.named_scope("moe_experts"):
+            hid = jax.nn.silu(_gmm(xs, experts["w1"], mine, l)) * _gmm(
+                xs, experts["w3"], mine, l
+            )
+            out = _gmm(hid, experts["w2"], mine, l)
+        with jax.named_scope("moe_combine"):
+            # timed on the chip at the published widths, 2048 tokens, a
+            # slab of 3072 x 6144 / 6144 x 4096 (PERF.md, PR 35): this
+            # product 0.45 / 0.60 ms, a float32 scatter-add by token
+            # 1.10 / 1.19, a Pallas kernel adding rows by a prefetched
+            # table 0.32 / 0.38 alone but 2% of the layer when in it;
+            # un-sorting all 16384 rows for an einsum took 2.46 / 1.66
+            out = jnp.where(valid[:, None], out, jnp.zeros_like(out))
+            # a row past the valid count is zero now: its weight is moot
+            wt = weights[pairs].astype(h.dtype)
+            place = jnp.where(
+                token[None, :] == jnp.arange(T)[:, None], wt[None, :],
+                jnp.zeros((), h.dtype),
+            )
+            return y + jnp.dot(
+                place, out, preferred_element_type=jnp.float32
+            )
+
+    y = lax.fori_loop(
+        0, trips, trip, jnp.zeros((T, h.shape[-1]), jnp.float32)
+    )
+    return y.astype(h.dtype), n_here, trips

@@ -426,10 +426,12 @@ def sarvam_prefill(
     (``_moe_grouped``); ``"dense"`` runs every held expert over every
     row (the parity form).
 
-    Returns (logits (B, V) of each row's last real position, the latent
+    Returns (logits (B, V) of each row's last real position; the latent
     (L, B, kv_len, pool_width), zero past each row's length, for the
-    pages, and the number of (token, choice) pairs of the positions
-    computed that landed on held experts, summed over the MoE layers)."""
+    pages; the number of (token, choice) pairs of the positions computed
+    that landed on held experts, summed over the MoE layers; and the
+    trips the grouped product's loop took for them, one a layer and
+    chunk unless its pairs overran a slab)."""
     with jax.named_scope("params_cast"):
         params = jax.tree.map(lambda a: a.astype(compute_dtype), params)
     B, S = tokens.shape
@@ -448,7 +450,7 @@ def sarvam_prefill(
     }
 
     def chunk(j, carry):
-        lat, last, pairs = carry
+        lat, last, pairs, slabs = carry
         start = j * c
         ahead = lengths - start  # of each row, from this chunk's start on
         live = jnp.arange(c, dtype=jnp.int32)[None, :] < ahead[:, None]
@@ -481,23 +483,25 @@ def sarvam_prefill(
             x = x + _mlp(h2, layer)
 
         def body(carry, inp):
-            x, lat, pairs = carry
+            x, lat, pairs, slabs = carry
             layer, i = inp
             x, lat, h2 = attend(x, lat, layer, Ld + i)
             if moe_impl == "routed":
-                y, n = _moe_grouped(
+                y, n, trips = _moe_grouped(
                     h2.reshape(B * c, -1), layer, cfg, experts, i
                 )
-                y, pairs = y.reshape(B, c, -1), pairs + n
+                y, pairs, slabs = y.reshape(B, c, -1), pairs + n, slabs + trips
             else:
                 mine = {name: stack[i] for name, stack in experts.items()}
                 y = _moe_dense_held(h2, dict(layer, **mine), cfg)
             with jax.named_scope("moe_combine"):
-                return (x + y + _shared(h2, layer), lat, pairs), None
+                return (x + y + _shared(h2, layer), lat, pairs, slabs), None
 
         with jax.named_scope("layers"):
-            (x, lat, pairs), _ = lax.scan(
-                body, (x, lat, pairs), (rest, jnp.arange(cfg.n_moe_layers))
+            (x, lat, pairs, slabs), _ = lax.scan(
+                body,
+                (x, lat, pairs, slabs),
+                (rest, jnp.arange(cfg.n_moe_layers)),
             )
         # the head reads a row's last real position alone
         at = ahead - 1
@@ -505,9 +509,9 @@ def sarvam_prefill(
             x, jnp.clip(at, 0, c - 1)[:, None, None], axis=1
         )[:, 0]
         last = jnp.where(((at >= 0) & (at < c))[:, None], row, last)
-        return lat, last, pairs
+        return lat, last, pairs, slabs
 
-    lat, last, pairs = lax.fori_loop(
+    lat, last, pairs, slabs = lax.fori_loop(
         0,
         (jnp.max(lengths) + c - 1) // c,
         chunk,
@@ -515,11 +519,12 @@ def sarvam_prefill(
             jnp.zeros((cfg.nlayers, B, kv_len, pool_width(cfg)), compute_dtype),
             jnp.zeros((B, cfg.emb_dim), compute_dtype),
             jnp.zeros((), jnp.int32),
+            jnp.zeros((), jnp.int32),
         ),
     )
     with jax.named_scope("lm_head"):
         logits = _norm(last, params["norm"], cfg) @ params["lm_head"]
-    return logits, lat, pairs
+    return logits, lat, pairs, slabs
 
 
 # ---------------------------------------------------------------------------

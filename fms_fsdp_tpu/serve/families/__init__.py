@@ -804,12 +804,14 @@ class HeldExpertsAdapter(FamilyAdapter):
     share (sarvam, kexaone): the ``moe_impl`` rule, which loop the decode
     program runs over the held experts (``moe_form``, the gauge
     ``serve.moe_expert_reads_per_layer``), the gauges of the share, and
-    the count of the (token, choice) pairs a prefill routed and of those
-    that landed on a held expert. ``model_cfg`` has ``top_k``, ``held``,
+    the count of the (token, choice) pairs a prefill routed, of those
+    that landed on a held expert and of the trips the grouped product's
+    loop took for them. ``model_cfg`` has ``top_k``, ``held``,
     ``num_experts`` and ``n_moe_layers``; the family's ``_call_prefill``
-    leaves the prefill program's own count in ``self._pairs_held``."""
+    leaves the prefill program's own counts (pairs held, trips) in
+    ``self._moe_counts``."""
 
-    _pairs_held = 0  # on the device until the count is read
+    _moe_counts = (0, 0)  # on the device until the counts are read
 
     def _init_held_experts(self) -> None:
         from fms_fsdp_tpu.models.mixtral import routed_moe_form
@@ -848,17 +850,25 @@ class HeldExpertsAdapter(FamilyAdapter):
 
     def _count_prefill(self, rid: int, computed: int) -> None:
         """Beside the positions computed: the (token, choice) pairs they
-        routed, and those that landed on a held expert (the program's
-        own count; reading it waits for the prefill, which the engine's
-        sampler does next anyway; the dense form weighs every pair and
-        counts none)."""
+        routed, those that landed on a held expert, and the trips the
+        grouped product's loop took for them: one a MoE layer and chunk
+        where the landed pairs fit a slab
+        (models/moe_held.py::grouped_slab), more where the routing was
+        skewed onto the experts held (the program's own counts; reading
+        them waits for the prefill, which the engine's sampler does next
+        anyway; the dense form weighs every pair, counts none and takes
+        no trip)."""
         cfg = self.model_cfg
         routed = computed * cfg.top_k * cfg.n_moe_layers
-        held = int(self._pairs_held) if self.moe_impl == "routed" else 0
+        held, slabs = (
+            map(int, self._moe_counts) if self.moe_impl == "routed" else (0, 0)
+        )
         self.registry.counter("serve.moe_pairs_routed").add(routed)
         self.registry.counter("serve.moe_pairs_held").add(held)
+        self.registry.counter("serve.moe_slabs").add(slabs)
         super()._count_prefill(
-            rid, computed, moe_pairs_routed=routed, moe_pairs_held=held
+            rid, computed, moe_pairs_routed=routed, moe_pairs_held=held,
+            moe_slabs=slabs,
         )
 
 

@@ -38,8 +38,9 @@ in a loop inside its bucket's program that stops at the prompt's length
 flash kernel over the chunk's band and carries its last
 ``sliding_window`` positions to the next chunk, a full layer walks its
 earlier blocks; ``attn_form`` on ``prefill.dispatch`` says which forms a
-program runs. The pairs that landed on held experts are counted as the
-sarvam adapter counts them (``serve.moe_pairs_held`` / ``_routed``).
+program runs. The pairs that landed on held experts and the grouped
+product's trips are counted as the sarvam adapter counts them
+(``serve.moe_pairs_held`` / ``_routed``, ``serve.moe_slabs``).
 
 Not here yet (PERF.md section 7): a serving layout over chips (the expert
 layer's exchange), handoff of rings and pages, quantized pages,
@@ -252,8 +253,8 @@ class KExaoneAdapter(HeldExpertsAdapter):
         return {"attn_form": form}
 
     def _call_prefill(self, fn, toks, p: int):
-        logits, kv, ring, pairs = fn(
+        logits, kv, ring, pairs, slabs = fn(
             self.params, jnp.asarray(toks), jnp.asarray([p], np.int32)
         )
-        self._pairs_held = pairs  # on the device until the count is read
+        self._moe_counts = (pairs, slabs)  # on the device until read
         return logits[0], kv, ring, prefill_positions(p, toks.shape[1])

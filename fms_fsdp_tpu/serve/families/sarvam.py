@@ -28,8 +28,9 @@ the expanded form, keys 192 and values 128 wide as published, made from
 each latent block as it is met (``attn_form`` on ``prefill.dispatch``
 says which form a program runs); the experts routed and grouped, no pair
 on a held expert dropped. Beside the first token's logits and the latent
-for the pages the program returns the pairs that landed on held experts:
-``serve.moe_pairs_held`` / ``_routed``, both on ``serve/prefill.done``.
+for the pages the program returns the pairs that landed on held experts
+and the trips its grouped product took for them: ``serve.moe_pairs_held``
+/ ``_routed`` and ``serve.moe_slabs``, all on ``serve/prefill.done``.
 
 Not here yet (PERF.md section 7): a serving layout over chips (the
 expert layer's exchange), handoff of latent pages, quantized latent
@@ -184,10 +185,10 @@ class SarvamAdapter(HeldExpertsAdapter):
         return {"attn_form": form}
 
     def _call_prefill(self, fn, toks, p: int):
-        logits, latent, pairs = fn(
+        logits, latent, pairs, slabs = fn(
             self.params, jnp.asarray(toks), jnp.asarray([p], np.int32)
         )
-        self._pairs_held = pairs  # on the device until the count is read
+        self._moe_counts = (pairs, slabs)  # on the device until read
         return (
             logits[0],
             {"latent": latent},

@@ -280,6 +280,20 @@ def test_hybrid_prefill_of_the_longest_bucket_is_one_loop_over_chunks(
     assert f"[1,{c},{cfg.d_inner}]" in text  # the chunk's are there
 
 
+def _moe_row_counts(text, width):
+    """Leading sizes of the arrays ``width`` wide that an instruction
+    under a ``moe_*`` scope of a compiled prefill program makes or
+    takes: the rows moved around the grouped product."""
+    import re
+
+    rows = set()
+    for line in text.splitlines():
+        if re.search(r'op_name="[^"]*/moe_(group|experts|combine)/', line):
+            rows.update(
+                int(n) for n in re.findall(r"\[(\d+),%d\]" % width, line))
+    return rows
+
+
 def test_sarvam_programs_keep_one_latent_pool_and_no_dense_expert_array(
     v5e, monkeypatch
 ):
@@ -294,12 +308,15 @@ def test_sarvam_programs_keep_one_latent_pool_and_no_dense_expert_array(
     peak 12.18 GB with 1.24 GB of temporaries since PR 32 (the flash
     kernel at the published widths, keys 192 and values 128; padded to
     256 the same compile gives 12.28 and 1.37; the pool stands beside
-    it: 14.58 GB of the 16.9). Neither holds keys or values expanded for
+    it: 14.58 GB of the 16.9), 1.25 GB since PR 35 (a slab of the sorted
+    pairs' rows around the grouped product where all 16384 pairs' were).
+    Neither holds keys or values expanded for
     a whole cache, nor an array of (tokens, experts, width), and no
     operand of the flash call is 256 wide."""
     import json
     import re
 
+    from fms_fsdp_tpu.models.moe_held import grouped_slab
     from fms_fsdp_tpu.models.sarvam import (
         init_sarvam_params,
         pool_width,
@@ -381,8 +398,17 @@ def test_sarvam_programs_keep_one_latent_pool_and_no_dense_expert_array(
             # dense over experts: (tokens, experts, width)
             assert not (len(s) >= 3 and s[-2] in (32, 128)
                         and s[-1] in (2048, 4096) and s[-3] >= c), s
-    # the chunk's own are there: its pairs' rows, sorted by expert
-    assert f"bf16[{c * cfg.top_k},{cfg.emb_dim}]" in ptext
+    # around the grouped product the rows of one slab of the chunk's
+    # sorted pairs (models/moe_held.py::grouped_slab: 1.5 x 32 / 128 of
+    # them), never those of all its 16384 (token, choice) pairs (PR 35:
+    # five arrays of 134 MB a layer and chunk went; the program's
+    # temporaries read 1.254 GB where they read 1.237)
+    slab = grouped_slab(cfg, c * cfg.top_k)
+    assert (slab, c * cfg.top_k) == (6144, 16384)
+    for width in (cfg.emb_dim, 2048):
+        rows = _moe_row_counts(ptext, width)
+        assert slab in rows and c * cfg.top_k not in rows, (width, rows)
+    assert pm.temp_size_in_bytes < 1.26e9
 
 
 def test_kexaone_programs_fit_beside_a_cache_of_each_kind_at_eight_layers(
@@ -397,8 +423,10 @@ def test_kexaone_programs_fit_beside_a_cache_of_each_kind_at_eight_layers(
     GB of rings for the six window layers, all donated and written in
     place; prefill peak 12.92 GB with 0.98 GB of temporaries, 14.6 GB
     beside pools and rings of the 16.9 the compiler has: 8 layers are
-    kept. With the products of W_q asked for by head (the reshape in
-    front of the QK-norm's sum) the compiler laid W_q out by head first:
+    kept (since PR 35 12.60 and 0.75 GB: a slab of the sorted pairs'
+    rows around the grouped product where all 16384 pairs' were). With
+    the products of W_q asked for by head (the reshape in front of the
+    QK-norm's sum) the compiler laid W_q out by head first:
     a transposed copy of 100 MB a layer in every decode step, 0.61 GB of
     temporaries there and 2.46 GB in the prefill (16.0 GB beside the
     pools): models/kexaone.py::_qkv ends the products before the
@@ -409,6 +437,7 @@ def test_kexaone_programs_fit_beside_a_cache_of_each_kind_at_eight_layers(
     import re
 
     from fms_fsdp_tpu.models.kexaone import init_kexaone_params, prefill_chunk
+    from fms_fsdp_tpu.models.moe_held import grouped_slab
     from fms_fsdp_tpu.serve.engine import ServeConfig
     from fms_fsdp_tpu.serve.families import load_model_config
     from fms_fsdp_tpu.serve.families.kexaone import (
@@ -493,6 +522,17 @@ def test_kexaone_programs_fit_beside_a_cache_of_each_kind_at_eight_layers(
             # dense over experts: (tokens, experts, width)
             assert not (len(s) >= 3 and s[-2] in (16, 128)
                         and s[-1] in (2048, 6144) and s[-3] >= c), s
+    # around the grouped product the rows of one slab of the chunk's
+    # sorted pairs (1.5 x 16 / 128 of them), never those of all its 16384
+    # (token, choice) pairs (PR 35: five arrays of 201 MB a layer and
+    # chunk went, and with them 0.23 GB of the program's temporaries)
+    slab = grouped_slab(cfg, c * cfg.top_k)
+    assert (slab, c * cfg.top_k) == (3072, 16384)
+    for width in (cfg.emb_dim, 2048):
+        rows = _moe_row_counts(ptext, width)
+        assert slab in rows and c * cfg.top_k not in rows, (width, rows)
+    assert pm.temp_size_in_bytes < 0.76e9 < 0.98e9
+    assert pm.peak_memory_in_bytes < 12.7e9
     # the prompt's keys and values for the pages: the two full layers'
     assert f"bf16[2,1,{top},8,128]" in ptext
     assert f"bf16[6,1,{top},8,128]" not in ptext

@@ -180,7 +180,7 @@ def test_routing_skewed_onto_one_held_expert_drops_no_pair(c):
     layer = dict(layer, gate_bias=bias)
     h = jax.random.normal(jax.random.PRNGKey(2), (40, 64))
     want = _moe_layer_by_token_loop(h, layer, cfg)
-    y, n = H._moe_grouped(h, layer, cfg)
+    y, n, _ = H._moe_grouped(h, layer, cfg)
     idx, _ = H._router(h, layer, cfg)
     here = (idx >= cfg.held[0]) & (idx < sum(cfg.held))
     assert int(n) == int(here.sum()) >= 40  # every row's pair on it counted
@@ -212,7 +212,7 @@ def test_the_eight_shares_add_up_to_the_uncut_layer():
             w: layer[w][first:first + 2] for w in ("w1", "w3", "w2")})
         assert cfg.held == (first, 2) and cfg.num_experts == 16
         sums["dense"] += H._moe_dense_held(h, part, cfg)
-        y, n = H._moe_grouped(h[0], part, cfg)
+        y, n, _ = H._moe_grouped(h[0], part, cfg)
         sums["grouped"] += y[None]
         n_pairs += int(n)
         sums["token"] += H._moe_token(
@@ -223,6 +223,61 @@ def test_the_eight_shares_add_up_to_the_uncut_layer():
     for form, y in sums.items():
         np.testing.assert_allclose(
             np.asarray(y + shared), np.asarray(want), atol=3e-5, err_msg=form)
+
+
+# 448 rows x 4 choices = 1792 pairs; a quarter of the experts is here, so
+# a slab is 1.5 x a quarter of them in whole row tiles: 768, and three
+# trips (the last one short, past the sorted pairs' end) take them all
+SLAB_ROWS, SLAB = 448, 768
+
+
+@pytest.mark.parametrize("poisoned", [False, True], ids=["clean", "nan-past"])
+@pytest.mark.parametrize(
+    "landed", [0, SLAB, SLAB + 1, SLAB_ROWS * 4],
+    ids=["none", "one-slab", "one-slab-and-a-pair", "all"])
+def test_grouped_prefill_takes_the_landed_pairs_a_slab_at_a_time(
+        landed, poisoned, monkeypatch):
+    """The first ``landed`` (token, choice) pairs of a chunk on the four
+    held experts, the rest elsewhere: ``_moe_grouped`` equals the token
+    loop, counts them, and takes ``ceil(landed / slab)`` trips. With every
+    row that a grouped product did not visit poisoned, the result is the
+    same to the bit: a slab's rows past its valid count are zeroed before
+    any product reads them."""
+    cfg, tree = kexaone_config(SHARE), _tree(SHARE)
+    layer = jax.tree.map(lambda a: a[1], tree["sliding_sparse"])
+    first, held = cfg.held
+    T, K = SLAB_ROWS, cfg.top_k
+    assert H.grouped_slab(cfg, T * K) == SLAB
+    assert H.grouped_slab(kexaone_config(TINY), T * K) == T * K  # all here
+    rng = np.random.default_rng(landed)
+    # choice k of a row: held expert k where the pair lands here (a row's
+    # experts stay distinct), else one of the others
+    here = (np.arange(T * K) < landed).reshape(T, K)
+    away = (first + held + rng.integers(0, 16 - held, (T, K))) % 16
+    idx = np.where(here, first + np.arange(K)[None, :], away)
+    w = rng.uniform(0.1, 1.0, (T, K)).astype(np.float32)
+    monkeypatch.setattr(
+        H, "_router", lambda h, layer, cfg: (jnp.asarray(idx), jnp.asarray(w)))
+    h = jax.random.normal(jax.random.PRNGKey(landed), (T, 64))
+    want = np.zeros((T, 64), np.float32)
+    for t, k in zip(*np.nonzero(here)):
+        want[t] += w[t, k] * np.asarray(H._swiglu(
+            h[t], layer["w1"][k], layer["w3"][k], layer["w2"][k]))
+
+    run = jax.jit(lambda h: H._moe_grouped(h, layer, cfg))
+    y, n, trips = run(h)
+    assert (int(n), int(trips)) == (landed, -(-landed // SLAB))
+    np.testing.assert_allclose(np.asarray(y), want, atol=2e-5)
+    if poisoned:
+        gmm = H._gmm
+
+        def poisoned_gmm(x, stack, sizes, l):
+            visited = jnp.arange(x.shape[0])[:, None] < jnp.sum(sizes)
+            return jnp.where(visited, gmm(x, stack, sizes, l), jnp.nan)
+
+        monkeypatch.setattr(H, "_gmm", poisoned_gmm)
+        again = jax.jit(lambda h: H._moe_grouped(h, layer, cfg))(h)
+        np.testing.assert_array_equal(np.asarray(again[0]), np.asarray(y))
 
 
 # ---------------------------------------------------------------------------
@@ -358,7 +413,7 @@ def test_prefill_in_chunks_is_the_forward(
         toks[i, :n] = rng.integers(1, 256, size=n)
     assert M.prefill_attn_form(cfg, attn_impl, S) == (
         "einsum" if attn_impl == "xla" else "flash_window+flash")
-    logits, kv, ring, pairs = jax.jit(lambda p, t, l: M.kexaone_prefill(
+    logits, kv, ring, pairs, slabs = jax.jit(lambda p, t, l: M.kexaone_prefill(
         p, t, l, cfg, compute_dtype=jnp.float32, kv_len=S,
         attn_impl=attn_impl, moe_impl="routed"))(
         tree, jnp.asarray(toks), jnp.asarray(lengths, jnp.int32))
@@ -367,6 +422,8 @@ def test_prefill_in_chunks_is_the_forward(
     assert ring["k"].shape == (len(cfg.window_layers), len(lengths), W,
                                cfg.kvheads, cfg.head_dim)
     assert int(pairs) > 0
+    # the landed pairs of a layer and chunk fit one slab: a trip each
+    assert int(slabs) == cfg.n_moe_layers * (S // chunk)
     for i, n in enumerate(lengths):
         want = _ref_logits(tree, c, toks[i, :n].tolist())[-1]
         assert _gap(np.asarray(logits[i]), want) < 2e-5
@@ -376,7 +433,7 @@ def test_prefill_in_chunks_is_the_forward(
     # keys of the prompt's last W positions as a prefill of the prompt
     # cut to its last position but one leaves them, shifted by one
     n = lengths[0]
-    _, _, short, _ = jax.jit(lambda p, t, l: M.kexaone_prefill(
+    _, _, short, _, _ = jax.jit(lambda p, t, l: M.kexaone_prefill(
         p, t, l, cfg, compute_dtype=jnp.float32, kv_len=S,
         attn_impl="xla", moe_impl="routed"))(
         tree, jnp.asarray(toks[:1]), jnp.asarray([n - 1], jnp.int32))
@@ -424,6 +481,35 @@ def _serve_capturing(eng, prompts, max_new):
     reqs = [eng.submit(p, max_new) for p in prompts]
     eng.run()
     return reqs, [np.stack(rows[r.rid][: len(r.generated)]) for r in reqs]
+
+
+def test_a_prefill_counts_its_grouped_products_trips_beside_its_pairs(
+        tmp_path):
+    """``serve.moe_slabs`` and ``moe_slabs`` on the ``prefill.done`` span,
+    beside ``moe_pairs_held`` (``HeldExpertsAdapter._count_prefill``, as
+    for sarvam): the trips the grouped product's loop took, one a sparse
+    layer and chunk here."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    cfg = kexaone_config(SHARE4)
+    eng = _engine(_tree(SHARE4), cfg, moe_impl="routed")
+    prompt = np.random.default_rng(2).integers(1, 256, size=37).tolist()
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    with jax.profiler.trace(str(tmp_path), profiler_options=opts):
+        eng.submit(prompt, 2)
+        eng.run()
+    want = cfg.n_moe_layers * -(-len(prompt) // CHUNK)
+    count = eng.registry.counter
+    assert count("serve.moe_slabs").value == want == 3 * 3
+    (path,) = glob.glob(str(tmp_path / "plugins/profile/*/*.xplane.pb"))
+    (done,) = [dict(e.stats) for plane in ProfileData.from_file(path).planes
+               for line in plane.lines for e in line.events
+               if e.name == "serve/prefill.done"]
+    assert done["moe_slabs"] == want
+    assert done["moe_pairs_held"] == count("serve.moe_pairs_held").value > 0
 
 
 ENGINES = [(SHARE, 2, "routed", "all_experts", "reference"),
@@ -575,16 +661,20 @@ JAX_VERSION = "0.9.0"
 # programs, read off the tree before the held experts' layer moved out of
 # models/sarvam.py (PR 33's parent) on that jax, under this file's
 # highest matmul precision (at the default: bbeeb528..., 8b794284...,
-# 8e09e6bc..., 064218f2..., the same on both trees)
+# 8e09e6bc..., 064218f2..., the same on both trees). The two prefill
+# programs are PR 35's: they return the grouped product's trips beside
+# the pairs (8ae51275... and 4351de2c... until then), and the routed
+# one takes the landed pairs a slab at a time; the decode programs are
+# the text they were
 SARVAM_DIGESTS = {
     "decode all_experts":
         "3625f5e13a91ad80336aa90508c9bde832bb9ed1ad2eb868db5598fd0c49b4b8",
     "decode per_pair":
         "6a85865e42e78975aad0d4a44498f40f116872ab0de706ce105dff3abef4144c",
     "prefill routed":
-        "8ae51275df08e6400aa67849e775adb65ab2f607545f071f48f97ef515bcebaf",
+        "b5ab43b9fe1ead40a291719dc2b0c2acf8228902f565e339be2d4caa53d36351",
     "prefill dense":
-        "4351de2c3d45b8c1080265ae40c2dd65090cb5111099801764cd58dbe1149904",
+        "c284750a554dbd85c195f6426f6cf20243c4a12e4511a158de0531a9440b08cd",
 }
 
 

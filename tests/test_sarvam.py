@@ -236,7 +236,7 @@ def test_routed_prefill_equals_the_token_loop_under_skewed_routing(c):
     layer = jax.tree.map(lambda a: a[1], _tree(c)["layers"])
     layer["gate_bias"] = layer["gate_bias"].at[5].set(3.0).at[6].set(-3.0)
     h = jax.random.normal(jax.random.PRNGKey(4), (96, 64))
-    y, n = jax.jit(lambda h, l: M._moe_grouped(h, l, cfg))(h, layer)
+    y, n, _ = jax.jit(lambda h, l: M._moe_grouped(h, l, cfg))(h, layer)
     want, pairs = _moe_layer_by_token_loop(h, layer, cfg)
     idx, _ = H._router(h[None], layer, cfg)
     counts = np.bincount(np.asarray(idx).ravel(), minlength=16)
@@ -276,7 +276,7 @@ def test_the_shares_add_up_to_the_uncut_layer():
         c, cfg, part = cut(first)
         assert cfg.held == (first, 4) and cfg.num_experts == 16
         sums["dense"] += M._moe_dense_held(h, part, cfg)
-        y, n = M._moe_grouped(h[0], part, cfg)
+        y, n, _ = M._moe_grouped(h[0], part, cfg)
         sums["grouped"] += y[None]
         n_pairs += int(n)
         sums["token"] += M._moe_token(
@@ -333,7 +333,7 @@ def test_prefill_in_chunks_is_the_forward(attn_impl, c, monkeypatch):
             p, t, l, cfg, compute_dtype=jnp.float32, kv_len=S + 16,
             attn_impl=attn_impl))
     args = (tree, jnp.asarray(toks), jnp.asarray(lengths))
-    logits, lat, pairs = prefill(*args)
+    logits, lat, pairs, slabs = prefill(*args)
     form = M.prefill_attn_form(cfg, attn_impl, S)
     assert form == {"xla": "einsum"}.get(
         attn_impl, "flash" if c is SHARE else "flash_two_width")
@@ -355,6 +355,8 @@ def test_prefill_in_chunks_is_the_forward(attn_impl, c, monkeypatch):
     # every computed position routes top_k pairs a MoE layer; a quarter
     # of the experts is here
     assert 0.1 < int(pairs) / (2 * S * 4 * 2) < 0.45
+    # and the landed pairs of a layer and chunk fit one slab: a trip each
+    assert int(slabs) == 2 * 3
 
 
 # ---------------------------------------------------------------------------
@@ -451,6 +453,39 @@ def test_engine_agrees_with_the_reference_on_logits_float32(
     assert gauges("serve.latent_bytes_per_token").value == 3 * 128 * 4
     assert eng.adapter.cache.pools["latent"].shape[2:] == (8, 128)
     assert list(eng.adapter.cache.pools) == ["latent"]
+
+
+@pytest.mark.parametrize("moe_impl", ["routed", "dense"])
+def test_a_prefill_counts_its_grouped_products_trips_beside_its_pairs(
+        moe_impl, tmp_path):
+    """``serve.moe_slabs`` and ``moe_slabs`` on the ``prefill.done`` span,
+    beside ``moe_pairs_held``: the trips the grouped product's loop took,
+    one a MoE layer and chunk here (a chunk's landed pairs fit a slab);
+    the dense form takes none."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    cfg = sarvam_config(SHARE)
+    eng = _engine(_tree(SHARE), cfg, moe_impl=moe_impl)
+    prompt = np.random.default_rng(2).integers(1, 256, size=37).tolist()
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    with jax.profiler.trace(str(tmp_path), profiler_options=opts):
+        eng.submit(prompt, 2)
+        eng.run()
+    chunks = -(-len(prompt) // CHUNK)
+    want = cfg.n_moe_layers * chunks if moe_impl == "routed" else 0
+    count = eng.registry.counter
+    assert count("serve.moe_slabs").value == want
+    (path,) = glob.glob(str(tmp_path / "plugins/profile/*/*.xplane.pb"))
+    (done,) = [dict(e.stats) for plane in ProfileData.from_file(path).planes
+               for line in plane.lines for e in line.events
+               if e.name == "serve/prefill.done"]
+    assert done["moe_slabs"] == want
+    assert done["moe_pairs_held"] == count("serve.moe_pairs_held").value
+    assert (done["moe_pairs_held"] > 0) == (moe_impl == "routed")
+    assert done["moe_pairs_routed"] == chunks * CHUNK * 4 * cfg.n_moe_layers
 
 
 def test_bfloat16_serving_is_within_a_tolerance_that_float8_fails():
