@@ -52,6 +52,8 @@ path stays dispatch-then-collect inside one ``step()``: how many tokens
 a stream gains there is known only from the result.
 """
 
+import json
+import logging
 import time
 from dataclasses import dataclass
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
@@ -59,10 +61,11 @@ from typing import Callable, Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from fms_fsdp_tpu.models.generation import sample_token
 from fms_fsdp_tpu.obs.registry import MetricRegistry
-from fms_fsdp_tpu.obs.spans import done, span
+from fms_fsdp_tpu.obs.spans import ADMIT_STOPPED, SLOT, StepLog, done, span
 from fms_fsdp_tpu.serve.families import FAMILY_CODES, resolve_adapter
 from fms_fsdp_tpu.serve.scheduler import (
     FINISHED,
@@ -73,6 +76,16 @@ from fms_fsdp_tpu.serve.scheduler import (
     Request,
     RequestRejected,
 )
+
+logger = logging.getLogger("fms_fsdp_tpu.serve")
+
+# the fields of a step's record that no span carries, written here
+_BUSY_AFTER_ADMIT = SLOT["busy_after_admit"]
+_ADMIT_STOPPED = SLOT["admit_stopped"]
+_PAGES_IN_USE = SLOT["pages_in_use"]
+_HBM = (SLOT["hbm_in_use"], SLOT["hbm_largest_free"])
+# what the runtime's ``memory_stats()`` calls those two
+_HBM_KEYS = ("bytes_in_use", "largest_free_block_bytes")
 
 _DTYPES = {
     "bfloat16": jnp.bfloat16,
@@ -254,12 +267,12 @@ class ServingEngine:
         self._fresh = np.zeros((scfg.max_batch,), bool)
         self._inflight: Optional[_InFlight] = None
         self._key = jax.random.PRNGKey(seed)
-        self._decode_tokens = 0
-        self._prefill_tokens = 0
-        # host seconds inside the decode phase: a step's dispatch plus
-        # what is left of it to wait for at its collect (device time that
-        # runs behind other host work is not in it)
-        self._decode_wall = 0.0
+        # one record per step() (obs/spans.py): the spans' host times
+        # and what the engine writes beside them; ``_rec`` is the open
+        # one, None outside a step
+        self.step_log = StepLog()
+        self._rec = None
+        self._device = jax.local_devices()[0]  # whose memory a record samples
         self._finished_buf: List[Request] = []
         # handoff imports that failed typed AFTER admission (the
         # adapter freed its allocations): the replica loop drains these
@@ -542,7 +555,6 @@ class ServingEngine:
             req.first_token_time = now
             self.registry.hist("serve.ttft_s").record(now - req.submit_time)
         req.generated.append(tok)
-        self._prefill_tokens += p
         self.registry.counter("serve.prefill_tokens").add(p)
         self._slots[slot] = req
         self._admit_order.append(req)
@@ -669,43 +681,86 @@ class ServingEngine:
 
         Every phase runs under a host span (obs/spans.py: ``serve/step``
         and its children, each carrying ``step=<iterations>``), which
-        costs a flag check while no profiler session runs."""
+        also times itself into the step's record (``step_log``); a
+        profiler session, where one runs, is handed the record at the
+        end, behind the decode step in flight."""
         self.iterations += 1
         it = self.iterations
         reg = self.registry
         reg.counter("serve.steps").add()
-        with span(
-            "step",
-            step=it,
-            queued=self.scheduler.queue_depth(),
-            busy=sum(r is not None for r in self._slots),
-        ):
-            with span("expire", step=it):
-                expired = self._expire(self.clock())
-                done("expire", step=it, expired=expired)
-            with span("admit", step=it):
-                admitted = self._admit()
-                done("admit", step=it, admitted=admitted)
-            chunks = self._advance_chunks(it)
-            if admitted or chunks:
-                reg.counter("serve.steps_with_prefill").add()
-            with span("grow", step=it):
-                evicted = self._grow()
-                done("grow", step=it, evicted=evicted)
-            self._decode(it)
-            with span("publish", step=it):
-                reg.gauge("serve.queue_depth").set(
-                    self.scheduler.queue_depth()
-                )
-                reg.gauge("serve.kv_pages_in_use").set(
-                    self.adapter.pages_in_use
-                )
-                if self._decode_wall > 0:
-                    reg.gauge("serve.tokens_per_s").set(
-                        self._decode_tokens / self._decode_wall
-                    )
+        now = self.clock()
+        queued = self.scheduler.queue_depth()
+        busy = self._busy()
+        rec = self._rec = self.step_log.open(it, now, queued, busy)
+        try:
+            with span("step", step=it, queued=queued, busy=busy):
+                with span("expire", step=it):
+                    expired = self._expire(now)
+                    done("expire", step=it, expired=expired)
+                with span("admit", step=it):
+                    admitted = self._admit()
+                    done("admit", step=it, admitted=admitted)
+                chunks = self._advance_chunks(it)
+                if admitted or chunks:
+                    reg.counter("serve.steps_with_prefill").add()
+                    self._sample_memory(rec)
+                with span("grow", step=it):
+                    evicted = self._grow()
+                    done("grow", step=it, evicted=evicted)
+                self._decode(it)
+                with span("publish", step=it):
+                    pages = rec[_PAGES_IN_USE] = self.adapter.pages_in_use
+                    reg.gauge("serve.kv_pages_in_use").set(pages)
+                    peak = reg.gauge("serve.kv_pages_peak")
+                    if pages > peak.value:
+                        peak.set(pages)
+        finally:
+            self._rec = None
+            self.step_log.close(rec)
+        if self.step_log.is_slow(rec):
+            self._log_slow_step()
+        if TraceAnnotation.is_enabled():
+            self._replay()
+        else:
+            self.step_log.session_over()
         out, self._finished_buf = self._finished_buf, []
         return out
+
+    def _sample_memory(self, rec) -> None:
+        """The device's bytes in use and largest free block into
+        ``rec``, as far as its ``memory_stats()`` gives them (a CPU
+        gives none)."""
+        stats = self._device.memory_stats() or {}
+        for slot, key in zip(_HBM, _HBM_KEYS):
+            rec[slot] = stats.get(key, -1)
+
+    def _log_slow_step(self) -> None:
+        """A step that stood still (``StepLog.is_slow``) writes itself
+        out: one WARNING line, a JSON object with its record, the three
+        before it and the device's memory now, and a count."""
+        self.registry.counter("serve.steps_slow").add()
+        log = self.step_log
+        stats = self._device.memory_stats() or {}
+        *before, slow = (log[i] for i in range(-min(4, len(log)), 0))
+        logger.warning(json.dumps({
+            "slow_step": slow,
+            "before": before,
+            "memory": {
+                k: stats[k] for k in _HBM_KEYS + (
+                    "peak_bytes_in_use", "bytes_limit") if k in stats
+            } or "the device gives no memory_stats",
+        }))
+
+    def _replay(self) -> None:
+        """Hand the running profiler session this step's record and the
+        earlier ones it has not been given (``StepLog.unwritten``: as
+        many as the step's wait for the device leaves room for), each a
+        zero-length ``serve/step.log`` span whose counts are the fields,
+        with the two facts of the engine a reader sizes them by."""
+        slots, total = self.serve_cfg.max_batch, self.adapter.pages_total
+        for fields in self.step_log.unwritten():
+            with span("step.log", slots=slots, pages_total=total, **fields):
+                pass
 
     def _expire(self, now: float) -> int:
         """Deadline expiry at the step boundary -> requests expired."""
@@ -749,12 +804,16 @@ class ServingEngine:
         # recounted live too: a request that finishes inside its own
         # prefill releases its slot immediately.
         admitted = 0
+        stopped = "draining" if self._draining else "budget"
         for _ in range(0 if self._draining else
                        self.serve_cfg.max_prefill_per_step):
             if self._slots.count(None) <= 0:
+                stopped = "no_slot"
                 break
             got = self.scheduler.admit(1, can_fit)
             if not got:
+                # the head of the queue did not fit, or there is none
+                stopped = "no_pages" if self.scheduler.queue else "queue_empty"
                 break
             req = got[0]
             if req.evictions == 0:
@@ -763,6 +822,9 @@ class ServingEngine:
                 )
             admitted += 1
             self._prefill_request(req, self._slots.index(None))
+        self.registry.counter(f"serve.admit_stopped.{stopped}").add()
+        self._rec[_ADMIT_STOPPED] = ADMIT_STOPPED.index(stopped)
+        self._rec[_BUSY_AFTER_ADMIT] = self._busy()
         return admitted
 
     def _advance_chunks(self, it: int) -> int:
@@ -837,6 +899,10 @@ class ServingEngine:
             if prev is not None:
                 self._commit(prev)
 
+    def _busy(self) -> int:
+        """Slots that hold a stream."""
+        return sum(r is not None for r in self._slots)
+
     def _active(self) -> List[Tuple[int, Request]]:
         """(slot, request) of the streams a decode step serves."""
         return [
@@ -861,7 +927,6 @@ class ServingEngine:
         the step by the slab and the pools, which every program takes
         donated), so the next admission comes when it always came."""
         reg = self.registry
-        t0 = self.clock()
         self._key, sub = jax.random.split(self._key)
         # copies: the host's arrays change before the device has run
         toks, logits = self.adapter.decode_dispatch(
@@ -873,7 +938,6 @@ class ServingEngine:
             in_flight=int(overlapped),
         )
         self._fresh[:] = False
-        self._decode_wall += self.clock() - t0
         reg.counter("serve.decode_live_slots").add(len(active))
         if overlapped:
             reg.counter("serve.decode_steps_overlapped").add()
@@ -903,13 +967,10 @@ class ServingEngine:
         it = self.iterations
         reg = self.registry
         finished = len(self._finished_buf)
-        t0 = self.clock()
         toks = self.adapter.decode_collect(flight.toks)
-        self._decode_wall += self.clock() - t0
         self.last_logits = flight.logits
         with span("decode.commit", step=it):
             live = [sr for sr in flight.streams if sr[1].state != FINISHED]
-            self._decode_tokens += len(live)
             reg.counter("serve.decode_tokens").add(len(live))
             if len(live) < len(flight.streams):
                 reg.counter("serve.decode_tokens_discarded").add(
@@ -922,6 +983,7 @@ class ServingEngine:
                 "decode.commit",
                 step=it,
                 finished=len(self._finished_buf) - finished,
+                tokens=len(live),
             )
 
     def _decode_spec(self, it: int) -> None:
@@ -938,14 +1000,13 @@ class ServingEngine:
             live=len(active),
             kv_tokens=int(sum(self._lens[slot] for slot, _ in active)),
         ):
-            t0 = self.clock()
             emit, counts, logits = self.adapter.decode_spec(
                 self._slot_rids(active), self._lens, self._tokens
             )
             self.last_logits = logits
-            self._decode_wall += self.clock() - t0
             with span("decode.commit", step=it):
                 draft = self.adapter.spec_draft_tokens
+                tokens = 0
                 for slot, req in active:
                     self._spec_draft_total += draft
                     self._spec_accept_total += int(counts[slot]) - 1
@@ -957,14 +1018,15 @@ class ServingEngine:
                         tok = int(emit[slot, j])
                         req.generated.append(tok)
                         self._tokens[slot] = tok
-                        self._decode_tokens += 1
-                        reg.counter("serve.decode_tokens").add()
+                        tokens += 1
                         if self._finish_if_done(req, slot):
                             break
+                reg.counter("serve.decode_tokens").add(tokens)
                 done(
                     "decode.commit",
                     step=it,
                     finished=len(self._finished_buf) - finished,
+                    tokens=tokens,
                 )
         reg.counter("serve.decode_live_slots").add(len(active))
 
@@ -1063,9 +1125,7 @@ class ServingEngine:
         sizes the replica's load for the router's dispatch choice."""
         return {
             "iterations": float(self.iterations),
-            "slots_busy": float(
-                sum(r is not None for r in self._slots)
-            ),
+            "slots_busy": float(self._busy()),
             "queue_depth": float(self.scheduler.queue_depth()),
             "kv_pages_in_use": float(self.adapter.pages_in_use),
             "draining": float(self._draining),
@@ -1084,11 +1144,9 @@ class ServingEngine:
         lat = sorted(self.registry.hist("serve.request_latency_s").samples)
         p99 = lat[min(len(lat) - 1, int(0.99 * len(lat)))] if lat else 0.0
         return {
-            "tokens_per_s": (
-                self._decode_tokens / self._decode_wall
-                if self._decode_wall > 0
-                else 0.0
-            ),
+            # decode tokens committed over the engine's clock, across
+            # the steps the step log holds
+            "tokens_per_s": self.step_log.tokens_per_s(),
             "ttft_s": ttft.get("mean", 0.0),
             "queue_depth": float(self.scheduler.queue_depth()),
             "kv_pages_in_use": float(self.adapter.pages_in_use),

@@ -715,6 +715,13 @@ class FamilyAdapter:
         return self.cache.pages_in_use if self.cache is not None else 0
 
     @property
+    def pages_total(self) -> int:
+        """The pages streams can hold: the pool's less the reserved."""
+        if self.cache is None:
+            return 0
+        return self.cache.capacity_tokens // self.cache.page_size
+
+    @property
     def state_bytes_per_stream(self) -> int:
         """Constant per-stream recurrent-state bytes (0 for families
         whose only decode state is paged KV — that grows, and is

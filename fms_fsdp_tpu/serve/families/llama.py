@@ -283,13 +283,16 @@ class LlamaAdapter(PagedAdapter):
         st = self._chunk_state[rid]
         pos = st["pos"]
         m = min(self.scfg.prefill_chunk_tokens, st["p_pad"] - pos)
-        fn, _ = self._program(("chunk", m, st["p_pad"]), self._build_chunk)
-        logits, embeds, st["cache"] = fn(
-            self.params,
-            st["cache"],
-            self._dev(st["toks"][:, pos : pos + m]),
-            pos,
+        fn, built = self._program(
+            ("chunk", m, st["p_pad"]), self._build_chunk
         )
+        with span("prefill.dispatch", rid=rid, built=built):
+            logits, embeds, st["cache"] = fn(
+                self.params,
+                st["cache"],
+                self._dev(st["toks"][:, pos : pos + m]),
+                pos,
+            )
         last = st["p"] - 1
         if pos <= last < pos + m:
             # the chunk holding the last REAL prompt position carries

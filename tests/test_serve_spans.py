@@ -72,7 +72,8 @@ PARENT = {
 }
 # the chunked path (llama only) adds one name
 CHUNKED_PARENT = {"prefill_chunk": "step", "prefill.sample": "prefill_chunk",
-                  "prefill.write_pages": "prefill_chunk"}
+                  "prefill.write_pages": "prefill_chunk",
+                  "prefill.dispatch": "prefill_chunk"}
 
 
 class Span:
@@ -300,7 +301,7 @@ COUNTERS = {
     "serve.decode_live_slots": lambda sp: sum(
         s.stats["live"] for s in named(sp, "decode")),
     "serve.decode_tokens": lambda sp: sum(
-        s.stats["live"] for s in named(sp, "decode")),
+        s.stats["tokens"] for s in named(sp, "decode.commit.done")),
     "serve.requests_completed": lambda sp: sum(
         s.stats["finished"] for s in named(sp, "decode.commit.done")),
     "serve.requests_submitted": lambda sp: sum(
@@ -345,11 +346,19 @@ def test_counters_read_what_happened(traced):
 def test_no_session_same_tokens_and_nothing_written(
         traced, mixtral_params, tmp_path, monkeypatch):
     monkeypatch.chdir(tmp_path)
-    _, reqs, spans = serve(mixtral_params, TINY_MIXTRAL, SCFG)
+    engine, reqs, spans = serve(mixtral_params, TINY_MIXTRAL, SCFG)
     assert spans is None
     assert [r.generated for r in reqs] == [r.generated for r in traced[1]]
     assert all(r.state == "finished" for r in reqs)
     assert list(tmp_path.iterdir()) == []
+    # the step log needs no session: a record a step, the same counts as
+    # the traced engine's (tests/test_engine_step_log.py has the rest)
+    log, was = list(engine.step_log), list(traced[0].step_log)
+    assert len(log) == engine.iterations == len(was)
+    for field in ("admitted", "admit_stopped", "live", "kv_tokens", "tokens",
+                  "padded_tokens", "computed_tokens", "pages_in_use"):
+        assert [r[field] for r in log] == [r[field] for r in was], field
+    assert all(r["wall_us"] > 0 for r in log)
 
 
 # -- (c) the request's own timeline -------------------------------------------
