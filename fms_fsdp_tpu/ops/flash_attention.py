@@ -324,18 +324,33 @@ def _band_blocks(i, block_q, block_k, window):
 
 
 def _fwd_kernel_window(
-    q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
+    q_ref, k_ref, vt_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
     *, scale, window, num_kb,
 ):
-    """kv-streamed forward over the band: grid (b, h, qi, j), cell ``j``
-    holding the ``j``-th K block of the Q block's band. A K block that
-    lies wholly outside the band is neither fetched (the index map never
-    names it; cells past the band's last block are clamped onto it, a
-    repeat fetch Mosaic elides) nor computed (pl.when); the band's two
-    edges are masked element by element in every cell that runs (at a
-    window no wider than a block there is no cell without an edge)."""
-    block_q = q_ref.shape[2]
+    """kv-streamed forward over the band: grid (b, kv head, qi, j), cell
+    ``j`` holding the ``j``-th K block of the Q block's band and the Q
+    block of every query head of the KV head's group, ``group * BQ``
+    queries (head ``g``'s at ``g * BQ``), so that a K and a V block are
+    fetched once a group and both products run over all its queries. A K
+    block that lies wholly outside the band is neither fetched (the index
+    map never names it; cells past the band's last block are clamped onto
+    it, a repeat fetch Mosaic elides) nor computed (pl.when); the band's
+    two edges are masked element by element in every cell that runs (at a
+    window no wider than a block there is no cell without an edge), by a
+    query's position in its own head's block.
+
+    Scores are held keys down, queries across: (BK, group * BQ). A band
+    is a block or two of keys wide, so a query's max and sum over its
+    keys are what a cell does most. With queries down, both are
+    cross-lane reductions, two a vreg of scores, and the (BQ, 1) state
+    is a vreg every eight queries, as many as the scores have: 23 ns a
+    vreg of scores on a v5e, one head a cell or eight (PERF.md section
+    6, PR 38). Down the sublanes both are element-wise, the state is a
+    vreg every 1024 queries, and the accumulator (Hv, group * BQ) is
+    turned once a Q block: 4 ns."""
+    group, block_q, head = q_ref.shape[2:]
     block_k = k_ref.shape[2]
+    rows = group * block_q
     qi = pl.program_id(2)
     j = pl.program_id(3)
     q_start = qi * block_q
@@ -351,37 +366,40 @@ def _fwd_kernel_window(
     def _():
         k_start = (first + j) * block_k
         q = (q_ref[0, 0] * (scale * LOG2E)).astype(q_ref.dtype)
-        k = k_ref[0, 0]
-        v = v_ref[0, 0]
+        vt = vt_ref[0, 0]
         s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )  # (BQ, BK), base-2 domain
+            k_ref[0, 0], q.reshape(rows, head), (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )  # (BK, group * BQ), base-2 domain
         back = (
             q_start - k_start
-            + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
-            - jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
-        )  # how far the key lies behind the query
+            + jax.lax.broadcasted_iota(jnp.int32, (block_k, block_q), 1)
+            - jax.lax.broadcasted_iota(jnp.int32, (block_k, block_q), 0)
+        )  # how far the key lies behind the query, whatever its head
+        back = jnp.concatenate([back] * group, axis=1)
         s = jnp.where((back >= 0) & (back < window), s, NEG_INF)
-        # a row that sees nothing of this block and nothing yet keeps
+        # a query that sees nothing of this block and nothing yet keeps
         # m = NEG_INF and adds p = 1 a key; the first block it does see
-        # (its own position's, which every row has) wipes that: alpha = 0
+        # (its own position's, which every query has) wipes that: alpha = 0
         m = m_ref[...]
-        m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+        m_new = jnp.maximum(m, jnp.max(s, axis=0, keepdims=True))
         p = jnp.exp2(s - m_new)
         alpha = jnp.exp2(m - m_new)
-        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
+        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=0, keepdims=True)
         pv = jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            vt, p.astype(vt.dtype), (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
-        )
+        )  # (Hv, group * BQ)
         acc_ref[...] = acc_ref[...] * alpha + pv
         m_ref[...] = m_new
 
     @pl.when(j == num_kb - 1)
     def _():
-        l = l_ref[...]
-        o_ref[0, 0] = (acc_ref[...] / l).astype(o_ref.dtype)
-        lse_ref[0, 0] = m_ref[...] * LN2 + jnp.log(l)
+        for g in range(group):
+            at = pl.ds(g * block_q, block_q)
+            l = l_ref[:, at]
+            o_ref[0, 0, g] = (acc_ref[:, at] / l).T.astype(o_ref.dtype)
+            lse_ref[0, 0, g] = m_ref[:, at] * LN2 + jnp.log(l)
 
 
 @scoped("flash_attention_fwd")
@@ -389,10 +407,14 @@ def _flash_fwd_window(q, k, v, scale, window, block_q, block_k, interpret):
     """q (B, Nq, S, H), k (B, Nkv, S, H), v (B, Nkv, S, Hv) -> (o, lse) of
     causal attention in which a position sees itself and the ``window -
     1`` before it. The work follows the band, ``S * window``, and not
-    ``S * S / 2``."""
+    ``S * S / 2``; a grid cell holds the ``Nq // Nkv`` query heads of one
+    KV head (q seen as (B, Nkv, group, S, H): no copy) and reads V keys
+    across, (B, Nkv, Hv, S): the caller's transpose of v lands there. On
+    a TPU both blocks are multiples of 128 or the whole length."""
     batch, nq, seq, head = q.shape
     nkv, vdim = k.shape[1], v.shape[3]
     group = nq // nkv
+    rows = group * block_q
     # the widest band of any Q block, in K blocks: static
     num_kb = max(
         (i * block_q + block_q - 1) // block_k
@@ -400,38 +422,48 @@ def _flash_fwd_window(q, k, v, scale, window, block_q, block_k, interpret):
         for i in range(seq // block_q)
     )
 
-    def kvmap(b, h, i, j):
-        first, last = _band_blocks(i, block_q, block_k, window)
-        return (b, h // group, jnp.minimum(first + j, last), 0)
+    def qmap(b, h, i, j):
+        return (b, h, 0, i, 0)
 
-    return pl.pallas_call(
+    def kb(i, j):
+        first, last = _band_blocks(i, block_q, block_k, window)
+        return jnp.minimum(first + j, last)
+
+    o, lse = pl.pallas_call(
         functools.partial(
             _fwd_kernel_window, scale=scale, window=window, num_kb=num_kb
         ),
-        grid=(batch, nq, seq // block_q, num_kb),
+        grid=(batch, nkv, seq // block_q, num_kb),
         in_specs=[
-            pl.BlockSpec((1, 1, block_q, head), lambda b, h, i, j: (b, h, i, 0)),
-            pl.BlockSpec((1, 1, block_k, head), kvmap),
-            pl.BlockSpec((1, 1, block_k, vdim), kvmap),
+            pl.BlockSpec((1, 1, group, block_q, head), qmap),
+            pl.BlockSpec(
+                (1, 1, block_k, head), lambda b, h, i, j: (b, h, kb(i, j), 0)
+            ),
+            pl.BlockSpec(
+                (1, 1, vdim, block_k), lambda b, h, i, j: (b, h, 0, kb(i, j))
+            ),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, block_q, vdim), lambda b, h, i, j: (b, h, i, 0)),
-            pl.BlockSpec((1, 1, block_q, 1), lambda b, h, i, j: (b, h, i, 0)),
+            pl.BlockSpec((1, 1, group, block_q, vdim), qmap),
+            pl.BlockSpec(
+                (1, 1, group, 1, block_q), lambda b, h, i, j: (b, h, 0, 0, i)
+            ),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((batch, nq, seq, vdim), q.dtype),
-            jax.ShapeDtypeStruct((batch, nq, seq, 1), jnp.float32),
+            jax.ShapeDtypeStruct((batch, nkv, group, seq, vdim), q.dtype),
+            jax.ShapeDtypeStruct((batch, nkv, group, 1, seq), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((block_q, vdim), jnp.float32),
-            pltpu.VMEM((block_q, 1), jnp.float32),
-            pltpu.VMEM((block_q, 1), jnp.float32),
+            pltpu.VMEM((vdim, rows), jnp.float32),  # acc, queries across
+            pltpu.VMEM((1, rows), jnp.float32),  # running max (base 2)
+            pltpu.VMEM((1, rows), jnp.float32),  # running denominator
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,
-    )(q, k, v)
+    )(q.reshape(batch, nkv, group, seq, head), k, jnp.swapaxes(v, 2, 3))
+    return o.reshape(batch, nq, seq, vdim), lse.reshape(batch, nq, seq, 1)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
@@ -1108,8 +1140,12 @@ def flash_attention(
     ``window`` (None: none): a position sees itself and the ``window -
     1`` before it. Causal, queries and keys of one length, forward only:
     a kernel of its own that fetches and computes the K blocks of each Q
-    block's band and no others (blocks of ``block_q`` x ``block_k``,
-    256 x 128 unless pinned; the tuning table has no entry for it).
+    block's band and no others, a KV head's group of query heads a grid
+    cell (blocks of ``block_q`` x ``block_k``, 256 x 128 unless pinned;
+    the tuning table has no entry for it). Scores are kept keys down, so
+    a query's sum over its keys runs down the sublanes and not across
+    the lanes as in the causal kernel: the same float32 work in another
+    order, equal to the last place.
     """
     if window is not None:
         if not causal or q.shape[1] != k.shape[1] or window < 1:
@@ -1119,6 +1155,14 @@ def flash_attention(
                 f"{k.shape[1]} keys, window={window})"
             )
         scale = float(scale if scale is not None else q.shape[-1] ** -0.5)
+        # timed on the chip (PERF.md section 6, PR 38): 2048 positions, a
+        # window of 128, heads of 128, transposes in and out included. 64
+        # query heads on 8: 256 x 128 278 us, 128 x 128 285, 128 x 256
+        # 340, 256 x 256 340; with a loop over the group's heads in the
+        # cell 324, 340, 379, 375 and 512 x 128 505 (one head a cell,
+        # queries down: 1153). 8 on 8: 256 x 128 131, 128 x 128 154-157
+        # (159). A block of 128 queries walks 256 keys for a band of 128
+        # where one of 256 walks 384, in 256 cells and not 192: level.
         ot, lse = _flash_window_bnsh(
             jnp.swapaxes(q, 1, 2), jnp.swapaxes(k, 1, 2),
             jnp.swapaxes(v, 1, 2), scale, int(window),

@@ -297,15 +297,23 @@ def _banded(q, k, v, window):
     return o, jnp.moveaxis(lse, 1, 2)[..., None]
 
 
-@pytest.mark.parametrize("window,bq,bk", [
-    (37, 256, 128), (128, 256, 128), (128, 128, 128), (300, 128, 128),
-    (129, 128, 256), (1, 128, 128), (4096, 256, 128)],
+@pytest.mark.parametrize("window,bq,bk,nq,nkv", [
+    (37, 256, 128, 4, 2), (128, 256, 128, 4, 2), (128, 128, 128, 4, 2),
+    (300, 128, 128, 4, 2), (129, 128, 256, 4, 2), (1, 128, 128, 4, 2),
+    (4096, 256, 128, 4, 2),
+    # the cell's real ratios: a KV head's eight query heads in one cell,
+    # a head alone, a window wider than a Q block,
+    # and the blocks the call picks for itself
+    (128, 256, 128, 8, 1), (128, 256, 128, 2, 2), (300, 128, 128, 8, 1),
+    (128, None, None, 8, 1), (128, None, None, 16, 2)],
     ids=["under-a-block", "a-block", "a-block-square", "over-a-block",
-         "one-over", "self-only", "wider-than-all"])
-def test_windowed_flash_forward_is_the_masked_einsum(window, bq, bk):
+         "one-over", "self-only", "wider-than-all", "group-8", "group-1",
+         "group-8-over-a-block", "group-8-own-blocks",
+         "two-groups-of-8-own-blocks"])
+def test_windowed_flash_forward_is_the_masked_einsum(window, bq, bk, nq, nkv):
     ks = jax.random.split(jax.random.PRNGKey(0), 3)
     q, k, v = (jax.random.normal(kk, (2, 512, n, 128))
-               for kk, n in zip(ks, (4, 2, 2)))
+               for kk, n in zip(ks, (nq, nkv, nkv)))
     o, lse = flash_attention(
         q, k, v, window=window, block_q=bq, block_k=bk, interpret=True,
         return_lse=True)
@@ -313,6 +321,38 @@ def test_windowed_flash_forward_is_the_masked_einsum(window, bq, bk):
     np.testing.assert_allclose(np.asarray(o), np.asarray(want_o), atol=1e-5)
     np.testing.assert_allclose(
         np.asarray(lse), np.asarray(want_lse), atol=1e-5)
+
+
+def _pallas_calls(jaxpr):
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _pallas_calls(sub)
+
+
+@pytest.mark.parametrize("nq,nkv", [(64, 8), (8, 8)], ids=["group-8", "group-1"])
+def test_windowed_flash_grid_has_a_cell_a_kv_head(nq, nkv):
+    """The engagement check, off the chip: at the k-exaone chunk's shape
+    the one Mosaic call's grid has the KV heads on its head axis and the
+    group's query heads inside the cell's Q block; a K block and a V
+    block (keys across) are named by the KV head alone; 192 cells a
+    layer and chunk where a head a cell ran 1536."""
+    B, S, H, window = 1, 2048, 128, 128
+    q = jax.ShapeDtypeStruct((B, S, nq, H), jnp.bfloat16)
+    kv = jax.ShapeDtypeStruct((B, S, nkv, H), jnp.bfloat16)
+    (call,) = _pallas_calls(jax.make_jaxpr(
+        lambda q, k, v: flash_attention(q, k, v, window=window)
+    )(q, kv, kv).jaxpr)
+    mapping = call.params["grid_mapping"]
+    blocks = [
+        tuple(getattr(d, "block_size", d) for d in m.block_shape)
+        for m in mapping.block_mappings]
+    group = nq // nkv
+    assert mapping.grid == (B, nkv, S // 256, 3)
+    assert blocks == [
+        (1, 1, group, 256, H), (1, 1, 128, H), (1, 1, H, 128),  # q, k, v
+        (1, 1, group, 256, H), (1, 1, group, 1, 256)]  # o, lse
 
 
 def test_windowed_flash_refuses_what_is_not_built_by_name():
