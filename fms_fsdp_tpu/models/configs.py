@@ -627,3 +627,230 @@ def kexaone_config(d: dict) -> KExaoneConfig:
         rope_theta=float(rp.get("rope_theta", d.get("rope_theta", 10000.0))),
         norm_eps=d["rms_norm_eps"],
     )
+
+
+# the kinds of layer of the sparse-and-linear family, by ``mixer_types``'
+# names: the name of the kind's stack in the parameter tree
+SALA_MIXER_KINDS = {"minicpm4": "sparse", "lightning-attn": "lightning"}
+
+
+@dataclass(frozen=True)
+class SalaSparseConfig:
+    """The sizes of a ``minicpm4`` layer's block choice (InfLLM-V2;
+    MiniCPM4's published ``sparse_config``): keys are mean-pooled over
+    ``kernel_size`` positions every ``kernel_stride``; a query scores
+    those, the scores pool to blocks of ``block_size`` positions, and
+    ``topk`` blocks are attended, among them always the first
+    ``init_blocks`` and the ``window_size`` positions' worth that end at
+    the query's own. A query with ``t + 1 <= dense_len`` attends
+    everything before it."""
+
+    kernel_size: int = 32
+    kernel_stride: int = 16
+    block_size: int = 64
+    topk: int = 64
+    init_blocks: int = 1
+    window_size: int = 2048
+    dense_len: int = 8192
+
+    def __post_init__(self):
+        if self.kernel_size != 2 * self.kernel_stride:
+            raise ValueError(
+                f"sparse_config kernel_size={self.kernel_size} with "
+                f"kernel_stride={self.kernel_stride}: the block pooling is "
+                "built for windows that overlap by half (kernel = 2 x stride)"
+            )
+        for name in ("block_size", "window_size", "dense_len"):
+            whole = self.kernel_stride if name == "block_size" else self.block_size
+            if getattr(self, name) % whole:
+                raise ValueError(
+                    f"sparse_config {name}={getattr(self, name)} is no "
+                    f"multiple of {whole}"
+                )
+        if self.topk < self.init_blocks + self.window_blocks:
+            raise ValueError(
+                f"sparse_config topk={self.topk} cannot hold the "
+                f"{self.init_blocks} initial and {self.window_blocks} "
+                "window blocks that are always chosen"
+            )
+
+    @property
+    def per_block(self) -> int:
+        """Compressed keys that start inside one block."""
+        return self.block_size // self.kernel_stride
+
+    @property
+    def window_blocks(self) -> int:
+        return self.window_size // self.block_size
+
+    @property
+    def dense_blocks(self) -> int:
+        """Blocks a position that attends everything can see at most."""
+        return self.dense_len // self.block_size
+
+    @property
+    def list_blocks(self) -> int:
+        """The longest list of blocks a position attends."""
+        return max(self.topk, self.dense_blocks)
+
+
+@dataclass(frozen=True)
+class SalaConfig:
+    """Sparse-and-linear-attention family (``model_type: minicpm_sala``;
+    models/minicpm_sala.py): layer ``i`` is of kind ``mixer_types[i]``,
+    ``"minicpm4"`` (grouped-query attention over the blocks of its
+    context that a query chooses through compressed keys, ``sparse``) or
+    ``"lightning-attn"`` (linear attention with a decay a head, a
+    ``head_dim`` x ``head_dim`` state a head and stream), each before a
+    SwiGLU of ``hidden_dim``. The family scales its embedding
+    (``scale_emb``), what a sub-block adds to the residual
+    (``scale_depth / sqrt(depth_published)``) and its logits (``1 /
+    (emb_dim / dim_model_base)``)."""
+
+    src_vocab_size: int = 73448
+    emb_dim: int = 4096
+    nheads: int = 32
+    kvheads: int = 2
+    head_dim: int = 128
+    nlayers: int = 32
+    mixer_types: Tuple[str, ...] = ()
+    hidden_dim: int = 16384
+    lightning_nh: int = 32
+    lightning_head_dim: int = 128
+    sparse: SalaSparseConfig = SalaSparseConfig()
+    scale_emb: float = 12.0
+    scale_depth: float = 1.4
+    dim_model_base: int = 256
+    depth_published: int = 32
+    max_expected_seq_len: int = 524288
+    rope_theta: float = 10000.0
+    norm_eps: float = 1e-6
+
+    def __post_init__(self):
+        got = self.mixer_types
+        if len(got) != self.nlayers or set(got) - set(SALA_MIXER_KINDS):
+            raise ValueError(
+                f"mixer_types must name one of {tuple(SALA_MIXER_KINDS)} "
+                f"for each of the {self.nlayers} layers, got {got}"
+            )
+        if self.nheads % self.kvheads:
+            raise ValueError(
+                f"{self.nheads} query heads do not share {self.kvheads} "
+                "kv heads evenly"
+            )
+
+    def kind(self, i: int) -> str:
+        """Layer ``i``'s kind, the name of its stack in the tree."""
+        return SALA_MIXER_KINDS[self.mixer_types[i]]
+
+    @property
+    def stacks(self):
+        """``{kind: the indices of its layers}``, kinds in the order they
+        first occur."""
+        out = {}
+        for i in range(self.nlayers):
+            out.setdefault(self.kind(i), []).append(i)
+        return out
+
+    @property
+    def sparse_layers(self) -> Tuple[int, ...]:
+        return tuple(self.stacks.get("sparse", ()))
+
+    @property
+    def lightning_layers(self) -> Tuple[int, ...]:
+        return tuple(self.stacks.get("lightning", ()))
+
+    @property
+    def residual_gain(self) -> float:
+        return self.scale_depth / self.depth_published**0.5
+
+    @property
+    def logit_divisor(self) -> float:
+        return self.emb_dim / self.dim_model_base
+
+    def layer_params(self, kind: str) -> int:
+        d, f = self.emb_dim, self.hidden_dim
+        if kind == "sparse":
+            heads, kv, hd = self.nheads * self.head_dim, (
+                self.kvheads * self.head_dim), self.head_dim
+            norms = 2 * hd + 2 * d
+        else:
+            heads = kv = self.lightning_nh * self.lightning_head_dim
+            norms = 2 * self.lightning_head_dim + heads + 2 * d
+        return 3 * d * heads + 2 * d * kv + 3 * d * f + norms
+
+    def n_params(self) -> int:
+        return int(
+            sum(self.layer_params(self.kind(i)) for i in range(self.nlayers))
+            + self.emb_dim
+            + 2 * self.src_vocab_size * self.emb_dim
+        )
+
+
+# what the family's code has and a ``config.json`` could switch off or
+# on: a config that asks otherwise is refused by the key's name
+_SALA_FIXED = {
+    "attention_bias": False,
+    "attn_use_rope": False,
+    "qk_norm": True,
+    "lightning_use_rope": True,
+    "use_output_gate": True,
+    "use_output_norm": True,
+    "attn_use_output_gate": True,
+    "tie_word_embeddings": False,
+    "hidden_act": "silu",
+    "lightning_scale": "1/sqrt(d)",
+}
+
+
+def minicpm_sala_config(d: dict) -> SalaConfig:
+    """A published ``config.json`` of ``model_type: minicpm_sala`` as the
+    family's config. A file that keeps a slice of the stack states the
+    layers kept as ``num_hidden_layers`` and ``mixer_types`` and the
+    published depth under ``published`` (the residual gain keeps the
+    published depth; benchmark/configs/minicpm-sala-9b.1chip.json). The
+    sizes of the block choice come from ``sparse_config`` (MiniCPM4's
+    published key; the family's defaults where the file has none).
+    models/minicpm_sala.py says how the keys the config does not have
+    are read; a key that asks for what is not built is refused by
+    name."""
+    for key, built in _SALA_FIXED.items():
+        if key in d and d[key] != built:
+            raise ValueError(
+                f"minicpm_sala with {key}={d[key]!r}: only {key}={built!r} "
+                "is built"
+            )
+    if d.get("lightning_nkv", d["lightning_nh"]) != d["lightning_nh"]:
+        raise ValueError(
+            f"lightning_nkv={d['lightning_nkv']} != lightning_nh="
+            f"{d['lightning_nh']}: the lightning layers are built with a "
+            "key and value head for every query head"
+        )
+    L = d["num_hidden_layers"]
+    published = (d.get("published") or {}).get("num_hidden_layers", L)
+    if d.get("mup_denominator", published) != published:
+        raise ValueError(
+            f"mup_denominator={d['mup_denominator']} is not the published "
+            f"depth {published}: the residual gain is scale_depth / "
+            "sqrt(published depth)"
+        )
+    return SalaConfig(
+        src_vocab_size=d["vocab_size"],
+        emb_dim=d["hidden_size"],
+        nheads=d["num_attention_heads"],
+        kvheads=d["num_key_value_heads"],
+        head_dim=d["head_dim"],
+        nlayers=L,
+        mixer_types=tuple(d["mixer_types"]),
+        hidden_dim=d["intermediate_size"],
+        lightning_nh=d["lightning_nh"],
+        lightning_head_dim=d["lightning_head_dim"],
+        sparse=SalaSparseConfig(**(d.get("sparse_config") or {})),
+        scale_emb=float(d["scale_emb"]),
+        scale_depth=float(d["scale_depth"]),
+        dim_model_base=d["dim_model_base"],
+        depth_published=published,
+        max_expected_seq_len=d["max_position_embeddings"],
+        rope_theta=float(d.get("rope_theta", 10000.0)),
+        norm_eps=d["rms_norm_eps"],
+    )

@@ -149,6 +149,45 @@ KEXAONE_SCOPES = (
     "sample",
 )
 
+# the scopes of a minicpm_sala engine's two programs
+# (models/minicpm_sala.py, serve/families/minicpm_sala.py: ``jit__step``
+# and ``jit__prefill_<tokens>``), in program order; the two kinds of
+# mixer are told apart in every phase. Sparse layers: ``kv_write`` (the
+# page write; the prefill's buffer write), ``sparse_compress`` (a
+# prefill chunk's compressed keys, the windows that straddle its start
+# among them), ``index_write`` (the decode step's compressed key, when
+# its position completes a window), ``sparse_select`` (the scores
+# against the compressed keys, their pooling to blocks, the top-k),
+# ``sparse_attn`` (the chosen pages read and attended; in a prefill the
+# walk under each query's mask of chosen blocks) and ``attn`` (a prefill
+# chunk whose every position is still dense). Lightning layers:
+# ``lin_scan`` (a prompt's chunked form), ``lin_step`` (the one-position
+# update of the state) and ``lin_gate`` (the output norm and gate).
+# ``qk_norm`` is the RMSNorm by head of q and k, ``rope`` the lightning
+# layers' rotary embedding. ``layers`` is around the (unrolled) stack
+SALA_SCOPES = (
+    "params_cast",
+    "embed",
+    "norm",
+    "layers",
+    "qkv",
+    "qk_norm",
+    "rope",
+    "kv_write",
+    "sparse_compress",
+    "index_write",
+    "sparse_select",
+    "sparse_attn",
+    "attn",
+    "lin_scan",
+    "lin_step",
+    "lin_gate",
+    "attn_out",
+    "mlp",
+    "lm_head",
+    "sample",
+)
+
 _COMPUTATION = re.compile(r"^(?:ENTRY\s+)?%?([\w.\-]+)\s.*\{\s*$")
 _INSTRUCTION = re.compile(r"^\s+(?:ROOT\s+)?%?([\w.\-]+) = ")
 _NAME = re.compile(r"%([\w.\-]+)")

@@ -54,7 +54,11 @@ FIELDS = (
     "queued", "busy",  # at entry
     "admitted", "busy_after_admit", "admit_stopped",
     "padded_tokens", "computed_tokens", "built",  # the step's prefills
+    # of them, in a family whose positions choose their context (minicpm_sala):
+    # the positions that chose and the blocks they chose
+    "chose_tokens", "chosen_blocks",
     "live", "kv_tokens",  # of the decode step dispatched
+    "live_chose",  # its live streams that chose their context
     "tokens",  # decode tokens committed in the step
     "pages_in_use",  # at publish
     "hbm_in_use", "hbm_largest_free",  # -1: not sampled
@@ -92,9 +96,10 @@ TIMED = {
 COUNTED = {
     name: tuple((k, SLOT[k]) for k in keys) for name, keys in {
         "prefill": ("padded_tokens",),
-        "prefill.done": ("computed_tokens",),
+        "prefill.done": ("computed_tokens", "chose_tokens", "chosen_blocks"),
         "prefill.dispatch": ("built",),
         "decode": ("live", "kv_tokens"),
+        "decode.dispatch": ("live_chose",),
         "admit.done": ("admitted",),
         "decode.commit.done": ("tokens",),
     }.items()
@@ -148,7 +153,7 @@ def span(name: str, **counts):
         return ann
     slot, counted = plan
     for key, at in counted:
-        rec[at] += counts[key]
+        rec[at] += counts.get(key, 0)  # a family sends the counts it has
     return ann if slot is None else _Timed(ann, rec, slot)
 
 

@@ -465,3 +465,136 @@ def latent_attention_kernel(
         q,
         *([pool] * ppb),
     )
+
+
+# ---------------------------------------------------------------------------
+# chosen pages: block-sparse attention through compressed keys (InfLLM-V2)
+# ---------------------------------------------------------------------------
+#
+# A query does not walk its stream's whole table. It scores the stream's
+# compressed keys (the mean of ``kernel_size`` keys every
+# ``kernel_stride`` positions: an index cache beside the pages), pools the
+# scores to blocks of ``block_size`` positions and attends ``topk`` blocks
+# only. With a page as long as a block a chosen block is a page, so the
+# ragged kernel above walks a list of chosen pages in place of the table.
+# ``sp`` is models/configs.py::SalaSparseConfig. Scores, softmax and
+# pooling are float32.
+
+
+def compress_keys(k, sp):
+    """The means of the whole windows of k (B, S, Nkv, H) that start every
+    ``kernel_stride`` positions from 0 on: (B, Nkv, S // stride - 1, H) in
+    k's dtype (``kernel_size`` is twice the stride), summed in float32.
+    Window ``j`` covers positions ``stride * j`` to ``stride * j +
+    kernel_size - 1``."""
+    B, S, nkv, H = k.shape
+    halves = jnp.sum(
+        k.astype(jnp.float32).reshape(B, S // sp.kernel_stride,
+                                      sp.kernel_stride, nkv, H),
+        axis=2,
+    )
+    means = (halves[:, :-1] + halves[:, 1:]) / sp.kernel_size
+    return jnp.moveaxis(means, 2, 1).astype(k.dtype)
+
+
+def block_keys(q, kc, t, sp):
+    """What each query ranks the blocks of its context by. q (B, T, Nkv,
+    g, H): the ``g`` query heads of a kv head choose together; kc (B, Nkv,
+    nb * r, H): compressed key ``j`` at row ``j`` (``r`` a block; rows
+    whose window has not ended by a query's position are not read for
+    it); t (B, T) int32 the queries' positions. -> (key (B, Nkv, T, nb)
+    float32, exists (B, T, nb), dense (B, T, 1)): ``key`` is +inf for a
+    block that is always attended (the first ``init_blocks``, the
+    ``window_size`` positions' worth that end at the query's own, and
+    every block of a query with ``t + 1 <= dense_len``), -inf for a block
+    past the query's own, else the largest over the compressed keys that
+    touch the block of the softmax over compressed keys, summed over the
+    group's heads."""
+    B, T, nkv, g, H = q.shape
+    r = sp.per_block
+    nb = kc.shape[2] // r
+    s = jnp.einsum(
+        "btkgh,bkjh->bkgtj", q, kc, preferred_element_type=jnp.float32
+    ) * (H**-0.5)
+    ends = jnp.arange(nb * r, dtype=jnp.int32) * sp.kernel_stride + (
+        sp.kernel_size - 1
+    )
+    valid = ends[None, None, :] <= t[:, :, None]  # (B, T, nC)
+    v5 = valid[:, None, None]
+    s = jnp.where(v5, s, NEG_INF)
+    e = jnp.where(v5, jnp.exp(s - jnp.max(s, axis=-1, keepdims=True)), 0.0)
+    z = jnp.sum(e, axis=-1, keepdims=True)
+    p = jnp.sum(e / jnp.maximum(z, 1e-30), axis=2)  # (B, Nkv, T, nC)
+    p = jnp.where(valid[:, None], p, -1.0).reshape(B, nkv, T, nb, r)
+    # block b: windows r*b - 1 .. r*b + r - 1, every one that touches it
+    before = jnp.concatenate(
+        [jnp.full((B, nkv, T, 1), -1.0), p[..., :-1, r - 1]], axis=-1
+    )
+    score = jnp.maximum(jnp.max(p, axis=-1), before)
+    blocks = jnp.arange(nb, dtype=jnp.int32)
+    back = (t // sp.block_size)[..., None] - blocks  # (B, T, nb)
+    exists = back >= 0
+    dense = (t + 1 <= sp.dense_len)[..., None]
+    forced = (blocks < sp.init_blocks) | (back < sp.window_blocks) | dense
+    key = jnp.where(forced[:, None], jnp.inf, score)
+    return jnp.where(exists[:, None], key, -jnp.inf), exists, dense
+
+
+def chosen_mask(key, exists, dense, sp):
+    """``block_keys``' ranking -> (B, Nkv, T, nb) bool: the ``topk`` best
+    blocks of each query, ties to the lower index, and every block up to
+    its own where the query is ``dense``."""
+    nb = key.shape[-1]
+    top, idx = jax.lax.top_k(key, min(sp.topk, nb))
+    hit = (idx[..., None] == jnp.arange(nb, dtype=jnp.int32)) & (
+        top[..., None] > -jnp.inf
+    )
+    return jnp.where(dense[:, None], exists[:, None], jnp.any(hit, axis=-2))
+
+
+def chosen_list(key, dense, sp):
+    """``block_keys``' ranking for one query a row (T = 1) -> (blocks (B,
+    Nkv, w) int32, the chosen blocks in rising order and then zeros; n
+    (B, Nkv) int32, how many): ``topk`` of them, or every block up to the
+    query's own where it is ``dense``; ``w`` = ``sp.list_blocks`` (or
+    every block, if the context has fewer). The query's own block is the
+    last of its list."""
+    nb = key.shape[-1]
+    w = min(sp.list_blocks, nb)
+    top, idx = jax.lax.top_k(key[:, :, 0], w)  # (B, Nkv, w)
+    place = jnp.arange(w, dtype=jnp.int32)
+    keep = (top > -jnp.inf) & (dense[:, :, None, 0] | (place < sp.topk))
+    n = jnp.sum(keep, axis=-1).astype(jnp.int32)
+    idx = jnp.sort(jnp.where(keep, idx, nb), axis=-1)
+    return jnp.where(place < n[..., None], idx, 0).astype(jnp.int32), n
+
+
+def chosen_pages_attention(
+    q, k_pages, v_pages, page_table, seq_lens, blocks, n, *,
+    first_page=0, kernel=True, block_kv=None,
+):
+    """One query a row over its chosen pages. q (B, Nkv, g, H); k_pages,
+    v_pages (P', page_size, 1, H): pools of one kv head a page, the pages
+    of kv head ``h`` of the layer at ``first_page[h] + id`` (``first_page``
+    (Nkv,) int32); page_table (B, maxp); ``blocks`` (B, Nkv, w) and ``n``
+    (B, Nkv) from ``chosen_list``: row ``b``'s query at position
+    ``seq_lens[b]`` attends, for kv head ``h``, the positions up to its
+    own of pages ``page_table[b, blocks[b, h, :n[b, h]]]``. Each (row, kv
+    head) is a row of the ragged kernel (``kernel``) or of the gathered
+    reference, its table the chosen pages and its length the chosen
+    positions. -> (B, Nkv * g * H)."""
+    B, nkv, g, H = q.shape
+    w = blocks.shape[-1]
+    page_size = k_pages.shape[1]
+    table = jnp.take_along_axis(
+        page_table[:, None, :], blocks, axis=-1
+    ) + jnp.asarray(first_page, jnp.int32).reshape(1, nkv, 1)
+    # the chosen pages in a row: the last is the query's own block
+    lens = (n - 1) * page_size + (seq_lens % page_size)[:, None]
+    attend = paged_attention_kernel if kernel else paged_attention_reference
+    kw = {"block_kv": block_kv} if kernel else {}
+    o = attend(
+        q.reshape(B * nkv, g, H), k_pages, v_pages,
+        table.reshape(B * nkv, w), lens.reshape(B * nkv), **kw,
+    )
+    return o.reshape(B, nkv * g * H)

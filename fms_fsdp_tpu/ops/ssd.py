@@ -395,11 +395,15 @@ def ssd_scan(
     return y.astype(x.dtype)
 
 
-def _ssd_core_xla(x, dtf, a, Bm, Cm, L, return_state: bool = False):
+def _ssd_core_xla(
+    x, dtf, a, Bm, Cm, L, return_state: bool = False, init=None
+):
     """Checkpointed chunk scan over the XLA einsum formulation.
     Returns y (B, S, H, P) fp32 (no D term); with ``return_state`` also
     the final carried state (B, H, P, N) fp32 — the context-parallel
-    wrapper passes it across devices."""
+    wrapper passes it across devices. ``init``: the state to go on from
+    (zeros when None; ops/lightning_attention.py carries a prompt's state
+    from chunk to chunk through it)."""
     Bsz, S, H, P = x.shape
     G, N = Bm.shape[2], Bm.shape[3]
     C = S // L
@@ -417,7 +421,8 @@ def _ssd_core_xla(x, dtf, a, Bm, Cm, L, return_state: bool = False):
         y_c, s_new = _ssd_chunk(s, *inp, G)
         return s_new, y_c
 
-    init = jnp.zeros((Bsz, H, P, N), jnp.float32)
+    if init is None:
+        init = jnp.zeros((Bsz, H, P, N), jnp.float32)
     s_fin, ys = lax.scan(body, init, (xc, dtc, ac, Bc, Cc))
     y = jnp.moveaxis(ys, 0, 1).reshape(Bsz, S, H, P)
     if return_state:

@@ -1,4 +1,4 @@
-"""Family adapters: one serving engine, five model families.
+"""Family adapters: one serving engine, six model families.
 
 The ServingEngine owns admission, continuous batching, eviction and
 metrics — none of which care what a "slot" stores. What differs per
@@ -32,6 +32,14 @@ family          decode-state per stream
                 ``sliding_window`` keys and values a slot, constant
                 bytes whatever the context; the held share of the
                 expert layer is the code sarvam runs
+``minicpm_sala``  three kinds of state in one manager: paged KV pages
+                for the sparse-attention layers alone (a page is a
+                block of the choice; the only thing that grows), an
+                index cache of compressed keys beside them under the
+                same table (read whole by the choice, never by the
+                attention), and for the lightning linear-attention
+                layers a float32 ``(heads, H, H)`` state a slot,
+                constant bytes whatever the context
 ==============  ========================================================
 
 Every adapter is parity-anchored: greedy decode through the engine is
@@ -58,6 +66,8 @@ from fms_fsdp_tpu.models.configs import (
     LlamaConfig,
     MambaConfig,
     MixtralConfig,
+    SALA_MIXER_KINDS,
+    SalaConfig,
     SarvamConfig,
 )
 from fms_fsdp_tpu.obs.registry import MetricRegistry
@@ -67,6 +77,7 @@ from fms_fsdp_tpu.obs.spans import done, span
 # "serving"): family = FAMILY_CODES[name]
 FAMILY_CODES = {
     "llama": 0, "mamba": 1, "mixtral": 2, "sarvam": 3, "kexaone": 4,
+    "minicpm_sala": 5,
 }
 FAMILY_NAMES = {v: k for k, v in FAMILY_CODES.items()}
 
@@ -75,6 +86,7 @@ _CONFIG_FAMILIES = (
     (MixtralConfig, "mixtral"),
     (SarvamConfig, "sarvam"),
     (KExaoneConfig, "kexaone"),
+    (SalaConfig, "minicpm_sala"),
     (LlamaConfig, "llama"),
 )
 
@@ -84,11 +96,12 @@ def family_of(model_cfg) -> str:
     for cls, name in _CONFIG_FAMILIES:
         if isinstance(model_cfg, cls):
             return name
+    known = ", ".join(
+        f"{cls.__name__} ({name})" for cls, name in _CONFIG_FAMILIES
+    )
     raise ValueError(
         f"unknown model config type {type(model_cfg).__name__}: expected "
-        f"LlamaConfig, MambaConfig, MixtralConfig, SarvamConfig or "
-        f"KExaoneConfig "
-        f"(fms_fsdp_tpu/models/configs.py)"
+        f"one of {known} (fms_fsdp_tpu/models/configs.py)"
     )
 
 
@@ -103,7 +116,9 @@ def load_model_config(d: dict):
     published ``"model_type": "sarvam_mla"`` one (or ``"family":
     "sarvam"``) to the sarvam family through its own, a published
     ``"model_type": "exaone_moe"`` one (or ``"family": "kexaone"``) to
-    the kexaone family through its own. This is the single
+    the kexaone family through its own, a published ``"model_type":
+    "minicpm_sala"`` one (or ``"family": "minicpm_sala"``) to the
+    minicpm_sala family through its own. This is the single
     resolution point replica.py and the engine share — the two can no
     longer diverge on model construction (the PR-11 bug this replaces:
     replica.py:71 hardwired its own ``init_llama_params`` copy)."""
@@ -123,6 +138,10 @@ def load_model_config(d: dict):
         from fms_fsdp_tpu.models.configs import kexaone_config
 
         return kexaone_config(d)
+    if family == "minicpm_sala" or d.get("model_type") == "minicpm_sala":
+        from fms_fsdp_tpu.models.configs import minicpm_sala_config
+
+        return minicpm_sala_config(d)
     if family is None:
         if "d_model" in d or "n_layer" in d:
             family = "mamba"
@@ -166,9 +185,12 @@ def check_params_family(params, family: str) -> None:
     the checkpoint and the model config disagree — fail at build with
     the fix spelled out, not at the first prefill with a shape error."""
     layers = params.get("layers") if hasattr(params, "get") else None
-    if layers is None and hasattr(params, "get") and any(
-        kind in params for kind in KEXAONE_LAYER_KINDS
-    ):
+    stacks = (
+        set(params) if layers is None and hasattr(params, "get") else set()
+    )
+    if stacks & set(SALA_MIXER_KINDS.values()):
+        actual = "minicpm_sala"  # a stack for each kind of mixer
+    elif stacks & set(KEXAONE_LAYER_KINDS):
         actual = "kexaone"  # a stack for each kind of layer, no "layers"
     elif isinstance(layers, (list, tuple)):
         actual = "mamba"
@@ -181,10 +203,10 @@ def check_params_family(params, family: str) -> None:
     else:
         raise ValueError(
             "params do not look like any serveable family (no "
-            "recognizable 'layers' structure): expected init_llama_params"
-            " / init_mamba_params / init_mixtral_params / "
-            "init_sarvam_params / init_kexaone_params output or a "
-            "checkpoint thereof"
+            "recognizable 'layers' structure): expected the output of "
+            "the params initializer of one of "
+            f"{sorted(FAMILY_CODES)} (init_params_for) or a checkpoint "
+            "thereof"
         )
     if actual != family:
         raise ValueError(
@@ -215,6 +237,10 @@ def init_params_for(model_cfg):
         from fms_fsdp_tpu.models.kexaone import init_kexaone_params
 
         return lambda key: init_kexaone_params(key, model_cfg)
+    if family == "minicpm_sala":
+        from fms_fsdp_tpu.models.minicpm_sala import init_sala_params
+
+        return lambda key: init_sala_params(key, model_cfg)
     from fms_fsdp_tpu.models.llama import init_llama_params
 
     return lambda key: init_llama_params(key, model_cfg)
@@ -268,6 +294,10 @@ def resolve_adapter(
         from fms_fsdp_tpu.serve.families.sarvam import SarvamAdapter as cls
     elif family == "kexaone":
         from fms_fsdp_tpu.serve.families.kexaone import KExaoneAdapter as cls
+    elif family == "minicpm_sala":
+        from fms_fsdp_tpu.serve.families.minicpm_sala import (
+            MiniCPMSalaAdapter as cls,
+        )
     else:
         from fms_fsdp_tpu.serve.families.llama import LlamaAdapter as cls
     return cls(params, model_cfg, serve_cfg, compute_dtype, registry)
@@ -361,6 +391,16 @@ class FamilyAdapter:
         """The family's refusals, state (``_init_pages``, ``_state``) and
         ``_decode_fn``, from ``self.params``, ``model_cfg`` and ``scfg``."""
         raise NotImplementedError
+
+    def _refuse(self, *knobs) -> None:
+        """``(knob, value, why)``: a set knob that this family does not
+        take is refused by name at build."""
+        for knob, value, why in knobs:
+            if value:
+                raise ValueError(
+                    f"{self.family} serving does not take {knob}={value!r}: "
+                    f"{why}"
+                )
 
     def _init_pages(
         self, nlayers, nheads, n_kv_heads, head_dim, quant="none", tuned=True
@@ -844,16 +884,6 @@ class HeldExpertsAdapter(FamilyAdapter):
         self.registry.gauge("serve.moe_experts_published").set(
             cfg.num_experts
         )
-
-    def _refuse(self, *knobs) -> None:
-        """``(knob, value, why)``: a set knob that this family does not
-        take is refused by name at build."""
-        for knob, value, why in knobs:
-            if value:
-                raise ValueError(
-                    f"{self.family} serving does not take {knob}={value!r}: "
-                    f"{why}"
-                )
 
     def _count_prefill(self, rid: int, computed: int) -> None:
         """Beside the positions computed: the (token, choice) pairs they

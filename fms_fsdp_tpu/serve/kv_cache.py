@@ -11,7 +11,11 @@ head:
     pools["k"]: (L, P, page_size, Nkv, H)   P = num_pages
 
 and for latent attention (models/sarvam.py) one pool and no head axis,
-``pools["latent"]: (L, P, page_size, latent_dim)``. The allocator, the
+``pools["latent"]: (L, P, page_size, latent_dim)``. A pool may hold fewer
+rows a page than positions (``page_rows``: the index cache of
+models/minicpm_sala.py keeps one compressed key for every 16 positions,
+``pools["kc"]: (L, P, 4, H)`` beside pages of 64), so one allocator and
+one table serve caches of more than one grain. The allocator, the
 page tables, ``write_prompt``, the page export and import and ``defrag``
 work on whatever pools were declared. Each sequence owns an ordered list
 of page ids; logical cache
@@ -82,6 +86,8 @@ class PagedKVCache:
         quant: str = "none",
         shardings: Optional[Dict] = None,
         pools: Optional[Dict[str, tuple]] = None,
+        page_rows: Optional[Dict[str, int]] = None,
+        scratch_tail: bool = False,
     ):
         assert num_pages > RESERVED_PAGES, (
             f"num_pages={num_pages}: pages 0/1 are reserved (zero/scratch), "
@@ -96,6 +102,13 @@ class PagedKVCache:
         self.head_dim = head_dim
         self.dtype = dtype
         self.quant = quant
+        # a family whose prefill program is longer than the prompt it was
+        # given (serve/families/minicpm_sala.py: one program a doubling of
+        # the bucket) asks for this: ``write_prompt`` then takes values that
+        # cover more pages than the sequence holds and lands the tail,
+        # the prefill's zero padding, on the scratch page, so that a
+        # write's shape follows the program and not the prompt
+        self.scratch_tail = scratch_tail
 
         # name -> shape of one position's entry, in write_prompt's order
         self.entry_shapes = dict(pools) if pools else {
@@ -107,9 +120,18 @@ class PagedKVCache:
                 "quantized page storage is the k/v pools' wire format "
                 f"(per kv head): pools {sorted(pools)} store full-width"
             )
+        # name -> rows a page of that pool holds: a row a position unless
+        # the family says otherwise (``write_prompt`` takes that many rows
+        # a page of such a pool)
+        self.page_rows = {
+            name: int((page_rows or {}).get(name, page_size))
+            for name in self.entry_shapes
+        }
         store = _QUANT_STORE_DTYPE.get(quant, dtype)
         self.pools = {
-            name: jnp.zeros((n_layers, num_pages, page_size) + tuple(e), store)
+            name: jnp.zeros(
+                (n_layers, num_pages, self.page_rows[name]) + tuple(e), store
+            )
             for name, e in self.entry_shapes.items()
         }
         if quant != "none":
@@ -242,23 +264,27 @@ class PagedKVCache:
     def write_prompt(self, seq_id: int, *values):
         """Scatter a prefilled prompt into seq_id's pages: one (L, S_pad,
         *entry) array for each declared pool, in their order (``k, v``
-        for the default pools). ``S_pad`` must be a page multiple
+        for the default pools; ``S_pad / page_size * page_rows[name]``
+        rows for a pool of another grain). ``S_pad`` must be a page multiple
         covering the prompt (positions past the prompt are the prefill's
-        zero padding, which keeps page tails dense-identical). Call
-        ``ensure`` first."""
+        zero padding, which keeps page tails dense-identical; pages past
+        the sequence's own go to the scratch page under ``scratch_tail``).
+        Call ``ensure`` first."""
         assert len(values) == len(self.entry_shapes), (
             len(values), list(self.entry_shapes))
         L, s_pad = values[0].shape[0], values[0].shape[1]
         assert s_pad % self.page_size == 0, (s_pad, self.page_size)
         n = s_pad // self.page_size
         pages = self._seq_pages.get(seq_id, [])
-        assert n <= len(pages), (
+        assert n <= len(pages) or self.scratch_tail, (
             f"write_prompt needs {n} pages, sequence {seq_id} holds "
             f"{len(pages)} — call ensure() first"
         )
-        ids = jnp.asarray(pages[:n], jnp.int32)
+        ids = jnp.asarray(
+            pages[:n] + [SCRATCH_PAGE] * (n - len(pages)), jnp.int32
+        )
         paged = {
-            name: x.reshape((L, n, self.page_size) + tuple(entry))
+            name: x.reshape((L, n, self.page_rows[name]) + tuple(entry))
             for (name, entry), x in zip(self.entry_shapes.items(), values)
         }
         if self.quant == "none":
