@@ -71,6 +71,8 @@ from fms_fsdp_tpu.ops.paged_attention import (
     chosen_mask,
     chosen_pages_attention,
     compress_keys,
+    free_list,
+    gathered_blocks_attention,
 )
 from fms_fsdp_tpu.ops.ring_attention import merge_partial
 from fms_fsdp_tpu.ops.selective_scan import largest_divisor
@@ -90,6 +92,8 @@ Params = Dict[str, Any]
 PREFILL_CHUNK = 2048
 # queries whose choice of blocks is made in one piece inside a chunk
 SELECT_TILE = 256
+# queries that share one product against the band of forced blocks
+BAND_TILE = 256
 
 
 def init_sala_params(key, cfg: SalaConfig, dtype=jnp.float32) -> Params:
@@ -348,6 +352,22 @@ def prefill_choices(p: int, cfg: SalaConfig):
     return len(exist), sum(min(n, sp.topk) for n in exist), sum(exist)
 
 
+def prefill_multiplied(p: int, p_pad: int, cfg: SalaConfig) -> int:
+    """The blocks a kv head's products touch for the choosing positions
+    of a prompt of ``p`` tokens in the program of ``p_pad``
+    (``multiplied_blocks`` over its chunks): ``sala_prefill``'s fourth
+    count, from the sizes alone."""
+    sp = cfg.sparse
+    c = prefill_chunk(p_pad, cfg)
+    t = jnp.arange(min(p, sp.dense_len), p, dtype=jnp.int32)
+    chosen = jnp.asarray(
+        [f == "chosen" for f in chunk_forms(p_pad, cfg)]
+    )[t // c]
+    return int(jnp.sum(multiplied_blocks(
+        t // sp.block_size + 1, t // c * c, c, chosen, sp
+    )))
+
+
 def _use_flash(cfg: SalaConfig, attn_impl: str, c: int) -> bool:
     return c % 256 == 0 and cfg.head_dim % 128 == 0 and (
         attn_impl == "pallas"
@@ -355,15 +375,42 @@ def _use_flash(cfg: SalaConfig, attn_impl: str, c: int) -> bool:
     )
 
 
+def chunk_forms(p_pad: int, cfg: SalaConfig):
+    """What the sparse layers' attention is in each chunk of the prefill
+    program of ``p_pad`` positions, in the chunks' order: ``"dense"``
+    where every position of the chunk attends everything before it
+    (``start + c <= dense_len``), ``"chosen"`` where every position
+    chooses and its forced blocks stand apart (``start >= dense_len``
+    and the first block lies before the band), ``"masked"`` for a chunk
+    that holds both kinds (only where the chunk does not divide
+    ``dense_len``)."""
+    sp = cfg.sparse
+    c = prefill_chunk(p_pad, cfg)
+    apart = (sp.init_blocks + sp.window_blocks - 1) * sp.block_size
+    free = min(sp.topk, p_pad // sp.block_size) > (
+        sp.init_blocks + sp.window_blocks
+    )
+    return [
+        "dense" if start + c <= sp.dense_len
+        else "chosen" if free and start >= max(sp.dense_len, apart)
+        else "masked"
+        for start in range(0, p_pad, c)
+    ]
+
+
 def prefill_attn_form(cfg: SalaConfig, attn_impl: str, p_pad: int) -> str:
     """What the sparse layers' attention runs in the prefill program of
     ``p_pad`` positions (``attn_form`` on ``serve/prefill.dispatch``):
     chunks whose every position is dense take the causal flash walk or
-    its einsum form; later chunks the masked walk over their chosen
-    blocks."""
+    its einsum form; chunks past ``dense_len`` the band of forced blocks
+    and the kernel over each query's list of free blocks
+    (``+chosen_blocks``); a chunk that holds both kinds the masked walk
+    over every block (``+masked_blocks``)."""
     flash = _use_flash(cfg, attn_impl, prefill_chunk(p_pad, cfg))
-    dense = "flash" if flash else "einsum"
-    return dense + ("+masked_blocks" if p_pad > cfg.sparse.dense_len else "")
+    forms = chunk_forms(p_pad, cfg)
+    return ("flash" if flash else "einsum") + "".join(
+        f"+{form}_blocks" for form in ("masked", "chosen") if form in forms
+    )
 
 
 @scoped("sparse_compress")
@@ -386,37 +433,47 @@ def _compress_chunk(kc, k, before, start, ahead, sp):
 
 
 @scoped("sparse_select")
-def _select_chunk(q, kc, positions, sp):
-    """``_choose`` for a chunk's queries q (B, c, Nkv, g, H),
-    ``SELECT_TILE`` at a time: (B, Nkv, c, nb) bool."""
+def _select_chunk(q, kc, positions, sp, lists: bool):
+    """The choice of a chunk's queries q (B, c, Nkv, g, H), ``SELECT_TILE``
+    at a time: ``_choose``'s mask (B, Nkv, c, nb) bool, or with ``lists``
+    (a ``"chosen"`` chunk) each query's free blocks and their count
+    (``free_list``: (B, Nkv, c, W) and (B, Nkv, c) int32)."""
     B, c = q.shape[:2]
     tile = largest_divisor(c, SELECT_TILE)
+
+    def choose(q, t):
+        if lists:
+            return free_list(block_keys(q, kc, t, sp)[0], sp)
+        return (_choose(q, kc, t, sp),)
+
     if tile == c:
-        return _choose(q, kc, positions, sp)
+        out = choose(q, positions)
+    else:
 
-    def tiles(a):
-        return jnp.moveaxis(
-            a.reshape((B, c // tile, tile) + a.shape[2:]), 1, 0
+        def tiles(a):
+            return jnp.moveaxis(
+                a.reshape((B, c // tile, tile) + a.shape[2:]), 1, 0
+            )
+
+        out = lax.map(lambda a: choose(*a), (tiles(q), tiles(positions)))
+        # (c / tile, B, Nkv, tile, ...) -> (B, Nkv, c, ...)
+        out = tuple(
+            jnp.moveaxis(a, 0, 2).reshape(a.shape[1:3] + (c,) + a.shape[4:])
+            for a in out
         )
-
-    out = lax.map(
-        lambda a: _choose(a[0], kc, a[1], sp), (tiles(q), tiles(positions))
-    )  # (c / tile, B, Nkv, tile, nb)
-    return jnp.moveaxis(out, 0, 2).reshape(
-        B, out.shape[2], c, out.shape[-1]
-    )
+    return out if lists else out[0]
 
 
 @scoped("sparse_attn")
-def _chosen_chunk_attention(q, kb, vb, chosen, start, sp):
+def _masked_chunk_attention(q, kb, vb, chosen, start, sp):
     """A chunk's queries q (B, c, Nkv, g, H) at positions ``start`` on
     over the positions up to their own of the blocks ``chosen`` (B, Nkv,
     c, nb), out of the buffers kb, vb (B, kv_len, Nkv, H): the buffer is
     walked ``c`` positions at a time up to the chunk's own, each piece
     under the mask of its blocks, the partials merged through their
     log-sum-exp. Every block is multiplied and the unchosen masked: the
-    cost is the dense walk's (PERF.md section 7 has what a walk of the
-    chosen blocks alone needs). -> (B, c, N, H)."""
+    cost is the dense walk's, which only a chunk that holds dense
+    positions and choosing ones pays. -> (B, c, N, H)."""
     B, c, nkv, g, H = q.shape
     bs = sp.block_size
     q_pos = start + jnp.arange(c, dtype=jnp.int32)
@@ -441,6 +498,85 @@ def _chosen_chunk_attention(q, kb, vb, chosen, start, sp):
     return o.astype(kb.dtype)
 
 
+def _band_attention(q, kb, vb, start, sp):
+    """The forced half of a ``"chosen"`` chunk's attention: queries q (B,
+    c, Nkv, g, H) at positions ``start`` on over the first
+    ``init_blocks`` blocks and the ``window_blocks`` that end at their
+    own, up to their own position. The queries of a block share those
+    blocks and the band slides a block a block, so ``BAND_TILE`` queries
+    at a time take one masked product against their own positions, the
+    ``window_size - block_size`` before them and the first blocks.
+    -> the partial (B, c, N, H), (B, c, N, 1) float32."""
+    B, c, nkv, g, H = q.shape
+    bs = sp.block_size
+    tile = bs * largest_divisor(c // bs, max(1, BAND_TILE // bs))
+    first, back = sp.init_blocks * bs, (sp.window_blocks - 1) * bs
+    # a band key's position and a query's, both from the tile's start
+    k_pos = jnp.arange(-back, tile, dtype=jnp.int32)
+    t = jnp.arange(tile, dtype=jnp.int32)
+    seen = jnp.concatenate([
+        jnp.ones((tile, first), bool),
+        (k_pos[None, :] // bs > (t // bs)[:, None] - sp.window_blocks)
+        & (k_pos[None, :] <= t[:, None]),
+    ], axis=1)
+
+    def band(i):
+        at = start + i * tile
+
+        def keys(buf):
+            return jnp.concatenate([
+                buf[:, :first],
+                lax.dynamic_slice_in_dim(buf, at - back, back + tile, axis=1),
+            ], axis=1)
+
+        return _masked_attention(
+            lax.dynamic_slice_in_dim(q, i * tile, tile, axis=1),
+            keys(kb), keys(vb), seen[None, None],
+        )
+
+    if tile == c:
+        return band(0)
+    o, lse = lax.map(band, jnp.arange(c // tile, dtype=jnp.int32))
+    # (c / tile, B, tile, N, ...) -> (B, c, N, ...)
+    return tuple(
+        jnp.moveaxis(a, 0, 1).reshape((B, c) + a.shape[3:]) for a in (o, lse)
+    )
+
+
+@scoped("sparse_attn")
+def _chosen_chunk_attention(q, kb, vb, free, n, start, sp):
+    """A ``"chosen"`` chunk's queries q (B, c, Nkv, g, H) at positions
+    ``start`` on over the blocks each chose, out of the buffers kb, vb (B,
+    kv_len, Nkv, H): the forced half as a band (``_band_attention``), the
+    free half (``free`` (B, Nkv, c, W), ``n`` (B, Nkv, c) of
+    ``_select_chunk``) by the kernel that takes each query's blocks out
+    of a context resident in vector memory; the two partials merged
+    through their log-sum-exp. Only the chosen blocks and the band's
+    masked corners are multiplied. -> (B, c, N, H)."""
+    c = q.shape[1]
+    o, _ = merge_partial(
+        _band_attention(q, kb, vb, start, sp),
+        *gathered_blocks_attention(
+            q, kb, vb, free, n, start + c, block_size=sp.block_size
+        ),
+    )
+    return o.astype(kb.dtype)
+
+
+def multiplied_blocks(exist, start, c: int, chosen, sp):
+    """The blocks a kv head's products touch for a choosing position with
+    ``exist`` blocks up to its own, in the chunk at ``start``: where the
+    chunk is ``chosen``, the band (the forced blocks and the masked
+    corners of ``BAND_TILE`` queries) and the position's free blocks;
+    else every block up to the chunk's end, which the masked walk
+    multiplies."""
+    bs = sp.block_size
+    tile = largest_divisor(c // bs, max(1, BAND_TILE // bs))
+    forced = sp.init_blocks + sp.window_blocks
+    listed = jnp.clip(exist - forced, 0, sp.topk - forced)
+    return jnp.where(chosen, forced - 1 + tile + listed, (start + c) // bs)
+
+
 def sala_prefill(
     params: Params,
     tokens,
@@ -460,8 +596,13 @@ def sala_prefill(
     windows that straddle a chunk's start), each lightning layer's
     state, and each row's residual at its last real position. A chunk
     whose every position is dense (``start + c <= dense_len``) attends
-    as models/kexaone.py's full layers do; a later one chooses each
-    position's blocks and attends them under a mask.
+    as models/kexaone.py's full layers do; a chunk past ``dense_len``
+    chooses each position's free blocks as a list and multiplies the
+    chosen blocks alone (``_chosen_chunk_attention``); a chunk that holds
+    both kinds of position chooses under a mask and walks every block
+    (``_masked_chunk_attention``). The program decides by the chunk's
+    start, its length and ``dense_len`` (``chunk_forms``), and holds the
+    forms its chunks take and no others.
 
     Returns (logits (B, V) of each row's last real position; the sparse
     layers' pages ``{"k", "v"}`` (L_sparse * Nkv, B, kv_len, 1, H), a kv
@@ -469,8 +610,9 @@ def sala_prefill(
     (L_sparse * Nkv, B, kv_len / stride, H), the index cache's rows; the
     lightning layers' state ``{"S"}`` (L_lightning, B, heads, H, H)
     float32; and the counts (positions that chose their blocks, blocks
-    chosen by them a kv head, blocks they chose from) of the first
-    sparse layer: every sparse layer's are the same)."""
+    chosen by them a kv head, blocks they chose from, blocks the
+    attention's products touched for them: ``multiplied_blocks``) of the
+    first sparse layer: every sparse layer's are the same)."""
     with jax.named_scope("params_cast"):
         params = jax.tree.map(lambda a: a.astype(compute_dtype), params)
     B, S = tokens.shape
@@ -487,6 +629,21 @@ def sala_prefill(
     kc_shape = (B, nkv, 1 + rows, hd)
     s_shape = (B, cfg.lightning_nh) + (cfg.lightning_head_dim,) * 2
     lin_scale = cfg.lightning_head_dim**-0.5
+
+    forms = chunk_forms(S, cfg)
+    present = [f for f in ("dense", "masked", "chosen") if f in forms]
+    # where each form after the first begins: the forms come in that
+    # order (``chunk_forms``)
+    edges = jnp.asarray([forms.index(f) * c for f in present[1:]], jnp.int32)
+    first_chosen = forms.index("chosen") * c if "chosen" in forms else S
+
+    def by_form(start, **branches):
+        """The branch of the chunk at ``start``: chosen by ``start``, the
+        chunk and ``dense_len`` alone, among the forms this program's
+        chunks take (one form: no conditional)."""
+        return lambda x: lax.switch(
+            jnp.sum(start >= edges), [branches[f] for f in present], x
+        )
 
     def chunk(j, carry):
         pages, states, last, counts = carry
@@ -536,23 +693,36 @@ def sala_prefill(
                             impl="pallas" if flash else "xla",
                         )
 
+                def masked(q):
+                    qg = q.reshape(B, c, nkv, -1, hd)
+                    mask = _select_chunk(
+                        qg, kc[:, :, 1:], positions, sp, lists=False
+                    )
+                    return _masked_chunk_attention(qg, kb, vb, mask, start, sp)
+
                 def chosen(q):
                     qg = q.reshape(B, c, nkv, -1, hd)
-                    mask = _select_chunk(qg, kc[:, :, 1:], positions, sp)
+                    free, n = _select_chunk(
+                        qg, kc[:, :, 1:], positions, sp, lists=True
+                    )
                     return _chosen_chunk_attention(
-                        qg, kb, vb, mask, start, sp
+                        qg, kb, vb, free, n, start, sp
                     )
 
-                o = lax.cond(start + c <= sp.dense_len, dense, chosen, q)
+                o = by_form(start, dense=dense, masked=masked, chosen=chosen)(q)
                 if si == 0:
                     chose = live & (positions + 1 > sp.dense_len)
                     exist = positions // sp.block_size + 1
+                    touched = multiplied_blocks(
+                        exist, start, c, start >= first_chosen, sp
+                    )
                     counts = counts + jnp.stack([
                         jnp.sum(chose),
                         jnp.sum(
                             jnp.where(chose, jnp.minimum(exist, sp.topk), 0)
                         ),
                         jnp.sum(jnp.where(chose, exist, 0)),
+                        jnp.sum(jnp.where(chose, touched, 0)),
                     ]).astype(jnp.int32)
                 si += 1
                 x = _mlp(_sparse_out(x, o, u, layer, cfg), layer, cfg)
@@ -578,7 +748,7 @@ def sala_prefill(
             ),
             tuple(zeros(s_shape, jnp.float32) for _ in range(n_lin)),
             zeros((B, cfg.emb_dim)),
-            jnp.zeros((3,), jnp.int32),
+            jnp.zeros((4,), jnp.int32),
         ),
     )
     logits = _head(last, params, cfg)

@@ -52,6 +52,8 @@ from jax.experimental.pallas import tpu as pltpu
 
 from fms_fsdp_tpu.obs.scopes import scoped
 from fms_fsdp_tpu.ops.pallas_mode import interpret_default
+from fms_fsdp_tpu.ops.ring_attention import merge_partial
+from fms_fsdp_tpu.ops.selective_scan import largest_divisor
 
 NEG_INF = -1e30
 LOG2E = 1.4426950408889634  # log2(e)
@@ -598,3 +600,258 @@ def chosen_pages_attention(
         table.reshape(B * nkv, w), lens.reshape(B * nkv), **kw,
     )
     return o.reshape(B, nkv * g * H)
+
+
+# ---------------------------------------------------------------------------
+# chosen blocks of a prefill chunk: each query's own list out of a context
+# that is resident in vector memory
+# ---------------------------------------------------------------------------
+#
+# A prefill chunk has thousands of queries and every one of them chose its
+# own blocks; what the queries of a block share is the forced half of the
+# choice (the first blocks and the band that ends at their own), which the
+# caller multiplies as one band. The free half has nothing to share: under
+# seeded weights the union of a tile's free blocks is the context. So the
+# kernel below holds one kv head's keys and values of the context in
+# vector memory (a segment of it where the whole would not fit) and takes
+# each query's free blocks out of that memory by index: no page is fetched
+# from HBM a second time and the scores never leave the chip. It shares no
+# logic with the decode kernel above, which walks one query's pages out
+# of a pool.
+
+# bytes of vector memory one kv head's keys and values may take while a
+# chunk's queries gather from them: a context that needs more is held a
+# segment at a time, each query's list cut by segment
+RESIDENT_KV_BYTES = 64 * 2**20
+# rows the resident keys and values are copied in at a time
+_COPY_ROWS = 2048
+# lanes the log-sum-exp of a query's heads is written over
+_LSE_LANES = 128
+
+
+def free_list(key, sp):
+    """``block_keys``' ranking -> (free (B, Nkv, T, W) int32, n (B, Nkv,
+    T) int32): the blocks each query chose beyond the forced ones, best
+    first and ties to the lower index (``lax.top_k``'s places after the
+    forced), then zeros; ``W = min(topk, nb) - init_blocks -
+    window_blocks``, which has to be at least 1. For queries that are not
+    dense and whose forced blocks all exist and stand apart (own block
+    ``>= init_blocks + window_blocks - 1``): with the forced blocks the
+    list is ``chosen_mask``'s row, block for block."""
+    forced = sp.init_blocks + sp.window_blocks
+    top, idx = jax.lax.top_k(key, min(sp.topk, key.shape[-1]))
+    keep = top[..., forced:] > -jnp.inf
+    free = jnp.where(keep, idx[..., forced:], 0).astype(jnp.int32)
+    return free, jnp.sum(keep, axis=-1).astype(jnp.int32)
+
+
+def _gather_blocks_kernel(
+    upto_ref,  # scalar prefetch: (1,) int32, positions written so far
+    lst_ref,  # SMEM (1, tq * w): the cell's lists, block indices in the segment
+    cnt_ref,  # SMEM (1, tq): how many slots of each list count
+    q_ref,  # (1, tq, g, H)
+    k_hbm,  # (B, Nkv, S, H), left in place
+    v_hbm,
+    o_ref,  # (1, 1, tq, g, H) float32
+    lse_ref,  # (1, 1, tq, g, _LSE_LANES) float32
+    k_res,  # VMEM (segment, H)
+    v_res,
+    sem,
+    *,
+    block_size,
+    width,
+    segment,
+    copy_rows,
+    scale,
+):
+    """One (row, kv head, segment, tile of queries) cell. At a segment's
+    first tile the kv head's keys and values of the segment, up to the
+    positions written so far, are copied into ``k_res``/``v_res``; every
+    tile of queries then gathers from them. A query's ``width`` slots are
+    taken out in one piece (a slot past its count reads block 0 and is
+    masked): one product of the kv head's query heads against them, the
+    softmax in float32, one product with the values."""
+    b, h, s = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    tq, g, H = q_ref.shape[1:]
+    bs = block_size
+
+    @pl.when(pl.program_id(3) == 0)
+    def _():
+        rows = jnp.clip(upto_ref[0] - s * segment, 0, segment)
+        pieces = (rows + copy_rows - 1) // copy_rows
+
+        def copies(i):
+            at = pl.ds(i * copy_rows, copy_rows)
+            src = pl.ds(s * segment + i * copy_rows, copy_rows)
+            return (
+                pltpu.make_async_copy(
+                    k_hbm.at[b, h, src], k_res.at[at], sem.at[0]),
+                pltpu.make_async_copy(
+                    v_hbm.at[b, h, src], v_res.at[at], sem.at[1]),
+            )
+
+        def start(i, _):
+            for c in copies(i):
+                c.start()
+
+        def wait(i, _):
+            for c in copies(i):
+                c.wait()
+
+        jax.lax.fori_loop(0, pieces, start, None)
+        jax.lax.fori_loop(0, pieces, wait, None)
+
+    col = jax.lax.broadcasted_iota(jnp.int32, (g, width * bs), 1)
+
+    def query(t, _):
+        def gathered(ref):
+            return jnp.concatenate([
+                ref[pl.ds(pl.multiple_of(
+                    lst_ref[0, t * width + i] * bs, bs), bs), :]
+                for i in range(width)
+            ], axis=0)  # (width * bs, H)
+
+        sc = jax.lax.dot_general(
+            q_ref[0, t], gathered(k_res), (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        ) * scale  # (g, width * bs)
+        live = col < cnt_ref[0, t] * bs
+        sc = jnp.where(live, sc, NEG_INF)
+        m = jnp.max(sc, axis=1, keepdims=True)
+        p = jnp.where(live, jnp.exp(sc - m), 0.0)
+        l = jnp.maximum(jnp.sum(p, axis=1, keepdims=True), 1e-30)
+        v = gathered(v_res)
+        o = jax.lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        o_ref[0, 0, t] = o / l
+        lse_ref[0, 0, t] = jnp.broadcast_to(
+            m + jnp.log(l), (g, lse_ref.shape[-1]))
+
+    jax.lax.fori_loop(0, tq, query, None)
+
+
+def gathered_blocks_attention(
+    q, kb, vb, free, n, upto, *, block_size, q_tile=128, segment=None,
+    interpret=None,
+):
+    """Each query over its own list of whole blocks. q (B, c, Nkv, g, H);
+    kb, vb (B, kv_len, Nkv, H), written up to ``upto`` positions (a traced
+    int32; what lies past it is not read); ``free`` (B, Nkv, c, W) int32
+    and ``n`` (B, Nkv, c) from ``free_list``: query ``t`` of row ``b``
+    attends, for kv head ``h``, every position of blocks ``free[b, h, t,
+    :n[b, h, t]]`` (blocks before its own: no causal edge inside them).
+    -> (normalised output (B, c, N, H) float32, log-sum-exp (B, c, N, 1)
+    float32), a partial that ``merge_partial`` joins with the band's; a
+    query with an empty list gives a log-sum-exp near ``NEG_INF``.
+
+    The keys and values of one kv head are resident in vector memory
+    while the chunk's queries gather from them, ``segment`` positions at
+    a time (by default the whole context, or what ``RESIDENT_KV_BYTES``
+    hold): each segment takes the lists' blocks that lie in it, in rising
+    order, and the segments' partials are merged here."""
+    H, kv_len, bs = q.shape[-1], kb.shape[1], block_size
+    if interpret is None:
+        interpret = interpret_default()
+    if segment is None:
+        fit = RESIDENT_KV_BYTES // (2 * H * kb.dtype.itemsize)
+        segment = bs * largest_divisor(kv_len // bs, fit // bs)
+    assert kv_len % segment == 0 and segment % bs == 0, (kv_len, segment, bs)
+    return _gathered_blocks(
+        q, kb, vb, free, n, jnp.asarray(upto, jnp.int32), block_size=bs,
+        q_tile=q_tile, segment=segment, interpret=interpret,
+    )
+
+
+# jitted, so that a program whose layers call it with one signature lowers
+# the kernel once
+@functools.partial(
+    jax.jit, static_argnames=("block_size", "q_tile", "segment", "interpret"))
+def _gathered_blocks(
+    q, kb, vb, free, n, upto, *, block_size, q_tile, segment, interpret
+):
+    B, c, nkv, g, H = q.shape
+    kv_len = kb.shape[1]
+    bs = block_size
+    W = free.shape[-1]
+    n_seg = kv_len // segment
+    copy_rows = bs * largest_divisor(segment // bs, max(1, _COPY_ROWS // bs))
+    tq = largest_divisor(c, q_tile)
+
+    if n_seg == 1:
+        lists, counts = free[None], n[None]
+    else:
+        # a segment's share of each list: the blocks that lie in it, in
+        # rising order from slot 0 on
+        seg_blocks = segment // bs
+        valid = jnp.arange(W, dtype=jnp.int32) < n[..., None]
+        rel = free[None] - (
+            jnp.arange(n_seg, dtype=jnp.int32) * seg_blocks
+        ).reshape(n_seg, 1, 1, 1, 1)
+        inside = valid[None] & (rel >= 0) & (rel < seg_blocks)
+        counts = jnp.sum(inside, axis=-1).astype(jnp.int32)
+        rel = jnp.sort(jnp.where(inside, rel, seg_blocks), axis=-1)
+        lists = jnp.where(rel < seg_blocks, rel, 0)
+    # (n_seg, B, Nkv, c, ...) -> a row of scalar memory a cell
+    cells = B * nkv * n_seg * (c // tq)
+    lists = jnp.moveaxis(lists, 0, 2).reshape(cells, 1, tq * W)
+    counts = jnp.moveaxis(counts, 0, 2).reshape(cells, 1, tq)
+
+    def cell(b, h, s, i, upto):
+        return (((b * nkv + h) * n_seg + s) * (c // tq) + i, 0, 0)
+
+    smem = functools.partial(pl.BlockSpec, memory_space=pltpu.SMEM)
+    out_map = lambda b, h, s, i, upto: (s, b, i, h, 0)  # noqa: E731
+    resident = 2 * segment * H * kb.dtype.itemsize
+    o, lse = pl.pallas_call(
+        functools.partial(
+            _gather_blocks_kernel, block_size=bs, width=W, segment=segment,
+            copy_rows=copy_rows, scale=H**-0.5,
+        ),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(B, nkv, n_seg, c // tq),
+            in_specs=[
+                smem((None, 1, tq * W), cell),
+                smem((None, 1, tq), cell),
+                pl.BlockSpec(
+                    (1, tq, g, H), lambda b, h, s, i, upto: (b, i, h, 0)),
+                pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec(memory_space=pl.ANY),
+            ],
+            out_specs=[
+                pl.BlockSpec((1, 1, tq, g, H), out_map),
+                pl.BlockSpec((1, 1, tq, g, _LSE_LANES), out_map),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((segment, H), kb.dtype),
+                pltpu.VMEM((segment, H), vb.dtype),
+                pltpu.SemaphoreType.DMA((2,)),
+            ],
+        ),
+        out_shape=[
+            jax.ShapeDtypeStruct((n_seg, B, c, nkv * g, H), jnp.float32),
+            jax.ShapeDtypeStruct(
+                (n_seg, B, c, nkv * g, _LSE_LANES), jnp.float32),
+        ],
+        # the resident keys and values cross the tiles of a segment
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",) * 4,
+            vmem_limit_bytes=min(resident + 24 * 2**20, 120 * 2**20),
+        ),
+        interpret=interpret,
+        name="gathered_blocks_attention",
+    )(
+        jnp.reshape(upto, (1,)), lists, counts,
+        q.reshape(B, c, nkv * g, H),
+        # by head: a copy may not take a head out of the (Nkv, H) minor
+        # tile, and the compiler keeps the prefill's buffers head-major
+        # anyway, so this moves nothing there
+        jnp.moveaxis(kb, 2, 1), jnp.moveaxis(vb, 2, 1),
+    )
+    lse = lse[..., :1]
+    out = (o[0], lse[0])
+    for s in range(1, n_seg):
+        out = merge_partial(out, o[s], lse[s])
+    return out

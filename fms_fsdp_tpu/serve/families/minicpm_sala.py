@@ -42,10 +42,17 @@ in a loop inside its program that stops at the prompt's length
 (``serve.prefill_computed_tokens``). A program serves every prompt up to
 its length, so the adapter builds one for each doubling of the bucket
 (``program_len``) and not one a bucket: a 64k deployment has six, not
-32. ``serve/prefill.done`` carries what the program counted:
+32. ``attn_form`` on ``serve/prefill.dispatch`` says what the sparse
+layers' attention runs in the program's chunks (``prefill_attn_form``:
+``flash`` or ``einsum``, ``+chosen_blocks`` where chunks lie past
+``dense_len``). ``serve/prefill.done`` carries what the program counted:
 ``chose_tokens`` (positions with ``t + 1 > dense_len``, which chose
 their blocks), ``chosen_blocks`` (the blocks those chose, a kv head and
-layer) and ``context_blocks`` (the blocks they could have chosen from).
+layer), ``context_blocks`` (the blocks they could have chosen from) and
+``multiplied_blocks`` (the blocks the attention's products touched for
+them: the chosen ones and the band's masked corners where a chunk lies
+past ``dense_len``, every block of the context where a chunk holds
+dense positions too and takes the masked walk).
 
 Not here (PERF.md section 7): a serving layout over chips, handoff of
 pages, index rows and state, quantized pages, speculative decode, prefix
@@ -188,7 +195,7 @@ def prefill_program(model_cfg, scfg, n: int, compute_dtype):
 class MiniCPMSalaAdapter(FamilyAdapter):
     family = "minicpm_sala"
     _pages_noun = "sparse-attention pages"
-    _counts = (0, 0, 0)  # a prefill's, on the device until they are read
+    _counts = (0, 0, 0, 0)  # a prefill's, on the device until they are read
 
     def _setup(self) -> None:
         cfg, scfg = self.model_cfg, self.scfg
@@ -311,18 +318,22 @@ class MiniCPMSalaAdapter(FamilyAdapter):
 
     def _count_prefill(self, rid: int, computed: int) -> None:
         """Beside the positions computed: those of them that chose their
-        blocks (``t + 1 > dense_len``), the blocks they chose and the
-        blocks they chose from, a kv head and sparse layer (the program's
-        own counts; reading them waits for the prefill, which the
-        engine's sampler does next anyway)."""
-        chose, blocks, context = map(int, np.asarray(self._counts))
+        blocks (``t + 1 > dense_len``), the blocks they chose, the blocks
+        they chose from and the blocks the attention's products touched
+        for them, a kv head and sparse layer (the program's own counts;
+        reading them waits for the prefill, which the engine's sampler
+        does next anyway)."""
+        chose, blocks, context, multiplied = map(
+            int, np.asarray(self._counts)
+        )
         counter = self.registry.counter
         counter("serve.sparse_chose_tokens").add(chose)
         counter("serve.sparse_chosen_blocks").add(blocks)
         counter("serve.sparse_context_blocks").add(context)
+        counter("serve.sparse_multiplied_blocks").add(multiplied)
         super()._count_prefill(
             rid, computed, chose_tokens=chose, chosen_blocks=blocks,
-            context_blocks=context,
+            context_blocks=context, multiplied_blocks=multiplied,
         )
 
     # -- decode: the step's count beside the skeleton's dispatch -----------

@@ -113,6 +113,35 @@ def test_paged_attention_compiles(v5e, page_size, pages_per_block, store):
     _compile(fn, *args)
 
 
+@pytest.mark.parametrize("kv_len,segment", [
+    (65536, None), (16384, None), (262144, None), (65536, 16384)],
+    ids=["64k", "16k", "256k_in_two_segments", "64k_in_four_segments"])
+def test_gathered_blocks_attention_compiles(v5e, kv_len, segment):
+    """The minicpm_sala prefill's kernel over each query's list of free
+    blocks, at the published sizes: a chunk of 2048 queries, 2 kv heads of
+    16 query heads of 128, 31 free blocks of 64 a query, one kv head's
+    keys and values of the context resident in vector memory (32 MB at
+    65536 positions; 131072 positions a segment where the context is
+    longer)."""
+    from fms_fsdp_tpu.ops import paged_attention as P
+
+    c, nkv, g, hd, w = 2048, 2, 16, 128, 31
+    if segment is None and kv_len > 131072:
+        assert P.RESIDENT_KV_BYTES // (2 * hd * 2) == 131072
+
+    def attend(q, kb, vb, free, n, upto):
+        return P.gathered_blocks_attention(
+            q, kb, vb, free, n, upto[0], block_size=64, segment=segment,
+            interpret=False)
+
+    kv = _sds(v5e, (1, kv_len, nkv, hd), jnp.bfloat16)
+    compiled = _compile(
+        attend, _sds(v5e, (1, c, nkv, g, hd), jnp.bfloat16), kv, kv,
+        _sds(v5e, (1, nkv, c, w), jnp.int32), _sds(v5e, (1, nkv, c), jnp.int32),
+        _sds(v5e, (1,), jnp.int32))
+    assert compiled.as_text().count("tpu_custom_call") == 1
+
+
 def test_ssd_fused_fwd_bwd_compiles(v5e, monkeypatch):
     from fms_fsdp_tpu.ops import pallas_mode
     from fms_fsdp_tpu.ops.ssd import ssd_scan

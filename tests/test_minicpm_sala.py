@@ -243,6 +243,131 @@ def test_sparse_is_dense_on_a_context_of_fewer_blocks():
     assert _gap(np.asarray(got), a) < 2e-5
 
 
+def _chunk(sparse, kv_len, start, c, nkv=2, g=2, seed=5, ties=False):
+    """A chunk's queries at ``start`` and buffers written up to its end,
+    with the compressed keys of what is written (``ties``: keys of zeros,
+    so every block scores alike)."""
+    sp = SalaSparseConfig(**sparse)
+    rng = np.random.default_rng(seed)
+    q = jnp.asarray(rng.normal(size=(1, c, nkv, g, 16)), jnp.float32)
+    kb, vb = (rng.normal(size=(1, kv_len, nkv, 16)) for _ in range(2))
+    kb[:, start + c:], vb[:, start + c:] = 0, 0
+    if ties:
+        kb[:] = 0
+    kb, vb = jnp.asarray(kb, jnp.float32), jnp.asarray(vb, jnp.float32)
+    kc = M._pad_rows(P.compress_keys(kb, sp), kv_len // sp.kernel_stride)
+    t = jnp.broadcast_to(start + jnp.arange(c, dtype=jnp.int32), (1, c))
+    return sp, q, kb, vb, kc, t
+
+
+# (sparse sizes, kv_len, start, chunk, kv heads, query heads a kv head,
+# bytes of resident keys and values or None for the module's)
+CHOSEN_CHUNKS = {
+    "deep_in_a_context": (SPARSE, 512, 448, 32, 2, 2, None),
+    "first_past_dense_len": (SPARSE, 256, 64, 32, 2, 2, None),
+    # 12 chosen of which 9 free, where blocks 4 and 5 have 2 and 3 to give
+    "fewer_free_blocks_than_the_list": (
+        {**SPARSE, "topk": 12}, 256, 64, 32, 2, 2, None),
+    # one block a chunk and band tile: its first and last query
+    "a_blocks_first_and_last_query": (SPARSE, 256, 160, 16, 2, 2, None),
+    "one_kv_head": (SPARSE, 256, 192, 32, 1, 4, None),
+    # 128 positions of float32 keys and values a segment: two segments
+    "lists_cut_across_two_segments": (
+        {**SPARSE, "topk": 10}, 256, 192, 32, 2, 2, 2 * 128 * 16 * 4),
+    # a chunk longer than the window: free blocks inside the chunk itself
+    "free_blocks_inside_the_chunk": (SPARSE, 256, 128, 128, 2, 2, None),
+}
+
+
+@pytest.mark.parametrize("case", list(CHOSEN_CHUNKS))
+def test_chosen_chunk_attention_is_the_masked_attention(case, monkeypatch):
+    """The band of forced blocks and the kernel over each query's list
+    (interpret mode) against one masked product under ``chosen_mask``'s
+    mask, float32."""
+    sparse, kv_len, start, c, nkv, g, resident = CHOSEN_CHUNKS[case]
+    if resident:
+        monkeypatch.setattr(P, "RESIDENT_KV_BYTES", resident)
+    monkeypatch.setattr(M, "BAND_TILE", 16)
+    sp, q, kb, vb, kc, t = _chunk(sparse, kv_len, start, c, nkv, g)
+    chosen = np.asarray(M._choose(q, kc, t, sp))  # (1, Nkv, c, nb)
+    pos = np.arange(kv_len)
+    mask = chosen[..., pos // sp.block_size] & (
+        pos[None, :] <= np.asarray(t)[0][:, None])
+    want, _ = M._masked_attention(q, kb, vb, jnp.asarray(mask))
+    free, n = M._select_chunk(q, kc, t, sp, lists=True)
+    width = min(sp.topk, kv_len // sp.block_size) - 3
+    assert free.shape == (1, nkv, c, width)
+    exist = np.asarray(t)[0] // sp.block_size + 1
+    assert (np.asarray(n)[0] == np.clip(exist - 3, 0, width)).all()
+    if case == "fewer_free_blocks_than_the_list":
+        assert int(n.max()) < width
+    if case == "free_blocks_inside_the_chunk":
+        assert int(free.max()) * sp.block_size >= start
+    got = M._chosen_chunk_attention(q, kb, vb, free, n, jnp.int32(start), sp)
+    assert got.shape == want.shape == (1, c, nkv * g, 16)
+    gap = np.abs(np.asarray(got) - np.asarray(want)).max(axis=(0, 2, 3))
+    assert gap.max() < 2e-6, (int(gap.argmax()), gap.max())
+    # and the free half alone, against the mask less the forced blocks
+    own = exist - 1
+    blocks = np.arange(kv_len // sp.block_size)
+    forced = (blocks[None] < sp.init_blocks) | (
+        (blocks[None] <= own[:, None])
+        & (blocks[None] > own[:, None] - sp.window_blocks))
+    rest = jnp.asarray((chosen & ~forced)[..., pos // sp.block_size])
+    want, lse = M._masked_attention(q, kb, vb, rest)
+    got, got_lse = P.gathered_blocks_attention(
+        q, kb, vb, free, n, start + c, block_size=sp.block_size)
+    some = np.asarray(n)[0, 0] > 0  # an empty list: no weight in the merge
+    assert np.abs(np.asarray(got - want))[:, some].max() < 2e-6
+    assert np.abs(np.asarray(got_lse - lse))[:, some].max() < 2e-6
+    assert (np.asarray(got_lse)[:, ~some] < -1e29).all()
+
+
+@pytest.mark.parametrize("ties", [False, True], ids=["ranked", "ties"])
+def test_free_lists_and_forced_blocks_are_the_masks_rows(ties):
+    """``_select_chunk``'s lists against ``chosen_mask``'s rows: the
+    first block, the band and the listed blocks are the mask, block for
+    block; where every block scores alike the list is the lowest free
+    indices, as ``chosen_mask`` breaks its ties."""
+    sparse = {**SPARSE, "topk": 8}
+    sp, q, kb, vb, kc, t = _chunk(sparse, 512, 320, 64, ties=ties)
+    mask = np.asarray(M._select_chunk(q, kc, t, sp, lists=False))
+    free, n = map(np.asarray, M._select_chunk(q, kc, t, sp, lists=True))
+    assert free.shape == (1, 2, 64, 5) and (n == 5).all()
+    for h in range(2):
+        for i in range(64):
+            own = (320 + i) // 16
+            listed = {0, own - 1, own, *free[0, h, i].tolist()}
+            assert listed == set(np.flatnonzero(mask[0, h, i]).tolist())
+            assert len(listed) == 8
+            if ties:
+                assert free[0, h, i].tolist() == [1, 2, 3, 4, 5]
+    if not ties:
+        assert (free[0, 0] != free[0, 1]).any()  # a kv head's own choice
+
+
+def test_prefill_attn_form_follows_the_chunks(monkeypatch):
+    cfg = minicpm_sala_config(TINY)
+    assert M.chunk_forms(192, cfg) == ["dense", "masked", "chosen", "chosen"]
+    assert M.prefill_attn_form(cfg, "xla", 192) == (
+        "einsum+masked_blocks+chosen_blocks")
+    assert M.prefill_attn_form(cfg, "xla", 48) == "einsum"
+    assert M.prefill_attn_form(cfg, "xla", 96) == "einsum+masked_blocks"
+    # the published sizes: the chunk divides dense_len, no chunk holds both
+    big = minicpm_sala_config({**TINY, "head_dim": 128, "sparse_config": {}})
+    assert cfg.sparse != big.sparse == SalaSparseConfig()
+    monkeypatch.setattr(M, "PREFILL_CHUNK", 2048)  # the module's own
+    assert M.prefill_chunk(65536, big) == 2048
+    assert M.chunk_forms(65536, big) == ["dense"] * 4 + ["chosen"] * 28
+    assert M.prefill_attn_form(big, "pallas", 65536) == "flash+chosen_blocks"
+    assert M.prefill_attn_form(big, "pallas", 8192) == "flash"
+    # no block to choose freely: the band is all, the walk stays
+    monkeypatch.setattr(M, "PREFILL_CHUNK", CHUNK)
+    none = minicpm_sala_config(
+        {**TINY, "sparse_config": {**SPARSE, "topk": 3}})
+    assert M.chunk_forms(192, none) == ["dense"] + ["masked"] * 3
+
+
 # ---------------------------------------------------------------------------
 # the model against the reference
 # ---------------------------------------------------------------------------
@@ -260,15 +385,24 @@ def test_full_forward_agrees_with_the_reference(lightning):
     assert _gap(np.asarray(got), np.asarray(want)) < 2e-5
 
 
-def test_prefill_in_chunks_is_the_forward():
+@pytest.mark.parametrize("chunk,forms", [
+    (48, ["dense", "masked", "chosen", "chosen"]),
+    (32, ["dense"] * 2 + ["chosen"] * 4),
+], ids=["a_chunk_holds_both_kinds", "every_chunk_past_dense_len_chosen"])
+def test_prefill_in_chunks_is_the_forward(chunk, forms, monkeypatch):
     """Rows that end inside a chunk, on a chunk's edge and inside a
     compression window; chunks of 48 cut every window of 8 that starts 4
-    before an edge. What is handed over is what a decode step reads."""
+    before an edge, and one of them holds positions up to ``dense_len``
+    and positions past it (the masked walk); with chunks of 32 every
+    chunk past ``dense_len`` takes the band and the kernel. What is
+    handed over is what a decode step reads."""
+    monkeypatch.setattr(M, "PREFILL_CHUNK", chunk)
     tree, cfg = _tree(), minicpm_sala_config(TINY)
     sp = cfg.sparse
     lengths = [190, 144, 67]
     tokens = jax.random.randint(jax.random.PRNGKey(4), (3, 192), 1, 256)
-    assert M.prefill_chunk(192, cfg) == 48
+    assert M.prefill_chunk(192, cfg) == chunk
+    assert M.chunk_forms(192, cfg) == forms
     logits, kv, state, counts = M.sala_prefill(
         tree, tokens, jnp.asarray(lengths, jnp.int32), cfg,
         compute_dtype=jnp.float32, attn_impl="xla")
@@ -278,14 +412,24 @@ def test_prefill_in_chunks_is_the_forward():
     assert kv["k"].shape == (4, 3, 192, 1, 16) and kv["kc"].shape == (4, 3, 48, 16)
     assert state["S"].shape == (2, 3, 4, 16, 16)
     assert tuple(map(int, counts)) == tuple(
-        sum(x) for x in zip(*(M.prefill_choices(n, cfg) for n in lengths)))
+        sum(x) for x in zip(*(
+            M.prefill_choices(n, cfg) + (M.prefill_multiplied(n, 192, cfg),)
+            for n in lengths)))
     assert M.prefill_choices(67, cfg) == (3, 3 * 5, 3 * 5)
     assert M.prefill_positions(67, 192, cfg) == 96
+    # positions 64-66: the walk of the chunk 48-95 multiplies its 6
+    # blocks; a chosen chunk the first block, the band of 2 with a tile's
+    # corner (a tile is the chunk's 2 blocks) and the 2 free blocks left
+    assert M.prefill_multiplied(67, 192, cfg) == (
+        3 * 6 if chunk == 48 else 3 * (1 + 1 + 2 + 2))
+    chose, chosen, context, multiplied = map(int, counts)
+    assert chosen <= multiplied < context
     # the index rows: the mean of each whole window, zeros past the end
     for b, n in enumerate(lengths):
         k = kv["k"][:2, b, :, 0]  # the first sparse layer's two heads
         whole = (n - sp.kernel_size) // sp.kernel_stride + 1
-        for j in (0, 11, 12, whole - 1):  # 11 and 12 straddle position 48
+        # 7 straddles position 32, 11 and 12 position 48
+        for j in (0, 7, 11, 12, whole - 1):
             mean = k[:, 4 * j: 4 * j + 8].mean(axis=1)
             assert np.allclose(kv["kc"][:2, b, j], mean, atol=1e-5)
         assert not np.asarray(kv["kc"][:, b, whole:]).any()
@@ -332,20 +476,31 @@ def _serve_capturing(eng, prompts, max_new):
     return reqs, [np.stack(rows[r.rid][: len(r.generated)]) for r in reqs]
 
 
-@pytest.mark.parametrize("attn", ["reference", "kernel"])
-def test_engine_agrees_with_the_reference_on_logits_float32(attn):
+@pytest.mark.parametrize("attn,bucket", [
+    ("reference", BUCKET), ("kernel", BUCKET), ("reference", 48)],
+    ids=["reference", "kernel", "a_chunk_holds_both_kinds"])
+def test_engine_agrees_with_the_reference_on_logits_float32(
+        attn, bucket, tmp_path):
     """Three requests on two slots (a slot's state is left and taken over
     while the other decodes): a prompt past ``dense_len`` prefilled in
     chunks and decoded through chosen pages, one that crosses
     ``dense_len`` while it decodes (50 + 20 positions over 64), a short
     one. Every served position's logits against the reference's full
-    forward."""
+    forward. With the bucket of 64 the programs' chunks are 32 and every
+    chunk past ``dense_len`` takes the band and the kernel; with the
+    bucket of 48 they are 48 and the chunk 48-95 takes the masked walk.
+    The prefill spans say which and what the products touched."""
+    from tests.test_serve_spans import named, read_spans
+
     cfg, tree = minicpm_sala_config(TINY), _tree()
-    eng = _engine(tree, cfg, attn_impl=attn)
+    eng = _engine(tree, cfg, attn_impl=attn, prefill_bucket=bucket)
     assert eng.adapter.attn_impl == attn and eng.family == "minicpm_sala"
     rng = np.random.default_rng(7)
     prompts = [rng.integers(1, 256, size=n).tolist() for n in (150, 50, 5)]
-    reqs, rows = _serve_capturing(eng, prompts, 20)
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    with jax.profiler.trace(str(tmp_path), profiler_options=opts):
+        reqs, rows = _serve_capturing(eng, prompts, 20)
     for prompt, req, got in zip(prompts, reqs, rows):
         assert req.state == "finished" and len(req.generated) == 20
         want = _ref_logits(tree, prompt + req.generated[:-1])
@@ -354,10 +509,11 @@ def test_engine_agrees_with_the_reference_on_logits_float32(attn):
     count = eng.registry.counter
     # programs by doubling of the bucket: 64 and 256 (for 192), not 192;
     # both tile into chunks of 32, and the loop stops at the prompt's end
-    assert sorted(k[0] for k in eng.adapter._prefill_cache) == [64, 256]
+    lens = {64: (256, 64, 64), 48: (192, 96, 48)}[bucket]
+    assert sorted(k[0] for k in eng.adapter._prefill_cache) == sorted(set(lens))
     computed = sum(M.prefill_positions(len(p), n, cfg)
-                   for p, n in zip(prompts, (256, 64, 64)))
-    assert computed == 160 + 64 + 32
+                   for p, n in zip(prompts, lens))
+    assert computed == {64: 160 + 64 + 32, 48: 192 + 96 + 48}[bucket]
     assert count("serve.prefill_computed_tokens").value == computed
     assert count("serve.prefill_state_writes").value == 3
     chose, blocks, context = M.prefill_choices(150, cfg)
@@ -365,6 +521,29 @@ def test_engine_agrees_with_the_reference_on_logits_float32(attn):
     assert count("serve.sparse_chose_tokens").value == chose
     assert count("serve.sparse_chosen_blocks").value == blocks
     assert count("serve.sparse_context_blocks").value == context
+    # the products touched the chosen blocks and the band's corners (a
+    # tile of 16 queries has none), or every block where a chunk walks
+    multiplied = M.prefill_multiplied(150, lens[0], cfg)
+    assert count("serve.sparse_multiplied_blocks").value == multiplied
+    if bucket == BUCKET:
+        assert M.chunk_forms(256, cfg)[:3] == ["dense", "dense", "chosen"]
+        # the band tile's second block beside the chosen ones
+        assert multiplied == blocks + chose < context
+    else:
+        assert M.chunk_forms(192, cfg)[1] == "masked"
+        assert blocks < multiplied
+    spans = read_spans(str(tmp_path))
+    forms = {s.stats["rid"]: s.stats["attn_form"]
+             for s in named(spans, "prefill.dispatch")}
+    assert [forms[r.rid] for r in reqs] == {
+        64: ["einsum+chosen_blocks", "einsum", "einsum"],
+        48: ["einsum+masked_blocks+chosen_blocks", "einsum+masked_blocks",
+             "einsum"]}[bucket]
+    done = {s.stats["rid"]: s.stats for s in named(spans, "prefill.done")}
+    assert [done[r.rid]["multiplied_blocks"] for r in reqs] == [
+        multiplied, 0, 0]
+    assert done[reqs[0].rid]["chosen_blocks"] == blocks
+    assert done[reqs[0].rid]["context_blocks"] == context
     # decode steps whose stream stood past dense_len: all 19 of the long
     # one's and the crossing one's from position 64 on (lens 64 .. 68)
     assert count("serve.sparse_decode_chose").value == 19 + 5

@@ -245,6 +245,59 @@ def test_prefill_dispatch_names_the_attention_form(traced_sarvam, traced):
     assert traced[0].adapter._prefill_fields((16, 16, True)) == {}
 
 
+def test_a_sala_prefill_names_its_form_and_counts_what_it_multiplied(
+        tmp_path, monkeypatch):
+    """The sparse-and-linear family: ``attn_form`` on ``prefill.dispatch``
+    says what the program's chunks past ``dense_len`` run (the band and
+    the kernel over each query's chosen blocks), and ``prefill.done``
+    carries the program's four counts, ``multiplied_blocks`` the last;
+    the counter sums it."""
+    from fms_fsdp_tpu.models import minicpm_sala as M
+    from fms_fsdp_tpu.models.configs import minicpm_sala_config
+
+    monkeypatch.setattr(M, "PREFILL_CHUNK", 4)
+    cfg = minicpm_sala_config({
+        "model_type": "minicpm_sala", "hidden_size": 32, "head_dim": 8,
+        "num_attention_heads": 4, "num_key_value_heads": 2,
+        "num_hidden_layers": 2,
+        "mixer_types": ["minicpm4", "lightning-attn"],
+        "intermediate_size": 64, "lightning_nh": 4, "lightning_nkv": 4,
+        "lightning_head_dim": 8, "lightning_use_rope": True,
+        "lightning_scale": "1/sqrt(d)", "attn_use_rope": False,
+        "qk_norm": True, "use_output_gate": True, "use_output_norm": True,
+        "attn_use_output_gate": True, "scale_emb": 12, "scale_depth": 1.4,
+        "dim_model_base": 16, "vocab_size": 128, "hidden_act": "silu",
+        "max_position_embeddings": 64, "rms_norm_eps": 1e-6,
+        "rope_theta": 10000.0, "tie_word_embeddings": False,
+        "sparse_config": {
+            "kernel_size": 4, "kernel_stride": 2, "block_size": 4, "topk": 4,
+            "init_blocks": 1, "window_size": 8, "dense_len": 8},
+    })
+    params = M.init_sala_params(jax.random.PRNGKey(5), cfg, jnp.float32)
+    scfg = ServeConfig(**{**SCFG.__dict__, "page_size": 0})
+    engine, reqs, spans = serve(params, cfg, scfg, tmp_path)
+    assert M.chunk_forms(16, cfg) == ["dense", "dense", "chosen", "chosen"]
+    forms = {s.stats["rid"]: s.stats["attn_form"]
+             for s in named(spans, "prefill.dispatch")}
+    # prompts of 5, 9 and 12: programs of 8, 16 and 16 positions
+    assert [forms[r.rid] for r in reqs] == [
+        "einsum", "einsum+chosen_blocks", "einsum+chosen_blocks"]
+    done = {s.stats["rid"]: s.stats for s in named(spans, "prefill.done")}
+    for req, p, n in zip(reqs, (5, 9, 12), (8, 16, 16)):
+        chose, chosen, context = M.prefill_choices(p, cfg)
+        assert chose == max(0, p - 8)
+        got = done[req.rid]
+        assert (got["chose_tokens"], got["chosen_blocks"],
+                got["context_blocks"]) == (chose, chosen, context)
+        # the first block and the band of 2 (a tile of one block has no
+        # corner); positions 8-11 stand in block 2 and have no free block
+        assert got["multiplied_blocks"] == M.prefill_multiplied(
+            p, n, cfg) == chose * 3
+    count = engine.registry.counter
+    assert count("serve.sparse_multiplied_blocks").value == (1 + 4) * 3
+    assert count("serve.sparse_context_blocks").value == 3 + 4 * 3
+
+
 def test_every_span_of_a_step_carries_its_step(traced):
     engine, _, spans = traced
     steps = named(spans, "step")
