@@ -854,3 +854,179 @@ def minicpm_sala_config(d: dict) -> SalaConfig:
         rope_theta=float(d.get("rope_theta", 10000.0)),
         norm_eps=d["rms_norm_eps"],
     )
+
+
+# the kinds of operator of the short-convolution family, by
+# ``layer_types``' names
+LFM2_OPERATOR_KINDS = ("conv", "full_attention")
+
+
+@dataclass(frozen=True)
+class Lfm2MoeConfig:
+    """Short-convolution-and-attention MoE family (``model_type:
+    lfm2_moe``; models/lfm2.py): layer ``i``'s operator is
+    ``layer_types[i]`` = ``"conv"`` (a gated depthwise causal convolution
+    of ``conv_kernel`` positions: a stream keeps ``conv_kernel - 1``
+    positions of ``B * x``, whatever its context) or ``"full_attention"``
+    (grouped-query attention, heads of ``emb_dim / nheads``, QK-norm by
+    head then rotary over the whole head); its feed-forward is a dense
+    SwiGLU of ``hidden_dim`` in the first ``num_dense_layers`` layers and
+    ``num_experts`` sigmoid-routed experts of ``moe_hidden_dim``, ``top_k``
+    a token and no shared one, after them. The head is the embedding.
+
+    ``experts_held``: as ``SarvamConfig`` has it (the routed experts this
+    program holds; all of them when None). ``router_sum_eps`` stands
+    under the sum that normalises the chosen scores."""
+
+    src_vocab_size: int = 65536
+    emb_dim: int = 2048
+    nheads: int = 32
+    kvheads: int = 8
+    nlayers: int = 40
+    layer_types: Tuple[str, ...] = ()
+    conv_kernel: int = 3  # conv_L_cache
+    num_dense_layers: int = 2
+    hidden_dim: int = 11776  # a dense layer's MLP
+    moe_hidden_dim: int = 1536  # one expert
+    num_experts: int = 64  # the router's width
+    experts_held: Optional[Tuple[int, int]] = None
+    top_k: int = 4
+    routed_scaling_factor: float = 1.0
+    router_sum_eps: float = 1e-6
+    max_expected_seq_len: int = 128000
+    rope_theta: float = 1000000.0
+    norm_eps: float = 1e-5
+
+    def __post_init__(self):
+        first, count = self.held
+        if not (0 <= first and count >= 1
+                and first + count <= self.num_experts):
+            raise ValueError(
+                f"experts_held={self.experts_held} is no range of the "
+                f"{self.num_experts} routed experts"
+            )
+        got = self.layer_types
+        if len(got) != self.nlayers or set(got) - set(LFM2_OPERATOR_KINDS):
+            raise ValueError(
+                f"layer_types must name one of {LFM2_OPERATOR_KINDS} for "
+                f"each of the {self.nlayers} layers, got {got}"
+            )
+        if self.nheads % self.kvheads or self.emb_dim % self.nheads:
+            raise ValueError(
+                f"{self.nheads} query heads over {self.kvheads} kv heads "
+                f"of a hidden size of {self.emb_dim} do not divide evenly"
+            )
+        if self.conv_kernel < 2:
+            raise ValueError(
+                f"conv_L_cache={self.conv_kernel}: a convolution of one "
+                "position keeps no window"
+            )
+
+    @property
+    def head_dim(self) -> int:
+        return self.emb_dim // self.nheads
+
+    @property
+    def held(self) -> Tuple[int, int]:
+        """(first id, count) of the routed experts held here."""
+        return self.experts_held or (0, self.num_experts)
+
+    @property
+    def conv_layers(self) -> Tuple[int, ...]:
+        return tuple(
+            i for i, t in enumerate(self.layer_types) if t == "conv"
+        )
+
+    @property
+    def attn_layers(self) -> Tuple[int, ...]:
+        return tuple(
+            i for i, t in enumerate(self.layer_types)
+            if t == "full_attention"
+        )
+
+    def sparse(self, i: int) -> bool:
+        """Layer ``i``'s feed-forward is the expert layer."""
+        return i >= self.num_dense_layers
+
+    @property
+    def n_moe_layers(self) -> int:
+        return max(0, self.nlayers - self.num_dense_layers)
+
+    def n_params(self) -> int:
+        """Parameters held here (the head is the embedding's)."""
+        d, hd = self.emb_dim, self.head_dim
+        conv = 3 * d * d + d * d + d * self.conv_kernel
+        attn = 2 * d * self.nheads * hd + 2 * d * self.kvheads * hd + 2 * hd
+        dense = 3 * d * self.hidden_dim
+        moe = (
+            d * self.num_experts + self.num_experts
+            + 3 * d * self.moe_hidden_dim * self.held[1]
+        )
+        n_moe = self.n_moe_layers
+        return int(
+            len(self.conv_layers) * conv
+            + len(self.attn_layers) * attn
+            + (self.nlayers - n_moe) * dense
+            + n_moe * moe
+            + 2 * d * self.nlayers
+            + d
+            + self.src_vocab_size * d
+        )
+
+
+def lfm2_moe_config(d: dict) -> Lfm2MoeConfig:
+    """A published ``config.json`` of ``model_type: lfm2_moe`` as the
+    family's config. A file that keeps a slice of the stack states the
+    layers kept as ``num_hidden_layers`` and ``layer_types``
+    (benchmark/configs/lfm2-24b-a2b.1chip.json); one that states a chip's
+    share of the experts gives it as ``sarvam_config`` reads it.
+    models/lfm2.py says how the keys the config does not have are read; a
+    key that asks for what is not built is refused by name."""
+    if d.get("conv_bias", False):
+        raise ValueError(
+            "lfm2_moe with conv_bias=True: the short convolution is built "
+            "without a bias (conv_bias false, as published)"
+        )
+    if not d.get("norm_topk_prob", True):
+        raise ValueError(
+            "norm_topk_prob false: the family's chosen weights are normalised"
+        )
+    if not d.get("use_expert_bias", True):
+        raise ValueError(
+            "use_expert_bias false: the family's router chooses by a "
+            "biased score (a zero bias is the same router)"
+        )
+    if not d.get("tie_word_embeddings", True):
+        raise ValueError(
+            "tie_word_embeddings false: the family's head is its embedding"
+        )
+    rp = d.get("rope_parameters") or {}
+    if rp.get("rope_type", "default") != "default":
+        raise ValueError(
+            f"rope_type {rp.get('rope_type')!r}: the family has the "
+            "default frequencies"
+        )
+    held = d["num_experts"]
+    published = (d.get("published") or {}).get("num_experts", held)
+    return Lfm2MoeConfig(
+        src_vocab_size=d["vocab_size"],
+        emb_dim=d["hidden_size"],
+        nheads=d["num_attention_heads"],
+        kvheads=d["num_key_value_heads"],
+        nlayers=d["num_hidden_layers"],
+        layer_types=tuple(d["layer_types"]),  # another name: refused there
+        conv_kernel=d["conv_L_cache"],
+        num_dense_layers=d["num_dense_layers"],
+        hidden_dim=d["intermediate_size"],
+        moe_hidden_dim=d["moe_intermediate_size"],
+        num_experts=published,
+        experts_held=(
+            (int(d.get("first_expert_held", 0)), held)
+            if held != published else None
+        ),
+        top_k=d["num_experts_per_tok"],
+        routed_scaling_factor=float(d.get("routed_scaling_factor", 1.0)),
+        max_expected_seq_len=d["max_position_embeddings"],
+        rope_theta=float(rp.get("rope_theta", d.get("rope_theta", 1e6))),
+        norm_eps=d["norm_eps"],
+    )

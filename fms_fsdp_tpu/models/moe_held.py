@@ -3,7 +3,8 @@ expert-parallel deployment computes of a layer, without the exchange.
 
 ``s = sigmoid(W_g h)`` in float32 over all ``num_experts``; the ``top_k``
 largest of ``s + b`` are chosen (the bias chooses and does not weigh);
-``w_i = routed_scaling_factor * s_i / sum of the chosen s``; the layer
+``w_i = routed_scaling_factor * s_i / sum of the chosen s`` (with a
+config's ``router_sum_eps`` under the sum, where it has one); the layer
 adds ``sum over the chosen experts held here of w_i E_i(h)`` and, beside
 it, the shared expert's ``S(h)`` (whole on every chip). One copy for the
 families that route this way (models/sarvam.py, models/kexaone.py);
@@ -58,8 +59,14 @@ def _router(h, layer, cfg):
         scores + layer["gate_bias"].astype(jnp.float32), cfg.top_k
     )
     w = jnp.take_along_axis(scores, idx, axis=-1)
-    w = cfg.routed_scaling_factor * w / jnp.sum(w, axis=-1, keepdims=True)
-    return idx, w
+    scaled = cfg.routed_scaling_factor * w
+    total = jnp.sum(w, axis=-1, keepdims=True)
+    # a family that normalises over ``sum + eps`` says so in its config
+    # (models/configs.py::Lfm2MoeConfig); the others' programs have no add
+    eps = getattr(cfg, "router_sum_eps", 0.0)
+    if eps:
+        total = total + eps
+    return idx, scaled / total
 
 
 @scoped("moe_shared")
@@ -86,14 +93,18 @@ def _moe_dense_held(h, layer, cfg):
     return _held_mixture(h, layer, cfg, *_router(h, layer, cfg))
 
 
-def _moe_token(h, layer, cfg, moe_impl: str):
+def _moe_token(h, layer, cfg, moe_impl: str, routed=None):
     """Held experts' part of the mixture over a decode step's rows.
     h (B, m, D) post-ffn_norm. The two routed forms of
-    models/mixtral.py over the experts held."""
+    models/mixtral.py over the experts held. ``routed``: the rows'
+    ``_router`` answer where the caller has asked for it already (it
+    counts the experts chosen)."""
+    assert moe_impl in ("dense", "routed"), (
+        f"unknown decode moe_impl {moe_impl!r}"
+    )
+    idx, w = routed or _router(h, layer, cfg)
     if moe_impl == "dense":
-        return _moe_dense_held(h, layer, cfg)
-    assert moe_impl == "routed", f"unknown decode moe_impl {moe_impl!r}"
-    idx, w = _router(h, layer, cfg)
+        return _held_mixture(h, layer, cfg, idx, w)
     B, m, K = idx.shape
     first, held = cfg.held
     if routed_moe_form(B * m * K, held) == "all_experts":
@@ -145,7 +156,11 @@ def _gmm(x, stack, sizes, l):
     # (PERF.md, PR 31): (512, 1024, 1024) 1.91 / 1.95 ms up / down,
     # (256, 1024, 1024) 1.45 / 1.49, (128, 1024, 1024) 1.67 / 1.70,
     # (256, 2048, 1024) 1.44 / 1.26
-    tiling = (largest_divisor(M, _ROW_TILE), min(k, 2048), min(n, 1024))
+    # an expert 1536 wide takes tiles of 768: a tile that overhangs the
+    # width computes columns no one reads
+    tiling = (
+        largest_divisor(M, _ROW_TILE), min(k, 2048), largest_divisor(n, 1024)
+    )
     return gmm(
         x, stack.reshape(L * G, k, n), sizes, preferred_element_type=x.dtype,
         tiling=tiling, interpret=interpret_default(),

@@ -188,6 +188,42 @@ SALA_SCOPES = (
     "sample",
 )
 
+# the scopes of an lfm2 engine's two programs (models/lfm2.py,
+# serve/families/lfm2.py: ``jit__step`` and ``jit__prefill_<tokens>``), in
+# program order. Convolution layers: ``conv_in`` (``W_in`` and the gate
+# ``B * x``), ``short_conv`` (the taps over the window and the position,
+# the window's shift in a decode step; in a prefill the carry of a
+# chunk's last positions) and ``conv_out`` (the gate ``C *`` and
+# ``W_out``). Attention layers: the names ``KEXAONE_SCOPES`` gives its
+# full layers (``kv_write``, ``kv_read``, ``attn_full``), ``qk_norm`` and
+# ``rope`` as there. ``dense_mlp`` is the leading layers' SwiGLU, the
+# ``moe_*`` scopes are models/moe_held.py's (``moe_router`` holds the
+# count of the experts the live streams chose), ``head`` the final norm
+# and the tied head. ``layers`` is around the (unrolled) stack
+LFM2_SCOPES = (
+    "params_cast",
+    "embed",
+    "norm",
+    "layers",
+    "conv_in",
+    "short_conv",
+    "conv_out",
+    "qkv",
+    "qk_norm",
+    "rope",
+    "kv_write",
+    "kv_read",
+    "attn_full",
+    "attn_out",
+    "dense_mlp",
+    "moe_router",
+    "moe_group",
+    "moe_experts",
+    "moe_combine",
+    "head",
+    "sample",
+)
+
 _COMPUTATION = re.compile(r"^(?:ENTRY\s+)?%?([\w.\-]+)\s.*\{\s*$")
 _INSTRUCTION = re.compile(r"^\s+(?:ROOT\s+)?%?([\w.\-]+) = ")
 _NAME = re.compile(r"%([\w.\-]+)")

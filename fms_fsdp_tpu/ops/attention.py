@@ -159,7 +159,9 @@ def chunk_attention(q, k_cache, v_cache, start, *, impl: str = "auto"):
 
     b, c, _, h = q.shape
     scale = h**-0.5
-    flash = _fa.supports(q.shape, (b, c) + k_cache.shape[2:]) and (
+    flash = _fa.supports(
+        q.shape, (b, c) + k_cache.shape[2:], forward_only=True
+    ) and (
         impl == "pallas" or (impl == "auto" and jax.default_backend() == "tpu")
     )
 

@@ -20,7 +20,8 @@ Replaces the reference's SDPA FlashAttention-2 CUDA path
 The layout inside the kernels is (B, N, S, H). The training variants have
 one head width, a multiple of 128; the forward kernels also take values of
 another width than queries and keys (latent attention: 192 and 128, as they
-are). A "context" mesh axis walks remote kv blocks: ops/ring_attention.py.
+are) and heads 64 wide (a block of 64 lanes: ``supports(...,
+forward_only=True)``). A "context" mesh axis walks remote kv blocks: ops/ring_attention.py.
 """
 
 import functools
@@ -1083,8 +1084,10 @@ def _wire_ste_bwd(wire, res, g):
 _wire_ste.defvjp(_wire_ste_fwd, _wire_ste_bwd)
 
 
-def supports(q_shape, k_shape) -> bool:
-    """Eligibility of the Pallas path for these shapes."""
+def supports(q_shape, k_shape, forward_only: bool = False) -> bool:
+    """Eligibility of the Pallas path for these shapes. ``forward_only``:
+    the caller never differentiates (serving's prefill); the forward
+    kernels also take heads 64 wide, as blocks of 64 lanes."""
     _, sq, nq, h = q_shape
     _, sk, nkv, _ = k_shape
     if _VARIANT == "resident":
@@ -1092,7 +1095,7 @@ def supports(q_shape, k_shape) -> bool:
     else:
         max_seq = float("inf")  # kv-streamed kernels engage past the cap
     return (
-        h % 128 == 0
+        (h % 128 == 0 or (forward_only and h == 64))
         and sq % 256 == 0
         and sk % 256 == 0
         and sq <= max_seq

@@ -231,10 +231,14 @@ def _paged_decode_kernel(
 
 def paged_attention_kernel(
     q, k_pages, v_pages, page_table, seq_lens, *,
-    k_scales=None, v_scales=None, block_kv=None, interpret=None,
+    k_scales=None, v_scales=None, block_kv=None, interpret=None, scale=None,
+    nkv=None,
 ):
     """Pallas ragged paged-attention decode; contract of
-    :func:`paged_attention_reference` (same shapes, same masking rule).
+    :func:`paged_attention_reference` (same shapes, same masking rule;
+    ``scale``: the scores' factor where it is not ``H ** -0.5``; ``nkv``:
+    the pages come as the cells read them, (P, page_size * Nkv, H), and
+    this is their ``Nkv``).
 
     Grid (B, ceil(maxp / pages_per_block)); the page table and row
     positions ride as scalar prefetch, and each of a cell's
@@ -248,7 +252,11 @@ def paged_attention_kernel(
     (the ``arbitrary`` grid dim).
     """
     b, nq, hd = q.shape
-    num_pool_pages, page_size, nkv, _ = k_pages.shape
+    if nkv is None:
+        num_pool_pages, page_size, nkv, _ = k_pages.shape
+    else:
+        assert k_scales is None, "scales come a position and kv head"
+        num_pool_pages, page_size = k_pages.shape[0], k_pages.shape[1] // nkv
     maxp = page_table.shape[1]
     if interpret is None:
         interpret = interpret_default()
@@ -284,6 +292,7 @@ def paged_attention_kernel(
     # (P, ps, Nkv, H) -> (P, ps*Nkv, H): the trailing block dims equal the
     # array's, which is what the TPU lowering requires of a block that
     # is not (8, 128)-aligned
+    # (a no-op where ``nkv`` said that the pages come so)
     operands = [k_pages.reshape(num_pool_pages, cols, hd)] * ppb
     operands += [v_pages.reshape(num_pool_pages, cols, hd)] * ppb
     in_specs = [pl.BlockSpec((1, nq, hd), row_map)]
@@ -310,7 +319,7 @@ def paged_attention_kernel(
             page_size=page_size,
             pages_per_block=ppb,
             nkv=nkv,
-            scale=hd**-0.5,
+            scale=hd**-0.5 if scale is None else scale,
             quantized=quantized,
         ),
         grid_spec=grid_spec,
@@ -322,6 +331,63 @@ def paged_attention_kernel(
         interpret=interpret,
     )(seq_lens.astype(jnp.int32), page_table.astype(jnp.int32), q, *operands)
     return out.reshape(b, nq * hd)
+
+
+# ---------------------------------------------------------------------------
+# heads narrower than a tile: two heads of 64 share 128 lanes
+# ---------------------------------------------------------------------------
+
+
+def packed_row_width(nkv: int, hd: int) -> int:
+    """Lanes of a row of a page of ``packed_pages_attention_kernel``: the
+    tile's 128 where a position's ``nkv`` heads of ``hd`` fill whole
+    rows of it, else all of them in one row (test sizes)."""
+    return 128 if (nkv * hd) % 128 == 0 and 128 % hd == 0 else nkv * hd
+
+
+def tile_rows(nkv: int, hd: int) -> int:
+    """Rows a position's ``nkv`` heads of ``hd`` take in such a page."""
+    return nkv * hd // packed_row_width(nkv, hd)
+
+
+def packed_pages_attention_kernel(
+    q, k_pages, v_pages, page_table, seq_lens, *, nkv: int,
+    block_kv=None, interpret=None,
+):
+    """``paged_attention_kernel`` for heads narrower than the 128 lanes
+    of a tile (64: two a tile), over pages stored at their own width.
+
+    q (B, Nq, H); k_pages/v_pages (P, page_size * tile_rows, W) with W =
+    ``packed_row_width(nkv, H)``, 128 at the published sizes: a
+    position's ``nkv`` heads side by side and the positions behind one
+    another, as rows of W lanes, nothing padded. (A pool whose last axis
+    is one head of 64 the chip lays out with positions minor and copies
+    whole around every write, 6 GB of temporaries at the lfm2 widths;
+    one whose last axis is a position's 512 values it copies whole into
+    the rows the cells read, 2 GB: deviceless v5e compiles, PERF.md
+    PR 41.) ``pack = W / H`` neighbouring kv heads are read as one head
+    of W lanes, which is how they lie in a page; a query head's values
+    stand in its own kv head's lanes of the row and zeros in the others,
+    so its scores are its own head's; of the W lanes the values' product
+    gives it, it keeps its own head's. The kernel is the one the
+    128-wide families run, told ``scale = H ** -0.5``. Returns (B, Nq *
+    H)."""
+    b, nq, hd = q.shape
+    pack = k_pages.shape[-1] // hd
+    assert k_pages.shape[-1] == packed_row_width(nkv, hd), (k_pages.shape, hd)
+    assert nkv % pack == 0 and nq % nkv == 0, (hd, nkv, nq)
+    # which of a row's heads each query head's kv head is
+    lane = jax.nn.one_hot(
+        (jnp.arange(nq) // (nq // nkv)) % pack, pack, dtype=q.dtype
+    )  # (Nq, pack)
+    q_row = (q[:, :, None, :] * lane[None, :, :, None]).reshape(
+        b, nq, pack * hd
+    )
+    out = paged_attention_kernel(
+        q_row, k_pages, v_pages, page_table, seq_lens, block_kv=block_kv,
+        interpret=interpret, scale=hd**-0.5, nkv=nkv // pack,
+    ).reshape(b, nq, pack, hd)
+    return jnp.sum(out * lane[None, :, :, None], axis=2).reshape(b, nq * hd)
 
 
 # ---------------------------------------------------------------------------

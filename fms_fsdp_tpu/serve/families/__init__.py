@@ -1,4 +1,4 @@
-"""Family adapters: one serving engine, six model families.
+"""Family adapters: one serving engine, seven model families.
 
 The ServingEngine owns admission, continuous batching, eviction and
 metrics — none of which care what a "slot" stores. What differs per
@@ -40,6 +40,12 @@ family          decode-state per stream
                 attention), and for the lightning linear-attention
                 layers a float32 ``(heads, H, H)`` state a slot,
                 constant bytes whatever the context
+``lfm2``        paged KV pages for the attention layers alone (the only
+                thing that grows) and, for the gated short-convolution
+                layers, a window of ``conv_kernel - 1`` positions of
+                ``B * x`` a slot, constant bytes whatever the context;
+                the expert layer is the code sarvam runs, every expert
+                held and no shared one
 ==============  ========================================================
 
 Every adapter is parity-anchored: greedy decode through the engine is
@@ -63,6 +69,7 @@ import numpy as np
 from fms_fsdp_tpu.models.configs import (
     KEXAONE_LAYER_KINDS,
     KExaoneConfig,
+    Lfm2MoeConfig,
     LlamaConfig,
     MambaConfig,
     MixtralConfig,
@@ -77,7 +84,7 @@ from fms_fsdp_tpu.obs.spans import done, span
 # "serving"): family = FAMILY_CODES[name]
 FAMILY_CODES = {
     "llama": 0, "mamba": 1, "mixtral": 2, "sarvam": 3, "kexaone": 4,
-    "minicpm_sala": 5,
+    "minicpm_sala": 5, "lfm2": 6,
 }
 FAMILY_NAMES = {v: k for k, v in FAMILY_CODES.items()}
 
@@ -87,6 +94,7 @@ _CONFIG_FAMILIES = (
     (SarvamConfig, "sarvam"),
     (KExaoneConfig, "kexaone"),
     (SalaConfig, "minicpm_sala"),
+    (Lfm2MoeConfig, "lfm2"),
     (LlamaConfig, "llama"),
 )
 
@@ -118,7 +126,9 @@ def load_model_config(d: dict):
     ``"model_type": "exaone_moe"`` one (or ``"family": "kexaone"``) to
     the kexaone family through its own, a published ``"model_type":
     "minicpm_sala"`` one (or ``"family": "minicpm_sala"``) to the
-    minicpm_sala family through its own. This is the single
+    minicpm_sala family through its own, a published ``"model_type":
+    "lfm2_moe"`` one (or ``"family": "lfm2"``) to the lfm2 family through
+    its own. This is the single
     resolution point replica.py and the engine share — the two can no
     longer diverge on model construction (the PR-11 bug this replaces:
     replica.py:71 hardwired its own ``init_llama_params`` copy)."""
@@ -142,6 +152,10 @@ def load_model_config(d: dict):
         from fms_fsdp_tpu.models.configs import minicpm_sala_config
 
         return minicpm_sala_config(d)
+    if family == "lfm2" or d.get("model_type") == "lfm2_moe":
+        from fms_fsdp_tpu.models.configs import lfm2_moe_config
+
+        return lfm2_moe_config(d)
     if family is None:
         if "d_model" in d or "n_layer" in d:
             family = "mamba"
@@ -192,6 +206,10 @@ def check_params_family(params, family: str) -> None:
         actual = "minicpm_sala"  # a stack for each kind of mixer
     elif stacks & set(KEXAONE_LAYER_KINDS):
         actual = "kexaone"  # a stack for each kind of layer, no "layers"
+    elif isinstance(layers, (list, tuple)) and layers and (
+        "operator_norm" in layers[0]
+    ):
+        actual = "lfm2"  # a list of layers, each behind its operator's norm
     elif isinstance(layers, (list, tuple)):
         actual = "mamba"
     elif isinstance(layers, dict) and "wkv_a" in layers:
@@ -241,6 +259,10 @@ def init_params_for(model_cfg):
         from fms_fsdp_tpu.models.minicpm_sala import init_sala_params
 
         return lambda key: init_sala_params(key, model_cfg)
+    if family == "lfm2":
+        from fms_fsdp_tpu.models.lfm2 import init_lfm2_params
+
+        return lambda key: init_lfm2_params(key, model_cfg)
     from fms_fsdp_tpu.models.llama import init_llama_params
 
     return lambda key: init_llama_params(key, model_cfg)
@@ -298,6 +320,8 @@ def resolve_adapter(
         from fms_fsdp_tpu.serve.families.minicpm_sala import (
             MiniCPMSalaAdapter as cls,
         )
+    elif family == "lfm2":
+        from fms_fsdp_tpu.serve.families.lfm2 import Lfm2Adapter as cls
     else:
         from fms_fsdp_tpu.serve.families.llama import LlamaAdapter as cls
     return cls(params, model_cfg, serve_cfg, compute_dtype, registry)
@@ -848,7 +872,7 @@ def sequence_prefill_attn_impl(scfg) -> str:
 
 class HeldExpertsAdapter(FamilyAdapter):
     """What the adapters of the families that run models/moe_held.py
-    share (sarvam, kexaone): the ``moe_impl`` rule, which loop the decode
+    share (sarvam, kexaone, lfm2): the ``moe_impl`` rule, which loop the decode
     program runs over the held experts (``moe_form``, the gauge
     ``serve.moe_expert_reads_per_layer``), the gauges of the share, and
     the count of the (token, choice) pairs a prefill routed, of those

@@ -272,9 +272,10 @@ class PagedKVCache:
         Call ``ensure`` first."""
         assert len(values) == len(self.entry_shapes), (
             len(values), list(self.entry_shapes))
-        L, s_pad = values[0].shape[0], values[0].shape[1]
-        assert s_pad % self.page_size == 0, (s_pad, self.page_size)
-        n = s_pad // self.page_size
+        L, rows = values[0].shape[0], values[0].shape[1]
+        first = self.page_rows[next(iter(self.entry_shapes))]
+        assert rows % first == 0, (rows, first)
+        n = rows // first  # pages
         pages = self._seq_pages.get(seq_id, [])
         assert n <= len(pages) or self.scratch_tail, (
             f"write_prompt needs {n} pages, sequence {seq_id} holds "

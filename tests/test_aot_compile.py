@@ -113,6 +113,44 @@ def test_paged_attention_compiles(v5e, page_size, pages_per_block, store):
     _compile(fn, *args)
 
 
+@pytest.mark.parametrize("causal", [True, False], ids=["diagonal", "earlier"])
+def test_flash_forward_compiles_at_heads_of_64(v5e, causal):
+    """The lfm2 prefill's two partials of a chunk of 2048 (its own block
+    under the mask, an earlier block whole), 32 query heads on 8 kv heads
+    of 64 as published: blocks of 64 lanes, with the log-sum-exp that the
+    merge of the partials needs. Forward only: ``supports`` says so."""
+    from fms_fsdp_tpu.ops.flash_attention import flash_attention, supports
+
+    q = _sds(v5e, (1, 2048, 32, 64), jnp.bfloat16)
+    kv = _sds(v5e, (1, 2048, 8, 64), jnp.bfloat16)
+    assert supports(q.shape, kv.shape, forward_only=True)
+    assert not supports(q.shape, kv.shape)
+    _compile(
+        lambda q, k, v: flash_attention(
+            q, k, v, causal=causal, return_lse=True, interpret=False),
+        q, kv, kv)
+
+
+def test_packed_pages_attention_compiles(v5e):
+    """The lfm2 decode step's attention at the cell's sizes: 128 slots, 32
+    query heads of 64, the two attention layers' pools seen as one run of
+    pages of 512 rows of 128 lanes (128 positions of 8 kv heads of 64, two
+    heads a row), cells of 512 positions."""
+    from fms_fsdp_tpu.ops.paged_attention import (
+        packed_pages_attention_kernel,
+        packed_row_width,
+        tile_rows,
+    )
+
+    assert (packed_row_width(8, 64), tile_rows(8, 64)) == (128, 4)
+    pages = _sds(v5e, (2 * 3816, 128 * 4, 128), jnp.bfloat16)
+    _compile(
+        lambda q, k, v, table, lens: packed_pages_attention_kernel(
+            q, k, v, table, lens, nkv=8, block_kv=512, interpret=False),
+        _sds(v5e, (128, 32, 64), jnp.bfloat16), pages, pages,
+        _sds(v5e, (128, 40), jnp.int32), _sds(v5e, (128,), jnp.int32))
+
+
 @pytest.mark.parametrize("kv_len,segment", [
     (65536, None), (16384, None), (262144, None), (65536, 16384)],
     ids=["64k", "16k", "256k_in_two_segments", "64k_in_four_segments"])

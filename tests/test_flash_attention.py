@@ -169,6 +169,9 @@ def test_block_size_rounding():
 def test_supports_eligibility(monkeypatch):
     assert supports((2, 4096, 32, 128), (2, 4096, 8, 128))
     assert not supports((2, 4096, 32, 64), (2, 4096, 8, 64))  # head dim
+    # the forward kernels take heads of 64 (serving's prefill says so)
+    assert supports((1, 2048, 32, 64), (1, 2048, 8, 64), forward_only=True)
+    assert not supports((1, 2048, 32, 32), (1, 2048, 8, 32), forward_only=True)
     assert not supports((2, 100, 4, 128), (2, 100, 4, 128))  # seq align
     # past the resident cap: the kv-streamed kernels engage, no limit
     assert supports((1, 32768, 8, 128), (1, 32768, 2, 128))
@@ -283,11 +286,12 @@ def _rand_two_widths(nq, nkv, h, hv, seed, dtype=jnp.float32):
 @pytest.mark.parametrize("variant", ["resident", "kvgrid"])
 @pytest.mark.parametrize("nq,nkv", [(8, 8), (8, 1)])
 @pytest.mark.parametrize("causal", [True, False])
-@pytest.mark.parametrize("h,hv", [(192, 128), (128, 256)])
+@pytest.mark.parametrize("h,hv", [(192, 128), (128, 256), (64, 64)])
 def test_flash_two_widths_match_einsum(h, hv, causal, nq, nkv, variant):
     """Values narrower (latent attention's 192 and 128) or wider than
-    queries and keys, in both forward families: the output is as wide as
-    the values, and it and the log-sum-exp are the einsum form's."""
+    queries and keys, and heads 64 wide (a block of 64 lanes, no head
+    padded), in both forward families: the output is as wide as the
+    values, and it and the log-sum-exp are the einsum form's."""
     q, k, v = _rand_two_widths(nq, nkv, h, hv, seed=13)
     ref_o, ref_lse = _einsum_attention(q, k, v, causal)
     o, lse = flash_attention(
@@ -307,7 +311,23 @@ def test_flash_two_widths_match_einsum(h, hv, causal, nq, nkv, variant):
 
 
 @pytest.mark.parametrize("variant", ["resident", "kvgrid"])
-@pytest.mark.parametrize("h,hv", [(192, 128), (128, 256)])
+@pytest.mark.parametrize("causal", [True, False])
+def test_flash_heads_of_64_thirty_two_on_eight(causal, variant):
+    """The lfm2 geometry as published, 32 query heads on 8 kv heads of
+    64, a diagonal partial and an earlier block's as
+    ``ops/attention.py::chunk_attention`` asks for them."""
+    q, k, v = _rand_two_widths(32, 8, 64, 64, seed=23)
+    ref_o, ref_lse = _einsum_attention(q, k, v, causal)
+    o, lse = flash_attention(
+        q, k, v, causal=causal, interpret=True, return_lse=True,
+        variant=variant,
+    )
+    np.testing.assert_allclose(np.asarray(o), np.asarray(ref_o), atol=2e-5)
+    np.testing.assert_allclose(np.asarray(lse), np.asarray(ref_lse), atol=2e-5)
+
+
+@pytest.mark.parametrize("variant", ["resident", "kvgrid"])
+@pytest.mark.parametrize("h,hv", [(192, 128), (128, 256), (64, 64)])
 def test_flash_two_widths_bf16_parity(h, hv, variant):
     """bfloat16 inputs at the tolerance of the one-width parity test."""
     q, k, v = _rand_two_widths(8, 2, h, hv, seed=17, dtype=jnp.bfloat16)

@@ -196,9 +196,14 @@ def test_gather_matches_dense_cache_bitwise(tiny_params):
             assert (np.asarray(g) == np.asarray(cache[name][layer])).all()
 
 
-@pytest.mark.parametrize("nq,nkv", [(4, 4), (8, 2)])
-def test_paged_kernel_matches_reference(nq, nkv):
-    P, ps, hd, B = 10, 8, 128, 3
+@pytest.mark.parametrize(
+    "nq,nkv,hd", [(4, 4, 128), (8, 2, 128), (32, 8, 64), (8, 2, 64), (4, 1, 64)])
+def test_paged_kernel_matches_reference(nq, nkv, hd):
+    """Heads of 128, and pages whose rows are 8 kv heads of 64 under 32
+    query heads (the lfm2 geometry as published; no head padded): as
+    blocks of 64 lanes, and as the chip reads them, two heads a row of
+    128 lanes (``packed_pages_attention_kernel``)."""
+    P, ps, B = 10, 8, 3
     kp = jax.random.normal(jax.random.PRNGKey(2), (P, ps, nkv, hd), jnp.float32)
     vp = jax.random.normal(jax.random.PRNGKey(3), (P, ps, nkv, hd), jnp.float32)
     q = jax.random.normal(jax.random.PRNGKey(4), (B, nq, hd), jnp.float32)
@@ -207,6 +212,19 @@ def test_paged_kernel_matches_reference(nq, nkv):
     ref = paged_attention_reference(q, kp, vp, table, lens)
     ker = paged_attention_kernel(q, kp, vp, table, lens, interpret=True)
     assert jnp.allclose(ref, ker, atol=1e-5), float(jnp.abs(ref - ker).max())
+    if hd == 64 and nkv % 2 == 0:
+        from fms_fsdp_tpu.ops.paged_attention import (
+            packed_pages_attention_kernel,
+            packed_row_width,
+            tile_rows,
+        )
+
+        assert packed_row_width(nkv, hd) == 128
+        rows = (P, ps * tile_rows(nkv, hd), 128)
+        ker = packed_pages_attention_kernel(
+            q, kp.reshape(rows), vp.reshape(rows), table, lens, nkv=nkv,
+            block_kv=2 * ps, interpret=True)
+        assert jnp.allclose(ref, ker, atol=1e-5), float(jnp.abs(ref - ker).max())
 
 
 def test_paged_kernel_position_zero_rows():
