@@ -79,6 +79,7 @@ TIMED = {
         "admit": "admit_us",
         "prefill": "prefill_us",
         "prefill_chunk": "prefill_us",
+        "prefill.land": "prefill_us",
         "grow": "grow_us",
         "decode": "decode_us",
         "publish": "publish_us",

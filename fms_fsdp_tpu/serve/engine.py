@@ -50,6 +50,17 @@ step in flight (``_collect``). docs/serving.md "The decode loop" has the
 order and what it means for an end-of-sequence token. The speculative
 path stays dispatch-then-collect inside one ``step()``: how many tokens
 a stream gains there is known only from the result.
+
+Since PR 42 an admission reads nothing either: its prefill program and
+its sampler are dispatched, the first token stays on the device and goes
+into the next decode step's token row there, and the host reads it
+behind that step's dispatch, at the end of the same ``step()``
+(``_land``: TTFT, the adapter's counts and the ``prefill.done`` marker
+are written there). From the prefill program's end to the decode step's
+start the device has its work queued. ``_collect`` lands what is pending
+too, so it stays the one door; a prefill replica, a speculative engine
+and a mesh need the token on the host at once and land it where it is
+sampled.
 """
 
 import json
@@ -102,6 +113,20 @@ class _InFlight:
     toks: object  # (B,) int32, on the device
     logits: object  # (B, V), on the device
     streams: List[Tuple[int, Request]]  # (slot, request) it decodes
+
+
+@dataclass
+class _Admission:
+    """An admission whose prefill program and sampler were dispatched
+    and whose first token the host has not read yet."""
+
+    req: Request
+    slot: int
+    tok: object  # () int, on the device
+    prompt_tokens: int
+    # a decode step was dispatched over the stream: the token went into
+    # the step's row on the device and the host reads it behind the step
+    rode: bool = False
 
 
 @dataclass(frozen=True)
@@ -266,6 +291,18 @@ class ServingEngine:
         # alone.
         self._fresh = np.zeros((scfg.max_batch,), bool)
         self._inflight: Optional[_InFlight] = None
+        # admissions of the open step() whose first token is on the
+        # device, unread (``_land``); empty outside a step()
+        self._pending: List[_Admission] = []
+        # the host needs a first token before the next decode step's
+        # dispatch: a prefill replica packs it into the handoff, the
+        # speculative step reads its tokens on the host, and on a mesh
+        # the sampler's token lies on one device, the step's row on all
+        self._lands_at_once = (
+            scfg.role == "prefill"
+            or self.adapter.speculative
+            or self.adapter.mesh is not None
+        )
         self._key = jax.random.PRNGKey(seed)
         # one record per step() (obs/spans.py): the spans' host times
         # and what the engine writes beside them; ``_rec`` is the open
@@ -527,48 +564,84 @@ class ServingEngine:
                 self._chunking[req.rid] = (req, slot)
             else:
                 # the adapter allocates the stream's decode state (pages
-                # and/or slab slice), runs the family prefill and hands
-                # back the (V,) logits row of the last real prompt
-                # position; sampling stays here so every family shares one
-                # rng stream and one sampler
+                # and/or slab slice), dispatches the family prefill and
+                # hands back the (V,) logits row of the last real prompt
+                # position, unread; sampling stays here so every family
+                # shares one rng stream and one sampler
                 row = self.adapter.prefill(req.rid, slot, prompt)
-                self._complete_prefill(req, slot, row, p)
+                self._sample_first(req, slot, row, p)
         self.registry.counter("serve.prefill_padded_tokens").add(padded)
 
-    def _complete_prefill(self, req: Request, slot: int, row, p: int) -> None:
-        """Shared tail of whole-prompt and chunked prefill: sample the
-        first token from the last real prompt position's logits row,
-        record TTFT, promote the stream into the decode batch."""
-        with span("prefill.sample", step=self.iterations, rid=req.rid):
-            self._key, sub = jax.random.split(self._key)
-            tok = int(
-                sample_token(
-                    row[None],
-                    sub,
-                    self.serve_cfg.temperature,
-                    self.serve_cfg.top_k,
-                    self.serve_cfg.do_sample,
-                )[0]
-            )
-        now = self.clock()
-        if req.first_token_time is None:
-            req.first_token_time = now
-            self.registry.hist("serve.ttft_s").record(now - req.submit_time)
-        req.generated.append(tok)
-        self.registry.counter("serve.prefill_tokens").add(p)
+    def _sample_first(self, req: Request, slot: int, row, p: int) -> None:
+        """Shared tail of whole-prompt and chunked prefill, the dispatch
+        half: sample the first token from the last real prompt
+        position's logits row and promote the stream into the decode
+        batch. Nothing is read: the token stays on the device for the
+        next decode step to take, and ``_land`` reads it behind that
+        step's dispatch (at once where the host needs it first)."""
+        self._key, sub = jax.random.split(self._key)
+        tok = sample_token(
+            row[None],
+            sub,
+            self.serve_cfg.temperature,
+            self.serve_cfg.top_k,
+            self.serve_cfg.do_sample,
+        )[0]
         self._slots[slot] = req
         self._admit_order.append(req)
-        self._tokens[slot] = tok
-        self._fresh[slot] = True
         self._lens[slot] = p
-        if self._finish_if_done(req, slot, now=now):
-            return
-        if self.serve_cfg.role == "prefill":
-            # disaggregation: a prefill engine's job ends at the first
-            # token — pack the stream's pages + sampling state into wire
-            # bytes and retire the request; the replica loop emits it as
-            # a "handoff" message instead of "done"
-            self._export_handoff(req, slot)
+        self._pending.append(_Admission(req, slot, tok, p))
+        if self._lands_at_once:
+            self._land()
+
+    def _land(self) -> bool:
+        """The landing half of the admissions dispatched and not yet
+        read, oldest first: read the first token (the wait for the
+        prefill program), record TTFT, read the adapter's counts
+        (``prefill.done``), hand the token to its stream. False where
+        none is pending. ``step()`` lands behind its decode step's
+        dispatch; whatever needs the token or the stream's state earlier
+        comes through here first (``_collect``). A stream that ends at
+        its first token behind a step dispatched over it rode that step:
+        the commit drops the step's token for it, as for an
+        ``eos_token`` found at a commit."""
+        pending, self._pending = self._pending, []
+        it = self.iterations
+        reg = self.registry
+        for adm in pending:
+            req, slot = adm.req, adm.slot
+            with span("prefill.land", step=it, rid=req.rid):
+                with span(
+                    "prefill.sample",
+                    step=it,
+                    rid=req.rid,
+                    overlapped=int(adm.rode),
+                ):
+                    tok = int(adm.tok)
+                now = self.clock()
+                # the program has ended: its counts wait for nothing
+                self.adapter.count_prefill(req.rid)
+                if req.first_token_time is None:
+                    req.first_token_time = now
+                    reg.hist("serve.ttft_s").record(now - req.submit_time)
+                req.generated.append(tok)
+                reg.counter("serve.prefill_tokens").add(adm.prompt_tokens)
+                if adm.rode:
+                    reg.counter("serve.admissions_overlapped").add()
+                if self._finish_if_done(req, slot, now=now):
+                    continue
+                if not adm.rode:
+                    # the next dispatch writes it into the step's row
+                    self._tokens[slot] = tok
+                    self._fresh[slot] = True
+                if self.serve_cfg.role == "prefill":
+                    # disaggregation: a prefill engine's job ends at the
+                    # first token — pack the stream's pages + sampling
+                    # state into wire bytes and retire the request; the
+                    # replica loop emits it as a "handoff" message
+                    # instead of "done"
+                    self._export_handoff(req, slot)
+        return bool(pending)
 
     def _import_handoff(self, req: Request, slot: int) -> None:
         """The decode half of a handoff admission: scatter the shipped
@@ -672,12 +745,14 @@ class ServingEngine:
     # -- the engine iteration ----------------------------------------------
 
     def step(self) -> List[Request]:
-        """One continuous-batching iteration: expire, admit (+prefill),
-        dispatch one ragged decode step, then collect and commit the
-        step dispatched by the previous iteration. Returns the requests
-        that finished during this iteration. The first ``step()`` of an
-        idle engine therefore returns before its decode step's tokens
-        are visible; the next one brings them.
+        """One continuous-batching iteration: expire, admit (+prefill,
+        dispatched and unread), dispatch one ragged decode step, collect
+        and commit the step dispatched by the previous iteration, then
+        land the admissions: read their first tokens behind the step
+        just dispatched. Returns the requests that finished during this
+        iteration. The first ``step()`` of an idle engine therefore
+        returns with its admissions' first tokens and before its decode
+        step's tokens are visible; the next one brings them.
 
         Every phase runs under a host span (obs/spans.py: ``serve/step``
         and its children, each carrying ``step=<iterations>``), which
@@ -708,6 +783,7 @@ class ServingEngine:
                     evicted = self._grow()
                     done("grow", step=it, evicted=evicted)
                 self._decode(it)
+                self._land()
                 with span("publish", step=it):
                     pages = rec[_PAGES_IN_USE] = self.adapter.pages_in_use
                     reg.gauge("serve.kv_pages_in_use").set(pages)
@@ -801,12 +877,15 @@ class ServingEngine:
         # next can_fit evaluation — a single batched admit would check
         # every candidate against the pre-prefill pool and over-admit
         # when two requests each fit alone but not together. Slots are
-        # recounted live too: a request that finishes inside its own
-        # prefill releases its slot immediately.
+        # recounted live too: a request that ends at its first token
+        # gives its slot back when that token lands, so a step's further
+        # admissions land the one before them first (the last one's
+        # token is read behind the decode step).
         admitted = 0
         stopped = "draining" if self._draining else "budget"
         for _ in range(0 if self._draining else
                        self.serve_cfg.max_prefill_per_step):
+            self._land()
             if self._slots.count(None) <= 0:
                 stopped = "no_slot"
                 break
@@ -843,7 +922,7 @@ class ServingEngine:
                 self.registry.counter("serve.prefill_chunks").add()
                 if row is not None:
                     del self._chunking[rid]
-                    self._complete_prefill(
+                    self._sample_first(
                         req, slot, row, len(req.resume_prompt())
                     )
         return chunks
@@ -863,8 +942,9 @@ class ServingEngine:
                 continue
             need = int(self._lens[slot]) + 1 + draft
             while not self.adapter.grow(req.rid, need):
-                # a victim's resume prompt must hold its last token, and
-                # the commit may free pages (or end ``req``) by itself
+                # a victim's resume prompt must hold its last token (its
+                # first, where it is the stream just admitted), and the
+                # commit may free pages (or end ``req``) by itself
                 if self._collect():
                     if self._slots[slot] is not req:
                         break
@@ -928,6 +1008,11 @@ class ServingEngine:
         donated), so the next admission comes when it always came."""
         reg = self.registry
         self._key, sub = jax.random.split(self._key)
+        # first tokens still on the device go into the step's row there
+        first = []
+        for adm in self._pending:
+            adm.rode = True
+            first.append((adm.slot, adm.tok))
         # copies: the host's arrays change before the device has run
         toks, logits = self.adapter.decode_dispatch(
             self._slot_rids(active),
@@ -936,6 +1021,7 @@ class ServingEngine:
             sub,
             self._fresh.copy(),
             in_flight=int(overlapped),
+            first=first,
         )
         self._fresh[:] = False
         reg.counter("serve.decode_live_slots").add(len(active))
@@ -951,14 +1037,15 @@ class ServingEngine:
         return _InFlight(toks, logits, active)
 
     def _collect(self) -> bool:
-        """Wait for the decode step in flight and commit its tokens;
-        False where none is. The door for everything that takes a
-        stream away, or looks at its tokens, outside the commit."""
+        """Wait for the decode step in flight and commit its tokens,
+        then land the admissions whose first token is unread; False
+        where there was neither. The door for everything that takes a
+        stream away, or looks at its tokens, outside the commit and the
+        landing at the end of ``step()``."""
         flight, self._inflight = self._inflight, None
-        if flight is None:
-            return False
-        self._commit(flight)
-        return True
+        if flight is not None:
+            self._commit(flight)
+        return self._land() or flight is not None
 
     def _commit(self, flight: _InFlight) -> None:
         """Read a dispatched step's tokens (the wait for the device) and

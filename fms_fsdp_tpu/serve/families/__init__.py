@@ -61,7 +61,7 @@ Obs note: the schema-v12 ``serving`` map is flat str->number, so the
 family travels as a numeric code (:data:`FAMILY_CODES`), not a string.
 """
 
-from functools import partial
+from functools import cache, partial
 from typing import Optional
 
 import numpy as np
@@ -327,6 +327,15 @@ def resolve_adapter(
     return cls(params, model_cfg, serve_cfg, compute_dtype, registry)
 
 
+@cache
+def _put_token():
+    """The program that writes one token, a device scalar, into a step's
+    token row at a slot: jitted once for every engine of the process."""
+    import jax
+
+    return jax.jit(lambda row, slot, tok: row.at[slot].set(tok.astype(row.dtype)))
+
+
 class FamilyAdapter:
     """The skeleton of a family's device work (docs/serving.md "Family
     adapters" has the table). The engine owns scheduling, sampling, rng
@@ -340,11 +349,15 @@ class FamilyAdapter:
     - ``prefill(rid, slot, prompt)``: pad to the bucket, allocate, look
       the program up (``_program``), call it, land its outputs (slab
       rows, K/V pages), hand back the (V,) logits row of the last real
-      prompt position;
-    - ``decode_dispatch(slot_rids, lens, tokens, key, fresh)``: upload the
-      page table when stale, one jitted ragged step over all slots (pools,
-      slab donated) fed its predecessor's tokens on the device -> (tokens
-      (B,), logits (B, V)) unread; ``decode_collect(tokens)`` reads, waits.
+      prompt position; nothing is read, and ``count_prefill(rid)``, which
+      the engine calls once it has read the first token, reads what the
+      program counted and writes ``prefill.done``;
+    - ``decode_dispatch(slot_rids, lens, tokens, key, fresh, first=)``:
+      upload the page table when stale, one jitted ragged step over all
+      slots (pools, slab donated) fed its predecessor's tokens, and the
+      ``first`` tokens of the streams just prefilled, on the device ->
+      (tokens (B,), logits (B, V)) unread; ``decode_collect(tokens)``
+      reads, waits.
 
     A family sets its state (``cache``, ``_state``) and ``_decode_fn`` in
     ``_setup`` and writes the three prefill hooks (``_prefill_key``,
@@ -395,6 +408,9 @@ class FamilyAdapter:
     ssm_layers: int = 0
     _pages_noun: str = "pages"  # what a rejection calls the pool's pages
     _dispatch_fields: dict = {}  # the family's fields of decode.dispatch
+    # what the prefill program called last counted itself, on the device
+    # and unread: a family's ``_call_prefill`` leaves it here
+    _program_counts = ()
 
     def __init__(
         self, params, model_cfg, scfg, compute_dtype=None, registry=None
@@ -407,6 +423,9 @@ class FamilyAdapter:
         self.compute_dtype = compute_dtype or _DTYPES[scfg.compute_dtype]
         self.registry = MetricRegistry() if registry is None else registry
         self._prefill_cache: dict = {}
+        # rid -> (positions computed, the program's own counts unread) of
+        # the prefills dispatched and not yet counted (``count_prefill``)
+        self._uncounted: dict = {}
         self._table_key = None
         self._table_dev = None
         self._setup()
@@ -529,17 +548,32 @@ class FamilyAdapter:
         the pages or None, slab rows or None, positions computed)."""
         raise NotImplementedError
 
-    def _count_prefill(self, rid: int, computed: int, **counts) -> None:
+    def _count_prefill(
+        self, rid: int, computed: int, program_counts=(), **counts
+    ) -> None:
         """The positions the prefill programs compute for ``rid``: the
         counter and the ``prefill.done`` marker's field, beside which a
-        family may put ``counts`` of its own."""
+        family may put ``counts`` of its own, read from what its program
+        counted (``program_counts``, on the device)."""
         self.registry.counter("serve.prefill_computed_tokens").add(computed)
         done("prefill", rid=rid, computed_tokens=computed, **counts)
 
+    def count_prefill(self, rid: int) -> None:
+        """Count the prefill dispatched for ``rid`` (``_count_prefill``),
+        once: the engine calls it when it has read the stream's first
+        token, so the program has ended, the read of its counts waits for
+        nothing and ``prefill.done`` starts after the program's end.
+        Nothing where the positions were counted when they were staged
+        (a chunked prefill)."""
+        uncounted = self._uncounted.pop(rid, None)
+        if uncounted is not None:
+            self._count_prefill(rid, *uncounted)
+
     def prefill(self, rid: int, slot: int, prompt):
-        """Allocate the stream's state, run the family's prefill, write
-        the slot's state; -> the (V,) logits row of the last real prompt
-        position."""
+        """Allocate the stream's state, dispatch the family's prefill,
+        write the slot's state; -> the (V,) logits row of the last real
+        prompt position, on the device and unread (``count_prefill``
+        reads the program's counts later)."""
         p = len(prompt)
         p_pad = self._padded(p)
         kv_len = 0
@@ -566,7 +600,7 @@ class FamilyAdapter:
                 self.cache.write_prompt(
                     rid, *(kv[name][:, 0] for name in self.cache.entry_shapes)
                 )
-        self._count_prefill(rid, computed)
+        self._uncounted[rid] = (computed, self._program_counts)
         # on a mesh, hand the engine a host row: the engine's eager
         # sampler mixes it with its single-device rng key, which jax
         # refuses across device sets
@@ -580,15 +614,17 @@ class FamilyAdapter:
     _toks = None
 
     def decode_dispatch(
-        self, slot_rids, lens, tokens, key, fresh, in_flight=0
+        self, slot_rids, lens, tokens, key, fresh, in_flight=0, first=()
     ):
         """Dispatch one jitted ragged decode step over all slots and
         return its sampled tokens (B,) int32 and logits (B, V) as device
         arrays, unread: the call returns before the device ends. A slot's
-        token input is the host's ``tokens`` where ``fresh`` (a stream
-        prefilled since the last dispatch: its first token) and the last
-        dispatched step's own output elsewhere, which never leaves the
-        device.
+        token input is the host's ``tokens`` where ``fresh`` (a token the
+        host holds: an imported stream's last, a first token it had to
+        read early), the device scalar of ``first`` (``[(slot, token),
+        ...]``: the first token of a stream prefilled since the last
+        dispatch, sampled and not read) and the last dispatched step's
+        own output elsewhere, which never leaves the device.
         ``in_flight`` 1: the engine dispatches this step before it read
         the last one's tokens. The program takes ``(params, [slab],
         [pools, page table], seq_lens, tokens, key)`` and returns
@@ -602,6 +638,8 @@ class FamilyAdapter:
             row = jnp.where(
                 self._dev(fresh), host, host if row is None else row
             )
+        for slot, tok in first:
+            row = _put_token()(row, np.int32(slot), tok)
         state = []
         if self._state is not None:
             state.append(self._state)
@@ -880,9 +918,7 @@ class HeldExpertsAdapter(FamilyAdapter):
     loop took for them. ``model_cfg`` has ``top_k``, ``held``,
     ``num_experts`` and ``n_moe_layers``; the family's ``_call_prefill``
     leaves the prefill program's own counts (pairs held, trips) in
-    ``self._moe_counts``."""
-
-    _moe_counts = (0, 0)  # on the device until the counts are read
+    ``self._program_counts``."""
 
     def _init_held_experts(self) -> None:
         from fms_fsdp_tpu.models.mixtral import routed_moe_form
@@ -909,20 +945,19 @@ class HeldExpertsAdapter(FamilyAdapter):
             cfg.num_experts
         )
 
-    def _count_prefill(self, rid: int, computed: int) -> None:
+    def _count_prefill(self, rid: int, computed: int, program_counts) -> None:
         """Beside the positions computed: the (token, choice) pairs they
         routed, those that landed on a held expert, and the trips the
         grouped product's loop took for them: one a MoE layer and chunk
         where the landed pairs fit a slab
         (models/moe_held.py::grouped_slab), more where the routing was
-        skewed onto the experts held (the program's own counts; reading
-        them waits for the prefill, which the engine's sampler does next
-        anyway; the dense form weighs every pair, counts none and takes
-        no trip)."""
+        skewed onto the experts held (the program's own counts, read
+        behind the stream's first token; the dense form weighs every
+        pair, counts none and takes no trip)."""
         cfg = self.model_cfg
         routed = computed * cfg.top_k * cfg.n_moe_layers
         held, slabs = (
-            map(int, self._moe_counts) if self.moe_impl == "routed" else (0, 0)
+            map(int, program_counts) if self.moe_impl == "routed" else (0, 0)
         )
         self.registry.counter("serve.moe_pairs_routed").add(routed)
         self.registry.counter("serve.moe_pairs_held").add(held)

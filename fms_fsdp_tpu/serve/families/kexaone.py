@@ -256,5 +256,5 @@ class KExaoneAdapter(HeldExpertsAdapter):
         logits, kv, ring, pairs, slabs = fn(
             self.params, jnp.asarray(toks), jnp.asarray([p], np.int32)
         )
-        self._moe_counts = (pairs, slabs)  # on the device until read
+        self._program_counts = (pairs, slabs)  # on the device until read
         return logits[0], kv, ring, prefill_positions(p, toks.shape[1])

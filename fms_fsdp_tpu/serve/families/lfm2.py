@@ -281,7 +281,7 @@ class Lfm2Adapter(HeldExpertsAdapter):
         logits, kv, windows, pairs, slabs = fn(
             self.params, jnp.asarray(row), jnp.asarray([p], np.int32)
         )
-        self._moe_counts = (pairs, slabs)  # on the device until read
+        self._program_counts = (pairs, slabs)  # on the device until read
         self.registry.counter("serve.conv_windows_written").add(
             len(self.model_cfg.conv_layers)
         )

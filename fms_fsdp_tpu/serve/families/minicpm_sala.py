@@ -195,7 +195,6 @@ def prefill_program(model_cfg, scfg, n: int, compute_dtype):
 class MiniCPMSalaAdapter(FamilyAdapter):
     family = "minicpm_sala"
     _pages_noun = "sparse-attention pages"
-    _counts = (0, 0, 0, 0)  # a prefill's, on the device until they are read
 
     def _setup(self) -> None:
         cfg, scfg = self.model_cfg, self.scfg
@@ -309,22 +308,21 @@ class MiniCPMSalaAdapter(FamilyAdapter):
         logits, kv, state, counts = fn(
             self.params, jnp.asarray(row), jnp.asarray([p], np.int32)
         )
-        self._counts = counts  # on the device until read
+        self._program_counts = counts  # on the device until read
         # pages and index rows at the program's length: what lies past the
         # stream's own pages is zeros and lands on the scratch page
         # (``PagedKVCache.scratch_tail``), so a write has the program's
         # shape and compiles once a program, not once a padded length
         return logits[0], kv, state, prefill_positions(p, n, self.model_cfg)
 
-    def _count_prefill(self, rid: int, computed: int) -> None:
+    def _count_prefill(self, rid: int, computed: int, program_counts) -> None:
         """Beside the positions computed: those of them that chose their
         blocks (``t + 1 > dense_len``), the blocks they chose, the blocks
         they chose from and the blocks the attention's products touched
-        for them, a kv head and sparse layer (the program's own counts;
-        reading them waits for the prefill, which the engine's sampler
-        does next anyway)."""
+        for them, a kv head and sparse layer (the program's own counts,
+        read behind the stream's first token)."""
         chose, blocks, context, multiplied = map(
-            int, np.asarray(self._counts)
+            int, np.asarray(program_counts)
         )
         counter = self.registry.counter
         counter("serve.sparse_chose_tokens").add(chose)
@@ -338,7 +336,9 @@ class MiniCPMSalaAdapter(FamilyAdapter):
 
     # -- decode: the step's count beside the skeleton's dispatch -----------
 
-    def decode_dispatch(self, slot_rids, lens, tokens, key, fresh, in_flight=0):
+    def decode_dispatch(
+        self, slot_rids, lens, tokens, key, fresh, in_flight=0, first=()
+    ):
         dense_len = self.model_cfg.sparse.dense_len
         chose = sum(
             1 for rid, n in zip(slot_rids, lens)
@@ -347,5 +347,5 @@ class MiniCPMSalaAdapter(FamilyAdapter):
         self.registry.counter("serve.sparse_decode_chose").add(chose)
         self._dispatch_fields = dict(self._dispatch_fields, live_chose=chose)
         return super().decode_dispatch(
-            slot_rids, lens, tokens, key, fresh, in_flight
+            slot_rids, lens, tokens, key, fresh, in_flight, first
         )

@@ -188,7 +188,7 @@ class SarvamAdapter(HeldExpertsAdapter):
         logits, latent, pairs, slabs = fn(
             self.params, jnp.asarray(toks), jnp.asarray([p], np.int32)
         )
-        self._moe_counts = (pairs, slabs)  # on the device until read
+        self._program_counts = (pairs, slabs)  # on the device until read
         return (
             logits[0],
             {"latent": latent},

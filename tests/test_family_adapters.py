@@ -204,6 +204,11 @@ def test_an_adapter_given_no_registry_counts_into_its_own(params, family):
     assert isinstance(a.registry, MetricRegistry) and b.registry is other
     assert a.registry is not other
     a.prefill(0, 0, list(range(1, 10)))
+    # dispatched, not yet counted: the engine counts when it has read
+    # the first token (``count_prefill``, once)
+    assert counts(a.registry)[3] == 0
+    a.count_prefill(0)
+    a.count_prefill(0)
     computed = 12 if family == "hybrid" else 16
     slab = int(family not in ("llama", "mixtral"))
     assert counts(a.registry) == (1, 0, slab, computed, 0)

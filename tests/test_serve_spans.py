@@ -54,13 +54,16 @@ PARENT = {
     "prefill": "admit",
     "prefill.dispatch": "prefill",
     "prefill.write_pages": "prefill",
-    "prefill.sample": "prefill",
     "grow": "step",
     "decode": "step",
     "decode.table": "decode",
     "decode.dispatch": "decode",
     "decode.wait": "decode",
     "decode.commit": "decode",
+    # the landing of an admission, behind the decode step's dispatch: the
+    # read of the first token and the counts of the prefill program
+    "prefill.land": "step",
+    "prefill.sample": "prefill.land",
     "publish": "step",
     # counts known only at the end of their span
     "submit.done": "submit",
@@ -68,10 +71,14 @@ PARENT = {
     "admit.done": "admit",
     "grow.done": "grow",
     "decode.commit.done": "decode.commit",
-    "prefill.done": "prefill",
+    "prefill.done": "prefill.land",
 }
-# the chunked path (llama only) adds one name
-CHUNKED_PARENT = {"prefill_chunk": "step", "prefill.sample": "prefill_chunk",
+# the chunked path (llama only) adds one name; the last chunk's first
+# token lands like a whole prompt's, and the positions are counted where
+# they are staged
+CHUNKED_PARENT = {"prefill_chunk": "step", "prefill.land": "step",
+                  "prefill.sample": "prefill.land",
+                  "prefill.done": "prefill",
                   "prefill.write_pages": "prefill_chunk",
                   "prefill.dispatch": "prefill_chunk"}
 
@@ -321,9 +328,25 @@ def test_the_spans_of_a_request_share_its_rid(traced):
     for pf in prefills:
         kids = [s for s in spans if s is not pf and s.inside(pf)]
         assert {s.name for s in kids} == {
-            "prefill.dispatch", "prefill.write_pages", "prefill.sample",
-            "prefill.done"}
+            "prefill.dispatch", "prefill.write_pages"}
         assert {s.stats["rid"] for s in kids} == {pf.stats["rid"]}
+    # the landing half: a span a request, in the step of its admission
+    # and behind that step's decode dispatch
+    lands = named(spans, "prefill.land")
+    assert sorted(s.stats["rid"] for s in lands) == sorted(
+        r.rid for r in reqs)
+    for land in lands:
+        (pf,) = [s for s in prefills if s.stats["rid"] == land.stats["rid"]]
+        assert land.stats["step"] == pf.stats["step"] and pf.end <= land.start
+        kids = [s for s in spans if s is not land and s.inside(land)]
+        assert [s.name for s in kids] == ["prefill.sample", "prefill.done"]
+        assert {s.stats["rid"] for s in kids} == {land.stats["rid"]}
+        assert kids[0].stats["step"] == land.stats["step"]
+        assert kids[0].stats["overlapped"] == 1
+        # one decode step was dispatched between the two halves
+        assert len([
+            s for s in named(spans, "decode.dispatch")
+            if pf.end <= s.start and s.end <= land.start]) == 1
     accepted = [s.stats["rid"] for s in named(spans, "submit.done")
                 if not s.stats["rejected"]]
     assert accepted == [r.rid for r in reqs]
