@@ -414,8 +414,9 @@ def kexaone_prefill(
     (L_window, B, sliding_window, Nkv, H), position ``t`` at ``t mod
     sliding_window``; the number of (token, choice) pairs of the
     positions computed that landed on held experts, summed over the
-    sparse layers; and the trips the grouped product's loop took for
-    them, one a layer and chunk unless its pairs overran a slab)."""
+    sparse layers; the trips the grouped product's loop took for them,
+    one a layer and chunk unless its pairs overran a slab; and the row
+    tiles a product of those trips met)."""
     with jax.named_scope("params_cast"):
         params = jax.tree.map(lambda a: a.astype(compute_dtype), params)
     B, S = tokens.shape
@@ -436,7 +437,7 @@ def kexaone_prefill(
     }
 
     def chunk(j, carry):
-        full, tails, last, pairs, slabs = carry
+        full, tails, last, pairs, slabs, tiles = carry
         full, tails = list(full), list(tails)
         start = j * c
         ahead = lengths - start  # of each row, from this chunk's start on
@@ -492,11 +493,11 @@ def kexaone_prefill(
                     x = x + _mlp(h2, layer)
                     continue
                 if routed:
-                    y, n, trips = _moe_grouped(
+                    y, n, trips, met = _moe_grouped(
                         h2.reshape(B * c, -1), layer, cfg, experts[kind], at
                     )
                     y = y.reshape(B, c, -1)
-                    pairs, slabs = pairs + n, slabs + trips
+                    pairs, slabs, tiles = pairs + n, slabs + trips, tiles + met
                 else:
                     y = _moe_dense_held(h2, layer, cfg)
                 with jax.named_scope("moe_combine"):
@@ -507,12 +508,12 @@ def kexaone_prefill(
             x, jnp.clip(pos, 0, c - 1)[:, None, None], axis=1
         )[:, 0]
         last = jnp.where(((pos >= 0) & (pos < c))[:, None], row, last)
-        return tuple(full), tuple(tails), last, pairs, slabs
+        return tuple(full), tuple(tails), last, pairs, slabs, tiles
 
     def zeros(shape):
         return jnp.zeros(shape, compute_dtype)
 
-    full, tails, last, pairs, slabs = lax.fori_loop(
+    full, tails, last, pairs, slabs, tiles = lax.fori_loop(
         0,
         (jnp.max(lengths) + c - 1) // c,
         chunk,
@@ -522,8 +523,7 @@ def kexaone_prefill(
                 (zeros(tail_shape), zeros(tail_shape)) for _ in range(n_win)
             ),
             zeros((B, cfg.emb_dim)),
-            jnp.zeros((), jnp.int32),
-            jnp.zeros((), jnp.int32),
+            *(jnp.zeros((), jnp.int32),) * 3,
         ),
     )
     with jax.named_scope("lm_head"):
@@ -540,7 +540,7 @@ def kexaone_prefill(
         name: stack([_as_ring(p[i], lengths, W) for p in tails], tail_shape)
         for i, name in enumerate(("k", "v"))
     }
-    return logits, kv, ring, pairs, slabs
+    return logits, kv, ring, pairs, slabs, tiles
 
 
 # ---------------------------------------------------------------------------

@@ -301,8 +301,9 @@ def lfm2_prefill(
     windows ``{"z"}`` (L_conv, B, conv_kernel - 1, D), the last values of
     ``z`` of each row's prompt, oldest first, zeros before its start; the
     number of (token, choice) pairs of the positions computed that landed
-    on held experts, summed over the expert layers; and the trips the
-    grouped product's loop took for them)."""
+    on held experts, summed over the expert layers; the trips the
+    grouped product's loop took for them; and the row tiles a product of
+    those trips met)."""
     with jax.named_scope("params_cast"):
         params = jax.tree.map(lambda a: a.astype(compute_dtype), params)
     B, S = tokens.shape
@@ -316,7 +317,7 @@ def lfm2_prefill(
     tail_shape = (B, W, cfg.emb_dim)
 
     def chunk(j, carry):
-        kvs, tails, last, pairs, slabs = carry
+        kvs, tails, last, pairs, slabs, tiles = carry
         kvs, tails = list(kvs), list(tails)
         start = j * c
         ahead = lengths - start  # of each row, from this chunk's start on
@@ -365,8 +366,10 @@ def lfm2_prefill(
                 if not routed:
                     x = x + _ffn_forward(h2, layer, cfg, cfg.sparse(i))
                     continue
-                y, n, trips = _moe_grouped(h2.reshape(B * c, -1), layer, cfg)
-                pairs, slabs = pairs + n, slabs + trips
+                y, n, trips, met = _moe_grouped(
+                    h2.reshape(B * c, -1), layer, cfg
+                )
+                pairs, slabs, tiles = pairs + n, slabs + trips, tiles + met
                 with jax.named_scope("moe_combine"):
                     x = x + y.reshape(B, c, -1)
         # the head reads a row's last real position alone
@@ -375,12 +378,12 @@ def lfm2_prefill(
             x, jnp.clip(pos, 0, c - 1)[:, None, None], axis=1
         )[:, 0]
         last = jnp.where(((pos >= 0) & (pos < c))[:, None], row, last)
-        return tuple(kvs), tuple(tails), last, pairs, slabs
+        return tuple(kvs), tuple(tails), last, pairs, slabs, tiles
 
     def zeros(shape):
         return jnp.zeros(shape, compute_dtype)
 
-    kvs, tails, last, pairs, slabs = lax.fori_loop(
+    kvs, tails, last, pairs, slabs, tiles = lax.fori_loop(
         0,
         (jnp.max(lengths) + c - 1) // c,
         chunk,
@@ -388,8 +391,7 @@ def lfm2_prefill(
             tuple((zeros(kv_shape), zeros(kv_shape)) for _ in range(n_attn)),
             tuple(zeros(tail_shape) for _ in range(n_conv)),
             zeros((B, cfg.emb_dim)),
-            jnp.zeros((), jnp.int32),
-            jnp.zeros((), jnp.int32),
+            *(jnp.zeros((), jnp.int32),) * 3,
         ),
     )
     logits = _head(last, params, cfg)
@@ -403,7 +405,9 @@ def lfm2_prefill(
         )
         for i, name in enumerate(("k", "v"))
     }
-    return logits, kv, {"z": stack(list(tails), tail_shape)}, pairs, slabs
+    return (
+        logits, kv, {"z": stack(list(tails), tail_shape)}, pairs, slabs, tiles
+    )
 
 
 # ---------------------------------------------------------------------------

@@ -22,8 +22,8 @@ Three forms of the held part: over a decode step's rows the two of
 models/mixtral.py (``routed_moe_form``: every held expert streamed once,
 or one product a routed pair); over a prompt's chunk the (token, choice)
 pairs that land on held experts sorted by expert and multiplied group by
-group (``_moe_grouped``, the megablox grouped matmul), none dropped, and
-only their rows moved: a slab of the sorted pairs at a time
+group (``_moe_grouped``, through ops/grouped_matmul.py), none dropped,
+and only their rows moved: a slab of the sorted pairs at a time
 (``grouped_slab``), one trip unless the routing is skewed onto the
 experts held.
 """
@@ -40,8 +40,11 @@ from fms_fsdp_tpu.models.mixtral import (
     routed_moe_form,
 )
 from fms_fsdp_tpu.obs.scopes import scoped
-from fms_fsdp_tpu.ops.pallas_mode import interpret_default
-from fms_fsdp_tpu.ops.selective_scan import largest_divisor
+from fms_fsdp_tpu.ops.grouped_matmul import (
+    group_row_tiles,
+    grouped_matmul,
+    row_tile,
+)
 
 
 def _swiglu(h, w1, w3, w2):
@@ -131,40 +134,43 @@ def _moe_token(h, layer, cfg, moe_impl: str, routed=None):
         return jnp.einsum("bmkd,bmk->bmd", out, wt)
 
 
-# rows of a grouped product's tile: a slab is a whole number of them
+# a slab is a whole number of these rows (and of any row tile up to them)
 _ROW_TILE = 256
 
 
-def _gmm(x, stack, sizes, l):
+def _gmm(x, stacks, sizes, l):
     """Rows of ``x`` (M, k), sorted by group, times their group's matrix
-    in layer ``l`` of ``stack`` (L, G, k, n): the megablox grouped
-    matmul. The kernel is handed the whole stack, seen as ``L * G``
-    groups of which only layer ``l``'s have rows, so no layer's slice of
-    it is ever copied out (a sliced operand is: 1.6 GB a layer and
-    chunk at the published widths). Its grid follows ``sum(sizes)``:
-    rows past that are not visited and hold whatever the buffer held,
-    NaN included; the caller zeroes them before any product reads
-    them."""
-    from jax.experimental.pallas.ops.tpu.megablox import gmm
-
-    M, k = x.shape
-    L, G, _, n = stack.shape
-    sizes = lax.dynamic_update_slice(
-        jnp.zeros((L * G,), jnp.int32), sizes, (l * G,)
-    )
-    # timed on the chip at the published widths, 4096 rows in 32 groups
-    # (PERF.md, PR 31): (512, 1024, 1024) 1.91 / 1.95 ms up / down,
-    # (256, 1024, 1024) 1.45 / 1.49, (128, 1024, 1024) 1.67 / 1.70,
-    # (256, 2048, 1024) 1.44 / 1.26
-    # an expert 1536 wide takes tiles of 768: a tile that overhangs the
-    # width computes columns no one reads
-    tiling = (
-        largest_divisor(M, _ROW_TILE), min(k, 2048), largest_divisor(n, 1024)
-    )
-    return gmm(
-        x, stack.reshape(L * G, k, n), sizes, preferred_element_type=x.dtype,
-        tiling=tiling, interpret=interpret_default(),
-    )
+    in layer ``l`` of a stack (L, G, k, n), or of two (gate and up:
+    ``silu(x w1) * (x w3)`` in one pass over the rows):
+    ops/grouped_matmul.py. The kernel is handed the whole stacks and
+    finds layer ``l`` by its block index, so no layer's slice of them is
+    ever copied out (a sliced operand is: 1.6 GB a layer and chunk at the
+    published widths). Its grid follows ``sum(sizes)``: rows past that
+    are not visited and hold whatever the buffer held, NaN included; the
+    caller zeroes them before any product reads them."""
+    # timed on the chip at the published widths (PERF.md section 6, PRs 43-44;
+    # ms: up / down product alone, then gate, up and down together):
+    #   6144 rows, 4041 landed in 32 groups, 4096 x 2048 (sarvam)
+    #     megablox (256, 2048, 1024)      1.44 / 1.21   3.92-3.97
+    #     megablox (256, k whole, 512)    1.22 / 1.21
+    #     this kernel's tiles through the grid's own pipeline (a block
+    #     asked for one meeting ahead)    1.14 / 1.17   3.31
+    #     a block asked for a group ahead, rows 64 / 128 / 192 / 256
+    #       gate and up in one pass       1.93 / 1.58 / 1.97 / 2.17
+    #       down                          1.03 / 0.85 / 1.02 / 1.12
+    #     the schedule (128, 1024 | 4096) 1.61 / 0.85   2.38
+    #   3072 rows, 2048 landed in 16 groups, 6144 x 2048 (k-exaone)
+    #     megablox (256, 2048, 1024)      1.03 / 0.91   2.87-2.95
+    #     the schedule (128, 512 | 3072)  1.31 / 0.68   1.92
+    #   8192 | 2048 rows, all landed in 64 groups, 2048 x 1536 (lfm2)
+    #     megablox (256, 2048, 768)       0.94 | 0.72   2.68 | 2.01
+    #     rows 16 / 32 / 64 through the grid's pipeline, 2048 rows
+    #                                     0.76 / 0.69 / 0.66
+    #     the schedule (128, 1536 | 2048) 0.68 | 0.62   1.82 | 1.73
+    # a meeting takes the matrix unit as long for 16 rows as for 128 and
+    # twice as long for 256, so tiles shorter than the groups only add
+    # meetings; the bytes alone need 2.15 / 1.61 / 1.48 ms
+    return grouped_matmul(x, stacks, sizes, l)
 
 
 def grouped_slab(cfg, pairs: int) -> int:
@@ -175,6 +181,12 @@ def grouped_slab(cfg, pairs: int) -> int:
     them in one trip)."""
     want = -(-3 * pairs * cfg.held[1] // (2 * cfg.num_experts))
     return min(pairs, -(-want // _ROW_TILE) * _ROW_TILE)
+
+
+def grouped_tile_rows(cfg, pairs: int) -> int:
+    """Rows of the row tiles a grouped product takes a slab of a chunk's
+    ``pairs`` in (ops/grouped_matmul.py::row_tile)."""
+    return row_tile(grouped_slab(cfg, pairs))
 
 
 def _moe_grouped(h, layer, cfg, experts=None, l=0):
@@ -196,13 +208,18 @@ def _moe_grouped(h, layer, cfg, experts=None, l=0):
     MXU, products of the operands as they are summed in float32.
 
     Returns (y (T, D), the number of pairs that landed on held experts,
-    the loop's trips)."""
+    the loop's trips, and the (group, row tile) meetings that a grouped
+    product of those trips ran, counted from each slab's group sizes as
+    the kernel's grid counts them: times the tile's rows
+    (``grouped_tile_rows``) the rows a product multiplied, of which the
+    pairs landed are the ones kept)."""
     if experts is None:
         experts = {name: layer[name][None] for name in ("w1", "w3", "w2")}
     idx, w = _router(h, layer, cfg)  # (T, K)
     T, K = idx.shape
     first, held = cfg.held
     slab = grouped_slab(cfg, T * K)
+    tm = grouped_tile_rows(cfg, T * K)
     with jax.named_scope("moe_group"):
         local = idx.reshape(T * K) - first
         here = (local >= 0) & (local < held)
@@ -219,7 +236,8 @@ def _moe_grouped(h, layer, cfg, experts=None, l=0):
         trips = (n_here + slab - 1) // slab
         weights = w.reshape(T * K)
 
-    def trip(s, y):
+    def trip(s, carry):
+        y, met = carry
         lo = s * slab
         with jax.named_scope("moe_group"):
             pairs = lax.dynamic_slice_in_dim(order, lo, slab)
@@ -227,14 +245,13 @@ def _moe_grouped(h, layer, cfg, experts=None, l=0):
             mine = jnp.clip(
                 jnp.minimum(ends, lo + slab) - jnp.maximum(starts, lo), 0
             )
+            met = met + jnp.sum(group_row_tiles(mine, tm))
             valid = jnp.arange(slab) < n_here - lo
             token = pairs // K
             xs = h[token]
         with jax.named_scope("moe_experts"):
-            hid = jax.nn.silu(_gmm(xs, experts["w1"], mine, l)) * _gmm(
-                xs, experts["w3"], mine, l
-            )
-            out = _gmm(hid, experts["w2"], mine, l)
+            hid = _gmm(xs, (experts["w1"], experts["w3"]), mine, l)
+            out = _gmm(hid, (experts["w2"],), mine, l)
         with jax.named_scope("moe_combine"):
             # timed on the chip at the published widths, 2048 tokens, a
             # slab of 3072 x 6144 / 6144 x 4096 (PERF.md, PR 35): this
@@ -249,11 +266,11 @@ def _moe_grouped(h, layer, cfg, experts=None, l=0):
                 token[None, :] == jnp.arange(T)[:, None], wt[None, :],
                 jnp.zeros((), h.dtype),
             )
-            return y + jnp.dot(
-                place, out, preferred_element_type=jnp.float32
-            )
+            y = y + jnp.dot(place, out, preferred_element_type=jnp.float32)
+        return y, met
 
-    y = lax.fori_loop(
-        0, trips, trip, jnp.zeros((T, h.shape[-1]), jnp.float32)
+    y, met = lax.fori_loop(
+        0, trips, trip,
+        (jnp.zeros((T, h.shape[-1]), jnp.float32), jnp.zeros((), jnp.int32)),
     )
-    return y.astype(h.dtype), n_here, trips
+    return y.astype(h.dtype), n_here, trips, met

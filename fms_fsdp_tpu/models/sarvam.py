@@ -38,7 +38,7 @@ part: over a decode step's rows the two of models/mixtral.py
 (``routed_moe_form``: every held expert streamed once, or one product a
 routed pair); over a prompt's chunk the (token, choice) pairs that land
 on held experts are sorted by expert and multiplied group by group
-(``_moe_grouped``, the megablox grouped matmul), none dropped, the work
+(``_moe_grouped``, through ops/grouped_matmul.py), none dropped, the work
 following the pairs that land here.
 
 Read by the family's convention where ``config.json`` has no key:
@@ -429,9 +429,10 @@ def sarvam_prefill(
     Returns (logits (B, V) of each row's last real position; the latent
     (L, B, kv_len, pool_width), zero past each row's length, for the
     pages; the number of (token, choice) pairs of the positions computed
-    that landed on held experts, summed over the MoE layers; and the
-    trips the grouped product's loop took for them, one a layer and
-    chunk unless its pairs overran a slab)."""
+    that landed on held experts, summed over the MoE layers; the trips
+    the grouped product's loop took for them, one a layer and chunk
+    unless its pairs overran a slab; and the row tiles a product of
+    those trips met)."""
     with jax.named_scope("params_cast"):
         params = jax.tree.map(lambda a: a.astype(compute_dtype), params)
     B, S = tokens.shape
@@ -450,7 +451,7 @@ def sarvam_prefill(
     }
 
     def chunk(j, carry):
-        lat, last, pairs, slabs = carry
+        lat, last, pairs, slabs, tiles = carry
         start = j * c
         ahead = lengths - start  # of each row, from this chunk's start on
         live = jnp.arange(c, dtype=jnp.int32)[None, :] < ahead[:, None]
@@ -483,24 +484,27 @@ def sarvam_prefill(
             x = x + _mlp(h2, layer)
 
         def body(carry, inp):
-            x, lat, pairs, slabs = carry
+            x, lat, pairs, slabs, tiles = carry
             layer, i = inp
             x, lat, h2 = attend(x, lat, layer, Ld + i)
             if moe_impl == "routed":
-                y, n, trips = _moe_grouped(
+                y, n, trips, met = _moe_grouped(
                     h2.reshape(B * c, -1), layer, cfg, experts, i
                 )
                 y, pairs, slabs = y.reshape(B, c, -1), pairs + n, slabs + trips
+                tiles = tiles + met
             else:
                 mine = {name: stack[i] for name, stack in experts.items()}
                 y = _moe_dense_held(h2, dict(layer, **mine), cfg)
             with jax.named_scope("moe_combine"):
-                return (x + y + _shared(h2, layer), lat, pairs, slabs), None
+                return (
+                    x + y + _shared(h2, layer), lat, pairs, slabs, tiles
+                ), None
 
         with jax.named_scope("layers"):
-            (x, lat, pairs, slabs), _ = lax.scan(
+            (x, lat, pairs, slabs, tiles), _ = lax.scan(
                 body,
-                (x, lat, pairs, slabs),
+                (x, lat, pairs, slabs, tiles),
                 (rest, jnp.arange(cfg.n_moe_layers)),
             )
         # the head reads a row's last real position alone
@@ -509,22 +513,21 @@ def sarvam_prefill(
             x, jnp.clip(at, 0, c - 1)[:, None, None], axis=1
         )[:, 0]
         last = jnp.where(((at >= 0) & (at < c))[:, None], row, last)
-        return lat, last, pairs, slabs
+        return lat, last, pairs, slabs, tiles
 
-    lat, last, pairs, slabs = lax.fori_loop(
+    lat, last, pairs, slabs, tiles = lax.fori_loop(
         0,
         (jnp.max(lengths) + c - 1) // c,
         chunk,
         (
             jnp.zeros((cfg.nlayers, B, kv_len, pool_width(cfg)), compute_dtype),
             jnp.zeros((B, cfg.emb_dim), compute_dtype),
-            jnp.zeros((), jnp.int32),
-            jnp.zeros((), jnp.int32),
+            *(jnp.zeros((), jnp.int32),) * 3,
         ),
     )
     with jax.named_scope("lm_head"):
         logits = _norm(last, params["norm"], cfg) @ params["lm_head"]
-    return logits, lat, pairs, slabs
+    return logits, lat, pairs, slabs, tiles
 
 
 # ---------------------------------------------------------------------------

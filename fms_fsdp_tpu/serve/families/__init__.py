@@ -914,11 +914,12 @@ class HeldExpertsAdapter(FamilyAdapter):
     program runs over the held experts (``moe_form``, the gauge
     ``serve.moe_expert_reads_per_layer``), the gauges of the share, and
     the count of the (token, choice) pairs a prefill routed, of those
-    that landed on a held expert and of the trips the grouped product's
-    loop took for them. ``model_cfg`` has ``top_k``, ``held``,
-    ``num_experts`` and ``n_moe_layers``; the family's ``_call_prefill``
-    leaves the prefill program's own counts (pairs held, trips) in
-    ``self._program_counts``."""
+    that landed on a held expert, of the trips the grouped product's
+    loop took for them and of the row tiles a product of those trips
+    met. ``model_cfg`` has ``top_k``, ``held``, ``num_experts`` and
+    ``n_moe_layers``; the family's ``_call_prefill`` leaves the prefill
+    program's own counts (pairs held, trips, row tiles), on the device
+    until read, in ``self._program_counts``."""
 
     def _init_held_experts(self) -> None:
         from fms_fsdp_tpu.models.mixtral import routed_moe_form
@@ -951,20 +952,26 @@ class HeldExpertsAdapter(FamilyAdapter):
         grouped product's loop took for them: one a MoE layer and chunk
         where the landed pairs fit a slab
         (models/moe_held.py::grouped_slab), more where the routing was
-        skewed onto the experts held (the program's own counts, read
-        behind the stream's first token; the dense form weighs every
-        pair, counts none and takes no trip)."""
+        skewed onto the experts held; and the (group, row tile) meetings
+        a grouped product of those trips ran (``_moe_grouped``'s count:
+        times the tile's rows, ``grouped_tile_rows`` of the program's
+        chunk, the rows a product multiplied, of which the pairs held
+        are the ones stored). The
+        program's own counts, read behind the stream's first token; the
+        dense form weighs every pair, counts none and takes no trip."""
         cfg = self.model_cfg
         routed = computed * cfg.top_k * cfg.n_moe_layers
-        held, slabs = (
-            map(int, program_counts) if self.moe_impl == "routed" else (0, 0)
+        held, slabs, tiles = (
+            map(int, program_counts) if self.moe_impl == "routed"
+            else (0, 0, 0)
         )
         self.registry.counter("serve.moe_pairs_routed").add(routed)
         self.registry.counter("serve.moe_pairs_held").add(held)
         self.registry.counter("serve.moe_slabs").add(slabs)
+        self.registry.counter("serve.moe_row_tiles").add(tiles)
         super()._count_prefill(
             rid, computed, moe_pairs_routed=routed, moe_pairs_held=held,
-            moe_slabs=slabs,
+            moe_slabs=slabs, moe_row_tiles=tiles,
         )
 
 

@@ -38,9 +38,10 @@ in a loop inside its bucket's program that stops at the prompt's length
 flash kernel over the chunk's band and carries its last
 ``sliding_window`` positions to the next chunk, a full layer walks its
 earlier blocks; ``attn_form`` on ``prefill.dispatch`` says which forms a
-program runs. The pairs that landed on held experts and the grouped
-product's trips are counted as the sarvam adapter counts them
-(``serve.moe_pairs_held`` / ``_routed``, ``serve.moe_slabs``).
+program runs. The pairs that landed on held experts, the grouped
+product's trips and the row tiles it met are counted as the sarvam
+adapter counts them (``serve.moe_pairs_held`` / ``_routed``,
+``serve.moe_slabs``, ``serve.moe_row_tiles``).
 
 Not here yet (PERF.md section 7): a serving layout over chips (the expert
 layer's exchange), handoff of rings and pages, quantized pages,
@@ -147,9 +148,9 @@ def prefill_program(model_cfg, scfg, p_pad: int, kv_len: int, compute_dtype):
     """The jitted prefill of one padded prompt length: ``(params, tokens
     (1, p_pad), lengths (1,)) -> (logits (1, V), the full layers' k and v
     (L_full, 1, kv_len, Nkv, H), the window layers' rings (L_window, 1,
-    window, Nkv, H), pairs on held experts)``. The traced function is
-    named by the length: ``jit__prefill_<p_pad>`` in the profiler's
-    trace."""
+    window, Nkv, H), pairs on held experts, the grouped product's trips,
+    the row tiles it met)``. The traced function is named by the length:
+    ``jit__prefill_<p_pad>`` in the profiler's trace."""
     attn_impl, moe_impl = _prefill_attn_impl(scfg), scfg.moe_impl
 
     def _prefill(params, tokens, lengths):
@@ -253,8 +254,8 @@ class KExaoneAdapter(HeldExpertsAdapter):
         return {"attn_form": form}
 
     def _call_prefill(self, fn, toks, p: int):
-        logits, kv, ring, pairs, slabs = fn(
+        # the program's counts stay on the device until read
+        logits, kv, ring, *self._program_counts = fn(
             self.params, jnp.asarray(toks), jnp.asarray([p], np.int32)
         )
-        self._program_counts = (pairs, slabs)  # on the device until read
         return logits[0], kv, ring, prefill_positions(p, toks.shape[1])

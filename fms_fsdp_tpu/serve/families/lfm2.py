@@ -137,7 +137,7 @@ def prefill_program(model_cfg, scfg, n: int, kv_len: int, compute_dtype):
     k and v as the pages hold them (L_attn, 1, kv_len * tile_rows, 128), the
     convolution layers' windows
     (L_conv, 1, K - 1, D), pairs on held experts, the grouped product's
-    trips)``. The traced function is named by the length:
+    trips, the row tiles it met)``. The traced function is named by the length:
     ``jit__prefill_<n>`` in the profiler's trace."""
     attn_impl, moe_impl = _prefill_attn_impl(scfg), scfg.moe_impl
 
@@ -278,10 +278,10 @@ class Lfm2Adapter(HeldExpertsAdapter):
         n = self.program_len_of(p)
         row = np.zeros((1, n), np.int32)
         row[0, : toks.shape[1]] = toks[0]
-        logits, kv, windows, pairs, slabs = fn(
+        # the program's counts stay on the device until read
+        logits, kv, windows, *self._program_counts = fn(
             self.params, jnp.asarray(row), jnp.asarray([p], np.int32)
         )
-        self._program_counts = (pairs, slabs)  # on the device until read
         self.registry.counter("serve.conv_windows_written").add(
             len(self.model_cfg.conv_layers)
         )

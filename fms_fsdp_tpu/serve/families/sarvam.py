@@ -28,9 +28,10 @@ the expanded form, keys 192 and values 128 wide as published, made from
 each latent block as it is met (``attn_form`` on ``prefill.dispatch``
 says which form a program runs); the experts routed and grouped, no pair
 on a held expert dropped. Beside the first token's logits and the latent
-for the pages the program returns the pairs that landed on held experts
-and the trips its grouped product took for them: ``serve.moe_pairs_held``
-/ ``_routed`` and ``serve.moe_slabs``, all on ``serve/prefill.done``.
+for the pages the program returns the pairs that landed on held experts,
+the trips its grouped product took for them and the row tiles a product
+met: ``serve.moe_pairs_held`` / ``_routed``, ``serve.moe_slabs`` and
+``serve.moe_row_tiles``, all on ``serve/prefill.done``.
 
 Not here yet (PERF.md section 7): a serving layout over chips (the
 expert layer's exchange), handoff of latent pages, quantized latent
@@ -107,7 +108,8 @@ def decode_program(model_cfg, scfg, page_size: int, compute_dtype):
 def prefill_program(model_cfg, scfg, p_pad: int, kv_len: int, compute_dtype):
     """The jitted prefill of one padded prompt length: ``(params, tokens
     (1, p_pad), lengths (1,)) -> (logits (1, V), latent (L, 1, kv_len,
-    pool_width), pairs on held experts)``. The traced function is named
+    pool_width), pairs on held experts, the grouped product's trips, the
+    row tiles it met)``. The traced function is named
     by the length: ``jit__prefill_<p_pad>`` in the profiler's trace."""
     attn_impl, moe_impl = _prefill_attn_impl(scfg), scfg.moe_impl
 
@@ -185,10 +187,10 @@ class SarvamAdapter(HeldExpertsAdapter):
         return {"attn_form": form}
 
     def _call_prefill(self, fn, toks, p: int):
-        logits, latent, pairs, slabs = fn(
+        # the program's counts stay on the device until read
+        logits, latent, *self._program_counts = fn(
             self.params, jnp.asarray(toks), jnp.asarray([p], np.int32)
         )
-        self._program_counts = (pairs, slabs)  # on the device until read
         return (
             logits[0],
             {"latent": latent},

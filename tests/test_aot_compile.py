@@ -443,9 +443,10 @@ def test_sarvam_programs_keep_one_latent_pool_and_no_dense_expert_array(
     # the ragged paged latent kernel, in the dense layer and the scan
     assert dtext.count("tpu_custom_call") == 2
     # prefill: a flash call for the chunk's own block and one in the walk
-    # over earlier blocks (dense layer, and the scan's body), and three
-    # grouped matmuls in the scan's body
-    assert ptext.count("tpu_custom_call") == 2 * 2 + 3
+    # over earlier blocks (dense layer, and the scan's body), and two
+    # grouped matmuls in the scan's body (three until PR 44: gate and up
+    # are one pass over the rows since, ops/grouped_matmul.py)
+    assert ptext.count("tpu_custom_call") == 2 * 2 + 2
     N, c = cfg.nheads, prefill_chunk(top)
     # the flash calls take queries and keys 192 wide and values 128 wide
     # in the kernel's (B, N, c, H) layout, and nothing padded to 256
@@ -572,12 +573,13 @@ def test_kexaone_programs_fit_beside_a_cache_of_each_kind_at_eight_layers(
         assert not re.search(r"= bf16\[%s\]\S* copy\(" % dims, dtext)
     # the ragged paged kernel once a full layer; in the prefill a windowed
     # flash call a window layer, two flash calls a full layer (the chunk's
-    # own block, the walk over earlier ones), three grouped matmuls a
-    # sparse layer (PR 38: the windowed call's cell holds a KV group's
+    # own block, the walk over earlier ones), two grouped matmuls a
+    # sparse layer (three until PR 44: gate and up are one pass over the
+    # rows since; PR 38: the windowed call's cell holds a KV group's
     # query heads, scores keys down; still one call a window layer, and
     # the program's temporaries read 0.7536 GB where they read 0.7545)
     assert dtext.count("tpu_custom_call") == 2
-    assert ptext.count("tpu_custom_call") == 6 + 2 * 2 + 3 * 7
+    assert ptext.count("tpu_custom_call") == 6 + 2 * 2 + 2 * 7
     c = prefill_chunk(top)
     for text in (dtext, ptext):
         # no weight laid out again

@@ -180,7 +180,7 @@ def test_routing_skewed_onto_one_held_expert_drops_no_pair(c):
     layer = dict(layer, gate_bias=bias)
     h = jax.random.normal(jax.random.PRNGKey(2), (40, 64))
     want = _moe_layer_by_token_loop(h, layer, cfg)
-    y, n, _ = H._moe_grouped(h, layer, cfg)
+    y, n, *_ = H._moe_grouped(h, layer, cfg)
     idx, _ = H._router(h, layer, cfg)
     here = (idx >= cfg.held[0]) & (idx < sum(cfg.held))
     assert int(n) == int(here.sum()) >= 40  # every row's pair on it counted
@@ -212,7 +212,7 @@ def test_the_eight_shares_add_up_to_the_uncut_layer():
             w: layer[w][first:first + 2] for w in ("w1", "w3", "w2")})
         assert cfg.held == (first, 2) and cfg.num_experts == 16
         sums["dense"] += H._moe_dense_held(h, part, cfg)
-        y, n, _ = H._moe_grouped(h[0], part, cfg)
+        y, n, *_ = H._moe_grouped(h[0], part, cfg)
         sums["grouped"] += y[None]
         n_pairs += int(n)
         sums["token"] += H._moe_token(
@@ -239,7 +239,8 @@ def test_grouped_prefill_takes_the_landed_pairs_a_slab_at_a_time(
         landed, poisoned, monkeypatch):
     """The first ``landed`` (token, choice) pairs of a chunk on the four
     held experts, the rest elsewhere: ``_moe_grouped`` equals the token
-    loop, counts them, and takes ``ceil(landed / slab)`` trips. With every
+    loop, counts them, takes ``ceil(landed / slab)`` trips and counts
+    the row tiles each trip's groups lie in. With every
     row that a grouped product did not visit poisoned, the result is the
     same to the bit: a slab's rows past its valid count are zeroed before
     any product reads them."""
@@ -265,8 +266,16 @@ def test_grouped_prefill_takes_the_landed_pairs_a_slab_at_a_time(
             h[t], layer["w1"][k], layer["w3"][k], layer["w2"][k]))
 
     run = jax.jit(lambda h: H._moe_grouped(h, layer, cfg))
-    y, n, trips = run(h)
+    y, n, trips, met = run(h)
     assert (int(n), int(trips)) == (landed, -(-landed // SLAB))
+    # sorted, the landed pairs are held expert 0's, then 1's...: of each
+    # slab, the tiles of 128 rows that each group's rows lie in
+    group = np.sort(np.nonzero(here)[1])
+    by_hand = sum(
+        len({(g, r // 128) for r, g in enumerate(group[lo:lo + SLAB])})
+        for lo in range(0, landed, SLAB))
+    assert H.grouped_tile_rows(cfg, T * K) == 128
+    assert int(met) == by_hand
     np.testing.assert_allclose(np.asarray(y), want, atol=2e-5)
     if poisoned:
         gmm = H._gmm
@@ -453,7 +462,7 @@ def test_prefill_in_chunks_is_the_forward(
         toks[i, :n] = rng.integers(1, 256, size=n)
     assert M.prefill_attn_form(cfg, attn_impl, S) == (
         "einsum" if attn_impl == "xla" else "flash_window+flash")
-    logits, kv, ring, pairs, slabs = jax.jit(lambda p, t, l: M.kexaone_prefill(
+    logits, kv, ring, pairs, slabs, tiles = jax.jit(lambda p, t, l: M.kexaone_prefill(
         p, t, l, cfg, compute_dtype=jnp.float32, kv_len=S,
         attn_impl=attn_impl, moe_impl="routed"))(
         tree, jnp.asarray(toks), jnp.asarray(lengths, jnp.int32))
@@ -464,6 +473,7 @@ def test_prefill_in_chunks_is_the_forward(
     assert int(pairs) > 0
     # the landed pairs of a layer and chunk fit one slab: a trip each
     assert int(slabs) == cfg.n_moe_layers * (S // chunk)
+    assert int(tiles) >= int(slabs)  # a trip's product meets a row tile
     for i, n in enumerate(lengths):
         want = _ref_logits(tree, c, toks[i, :n].tolist())[-1]
         assert _gap(np.asarray(logits[i]), want) < 2e-5
@@ -473,7 +483,7 @@ def test_prefill_in_chunks_is_the_forward(
     # keys of the prompt's last W positions as a prefill of the prompt
     # cut to its last position but one leaves them, shifted by one
     n = lengths[0]
-    _, _, short, _, _ = jax.jit(lambda p, t, l: M.kexaone_prefill(
+    _, _, short, *_ = jax.jit(lambda p, t, l: M.kexaone_prefill(
         p, t, l, cfg, compute_dtype=jnp.float32, kv_len=S,
         attn_impl="xla", moe_impl="routed"))(
         tree, jnp.asarray(toks[:1]), jnp.asarray([n - 1], jnp.int32))
@@ -702,19 +712,20 @@ JAX_VERSION = "0.9.0"
 # models/sarvam.py (PR 33's parent) on that jax, under this file's
 # highest matmul precision (at the default: bbeeb528..., 8b794284...,
 # 8e09e6bc..., 064218f2..., the same on both trees). The two prefill
-# programs are PR 35's: they return the grouped product's trips beside
-# the pairs (8ae51275... and 4351de2c... until then), and the routed
-# one takes the landed pairs a slab at a time; the decode programs are
-# the text they were
+# programs are PR 44's: they return the row tiles the grouped product
+# met beside its trips and the pairs, and the routed one multiplies
+# through ops/grouped_matmul.py (b5ab43b9... and c284750a... since PR 35,
+# which brought the trips and the slab; 8ae51275... and 4351de2c... until
+# then); the decode programs are the text they were
 SARVAM_DIGESTS = {
     "decode all_experts":
         "3625f5e13a91ad80336aa90508c9bde832bb9ed1ad2eb868db5598fd0c49b4b8",
     "decode per_pair":
         "6a85865e42e78975aad0d4a44498f40f116872ab0de706ce105dff3abef4144c",
     "prefill routed":
-        "b5ab43b9fe1ead40a291719dc2b0c2acf8228902f565e339be2d4caa53d36351",
+        "d83569b8dca7fe9303dccae056a23677e9bceba231b64ed765d3cf760e468cb4",
     "prefill dense":
-        "c284750a554dbd85c195f6426f6cf20243c4a12e4511a158de0531a9440b08cd",
+        "802fc1b3226e1e6157726503b7e08f90a29857c3cc9a951bb397b9b89455fb06",
 }
 
 

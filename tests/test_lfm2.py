@@ -300,7 +300,7 @@ def test_prefill_in_chunks_is_the_forward(moe_impl="routed"):
     S = 48
     toks = _padded_prompts(LENGTHS, S)
     assert M.prefill_attn_form(cfg, "xla", S) == "einsum"
-    logits, kv, win, pairs, slabs = _prefill(
+    logits, kv, win, pairs, slabs, tiles = _prefill(
         tree, cfg, toks, LENGTHS, S, moe_impl=moe_impl)
     # a position's 2 kv heads of 16 side by side, a row a position
     assert kv["k"].shape == (2, len(LENGTHS), S, 32)
@@ -308,6 +308,7 @@ def test_prefill_in_chunks_is_the_forward(moe_impl="routed"):
     # every pair lands: every expert is here
     assert int(pairs) == S * len(LENGTHS) * 4 * cfg.n_moe_layers
     assert int(slabs) == cfg.n_moe_layers * (S // CHUNK)
+    assert int(tiles) >= int(slabs)  # a trip's product meets a row tile
     for i, n in enumerate(LENGTHS):
         want = _ref_logits(tree, TINY, toks[i, :n].tolist())[-1]
         assert _gap(np.asarray(logits[i]), want) < TOL
@@ -316,7 +317,7 @@ def test_prefill_in_chunks_is_the_forward(moe_impl="routed"):
     # a window holds the prompt's last two values of z, oldest first: the
     # newest of the prompt cut to its last position but one is the oldest
     # of the whole prompt's, and a prompt of one position has zeros first
-    _, _, short, _, _ = _prefill(tree, cfg, toks[:1], [LENGTHS[0] - 1], S)
+    _, _, short, *_ = _prefill(tree, cfg, toks[:1], [LENGTHS[0] - 1], S)
     np.testing.assert_allclose(
         np.asarray(win["z"][:, 0, 0]), np.asarray(short["z"][:, 0, 1]),
         atol=1e-5)

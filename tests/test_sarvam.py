@@ -236,7 +236,7 @@ def test_routed_prefill_equals_the_token_loop_under_skewed_routing(c):
     layer = jax.tree.map(lambda a: a[1], _tree(c)["layers"])
     layer["gate_bias"] = layer["gate_bias"].at[5].set(3.0).at[6].set(-3.0)
     h = jax.random.normal(jax.random.PRNGKey(4), (96, 64))
-    y, n, _ = jax.jit(lambda h, l: M._moe_grouped(h, l, cfg))(h, layer)
+    y, n, *_ = jax.jit(lambda h, l: M._moe_grouped(h, l, cfg))(h, layer)
     want, pairs = _moe_layer_by_token_loop(h, layer, cfg)
     idx, _ = H._router(h[None], layer, cfg)
     counts = np.bincount(np.asarray(idx).ravel(), minlength=16)
@@ -276,7 +276,7 @@ def test_the_shares_add_up_to_the_uncut_layer():
         c, cfg, part = cut(first)
         assert cfg.held == (first, 4) and cfg.num_experts == 16
         sums["dense"] += M._moe_dense_held(h, part, cfg)
-        y, n, _ = M._moe_grouped(h[0], part, cfg)
+        y, n, *_ = M._moe_grouped(h[0], part, cfg)
         sums["grouped"] += y[None]
         n_pairs += int(n)
         sums["token"] += M._moe_token(
@@ -333,7 +333,7 @@ def test_prefill_in_chunks_is_the_forward(attn_impl, c, monkeypatch):
             p, t, l, cfg, compute_dtype=jnp.float32, kv_len=S + 16,
             attn_impl=attn_impl))
     args = (tree, jnp.asarray(toks), jnp.asarray(lengths))
-    logits, lat, pairs, slabs = prefill(*args)
+    logits, lat, pairs, slabs, tiles = prefill(*args)
     form = M.prefill_attn_form(cfg, attn_impl, S)
     assert form == {"xla": "einsum"}.get(
         attn_impl, "flash" if c is SHARE else "flash_two_width")
@@ -357,6 +357,11 @@ def test_prefill_in_chunks_is_the_forward(attn_impl, c, monkeypatch):
     assert 0.1 < int(pairs) / (2 * S * 4 * 2) < 0.45
     # and the landed pairs of a layer and chunk fit one slab: a trip each
     assert int(slabs) == 2 * 3
+    # and a trip's product meets a row tile or more, at most the slab's
+    # and one more for each further group
+    slab = H.grouped_slab(cfg, 2 * chunk * 4)
+    most = slab // H.grouped_tile_rows(cfg, 2 * chunk * 4) + cfg.held[1] - 1
+    assert int(slabs) <= int(tiles) <= int(slabs) * most
 
 
 # ---------------------------------------------------------------------------
@@ -486,6 +491,65 @@ def test_a_prefill_counts_its_grouped_products_trips_beside_its_pairs(
     assert done["moe_pairs_held"] == count("serve.moe_pairs_held").value
     assert (done["moe_pairs_held"] > 0) == (moe_impl == "routed")
     assert done["moe_pairs_routed"] == chunks * CHUNK * 4 * cfg.n_moe_layers
+
+
+def test_a_prefill_counts_the_row_tiles_its_grouped_products_met(
+        tmp_path, monkeypatch):
+    """``serve.moe_row_tiles`` and ``moe_row_tiles`` on the
+    ``prefill.done`` span, beside ``moe_pairs_held`` and ``moe_slabs``:
+    the (group, row tile) meetings a grouped product of each trip ran,
+    equal to the count by hand from the chunk's group sizes. A prefill's
+    router is replaced by a fixed choice, the same for every layer, so the hand
+    knows the sizes: of a chunk's 256 pairs (64 positions) 164 land on
+    the four held experts (ids 4-7), 60, 64, 30 and 10 of them; a slab
+    is all 256 rows in two tiles of 128, the sorted groups lie at rows
+    0-60, 60-124, 124-154 and 154-164, so they meet 1, 1, 2 and 1
+    tiles."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    monkeypatch.setattr(M, "PREFILL_CHUNK", 64)
+    cfg = sarvam_config(SHARE)
+    t = np.arange(64)
+    chosen = np.stack([
+        np.where(t < 60, 4, 0), np.full(64, 5), np.where(t < 30, 6, 1),
+        np.where(t < 10, 7, 2)], axis=1)
+    sizes = [int((chosen == e).sum()) for e in (4, 5, 6, 7)]
+    assert sizes == [60, 64, 30, 10]
+    assert (H.grouped_slab(cfg, 256), H.grouped_tile_rows(cfg, 256)) == (
+        256, 128)
+    met = 1 + 1 + 2 + 1
+    weights = jnp.full(chosen.shape, 0.25, jnp.float32)
+    router = H._router
+
+    def fixed(h, layer, cfg):
+        if h.ndim == 2:  # a prefill chunk's rows
+            return jnp.asarray(chosen), weights
+        return router(h, layer, cfg)
+
+    monkeypatch.setattr(H, "_router", fixed)
+    eng = _engine(_tree(SHARE), cfg)
+    prompt = np.random.default_rng(2).integers(1, 256, size=37).tolist()
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    with jax.profiler.trace(str(tmp_path), profiler_options=opts):
+        eng.submit(prompt, 2)
+        eng.run()
+    want = cfg.n_moe_layers * met  # the prompt's 37 positions: one chunk
+    count = eng.registry.counter
+    assert count("serve.moe_row_tiles").value == want
+    assert count("serve.moe_pairs_held").value == (
+        cfg.n_moe_layers * sum(sizes))
+    assert count("serve.moe_slabs").value == cfg.n_moe_layers
+    (path,) = glob.glob(str(tmp_path / "plugins/profile/*/*.xplane.pb"))
+    (done,) = [dict(e.stats) for plane in ProfileData.from_file(path).planes
+               for line in plane.lines for e in line.events
+               if e.name == "serve/prefill.done"]
+    assert done["moe_row_tiles"] == want
+    # the share of the rows a product multiplied that were pairs
+    assert done["moe_pairs_held"] / (done["moe_row_tiles"] * 128) == (
+        164 / (5 * 128))
 
 
 def test_bfloat16_serving_is_within_a_tolerance_that_float8_fails():
