@@ -52,6 +52,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from fms_fsdp_tpu.models import sequence_prefill as seq
 from fms_fsdp_tpu.models.configs import KExaoneConfig
 from fms_fsdp_tpu.models.moe_held import (
     _moe_dense_held,
@@ -62,7 +63,12 @@ from fms_fsdp_tpu.models.moe_held import (
 )
 from fms_fsdp_tpu.obs.scopes import scoped
 from fms_fsdp_tpu.ops import flash_attention as _fa
-from fms_fsdp_tpu.ops.attention import chunk_attention
+from fms_fsdp_tpu.ops.attention import (
+    band_mask,
+    chunk_attention,
+    masked_attention,
+    qkv_by_head,
+)
 from fms_fsdp_tpu.ops.norms import rms_norm
 from fms_fsdp_tpu.ops.paged_attention import (
     gather_pages,
@@ -70,8 +76,7 @@ from fms_fsdp_tpu.ops.paged_attention import (
     paged_attention_kernel,
 )
 from fms_fsdp_tpu.ops.pallas_mode import interpret_default
-from fms_fsdp_tpu.ops.ring_attention import NEG_INF, merge_partial
-from fms_fsdp_tpu.ops.selective_scan import largest_divisor
+from fms_fsdp_tpu.ops.ring_attention import merge_partial
 
 __all__ = [
     "KExaoneConfig",
@@ -181,82 +186,6 @@ def _mlp(h, layer):
     return _swiglu(h, layer["w1"], layer["w3"], layer["w2"])
 
 
-def _rope(x, positions, cfg: KExaoneConfig):
-    """x (B, S, N, H) turned at ``positions`` (B, S): the two halves of a
-    head paired, angles in float32 from the positions themselves."""
-    half = cfg.head_dim // 2
-    freqs = 1.0 / (
-        cfg.rope_theta ** (jnp.arange(0, half, dtype=jnp.float32) / half)
-    )
-    ang = positions[..., None].astype(jnp.float32) * freqs
-    c, s = jnp.cos(ang)[:, :, None, :], jnp.sin(ang)[:, :, None, :]
-    x1 = x[..., :half].astype(jnp.float32)
-    x2 = x[..., half:].astype(jnp.float32)
-    return jnp.concatenate(
-        [x1 * c - x2 * s, x2 * c + x1 * s], axis=-1
-    ).astype(x.dtype)
-
-
-def _qkv(h, layer, cfg: KExaoneConfig, positions, sliding: bool):
-    """h (B, S, D) -> q (B, S, N, H), k and v (B, S, Nkv, H): projected,
-    q and k normed by head and, on a sliding layer, turned at
-    ``positions`` (B, S)."""
-    B, S, _ = h.shape
-    hd = cfg.head_dim
-    with jax.named_scope("qkv"):
-        # the products end here, as (B, S, heads * H): asked for them by
-        # head, with the norm's sum over a head's values behind, the
-        # chip's compiler lays W_q out by head first, a transposed copy of
-        # it a layer and call (100 MB at the published widths, in every
-        # decode step; deviceless v5e compile, PERF.md PR 33)
-        q, k, v = lax.optimization_barrier(
-            (h @ layer["wq"], h @ layer["wk"], h @ layer["wv"])
-        )
-        q = q.reshape(B, S, cfg.nheads, hd)
-        k = k.reshape(B, S, cfg.kvheads, hd)
-        v = v.reshape(B, S, cfg.kvheads, hd)
-    with jax.named_scope("qk_norm"):
-        q = rms_norm(q, layer["q_norm"], cfg.norm_eps)
-        k = rms_norm(k, layer["k_norm"], cfg.norm_eps)
-    if sliding:
-        with jax.named_scope("rope"):
-            q, k = _rope(q, positions, cfg), _rope(k, positions, cfg)
-    return q, k, v
-
-
-def _masked_attention(q, k, v, mask):
-    """q (B, Sq, N, H) over k, v (B, Sk, Nkv, H) where ``mask`` (B or 1,
-    Sq, Sk) -> (normalised output (B, Sq, N, H) fp32, log-sum-exp (B, Sq,
-    N, 1) fp32): a partial that ``merge_partial`` joins with others. A
-    row that sees nothing gives a log-sum-exp near ``NEG_INF`` and weighs
-    nothing in a merge."""
-    B, Sq, N, H = q.shape
-    nkv = k.shape[2]
-    g = N // nkv
-    s = jnp.einsum(
-        "bqkgh,bskh->bkgqs", q.reshape(B, Sq, nkv, g, H), k,
-        preferred_element_type=jnp.float32,
-    ) * (H**-0.5)
-    s = jnp.where(mask[:, None, None], s, NEG_INF)
-    m = jnp.max(s, axis=-1, keepdims=True)
-    p = jnp.exp(s - m)
-    l = jnp.sum(p, axis=-1, keepdims=True)
-    o = jnp.einsum("bkgqs,bskh->bqkgh", p.astype(v.dtype), v)
-    o = o.astype(jnp.float32).reshape(B, Sq, N, H) / jnp.moveaxis(
-        l, 3, 1
-    ).reshape(B, Sq, N, 1)
-    lse = jnp.moveaxis(m + jnp.log(l), 3, 1).reshape(B, Sq, N, 1)
-    return o, lse
-
-
-def _band(q_pos, k_pos, window):
-    """(..., Sq, Sk) bool: key at ``k_pos`` is seen from ``q_pos``: not
-    after it and, with a ``window``, fewer than that many behind it."""
-    back = q_pos[..., :, None] - k_pos[..., None, :]
-    seen = back >= 0
-    return seen & (back < window) if window else seen
-
-
 # ---------------------------------------------------------------------------
 # forward (whole sequences, no cache): the parity form
 # ---------------------------------------------------------------------------
@@ -276,9 +205,9 @@ def kexaone_forward(
     for kind, at, sliding, sparse in layer_places(cfg):
         layer = _layer_at(params[kind], at)
         h = _norm(x, layer["attn_norm"], cfg)
-        q, k, v = _qkv(h, layer, cfg, positions, sliding)
-        mask = _band(pos, pos, cfg.sliding_window if sliding else 0)[None]
-        o, _ = _masked_attention(q, k, v, mask)
+        q, k, v = qkv_by_head(h, layer, cfg, positions, sliding)
+        mask = band_mask(pos, pos, cfg.sliding_window if sliding else 0)[None]
+        o, _ = masked_attention(q, k, v, mask)
         x = x + o.astype(x.dtype).reshape(B, S, -1) @ layer["wo"]
         h2 = _norm(x, layer["ffn_norm"], cfg)
         if sparse:
@@ -296,21 +225,18 @@ def kexaone_forward(
 def prefill_chunk(p_pad: int) -> int:
     """The chunk of a prompt padded to ``p_pad``: the largest divisor of
     ``p_pad`` up to ``PREFILL_CHUNK``, so that chunks tile the bucket."""
-    return largest_divisor(p_pad, PREFILL_CHUNK)
+    return seq.chunk_of(p_pad, PREFILL_CHUNK)
 
 
 def prefill_positions(p: int, p_pad: int) -> int:
     """Positions ``kexaone_prefill`` computes for a prompt of ``p`` tokens
     padded to ``p_pad``: whole chunks up to the prompt's end."""
-    c = prefill_chunk(p_pad)
-    return -(-p // c) * c
+    return seq.positions_computed(p, prefill_chunk(p_pad))
 
 
 def _use_flash(cfg: KExaoneConfig, attn_impl: str, c: int) -> bool:
-    return c % 256 == 0 and cfg.head_dim % 128 == 0 and (
-        attn_impl == "pallas"
-        or (attn_impl == "auto" and jax.default_backend() == "tpu")
-    )
+    fits = c % 256 == 0 and cfg.head_dim % 128 == 0
+    return fits and seq.kernel_wanted(attn_impl)
 
 
 def prefill_attn_form(cfg: KExaoneConfig, attn_impl: str, p_pad: int) -> str:
@@ -339,8 +265,8 @@ def _window_chunk_attention(q, k, v, tail_k, tail_v, start, window, flash):
     t_pos = start - window + jnp.arange(window, dtype=jnp.int32)
     if not flash:
         k_pos = jnp.concatenate([t_pos, q_pos])
-        mask = _band(q_pos, k_pos, window) & (k_pos >= 0)[None, :]
-        o, _ = _masked_attention(
+        mask = band_mask(q_pos, k_pos, window) & (k_pos >= 0)[None, :]
+        o, _ = masked_attention(
             q,
             jnp.concatenate([tail_k, k], axis=1),
             jnp.concatenate([tail_v, v], axis=1),
@@ -352,27 +278,12 @@ def _window_chunk_attention(q, k, v, tail_k, tail_v, start, window, flash):
         interpret=interpret_default(),
     )
     rows = min(window, c)  # the queries that can see into the tail
-    mask = _band(q_pos[:rows], t_pos, window) & (t_pos >= 0)[None, :]
-    o_t, lse_t = _masked_attention(q[:, :rows], tail_k, tail_v, mask[None])
+    mask = band_mask(q_pos[:rows], t_pos, window) & (t_pos >= 0)[None, :]
+    o_t, lse_t = masked_attention(q[:, :rows], tail_k, tail_v, mask[None])
     head, _ = merge_partial(
         (o[:, :rows].astype(jnp.float32), lse[:, :rows]), o_t, lse_t
     )
     return jnp.concatenate([head.astype(q.dtype), o[:, rows:]], axis=1)
-
-
-@scoped("win_write")
-def _next_tail(tail, new, ahead, window):
-    """The ``window`` positions that end where each row's prompt has got
-    to after this chunk: of tail (B, window, ...) then new (B, c, ...),
-    the ``window`` rows that end at ``min(ahead, c)`` of the chunk
-    (``ahead`` (B,): what each row had left at the chunk's start). A row
-    that goes on takes the chunk's last ``window``; a row that ends here
-    the last ``window`` of its prompt; a row that ended keeps its own."""
-    ext = jnp.concatenate([tail, new], axis=1)
-    at = jnp.clip(ahead, 0, new.shape[1])
-    return jax.vmap(
-        lambda e, a: lax.dynamic_slice_in_dim(e, a, window, axis=0)
-    )(ext, at)
 
 
 @scoped("win_write")
@@ -436,15 +347,10 @@ def kexaone_prefill(
         for kind in cfg.stacks if kind.endswith("_sparse")
     }
 
-    def chunk(j, carry):
+    def body(chunk, carry):
         full, tails, last, pairs, slabs, tiles = carry
         full, tails = list(full), list(tails)
-        start = j * c
-        ahead = lengths - start  # of each row, from this chunk's start on
-        live = jnp.arange(c, dtype=jnp.int32)[None, :] < ahead[:, None]
-        positions = jnp.broadcast_to(
-            start + jnp.arange(c, dtype=jnp.int32), (B, c)
-        )
+        start, ahead, positions = chunk.start, chunk.ahead, chunk.positions
         with jax.named_scope("embed"):
             toks = lax.dynamic_slice_in_dim(tokens, start, c, axis=1)
             x = params["embedding"][toks]
@@ -456,28 +362,21 @@ def kexaone_prefill(
                     params[kind], at, EXPERT_LEAVES if routed else ()
                 )
                 h = _norm(x, layer["attn_norm"], cfg)
-                q, k, v = _qkv(h, layer, cfg, positions, sliding)
+                q, k, v = qkv_by_head(h, layer, cfg, positions, sliding)
                 if sliding:
                     tk, tv = tails[wi]
                     o = _window_chunk_attention(
                         q, k, v, tk, tv, start, W, flash
                     )
                     tails[wi] = (
-                        _next_tail(tk, k, ahead, W),
-                        _next_tail(tv, v, ahead, W),
+                        seq.next_tail(tk, k, ahead, W),
+                        seq.next_tail(tv, v, ahead, W),
                     )
                     wi += 1
                 else:
-                    kb, vb = full[fi]
                     with jax.named_scope("kv_write"):
-                        keep = live[:, :, None, None]
-                        kb = lax.dynamic_update_slice(
-                            kb, jnp.where(keep, k, jnp.zeros_like(k)),
-                            (0, start, 0, 0),
-                        )
-                        vb = lax.dynamic_update_slice(
-                            vb, jnp.where(keep, v, jnp.zeros_like(v)),
-                            (0, start, 0, 0),
+                        kb, vb = seq.write_live(
+                            full[fi], (k, v), chunk.live, start
                         )
                     with jax.named_scope("attn_full"):
                         o = chunk_attention(
@@ -502,22 +401,14 @@ def kexaone_prefill(
                     y = _moe_dense_held(h2, layer, cfg)
                 with jax.named_scope("moe_combine"):
                     x = x + y + _shared(h2, layer)
-        # the head reads a row's last real position alone
-        pos = ahead - 1
-        row = jnp.take_along_axis(
-            x, jnp.clip(pos, 0, c - 1)[:, None, None], axis=1
-        )[:, 0]
-        last = jnp.where(((pos >= 0) & (pos < c))[:, None], row, last)
-        return tuple(full), tuple(tails), last, pairs, slabs, tiles
+        return x, (tuple(full), tuple(tails), last, pairs, slabs, tiles)
 
     def zeros(shape):
         return jnp.zeros(shape, compute_dtype)
 
-    full, tails, last, pairs, slabs, tiles = lax.fori_loop(
-        0,
-        (jnp.max(lengths) + c - 1) // c,
-        chunk,
-        (
+    full, tails, last, pairs, slabs, tiles = seq.chunk_loop(
+        lengths, c, body,
+        lambda: (
             tuple((zeros(kv_shape), zeros(kv_shape)) for _ in range(n_full)),
             tuple(
                 (zeros(tail_shape), zeros(tail_shape)) for _ in range(n_win)
@@ -525,19 +416,21 @@ def kexaone_prefill(
             zeros((B, cfg.emb_dim)),
             *(jnp.zeros((), jnp.int32),) * 3,
         ),
+        last=2,
     )
     with jax.named_scope("lm_head"):
         logits = _norm(last, params["norm"], cfg) @ params["lm_head"]
-
-    def stack(parts, shape):
-        return jnp.stack(parts) if parts else zeros((0,) + shape)
-
     kv = {
-        name: stack([p[i] for p in full], kv_shape)
+        name: seq.stack_or_empty(
+            [p[i] for p in full], kv_shape, compute_dtype
+        )
         for i, name in enumerate(("k", "v"))
     }
     ring = {
-        name: stack([_as_ring(p[i], lengths, W) for p in tails], tail_shape)
+        name: seq.stack_or_empty(
+            [_as_ring(p[i], lengths, W) for p in tails], tail_shape,
+            compute_dtype,
+        )
         for i, name in enumerate(("k", "v"))
     }
     return logits, kv, ring, pairs, slabs, tiles
@@ -556,7 +449,7 @@ def _ring_attend(q, ring_k, ring_v, seq_lens):
     entry once the ring has wrapped). Plain jax. Returns (B, N * H)."""
     W = ring_k.shape[1]
     mask = jnp.arange(W, dtype=jnp.int32)[None, :] <= seq_lens[:, None]
-    o, _ = _masked_attention(q[:, None], ring_k, ring_v, mask[:, None, :])
+    o, _ = masked_attention(q[:, None], ring_k, ring_v, mask[:, None, :])
     return o.astype(q.dtype).reshape(q.shape[0], -1)
 
 
@@ -630,7 +523,7 @@ def kexaone_paged_decode_step(
         for kind, at, sliding, sparse in layer_places(cfg):
             layer = _layer_at(params[kind], at)
             h = _norm(x, layer["attn_norm"], cfg)
-            q, k, v = _qkv(h, layer, cfg, positions, sliding)
+            q, k, v = qkv_by_head(h, layer, cfg, positions, sliding)
             if sliding:
                 with jax.named_scope("win_write"):
                     ring_k = ring_k.at[wi, rows, ring_at].set(k[:, 0])

@@ -21,8 +21,8 @@ slot keeps, ``(K - 1, D)`` a convolution layer whatever the context.
 ``head_dim = emb_dim / nheads``), ``k = W_k u``, ``v = W_v u``
 (``kvheads`` heads); RMSNorm with a learned weight over each head's values
 of ``q`` and of ``k``, then rotary embedding over the whole head (the two
-halves paired, ``rope_theta``): models/kexaone.py's ``_qkv``, whose window
-layers are built the same way; causal softmax of ``q_t . k_u /
+halves paired, ``rope_theta``): ops/attention.py's ``qkv_by_head``, which
+kexaone's window layers run too; causal softmax of ``q_t . k_u /
 sqrt(head_dim)`` in float32; ``W_o``. Keys and values live in pages
 (``serve/kv_cache.py::PagedKVCache`` over the attention layers alone)
 as rows of 128 lanes, a position's ``kvheads * head_dim`` values side by
@@ -54,13 +54,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from fms_fsdp_tpu.models import sequence_prefill as seq
 from fms_fsdp_tpu.models.configs import Lfm2MoeConfig
-from fms_fsdp_tpu.models.kexaone import (
-    _band,
-    _masked_attention,
-    _next_tail,
-    _qkv,
-)
 from fms_fsdp_tpu.models.moe_held import (
     _moe_grouped,
     _moe_token,
@@ -69,7 +64,12 @@ from fms_fsdp_tpu.models.moe_held import (
 )
 from fms_fsdp_tpu.obs.scopes import scoped
 from fms_fsdp_tpu.ops import flash_attention as _fa
-from fms_fsdp_tpu.ops.attention import chunk_attention
+from fms_fsdp_tpu.ops.attention import (
+    band_mask,
+    chunk_attention,
+    masked_attention,
+    qkv_by_head,
+)
 from fms_fsdp_tpu.ops.norms import rms_norm
 from fms_fsdp_tpu.ops.paged_attention import (
     gqa_attend,
@@ -77,7 +77,6 @@ from fms_fsdp_tpu.ops.paged_attention import (
     packed_row_width,
     tile_rows,
 )
-from fms_fsdp_tpu.ops.selective_scan import largest_divisor
 
 __all__ = [
     "Lfm2MoeConfig",
@@ -227,8 +226,8 @@ def lfm2_forward(
             tail = jnp.zeros((B, cfg.conv_kernel - 1, cfg.emb_dim), z.dtype)
             x = x + _conv_out(_short_conv(tail, z, layer["conv_w"]), gate, layer)
         else:
-            q, k, v = _qkv(h, layer, cfg, positions, True)
-            o, _ = _masked_attention(q, k, v, _band(pos, pos, 0)[None])
+            q, k, v = qkv_by_head(h, layer, cfg, positions, True)
+            o, _ = masked_attention(q, k, v, band_mask(pos, pos, 0)[None])
             x = x + o.astype(x.dtype).reshape(B, S, -1) @ layer["wo"]
         h2 = _norm(x, layer["ffn_norm"], cfg)
         x = x + _ffn_forward(h2, layer, cfg, cfg.sparse(i))
@@ -243,24 +242,20 @@ def lfm2_forward(
 def prefill_chunk(p_pad: int) -> int:
     """The chunk of a prompt padded to ``p_pad``: the largest divisor of
     ``p_pad`` up to ``PREFILL_CHUNK``, so that chunks tile the program."""
-    return largest_divisor(p_pad, PREFILL_CHUNK)
+    return seq.chunk_of(p_pad, PREFILL_CHUNK)
 
 
 def prefill_positions(p: int, p_pad: int) -> int:
     """Positions ``lfm2_prefill`` computes for a prompt of ``p`` tokens
     in a program of ``p_pad``: whole chunks up to the prompt's end."""
-    c = prefill_chunk(p_pad)
-    return -(-p // c) * c
+    return seq.positions_computed(p, prefill_chunk(p_pad))
 
 
 def _use_flash(cfg: Lfm2MoeConfig, attn_impl: str, c: int) -> bool:
     return _fa.supports(
         (1, c, cfg.nheads, cfg.head_dim), (1, c, cfg.kvheads, cfg.head_dim),
         forward_only=True,
-    ) and (
-        attn_impl == "pallas"
-        or (attn_impl == "auto" and jax.default_backend() == "tpu")
-    )
+    ) and seq.kernel_wanted(attn_impl)
 
 
 def prefill_attn_form(cfg: Lfm2MoeConfig, attn_impl: str, p_pad: int) -> str:
@@ -316,15 +311,10 @@ def lfm2_prefill(
     kv_shape = (B, kv_len, cfg.kvheads, cfg.head_dim)
     tail_shape = (B, W, cfg.emb_dim)
 
-    def chunk(j, carry):
+    def body(chunk, carry):
         kvs, tails, last, pairs, slabs, tiles = carry
         kvs, tails = list(kvs), list(tails)
-        start = j * c
-        ahead = lengths - start  # of each row, from this chunk's start on
-        live = jnp.arange(c, dtype=jnp.int32)[None, :] < ahead[:, None]
-        positions = jnp.broadcast_to(
-            start + jnp.arange(c, dtype=jnp.int32), (B, c)
-        )
+        start, ahead, positions = chunk.start, chunk.ahead, chunk.positions
         with jax.named_scope("embed"):
             toks = lax.dynamic_slice_in_dim(tokens, start, c, axis=1)
             x = params["embedding"][toks]
@@ -337,21 +327,14 @@ def lfm2_prefill(
                     z, gate = _conv_in(h, layer)
                     conv = _short_conv(tails[ci], z, layer["conv_w"])
                     with jax.named_scope("short_conv"):
-                        tails[ci] = _next_tail(tails[ci], z, ahead, W)
+                        tails[ci] = seq.next_tail(tails[ci], z, ahead, W)
                     x = x + _conv_out(conv, gate, layer)
                     ci += 1
                 else:
-                    q, k, v = _qkv(h, layer, cfg, positions, True)
-                    kb, vb = kvs[ai]
+                    q, k, v = qkv_by_head(h, layer, cfg, positions, True)
                     with jax.named_scope("kv_write"):
-                        keep = live[:, :, None, None]
-                        kb = lax.dynamic_update_slice(
-                            kb, jnp.where(keep, k, jnp.zeros_like(k)),
-                            (0, start, 0, 0),
-                        )
-                        vb = lax.dynamic_update_slice(
-                            vb, jnp.where(keep, v, jnp.zeros_like(v)),
-                            (0, start, 0, 0),
+                        kb, vb = seq.write_live(
+                            kvs[ai], (k, v), chunk.live, start
                         )
                     with jax.named_scope("attn_full"):
                         o = chunk_attention(
@@ -372,42 +355,30 @@ def lfm2_prefill(
                 pairs, slabs, tiles = pairs + n, slabs + trips, tiles + met
                 with jax.named_scope("moe_combine"):
                     x = x + y.reshape(B, c, -1)
-        # the head reads a row's last real position alone
-        pos = ahead - 1
-        row = jnp.take_along_axis(
-            x, jnp.clip(pos, 0, c - 1)[:, None, None], axis=1
-        )[:, 0]
-        last = jnp.where(((pos >= 0) & (pos < c))[:, None], row, last)
-        return tuple(kvs), tuple(tails), last, pairs, slabs, tiles
+        return x, (tuple(kvs), tuple(tails), last, pairs, slabs, tiles)
 
     def zeros(shape):
         return jnp.zeros(shape, compute_dtype)
 
-    kvs, tails, last, pairs, slabs, tiles = lax.fori_loop(
-        0,
-        (jnp.max(lengths) + c - 1) // c,
-        chunk,
-        (
+    kvs, tails, last, pairs, slabs, tiles = seq.chunk_loop(
+        lengths, c, body,
+        lambda: (
             tuple((zeros(kv_shape), zeros(kv_shape)) for _ in range(n_attn)),
             tuple(zeros(tail_shape) for _ in range(n_conv)),
             zeros((B, cfg.emb_dim)),
             *(jnp.zeros((), jnp.int32),) * 3,
         ),
+        last=2,
     )
     logits = _head(last, params, cfg)
-
-    def stack(parts, shape):
-        return jnp.stack(parts) if parts else zeros((0,) + shape)
-
     kv = {
-        name: stack([p[i] for p in kvs], kv_shape).reshape(
-            n_attn, B, -1, packed_row_width(cfg.kvheads, cfg.head_dim)
-        )
+        name: seq.stack_or_empty(
+            [p[i] for p in kvs], kv_shape, compute_dtype
+        ).reshape(n_attn, B, -1, packed_row_width(cfg.kvheads, cfg.head_dim))
         for i, name in enumerate(("k", "v"))
     }
-    return (
-        logits, kv, {"z": stack(list(tails), tail_shape)}, pairs, slabs, tiles
-    )
+    z = seq.stack_or_empty(list(tails), tail_shape, compute_dtype)
+    return logits, kv, {"z": z}, pairs, slabs, tiles
 
 
 # ---------------------------------------------------------------------------
@@ -512,7 +483,7 @@ def lfm2_paged_decode_step(
                 x = x + _conv_out(conv, gate, layer)
                 ci += 1
             else:
-                q, k, v = _qkv(h, layer, cfg, positions, True)
+                q, k, v = qkv_by_head(h, layer, cfg, positions, True)
                 with jax.named_scope("kv_write"):
                     pools["k"] = pools["k"].at[ai, page_ids, slots].set(
                         k.reshape(B, tr, -1)

@@ -49,6 +49,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
+from fms_fsdp_tpu.models import sequence_prefill as seq
 from fms_fsdp_tpu.models.configs import MambaConfig
 from fms_fsdp_tpu.obs.scopes import scoped
 from fms_fsdp_tpu.ops.attention import attention, chunk_attention
@@ -57,7 +58,6 @@ from fms_fsdp_tpu.ops.quant import matmul as qmatmul
 from fms_fsdp_tpu.ops.rope import apply_rotary, rope_table
 from fms_fsdp_tpu.ops.selective_scan import (
     freeze_past,
-    largest_divisor,
     selective_scan,
     selective_scan_reference,
     selective_scan_step,
@@ -735,7 +735,7 @@ PREFILL_CHUNK = 512
 def prefill_chunk(p_pad: int) -> int:
     """The chunk of a prompt padded to ``p_pad``: the largest divisor of
     ``p_pad`` up to ``PREFILL_CHUNK``, so that chunks tile the bucket."""
-    return largest_divisor(p_pad, PREFILL_CHUNK)
+    return seq.chunk_of(p_pad, PREFILL_CHUNK)
 
 
 def prefill_positions(cfg: MambaConfig, p: int, p_pad: int) -> int:
@@ -744,8 +744,7 @@ def prefill_positions(cfg: MambaConfig, p: int, p_pad: int) -> int:
     a Mamba-1 stack, the whole bucket for a Mamba-2 one."""
     if not cfg.mamba1:
         return p_pad
-    c = prefill_chunk(p_pad)
-    return -(-p // c) * c
+    return seq.positions_computed(p, prefill_chunk(p_pad))
 
 
 def _prefill_sequence(
@@ -773,11 +772,9 @@ def _prefill_sequence(
     n_attn = len(cfg.attn_layer_idx)
     kv_shape = (B, kv_len, a.num_heads_kv, a.head_dim)
 
-    def chunk(j, carry):
+    def body(chunk, carry):
         states, ks, vs, last = carry
-        start = j * c
-        ahead = lengths - start  # of each row, from this chunk's start on
-        live = jnp.arange(c, dtype=jnp.int32)[None, :] < ahead[:, None]
+        start, ahead = chunk.start, chunk.ahead
         with jax.named_scope("embed"):
             toks = lax.dynamic_slice_in_dim(tokens, start, c, axis=1)
             residual = params["embedding"][toks].astype(jnp.float32)
@@ -786,7 +783,7 @@ def _prefill_sequence(
             h = _block_norm(residual, layer["norm"], cfg, compute_dtype)
             if i in cfg.attn_layer_idx:
                 out, ks[attn_j], vs[attn_j] = _attn_prefill(
-                    h, layer["mixer"], a, cos, sin, live,
+                    h, layer["mixer"], a, cos, sin, chunk.live,
                     ks[attn_j], vs[attn_j], start, attn_impl,
                 )
                 attn_j += 1
@@ -799,24 +796,17 @@ def _prefill_sequence(
             if "mlp" in layer:
                 h2 = _block_norm(residual, layer["norm2"], cfg, compute_dtype)
                 residual = _add(residual, _mlp(h2, layer["mlp"], None))
-        # the head reads a row's last real position alone
-        at = ahead - 1
-        row = jnp.take_along_axis(
-            residual, jnp.clip(at, 0, c - 1)[:, None, None], axis=1
-        )[:, 0]
-        last = jnp.where(((at >= 0) & (at < c))[:, None], row, last)
-        return states, ks, vs, last
+        return residual, (states, ks, vs, last)
 
-    states, ks, vs, last = lax.fori_loop(
-        0,
-        (jnp.max(lengths) + c - 1) // c,
-        chunk,
-        (
+    states, ks, vs, last = seq.chunk_loop(
+        lengths, c, body,
+        lambda: (
             init_mamba_decode_state(cfg, B, compute_dtype),
             [jnp.zeros(kv_shape, compute_dtype)] * n_attn,
             [jnp.zeros(kv_shape, compute_dtype)] * n_attn,
             jnp.zeros((B, cfg.d_model), jnp.float32),
         ),
+        last=3,
     )
     x = _block_norm(last, params["norm_f"], cfg, compute_dtype)
     kv = {"k": jnp.stack(ks), "v": jnp.stack(vs)} if n_attn else None

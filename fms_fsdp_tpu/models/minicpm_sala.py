@@ -55,6 +55,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from fms_fsdp_tpu.models import sequence_prefill as seq
 from fms_fsdp_tpu.models.configs import SalaConfig
 from fms_fsdp_tpu.obs.scopes import scoped
 from fms_fsdp_tpu.ops.attention import chunk_attention
@@ -75,7 +76,7 @@ from fms_fsdp_tpu.ops.paged_attention import (
     gathered_blocks_attention,
 )
 from fms_fsdp_tpu.ops.ring_attention import merge_partial
-from fms_fsdp_tpu.ops.selective_scan import largest_divisor
+from fms_fsdp_tpu.ops.rope import rotate_halves
 
 __all__ = [
     "SalaConfig",
@@ -186,20 +187,6 @@ def _head(x, params, cfg):
     return logits / cfg.logit_divisor
 
 
-def _rope(x, positions, theta):
-    """x (B, S, N, H) turned at ``positions`` (B, S): the two halves of a
-    head paired, angles in float32 from the positions themselves."""
-    half = x.shape[-1] // 2
-    freqs = 1.0 / (theta ** (jnp.arange(0, half, dtype=jnp.float32) / half))
-    ang = positions[..., None].astype(jnp.float32) * freqs
-    c, s = jnp.cos(ang)[:, :, None, :], jnp.sin(ang)[:, :, None, :]
-    x1 = x[..., :half].astype(jnp.float32)
-    x2 = x[..., half:].astype(jnp.float32)
-    return jnp.concatenate(
-        [x1 * c - x2 * s, x2 * c + x1 * s], axis=-1
-    ).astype(x.dtype)
-
-
 def _qkv(u, layer, cfg: SalaConfig, kind: str, positions):
     """u (B, S, D) -> q, k, v by head: projected, q and k normed by head
     and, on a lightning layer, turned at ``positions`` (B, S)."""
@@ -211,7 +198,7 @@ def _qkv(u, layer, cfg: SalaConfig, kind: str, positions):
         hd = cfg.lightning_head_dim
     with jax.named_scope("qkv"):
         # the products end here, before the reshape by head
-        # (models/kexaone.py::_qkv says what the compiler does otherwise)
+        # (ops/attention.py::qkv_by_head says what the compiler does otherwise)
         q, k, v = lax.optimization_barrier(
             (u @ layer["wq"], u @ layer["wk"], u @ layer["wv"])
         )
@@ -223,8 +210,8 @@ def _qkv(u, layer, cfg: SalaConfig, kind: str, positions):
         k = rms_norm(k, layer["k_norm"], cfg.norm_eps)
     if kind == "lightning":
         with jax.named_scope("rope"):
-            q = _rope(q, positions, cfg.rope_theta)
-            k = _rope(k, positions, cfg.rope_theta)
+            q = rotate_halves(q, positions, cfg.rope_theta)
+            k = rotate_halves(k, positions, cfg.rope_theta)
     return q, k, v
 
 
@@ -331,16 +318,13 @@ def sala_forward(
 def prefill_chunk(p_pad: int, cfg: SalaConfig) -> int:
     """The chunk of a prompt padded to ``p_pad`` (whole blocks): the most
     blocks up to ``PREFILL_CHUNK`` positions that tile it."""
-    bs = cfg.sparse.block_size
-    assert p_pad % bs == 0, (p_pad, bs)
-    return bs * largest_divisor(p_pad // bs, max(1, PREFILL_CHUNK // bs))
+    return seq.chunk_of(p_pad, PREFILL_CHUNK, unit=cfg.sparse.block_size)
 
 
 def prefill_positions(p: int, p_pad: int, cfg: SalaConfig) -> int:
     """Positions ``sala_prefill`` computes for a prompt of ``p`` tokens
     padded to ``p_pad``: whole chunks up to the prompt's end."""
-    c = prefill_chunk(p_pad, cfg)
-    return -(-p // c) * c
+    return seq.positions_computed(p, prefill_chunk(p_pad, cfg))
 
 
 def prefill_choices(p: int, cfg: SalaConfig):
@@ -369,10 +353,8 @@ def prefill_multiplied(p: int, p_pad: int, cfg: SalaConfig) -> int:
 
 
 def _use_flash(cfg: SalaConfig, attn_impl: str, c: int) -> bool:
-    return c % 256 == 0 and cfg.head_dim % 128 == 0 and (
-        attn_impl == "pallas"
-        or (attn_impl == "auto" and jax.default_backend() == "tpu")
-    )
+    fits = c % 256 == 0 and cfg.head_dim % 128 == 0
+    return fits and seq.kernel_wanted(attn_impl)
 
 
 def chunk_forms(p_pad: int, cfg: SalaConfig):
@@ -439,7 +421,7 @@ def _select_chunk(q, kc, positions, sp, lists: bool):
     (a ``"chosen"`` chunk) each query's free blocks and their count
     (``free_list``: (B, Nkv, c, W) and (B, Nkv, c) int32)."""
     B, c = q.shape[:2]
-    tile = largest_divisor(c, SELECT_TILE)
+    tile = seq.largest_divisor(c, SELECT_TILE)
 
     def choose(q, t):
         if lists:
@@ -509,7 +491,7 @@ def _band_attention(q, kb, vb, start, sp):
     -> the partial (B, c, N, H), (B, c, N, 1) float32."""
     B, c, nkv, g, H = q.shape
     bs = sp.block_size
-    tile = bs * largest_divisor(c // bs, max(1, BAND_TILE // bs))
+    tile = bs * seq.largest_divisor(c // bs, max(1, BAND_TILE // bs))
     first, back = sp.init_blocks * bs, (sp.window_blocks - 1) * bs
     # a band key's position and a query's, both from the tile's start
     k_pos = jnp.arange(-back, tile, dtype=jnp.int32)
@@ -571,7 +553,7 @@ def multiplied_blocks(exist, start, c: int, chosen, sp):
     else every block up to the chunk's end, which the masked walk
     multiplies."""
     bs = sp.block_size
-    tile = largest_divisor(c // bs, max(1, BAND_TILE // bs))
+    tile = seq.largest_divisor(c // bs, max(1, BAND_TILE // bs))
     forced = sp.init_blocks + sp.window_blocks
     listed = jnp.clip(exist - forced, 0, sp.topk - forced)
     return jnp.where(chosen, forced - 1 + tile + listed, (start + c) // bs)
@@ -645,15 +627,11 @@ def sala_prefill(
             jnp.sum(start >= edges), [branches[f] for f in present], x
         )
 
-    def chunk(j, carry):
+    def body(chunk, carry):
         pages, states, last, counts = carry
         pages, states = list(pages), list(states)
-        start = j * c
-        ahead = lengths - start  # of each row, from this chunk's start on
-        live = jnp.arange(c, dtype=jnp.int32)[None, :] < ahead[:, None]
-        positions = jnp.broadcast_to(
-            start + jnp.arange(c, dtype=jnp.int32), (B, c)
-        )
+        start, ahead, live = chunk.start, chunk.ahead, chunk.live
+        positions = chunk.positions
         toks = lax.dynamic_slice_in_dim(tokens, start, c, axis=1)
         x = _embed(params, toks, cfg)
         si = li = 0
@@ -726,22 +704,14 @@ def sala_prefill(
                     ]).astype(jnp.int32)
                 si += 1
                 x = _mlp(_sparse_out(x, o, u, layer, cfg), layer, cfg)
-        # the head reads a row's last real position alone
-        pos = ahead - 1
-        row = jnp.take_along_axis(
-            x, jnp.clip(pos, 0, c - 1)[:, None, None], axis=1
-        )[:, 0]
-        last = jnp.where(((pos >= 0) & (pos < c))[:, None], row, last)
-        return tuple(pages), tuple(states), last, counts
+        return x, (tuple(pages), tuple(states), last, counts)
 
     def zeros(shape, dtype=compute_dtype):
         return jnp.zeros(shape, dtype)
 
-    pages, states, last, counts = lax.fori_loop(
-        0,
-        (jnp.max(lengths) + c - 1) // c,
-        chunk,
-        (
+    pages, states, last, counts = seq.chunk_loop(
+        lengths, c, body,
+        lambda: (
             tuple(
                 (zeros(kv_shape), zeros(kv_shape), zeros(kc_shape))
                 for _ in range(n_sparse)
@@ -750,6 +720,7 @@ def sala_prefill(
             zeros((B, cfg.emb_dim)),
             jnp.zeros((4,), jnp.int32),
         ),
+        last=2,
     )
     logits = _head(last, params, cfg)
 
@@ -767,9 +738,7 @@ def sala_prefill(
             (B, rows, hd),
         ),
     }
-    state = {
-        "S": jnp.stack(states) if states else zeros((0,) + s_shape, jnp.float32)
-    }
+    state = {"S": seq.stack_or_empty(list(states), s_shape, jnp.float32)}
     return logits, kv, state, counts
 
 

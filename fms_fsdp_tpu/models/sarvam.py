@@ -57,6 +57,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from fms_fsdp_tpu.models import sequence_prefill as seq
 from fms_fsdp_tpu.models.configs import SarvamConfig
 from fms_fsdp_tpu.models.moe_held import (
     _moe_dense_held,
@@ -76,7 +77,6 @@ from fms_fsdp_tpu.ops.rope import (
     yarn_mscale,
     yarn_rope_table,
 )
-from fms_fsdp_tpu.ops.selective_scan import largest_divisor
 
 __all__ = [
     "SarvamConfig",
@@ -288,10 +288,7 @@ def _attn_partial(q, k, v, causal: bool, scale: float, flash: bool):
 
 
 def _use_flash(attn_impl: str, c: int) -> bool:
-    return c % 256 == 0 and (
-        attn_impl == "pallas"
-        or (attn_impl == "auto" and jax.default_backend() == "tpu")
-    )
+    return c % 256 == 0 and seq.kernel_wanted(attn_impl)
 
 
 def prefill_attn_form(cfg: SarvamConfig, attn_impl: str, p_pad: int) -> str:
@@ -366,14 +363,13 @@ def sarvam_forward(
 def prefill_chunk(p_pad: int) -> int:
     """The chunk of a prompt padded to ``p_pad``: the largest divisor of
     ``p_pad`` up to ``PREFILL_CHUNK``, so that chunks tile the bucket."""
-    return largest_divisor(p_pad, PREFILL_CHUNK)
+    return seq.chunk_of(p_pad, PREFILL_CHUNK)
 
 
 def prefill_positions(p: int, p_pad: int) -> int:
     """Positions ``sarvam_prefill`` computes for a prompt of ``p`` tokens
     padded to ``p_pad``: whole chunks up to the prompt's end."""
-    c = prefill_chunk(p_pad)
-    return -(-p // c) * c
+    return seq.positions_computed(p, prefill_chunk(p_pad))
 
 
 def _chunk_attention(q, lat, l, layer, cfg, start, c: int, flash: bool):
@@ -450,14 +446,9 @@ def sarvam_prefill(
         if name not in experts
     }
 
-    def chunk(j, carry):
+    def body(chunk, carry):
         lat, last, pairs, slabs, tiles = carry
-        start = j * c
-        ahead = lengths - start  # of each row, from this chunk's start on
-        live = jnp.arange(c, dtype=jnp.int32)[None, :] < ahead[:, None]
-        positions = jnp.broadcast_to(
-            start + jnp.arange(c, dtype=jnp.int32), (B, c)
-        )
+        start, positions = chunk.start, chunk.positions
         with jax.named_scope("embed"):
             toks = lax.dynamic_slice_in_dim(tokens, start, c, axis=1)
             x = params["embedding"][toks]
@@ -469,10 +460,7 @@ def sarvam_prefill(
             )
             new = _mla_latent(h, layer, cfg, cos, sin, positions)
             with jax.named_scope("latent_write"):
-                new = jnp.where(live[:, :, None], new, jnp.zeros_like(new))
-                lat = lax.dynamic_update_slice(
-                    lat, _to_pool_width(new, cfg)[None], (l, 0, start, 0)
-                )
+                lat = seq.write_live(lat, new, chunk.live, start, layer=l)
             o = _chunk_attention(q, lat, l, layer, cfg, start, c, flash)
             with jax.named_scope("attn_out"):
                 x = x + o.reshape(B, c, -1) @ layer["wo"]
@@ -483,7 +471,7 @@ def sarvam_prefill(
             x, lat, h2 = attend(x, lat, layer, i)
             x = x + _mlp(h2, layer)
 
-        def body(carry, inp):
+        def moe_layer(carry, inp):
             x, lat, pairs, slabs, tiles = carry
             layer, i = inp
             x, lat, h2 = attend(x, lat, layer, Ld + i)
@@ -503,27 +491,20 @@ def sarvam_prefill(
 
         with jax.named_scope("layers"):
             (x, lat, pairs, slabs, tiles), _ = lax.scan(
-                body,
+                moe_layer,
                 (x, lat, pairs, slabs, tiles),
                 (rest, jnp.arange(cfg.n_moe_layers)),
             )
-        # the head reads a row's last real position alone
-        at = ahead - 1
-        row = jnp.take_along_axis(
-            x, jnp.clip(at, 0, c - 1)[:, None, None], axis=1
-        )[:, 0]
-        last = jnp.where(((at >= 0) & (at < c))[:, None], row, last)
-        return lat, last, pairs, slabs, tiles
+        return x, (lat, last, pairs, slabs, tiles)
 
-    lat, last, pairs, slabs, tiles = lax.fori_loop(
-        0,
-        (jnp.max(lengths) + c - 1) // c,
-        chunk,
-        (
+    lat, last, pairs, slabs, tiles = seq.chunk_loop(
+        lengths, c, body,
+        lambda: (
             jnp.zeros((cfg.nlayers, B, kv_len, pool_width(cfg)), compute_dtype),
             jnp.zeros((B, cfg.emb_dim), compute_dtype),
             *(jnp.zeros((), jnp.int32),) * 3,
         ),
+        last=1,
     )
     with jax.named_scope("lm_head"):
         logits = _norm(last, params["norm"], cfg) @ params["lm_head"]
@@ -539,7 +520,9 @@ def decode_block_pages(max_pages: int, page_size: int) -> int:
     """Pages one trip of the decode attention's loop gathers: the
     largest divisor of a stream's ``max_pages`` that holds at most
     ``DECODE_BLOCK_TOKENS`` positions."""
-    return largest_divisor(max_pages, max(1, DECODE_BLOCK_TOKENS // page_size))
+    return seq.largest_divisor(
+        max_pages, max(1, DECODE_BLOCK_TOKENS // page_size)
+    )
 
 
 def _latent_attend(

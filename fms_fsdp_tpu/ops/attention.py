@@ -26,6 +26,8 @@ import jax.numpy as jnp
 from jax import lax
 
 from fms_fsdp_tpu.ops import flash_attention as _fa
+from fms_fsdp_tpu.ops.norms import rms_norm
+from fms_fsdp_tpu.ops.rope import rotate_halves
 
 
 def xla_attention(q, k, v, *, causal: bool = True, scale: Optional[float] = None):
@@ -183,3 +185,66 @@ def chunk_attention(q, k_cache, v_cache, start, *, impl: str = "auto"):
         (o.astype(jnp.float32), lse),
     )
     return o.astype(q.dtype)
+
+
+def qkv_by_head(h, layer, cfg, positions, rotate: bool):
+    """h (B, S, D) -> q (B, S, N, H), k and v (B, S, Nkv, H): projected
+    through the layer's ``wq``, ``wk``, ``wv``, q and k normed by head
+    (``q_norm``, ``k_norm``) and, where ``rotate``, turned at
+    ``positions`` (B, S). ``cfg`` gives ``nheads``, ``kvheads``,
+    ``head_dim``, ``norm_eps`` and ``rope_theta``."""
+    B, S, _ = h.shape
+    hd = cfg.head_dim
+    with jax.named_scope("qkv"):
+        # the products end here, as (B, S, heads * H): asked for them by
+        # head, with the norm's sum over a head's values behind, the
+        # chip's compiler lays W_q out by head first, a transposed copy of
+        # it a layer and call (100 MB at K-EXAONE's published widths, in
+        # every decode step; deviceless v5e compile, PERF.md PR 33)
+        q, k, v = lax.optimization_barrier(
+            (h @ layer["wq"], h @ layer["wk"], h @ layer["wv"])
+        )
+        q = q.reshape(B, S, cfg.nheads, hd)
+        k = k.reshape(B, S, cfg.kvheads, hd)
+        v = v.reshape(B, S, cfg.kvheads, hd)
+    with jax.named_scope("qk_norm"):
+        q = rms_norm(q, layer["q_norm"], cfg.norm_eps)
+        k = rms_norm(k, layer["k_norm"], cfg.norm_eps)
+    if rotate:
+        with jax.named_scope("rope"):
+            q = rotate_halves(q, positions, cfg.rope_theta)
+            k = rotate_halves(k, positions, cfg.rope_theta)
+    return q, k, v
+
+
+def band_mask(q_pos, k_pos, window):
+    """(..., Sq, Sk) bool: key at ``k_pos`` is seen from ``q_pos``: not
+    after it and, with a ``window``, fewer than that many behind it."""
+    back = q_pos[..., :, None] - k_pos[..., None, :]
+    seen = back >= 0
+    return seen & (back < window) if window else seen
+
+
+def masked_attention(q, k, v, mask):
+    """q (B, Sq, N, H) over k, v (B, Sk, Nkv, H) where ``mask`` (B or 1,
+    Sq, Sk) -> (normalised output (B, Sq, N, H) fp32, log-sum-exp (B, Sq,
+    N, 1) fp32): a partial that ``ops/ring_attention.py::merge_partial``
+    joins with others. A row that sees nothing gives a log-sum-exp near
+    ``NEG_INF`` and weighs nothing in a merge."""
+    B, Sq, N, H = q.shape
+    nkv = k.shape[2]
+    g = N // nkv
+    s = jnp.einsum(
+        "bqkgh,bskh->bkgqs", q.reshape(B, Sq, nkv, g, H), k,
+        preferred_element_type=jnp.float32,
+    ) * (H**-0.5)
+    s = jnp.where(mask[:, None, None], s, _fa.NEG_INF)
+    m = jnp.max(s, axis=-1, keepdims=True)
+    p = jnp.exp(s - m)
+    l = jnp.sum(p, axis=-1, keepdims=True)
+    o = jnp.einsum("bkgqs,bskh->bqkgh", p.astype(v.dtype), v)
+    o = o.astype(jnp.float32).reshape(B, Sq, N, H) / jnp.moveaxis(
+        l, 3, 1
+    ).reshape(B, Sq, N, 1)
+    lse = jnp.moveaxis(m + jnp.log(l), 3, 1).reshape(B, Sq, N, 1)
+    return o, lse

@@ -48,6 +48,21 @@ def apply_rotary(x, cos, sin, positions=None):
     return jnp.concatenate([out1, out2], axis=-1).astype(x.dtype)
 
 
+def rotate_halves(x, positions, theta: float):
+    """x (B, S, N, H) turned at ``positions`` (B, S): the two halves of a
+    head paired, angles in float32 from the positions themselves (no
+    table: a position is as far out as its stream has got)."""
+    half = x.shape[-1] // 2
+    freqs = 1.0 / (theta ** (jnp.arange(0, half, dtype=jnp.float32) / half))
+    ang = positions[..., None].astype(jnp.float32) * freqs
+    c, s = jnp.cos(ang)[:, :, None, :], jnp.sin(ang)[:, :, None, :]
+    x1 = x[..., :half].astype(jnp.float32)
+    x2 = x[..., half:].astype(jnp.float32)
+    return jnp.concatenate(
+        [x1 * c - x2 * s, x2 * c + x1 * s], axis=-1
+    ).astype(x.dtype)
+
+
 def yarn_rope_table(
     seq_len: int,
     dim: int,

@@ -44,6 +44,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from fms_fsdp_tpu.models.sequence_prefill import largest_divisor
 from fms_fsdp_tpu.ops.pallas_mode import interpret_default
 
 LANES = 128
@@ -127,12 +128,6 @@ def kernel_supports(channels: int) -> bool:
         return False
     rows = channels // LANES
     return rows % SUBLANES == 0 or rows < SUBLANES
-
-
-def largest_divisor(n: int, cap: int) -> int:
-    """The largest divisor of ``n`` that is at most ``cap``: the chunk
-    that tiles ``n`` positions."""
-    return max(t for t in range(1, min(n, cap) + 1) if n % t == 0)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))

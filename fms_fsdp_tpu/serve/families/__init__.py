@@ -300,6 +300,91 @@ def paged_geometry(scfg, nheads: int, n_kv_heads: int, head_dim: int,
     return page_size, block_kv, tune_how, max_pages, num_pages
 
 
+# positions a page of a family's own pools holds unless ``scfg.page_size``
+# pins it (one page of 8 kv heads of 128 is 256 KB of keys in bfloat16, one
+# fetch of the decode kernel), and the positions one cell of that kernel's
+# grid walks: a cell costs about as much as a page's fetch, so a stream's
+# 132 pages are walked four at a time
+PAGE_SIZE = 128
+DECODE_BLOCK_TOKENS = 512
+
+
+def block_paged_geometry(
+    model_cfg, scfg, page_size: int = PAGE_SIZE, longest: int = 0
+):
+    """``(page_size, block_kv, max_pages, num_pages)`` of the paged cache
+    of a family whose decode kernel walks a stream's pages in cells
+    (kexaone, lfm2, minicpm_sala): ``page_size`` positions a page unless
+    ``scfg.page_size`` pins it, cells of up to ``DECODE_BLOCK_TOKENS``
+    positions in whole pages of the ``longest`` run of pages a row
+    attends (all a stream can hold, unless given)."""
+    import dataclasses
+
+    from fms_fsdp_tpu.models.sequence_prefill import largest_divisor
+
+    if not scfg.page_size:
+        scfg = dataclasses.replace(scfg, page_size=page_size)
+    page_size, _, _, max_pages, num_pages = paged_geometry(
+        scfg, model_cfg.nheads, model_cfg.kvheads, model_cfg.head_dim,
+        tuned=False,
+    )
+    block_kv = page_size * largest_divisor(
+        min(longest or max_pages, max_pages),
+        max(1, DECODE_BLOCK_TOKENS // page_size),
+    )
+    return page_size, block_kv, max_pages, num_pages
+
+
+def program_len(p_pad: int, bucket: int, longest: int) -> int:
+    """The length of the prefill program that takes a prompt padded to
+    ``p_pad``, for a family that builds one program a doubling of the
+    bucket (minicpm_sala, lfm2): the bucket doubled until it holds it, at
+    most ``longest`` (``max_seq_len`` in whole buckets). The program's
+    loop stops at the prompt's end, so a longer program costs a shorter
+    prompt its buffers' zeros and a choice's scores over their rows, not
+    positions."""
+    n = bucket
+    while n < p_pad:
+        n *= 2
+    return max(p_pad, min(n, longest))
+
+
+def jit_prefill(n: int, prefill, model_cfg, **how):
+    """The jitted prefill of prompts up to ``n`` positions by a model's
+    sequence prefill: ``(params, tokens (1, n), lengths (1,)) ->
+    prefill(params, tokens, lengths, model_cfg, **how)``. The traced
+    function is named by the length, so the profiler shows each shape's
+    program under its own name, ``jit__prefill_<n>``."""
+    import jax
+
+    def _prefill(params, tokens, lengths):
+        return prefill(params, tokens, lengths, model_cfg, **how)
+
+    _prefill.__name__ = f"_prefill_{n}"
+    return jax.jit(_prefill)
+
+
+@cache
+def slot_writer(axis: int):
+    """The program that lands one stream's state ``rows`` (a tree of
+    arrays one slot wide along ``axis``) in its slot of the engine's
+    ``state``: jitted with the state donated, so a write moves the rows
+    and not the whole. The slots lead a mamba slab (axis 0) and follow
+    the layers in the other families' states (axis 1)."""
+    import jax
+
+    def _write_slot(state, rows, slot):
+        return jax.tree.map(
+            lambda s, r: jax.lax.dynamic_update_slice_in_dim(
+                s, r.astype(s.dtype), slot, axis
+            ),
+            state,
+            rows,
+        )
+
+    return jax.jit(_write_slot, donate_argnums=(0,))
+
+
 def resolve_adapter(
     params, model_cfg, serve_cfg, compute_dtype=None, registry=None
 ):
@@ -377,8 +462,9 @@ class FamilyAdapter:
 
     family: str = "?"
     cache = None  # PagedKVCache when the family uses pages, else None
-    # the recurrent slab when the family keeps one (with ``_write_slot``,
-    # the program that lands one stream's rows in it: mamba.py)
+    # the state a slot keeps beside its pages, when the family has one
+    # (with ``_write_slot``, the program that lands one stream's rows in
+    # it: ``slot_writer``)
     _state = None
     page_size: int = 0
     max_pages: int = 0
@@ -532,6 +618,14 @@ class FamilyAdapter:
         self.registry.counter("serve.prefill_programs_built").add()
         fn = self._prefill_cache[key] = build(key)
         return fn, 1
+
+    def program_len_of(self, p: int) -> int:
+        """The length of the program that prefills a prompt of ``p``, in
+        a family that builds one a doubling of the bucket."""
+        return program_len(
+            self._padded(p), max(1, self.scfg.prefill_bucket),
+            self._padded(self.scfg.max_seq_len),
+        )
 
     def _prefill_key(self, p: int, p_pad: int, kv_len: int):
         """The key of the program that prefills ``p`` tokens padded to
@@ -981,12 +1075,16 @@ __all__ = [
     "FamilyAdapter",
     "HeldExpertsAdapter",
     "PagedAdapter",
+    "block_paged_geometry",
     "check_params_family",
     "family_of",
     "init_params_for",
+    "jit_prefill",
     "kernel_or_reference",
     "load_model_config",
     "paged_geometry",
+    "program_len",
     "resolve_adapter",
     "sequence_prefill_attn_impl",
+    "slot_writer",
 ]

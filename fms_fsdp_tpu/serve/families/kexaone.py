@@ -61,21 +61,14 @@ from fms_fsdp_tpu.models.kexaone import (
     prefill_attn_form,
     prefill_positions,
 )
-from fms_fsdp_tpu.ops.selective_scan import largest_divisor
 from fms_fsdp_tpu.serve.families import (
     HeldExpertsAdapter,
+    block_paged_geometry as page_geometry,  # the full layers' pages
+    jit_prefill,
     kernel_or_reference as resolve_attn_impl,
-    paged_geometry,
     sequence_prefill_attn_impl as _prefill_attn_impl,
+    slot_writer,
 )
-
-# positions a page of the full layers' pools holds unless
-# ``scfg.page_size`` pins it (one page of 8 kv heads of 128 is 256 KB of
-# keys in bfloat16, one fetch of the decode kernel), and the positions
-# one cell of that kernel's grid walks: a cell costs about as much as a
-# page's fetch, so a stream's 132 pages are walked four at a time
-PAGE_SIZE = 128
-DECODE_BLOCK_TOKENS = 512
 
 
 def cache_bytes(model_cfg, dtype) -> dict:
@@ -91,25 +84,6 @@ def cache_bytes(model_cfg, dtype) -> dict:
             len(model_cfg.window_layers) * model_cfg.sliding_window * row
         ),
     }
-
-
-def page_geometry(model_cfg, scfg):
-    """``(page_size, block_kv, max_pages, num_pages)`` of the full
-    layers' paged cache: ``PAGE_SIZE`` positions a page unless
-    ``scfg.page_size`` pins it, the decode kernel's cells of up to
-    ``DECODE_BLOCK_TOKENS`` positions in whole pages."""
-    import dataclasses
-
-    if not scfg.page_size:
-        scfg = dataclasses.replace(scfg, page_size=PAGE_SIZE)
-    page_size, _, _, max_pages, num_pages = paged_geometry(
-        scfg, model_cfg.nheads, model_cfg.kvheads, model_cfg.head_dim,
-        tuned=False,
-    )
-    block_kv = page_size * largest_divisor(
-        max_pages, max(1, DECODE_BLOCK_TOKENS // page_size)
-    )
-    return page_size, block_kv, max_pages, num_pages
 
 
 def ring_shape(model_cfg, scfg):
@@ -151,17 +125,11 @@ def prefill_program(model_cfg, scfg, p_pad: int, kv_len: int, compute_dtype):
     window, Nkv, H), pairs on held experts, the grouped product's trips,
     the row tiles it met)``. The traced function is named by the length:
     ``jit__prefill_<p_pad>`` in the profiler's trace."""
-    attn_impl, moe_impl = _prefill_attn_impl(scfg), scfg.moe_impl
-
-    def _prefill(params, tokens, lengths):
-        return kexaone_prefill(
-            params, tokens, lengths, model_cfg,
-            compute_dtype=compute_dtype, kv_len=kv_len,
-            attn_impl=attn_impl, moe_impl=moe_impl,
-        )
-
-    _prefill.__name__ = f"_prefill_{p_pad}"
-    return jax.jit(_prefill)
+    return jit_prefill(
+        p_pad, kexaone_prefill, model_cfg, compute_dtype=compute_dtype,
+        kv_len=kv_len, attn_impl=_prefill_attn_impl(scfg),
+        moe_impl=scfg.moe_impl,
+    )
 
 
 class KExaoneAdapter(HeldExpertsAdapter):
@@ -208,18 +176,7 @@ class KExaoneAdapter(HeldExpertsAdapter):
             for name in ("k", "v")
         }
 
-        # one stream's rings into its slot: jitted with the rings donated,
-        # so a write moves the rows and not the rings
-        def _write_slot(state, rows, slot):
-            return jax.tree.map(
-                lambda s, r: jax.lax.dynamic_update_slice_in_dim(
-                    s, r.astype(s.dtype), slot, 1
-                ),
-                state,
-                rows,
-            )
-
-        self._write_slot = jax.jit(_write_slot, donate_argnums=(0,))
+        self._write_slot = slot_writer(1)  # one stream's rings
         self._decode_fn = decode_program(
             cfg, scfg, self.page_size, self.block_kv, self.compute_dtype
         )

@@ -43,7 +43,7 @@ in a loop inside its program that stops at the prompt's length
 (``serve.prefill_computed_tokens``), each convolution layer's last values
 of ``z`` carried from chunk to chunk. A program serves every prompt up
 to its length, so the adapter builds one for each doubling of the bucket
-(``serve/families/minicpm_sala.py::program_len``). ``attn_form`` on
+(``serve/families/__init__.py::program_len``). ``attn_form`` on
 ``serve/prefill.dispatch`` says what the attention layers run
 (``flash_head64`` or ``einsum``), ``moe_form`` how the chunk's pairs meet
 their experts (``grouped`` or ``dense``).
@@ -71,11 +71,12 @@ from fms_fsdp_tpu.models.lfm2 import (
 from fms_fsdp_tpu.ops.paged_attention import packed_row_width, tile_rows
 from fms_fsdp_tpu.serve.families import (
     HeldExpertsAdapter,
+    block_paged_geometry as page_geometry,  # the attention layers' pages
+    jit_prefill,
     kernel_or_reference as resolve_attn_impl,
     sequence_prefill_attn_impl as _prefill_attn_impl,
+    slot_writer,
 )
-from fms_fsdp_tpu.serve.families.kexaone import page_geometry
-from fms_fsdp_tpu.serve.families.minicpm_sala import program_len
 
 
 def cache_bytes(model_cfg, dtype) -> dict:
@@ -139,17 +140,11 @@ def prefill_program(model_cfg, scfg, n: int, kv_len: int, compute_dtype):
     (L_conv, 1, K - 1, D), pairs on held experts, the grouped product's
     trips, the row tiles it met)``. The traced function is named by the length:
     ``jit__prefill_<n>`` in the profiler's trace."""
-    attn_impl, moe_impl = _prefill_attn_impl(scfg), scfg.moe_impl
-
-    def _prefill(params, tokens, lengths):
-        return lfm2_prefill(
-            params, tokens, lengths, model_cfg,
-            compute_dtype=compute_dtype, kv_len=kv_len,
-            attn_impl=attn_impl, moe_impl=moe_impl,
-        )
-
-    _prefill.__name__ = f"_prefill_{n}"
-    return jax.jit(_prefill)
+    return jit_prefill(
+        n, lfm2_prefill, model_cfg, compute_dtype=compute_dtype,
+        kv_len=kv_len, attn_impl=_prefill_attn_impl(scfg),
+        moe_impl=scfg.moe_impl,
+    )
 
 
 class Lfm2Adapter(HeldExpertsAdapter):
@@ -209,18 +204,7 @@ class Lfm2Adapter(HeldExpertsAdapter):
             "z": jnp.zeros(window_shape(cfg, scfg), self.compute_dtype)
         }
 
-        # one stream's windows into its slot: jitted with the windows
-        # donated, so a write moves the rows and not the whole
-        def _write_slot(state, rows, slot):
-            return jax.tree.map(
-                lambda s, r: jax.lax.dynamic_update_slice_in_dim(
-                    s, r.astype(s.dtype), slot, 1
-                ),
-                state,
-                rows,
-            )
-
-        self._write_slot = jax.jit(_write_slot, donate_argnums=(0,))
+        self._write_slot = slot_writer(1)  # one stream's windows
         program = decode_program(
             cfg, scfg, self.page_size, self.block_kv, self.compute_dtype
         )
@@ -250,13 +234,6 @@ class Lfm2Adapter(HeldExpertsAdapter):
         return cache_bytes(self.model_cfg, self.compute_dtype)["per_stream"]
 
     # -- prefill: one program a doubling of the bucket ---------------------
-
-    def program_len_of(self, p: int) -> int:
-        """The length of the program that prefills a prompt of ``p``."""
-        return program_len(
-            self._padded(p), max(1, self.scfg.prefill_bucket),
-            self._padded(self.scfg.max_seq_len),
-        )
 
     def _prefill_key(self, p: int, p_pad: int, kv_len: int):
         n = self.program_len_of(p)

@@ -65,7 +65,12 @@ from fms_fsdp_tpu.serve.disagg.slab import (
     pack_slab_leaves,
     split_slab_leaves,
 )
-from fms_fsdp_tpu.serve.families import FamilyAdapter, paged_geometry
+from fms_fsdp_tpu.serve.families import (
+    FamilyAdapter,
+    jit_prefill,
+    paged_geometry,
+    slot_writer,
+)
 
 
 def page_geometry(model_cfg, scfg):
@@ -140,16 +145,11 @@ def prefill_program(model_cfg, scfg, p_pad: int, kv_len: int, compute_dtype):
     (1, p_pad), lengths (1,)) -> (logits (1, V), slab rows, kv)``. The
     traced function is named by the length, so the profiler shows each
     shape's program under its own name, ``jit__prefill_<p_pad>``."""
-    attn_impl = "auto" if scfg.attn_impl == "auto" else "xla"
-
-    def _prefill(params, tokens, lengths):
-        return mamba_prefill(
-            params, tokens, lengths, model_cfg,
-            compute_dtype=compute_dtype, kv_len=kv_len, attn_impl=attn_impl,
-        )
-
-    _prefill.__name__ = f"_prefill_{p_pad}"
-    return jax.jit(_prefill)
+    return jit_prefill(
+        p_pad, mamba_prefill, model_cfg, compute_dtype=compute_dtype,
+        kv_len=kv_len,
+        attn_impl="auto" if scfg.attn_impl == "auto" else "xla",
+    )
 
 
 class MambaAdapter(FamilyAdapter):
@@ -213,18 +213,8 @@ class MambaAdapter(FamilyAdapter):
         self.ssm_layers = cfg.n_layer - len(cfg.attn_layer_idx)
 
         # one stream's rows into its slot of the slab (and zeros, on
-        # release): jitted with the slab donated, so a write moves the
-        # rows and not the slab
-        def _write_slot(state, rows, slot):
-            return jax.tree.map(
-                lambda s, r: jax.lax.dynamic_update_index_in_dim(
-                    s, r.astype(s.dtype), slot, 0
-                ),
-                state,
-                rows,
-            )
-
-        self._write_slot = jax.jit(_write_slot, donate_argnums=(0,))
+        # release)
+        self._write_slot = slot_writer(0)
         self._zero_rows = jax.tree.map(
             lambda s: jnp.zeros((1,) + s.shape[1:], s.dtype), self._state
         )

@@ -72,17 +72,15 @@ from fms_fsdp_tpu.models.minicpm_sala import (
     sala_paged_decode_step,
     sala_prefill,
 )
-from fms_fsdp_tpu.ops.selective_scan import largest_divisor
 from fms_fsdp_tpu.serve.families import (
     FamilyAdapter,
+    block_paged_geometry,
+    jit_prefill,
     kernel_or_reference as resolve_attn_impl,
-    paged_geometry,
+    program_len,  # noqa: F401  (benchmark/ takes it from here)
     sequence_prefill_attn_impl as _prefill_attn_impl,
+    slot_writer,
 )
-
-# the positions one cell of the decode kernel's grid walks, in whole
-# pages of 64: a cell costs about as much as four pages' fetch
-DECODE_BLOCK_TOKENS = 512
 
 
 def cache_bytes(model_cfg, dtype) -> dict:
@@ -107,25 +105,17 @@ def cache_bytes(model_cfg, dtype) -> dict:
 def page_geometry(model_cfg, scfg):
     """``(page_size, block_kv, max_pages, num_pages)`` of the sparse
     layers' paged cache: a page is a block of the choice, the decode
-    kernel's cells up to ``DECODE_BLOCK_TOKENS`` positions in whole pages
-    of the longest list a row attends."""
-    import dataclasses
-
+    kernel's cells in whole pages of the longest list a row attends
+    (``block_paged_geometry``)."""
     sp = model_cfg.sparse
     if scfg.page_size not in (0, sp.block_size):
         raise ValueError(
             f"minicpm_sala serving does not take page_size={scfg.page_size}"
             f": a page is a block of the choice ({sp.block_size} positions)"
         )
-    scfg = dataclasses.replace(scfg, page_size=sp.block_size)
-    page_size, _, _, max_pages, num_pages = paged_geometry(
-        scfg, model_cfg.nheads, model_cfg.kvheads, model_cfg.head_dim,
-        tuned=False,
+    return block_paged_geometry(
+        model_cfg, scfg, page_size=sp.block_size, longest=sp.list_blocks
     )
-    block_kv = page_size * largest_divisor(
-        min(sp.list_blocks, max_pages), max(1, DECODE_BLOCK_TOKENS // page_size)
-    )
-    return page_size, block_kv, max_pages, num_pages
 
 
 def state_shape(model_cfg, scfg):
@@ -134,18 +124,6 @@ def state_shape(model_cfg, scfg):
         len(model_cfg.lightning_layers), scfg.max_batch,
         model_cfg.lightning_nh,
     ) + (model_cfg.lightning_head_dim,) * 2
-
-
-def program_len(p_pad: int, bucket: int, longest: int) -> int:
-    """The length of the prefill program that takes a prompt padded to
-    ``p_pad``: the bucket doubled until it holds it, at most ``longest``
-    (``max_seq_len`` in whole buckets). The program's loop stops at the
-    prompt's end, so a longer program costs a shorter prompt its buffers'
-    zeros and the choice's scores over their rows, not positions."""
-    n = bucket
-    while n < p_pad:
-        n *= 2
-    return max(p_pad, min(n, longest))
 
 
 def decode_program(model_cfg, scfg, page_size: int, block_kv, compute_dtype):
@@ -180,16 +158,10 @@ def prefill_program(model_cfg, scfg, n: int, compute_dtype):
     states ``{"S"}``, the counts)`` (``sala_prefill``). The traced
     function is named by the length: ``jit__prefill_<n>`` in the
     profiler's trace."""
-    attn_impl = _prefill_attn_impl(scfg)
-
-    def _prefill(params, tokens, lengths):
-        return sala_prefill(
-            params, tokens, lengths, model_cfg,
-            compute_dtype=compute_dtype, kv_len=n, attn_impl=attn_impl,
-        )
-
-    _prefill.__name__ = f"_prefill_{n}"
-    return jax.jit(_prefill)
+    return jit_prefill(
+        n, sala_prefill, model_cfg, compute_dtype=compute_dtype,
+        kv_len=n, attn_impl=_prefill_attn_impl(scfg),
+    )
 
 
 class MiniCPMSalaAdapter(FamilyAdapter):
@@ -248,18 +220,7 @@ class MiniCPMSalaAdapter(FamilyAdapter):
         )
         self._state = {"S": jnp.zeros(state_shape(cfg, scfg), jnp.float32)}
 
-        # one stream's states into its slot: jitted with the states
-        # donated, so a write moves the rows and not the whole
-        def _write_slot(state, rows, slot):
-            return jax.tree.map(
-                lambda s, r: jax.lax.dynamic_update_slice_in_dim(
-                    s, r.astype(s.dtype), slot, 1
-                ),
-                state,
-                rows,
-            )
-
-        self._write_slot = jax.jit(_write_slot, donate_argnums=(0,))
+        self._write_slot = slot_writer(1)  # one stream's states
         self._decode_fn = decode_program(
             cfg, scfg, self.page_size, self.block_kv, self.compute_dtype
         )
@@ -279,13 +240,6 @@ class MiniCPMSalaAdapter(FamilyAdapter):
         return cache_bytes(self.model_cfg, self.compute_dtype)["per_stream"]
 
     # -- prefill: one program a doubling of the bucket ---------------------
-
-    def program_len_of(self, p: int) -> int:
-        """The length of the program that prefills a prompt of ``p``."""
-        return program_len(
-            self._padded(p), max(1, self.scfg.prefill_bucket),
-            self._padded(self.scfg.max_seq_len),
-        )
 
     def _prefill_key(self, p: int, p_pad: int, kv_len: int):
         return (self.program_len_of(p),)

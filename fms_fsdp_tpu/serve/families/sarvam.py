@@ -53,6 +53,7 @@ from fms_fsdp_tpu.models.sarvam import (
 )
 from fms_fsdp_tpu.serve.families import (
     HeldExpertsAdapter,
+    jit_prefill,
     kernel_or_reference as resolve_attn_impl,
     paged_geometry,
     sequence_prefill_attn_impl as _prefill_attn_impl,
@@ -111,17 +112,11 @@ def prefill_program(model_cfg, scfg, p_pad: int, kv_len: int, compute_dtype):
     pool_width), pairs on held experts, the grouped product's trips, the
     row tiles it met)``. The traced function is named
     by the length: ``jit__prefill_<p_pad>`` in the profiler's trace."""
-    attn_impl, moe_impl = _prefill_attn_impl(scfg), scfg.moe_impl
-
-    def _prefill(params, tokens, lengths):
-        return sarvam_prefill(
-            params, tokens, lengths, model_cfg,
-            compute_dtype=compute_dtype, kv_len=kv_len,
-            attn_impl=attn_impl, moe_impl=moe_impl,
-        )
-
-    _prefill.__name__ = f"_prefill_{p_pad}"
-    return jax.jit(_prefill)
+    return jit_prefill(
+        p_pad, sarvam_prefill, model_cfg, compute_dtype=compute_dtype,
+        kv_len=kv_len, attn_impl=_prefill_attn_impl(scfg),
+        moe_impl=scfg.moe_impl,
+    )
 
 
 class SarvamAdapter(HeldExpertsAdapter):
