@@ -19,6 +19,7 @@ capacity-based routing and expert parallelism (models/mixtral.py).
 """
 
 import dataclasses
+import math
 from dataclasses import dataclass, field
 from typing import Optional, Sequence, Tuple
 
@@ -1030,3 +1031,190 @@ def lfm2_moe_config(d: dict) -> Lfm2MoeConfig:
         rope_theta=float(rp.get("rope_theta", d.get("rope_theta", 1e6))),
         norm_eps=d["norm_eps"],
     )
+
+
+PHI4FLASH_LAYER_KINDS = ("mamba", "window", "full", "gmu", "cross")
+
+
+@dataclass(frozen=True)
+class Phi4FlashConfig:
+    """The Phi-4-mini-flash family (``model_type: phi4flash``;
+    models/phi4flash.py): a self-decoder of Mamba-1 mixers and
+    window-attention layers, one full-attention layer whose keys and
+    values are the only cache of the layers behind it, and a
+    cross-decoder of gated memory units (which gate the scan output of
+    the self-decoder's last Mamba layer) and cross-attention layers (a
+    query and an output projection alone, over the full layer's keys and
+    values). Every attention is differential: heads in pairs, two
+    softmaxes, their difference under a learned weight. LayerNorm with
+    weight and bias, a SwiGLU MLP of ``hidden_dim`` a layer, the head the
+    embedding, no positional embedding.
+
+    ``kind(i)`` is the layer rule: with ``half = nlayers / 2``, even
+    layers are ``mamba`` up to ``half`` (layer ``half`` hands its scan
+    output out) and ``gmu`` past it; odd layers are ``window`` below
+    ``half``, ``full`` at ``half + 1`` and ``cross`` past it. The Mamba
+    sizes are the family's defaults (``d_state`` 16, ``d_conv`` 4,
+    ``expand`` 2, ``dt_rank`` ceil(emb_dim / 16)): the published config
+    has no key for them."""
+
+    src_vocab_size: int = 200064
+    emb_dim: int = 2560
+    nheads: int = 40
+    kvheads: int = 20
+    nlayers: int = 32
+    hidden_dim: int = 10240
+    mb_per_layer: int = 2
+    sliding_window: int = 512
+    d_state: int = 16
+    d_conv: int = 4
+    expand: int = 2
+    max_expected_seq_len: int = 262144
+    norm_eps: float = 1e-5  # layer_norm_eps
+    subln_eps: float = 1e-5  # the norm by head of a differential output
+
+    def __post_init__(self):
+        if self.mb_per_layer != 2:
+            raise ValueError(
+                f"mb_per_layer={self.mb_per_layer}: the stack is built for "
+                "Mamba (or gated memory) layers alternating with attention "
+                "layers, mb_per_layer 2"
+            )
+        if self.nlayers % 4 or self.nlayers < 8:
+            raise ValueError(
+                f"num_hidden_layers={self.nlayers}: the layer rule needs a "
+                "multiple of 4, at least 8 (layer n/2 a Mamba layer that "
+                "hands its scan output out, n/2 + 1 the full layer, a gated "
+                "memory unit and a cross layer behind them)"
+            )
+        if (self.nheads % 2 or self.kvheads % 2
+                or self.nheads % self.kvheads or self.emb_dim % self.nheads):
+            raise ValueError(
+                f"{self.nheads} query heads over {self.kvheads} kv heads of "
+                f"a hidden size of {self.emb_dim}: differential attention "
+                "pairs neighbouring heads of both and the pairs divide evenly"
+            )
+        if self.sliding_window < 1:
+            raise ValueError(
+                f"sliding_window={self.sliding_window}: a window layer sees "
+                "at least its own position"
+            )
+
+    @property
+    def head_dim(self) -> int:
+        return self.emb_dim // self.nheads
+
+    @property
+    def d_inner(self) -> int:
+        return self.expand * self.emb_dim
+
+    @property
+    def dt_rank_(self) -> int:
+        return -(-self.emb_dim // 16)
+
+    @property
+    def hand_out_layer(self) -> int:
+        """The Mamba layer whose scan output the gated memory units read."""
+        return self.nlayers // 2
+
+    @property
+    def full_layer(self) -> int:
+        """The one layer whose keys and values are kept a position."""
+        return self.nlayers // 2 + 1
+
+    def kind(self, i: int) -> str:
+        half = self.nlayers // 2
+        if i % self.mb_per_layer == 0:
+            return "mamba" if i <= half else "gmu"
+        if i < half:
+            return "window"
+        return "full" if i == half + 1 else "cross"
+
+    def layers_of(self, kind: str) -> Tuple[int, ...]:
+        return tuple(i for i in range(self.nlayers) if self.kind(i) == kind)
+
+    @staticmethod
+    def lambda_init(i: int) -> float:
+        """The constant part of a differential layer's weight, by the
+        layer's index."""
+        return 0.8 - 0.6 * math.exp(-0.3 * i)
+
+    def n_params(self) -> int:
+        """Parameters of the whole model (the head is the embedding's)."""
+        d, hd, di = self.emb_dim, self.head_dim, self.d_inner
+        N, R = self.d_state, self.dt_rank_
+        mamba = (
+            2 * d * di + di * (self.d_conv + 1) + di * (R + 2 * N)
+            + R * di + di + di * N + di + di * d
+        )
+        cross = 2 * (d * self.nheads * hd) + self.nheads * hd + d + 6 * hd
+        attn = cross + 2 * (d * self.kvheads * hd + self.kvheads * hd)
+        per = {
+            "mamba": mamba, "window": attn, "full": attn, "cross": cross,
+            "gmu": 2 * d * di,
+        }
+        return int(
+            sum(per[self.kind(i)] for i in range(self.nlayers))
+            + self.nlayers * (3 * d * self.hidden_dim + 4 * d)
+            + 2 * d
+            + self.src_vocab_size * d
+        )
+
+
+# what a published phi4flash config.json says and the stack takes as it
+# is: the value the family is built for, by key
+_PHI4FLASH_FIXED = {
+    "hidden_act": "silu", "mlp_bias": False, "lm_head_bias": False,
+    "tie_word_embeddings": True, "embd_pdrop": 0, "resid_pdrop": 0,
+    "model_type": "phi4flash",
+}
+# keys of a configuration's file that say nothing of the model's shape
+_PHI4FLASH_NOTES = (
+    "family", "source", "published", "reduced", "assumed", "deployment",
+    "n_params",
+    "weight_bytes_bfloat16",
+)
+
+
+def phi4flash_config(d: dict) -> Phi4FlashConfig:
+    """A published ``config.json`` of ``model_type: phi4flash`` as the
+    family's config. models/phi4flash.py says how what the config has no
+    key for is read; a key this mapping does not cover, or a value the
+    stack is not built for, is refused by name."""
+    d = dict(d)
+    for key in _PHI4FLASH_NOTES:
+        d.pop(key, None)
+    for key, built in _PHI4FLASH_FIXED.items():
+        got = d.pop(key, built)
+        if got != built:
+            raise ValueError(
+                f"phi4flash with {key}={got!r}: the family is built for "
+                f"{key}={built!r}"
+            )
+    try:
+        cfg = Phi4FlashConfig(
+            src_vocab_size=d.pop("vocab_size"),
+            emb_dim=d.pop("hidden_size"),
+            nheads=d.pop("num_attention_heads"),
+            kvheads=d.pop("num_key_value_heads"),
+            nlayers=d.pop("num_hidden_layers"),
+            hidden_dim=d.pop("intermediate_size"),
+            mb_per_layer=d.pop("mb_per_layer"),
+            sliding_window=d.pop("sliding_window"),
+            max_expected_seq_len=d.pop("max_position_embeddings"),
+            norm_eps=d.pop("layer_norm_eps"),
+        )
+    except KeyError as e:
+        raise ValueError(
+            f"a phi4flash config.json has to give {e.args[0]!r}"
+        ) from None
+    if d:
+        raise ValueError(
+            f"phi4flash config keys {sorted(d)} are not covered: the family "
+            "reads hidden_size, intermediate_size, layer_norm_eps, "
+            "max_position_embeddings, mb_per_layer, num_attention_heads, "
+            "num_hidden_layers, num_key_value_heads, sliding_window and "
+            f"vocab_size, and takes {sorted(_PHI4FLASH_FIXED)} at the "
+            "values it is built for"
+        )
+    return cfg

@@ -62,12 +62,13 @@ from fms_fsdp_tpu.models.moe_held import (
     _swiglu,
 )
 from fms_fsdp_tpu.obs.scopes import scoped
-from fms_fsdp_tpu.ops import flash_attention as _fa
 from fms_fsdp_tpu.ops.attention import (
+    as_ring as _as_ring,
     band_mask,
     chunk_attention,
     masked_attention,
     qkv_by_head,
+    window_chunk_attention as _window_chunk_attention,
 )
 from fms_fsdp_tpu.ops.norms import rms_norm
 from fms_fsdp_tpu.ops.paged_attention import (
@@ -75,8 +76,6 @@ from fms_fsdp_tpu.ops.paged_attention import (
     gqa_attend,
     paged_attention_kernel,
 )
-from fms_fsdp_tpu.ops.pallas_mode import interpret_default
-from fms_fsdp_tpu.ops.ring_attention import merge_partial
 
 __all__ = [
     "KExaoneConfig",
@@ -247,53 +246,6 @@ def prefill_attn_form(cfg: KExaoneConfig, attn_impl: str, p_pad: int) -> str:
     odd chunks)."""
     flash = _use_flash(cfg, attn_impl, prefill_chunk(p_pad))
     return "flash_window+flash" if flash else "einsum"
-
-
-@scoped("attn_window")
-def _window_chunk_attention(q, k, v, tail_k, tail_v, start, window, flash):
-    """A window layer's attention over one chunk: the chunk's queries q
-    (B, c, N, H) at positions ``start`` to ``start + c`` over the chunk's
-    own keys and values k, v (B, c, Nkv, H) and the ``window`` positions
-    before it, tail_k, tail_v (B, window, Nkv, H) (position ``start -
-    window + u`` at ``u``; nothing real when ``start`` is 0). No earlier
-    position is walked. Where ``flash``: the windowed flash kernel over
-    the chunk's band, and the first ``window - 1`` queries' part of the
-    tail as a small einsum merged in through the log-sum-exp; else one
-    masked einsum over tail and chunk. Returns (B, c, N, H)."""
-    B, c, N, H = q.shape
-    q_pos = start + jnp.arange(c, dtype=jnp.int32)
-    t_pos = start - window + jnp.arange(window, dtype=jnp.int32)
-    if not flash:
-        k_pos = jnp.concatenate([t_pos, q_pos])
-        mask = band_mask(q_pos, k_pos, window) & (k_pos >= 0)[None, :]
-        o, _ = masked_attention(
-            q,
-            jnp.concatenate([tail_k, k], axis=1),
-            jnp.concatenate([tail_v, v], axis=1),
-            mask[None],
-        )
-        return o.astype(q.dtype)
-    o, lse = _fa.flash_attention(
-        q, k, v, causal=True, window=window, return_lse=True,
-        interpret=interpret_default(),
-    )
-    rows = min(window, c)  # the queries that can see into the tail
-    mask = band_mask(q_pos[:rows], t_pos, window) & (t_pos >= 0)[None, :]
-    o_t, lse_t = masked_attention(q[:, :rows], tail_k, tail_v, mask[None])
-    head, _ = merge_partial(
-        (o[:, :rows].astype(jnp.float32), lse[:, :rows]), o_t, lse_t
-    )
-    return jnp.concatenate([head.astype(q.dtype), o[:, rows:]], axis=1)
-
-
-@scoped("win_write")
-def _as_ring(tail, lengths, window):
-    """The last ``window`` positions of each row's prompt, in order
-    (B, window, ...), as the ring a decode step reads: position ``t`` at
-    ``t mod window``."""
-    return jax.vmap(lambda t, p: jnp.roll(t, p % window, axis=0))(
-        tail, lengths
-    )
 
 
 def kexaone_prefill(

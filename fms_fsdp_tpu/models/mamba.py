@@ -51,17 +51,17 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from fms_fsdp_tpu.models import sequence_prefill as seq
 from fms_fsdp_tpu.models.configs import MambaConfig
+from fms_fsdp_tpu.models.mamba1 import (
+    conv_step as _conv_step,
+    mamba1_mixer as _mamba1_mixer,
+    mamba1_mixer_step as _mamba1_mixer_step,
+)
 from fms_fsdp_tpu.obs.scopes import scoped
 from fms_fsdp_tpu.ops.attention import attention, chunk_attention
 from fms_fsdp_tpu.ops.norms import rms_norm
 from fms_fsdp_tpu.ops.quant import matmul as qmatmul
 from fms_fsdp_tpu.ops.rope import apply_rotary, rope_table
-from fms_fsdp_tpu.ops.selective_scan import (
-    freeze_past,
-    selective_scan,
-    selective_scan_reference,
-    selective_scan_step,
-)
+from fms_fsdp_tpu.ops.selective_scan import selective_scan
 from fms_fsdp_tpu.ops.ssd import causal_conv1d, ssd_scan
 from fms_fsdp_tpu.parallel.mesh import AXIS_CONTEXT, AXIS_FSDP, AXIS_TENSOR, DATA_AXES
 
@@ -231,80 +231,6 @@ def _mamba_mixer(x, p: Params, cfg: MambaConfig, mesh, kernel="auto", quant="non
     y = rms_norm(y * jax.nn.silu(z), p["norm"], cfg.norm_eps)
     out = qmatmul(y, p["out_proj"], quant=quant)
     return _constrain(out, P(DATA_AXES, AXIS_CONTEXT, None), mesh)
-
-
-def _mamba1_scan_inputs(u, p: Params, cfg: MambaConfig):
-    """x_proj, the three norms, dt_proj with bias and softplus, A: what
-    the scan reads besides ``u``. u (..., d_inner) post-conv. Returns
-    (dt (..., d_inner) fp32, A (N, d_inner) fp32, B, C (..., N))."""
-    N, R = cfg.d_state, cfg.dt_rank_
-    dbc = u @ p["x_proj"]
-    dt_r = rms_norm(dbc[..., :R], p["dt_norm"], cfg.norm_eps)
-    Bm = rms_norm(dbc[..., R : R + N], p["B_norm"], cfg.norm_eps)
-    Cm = rms_norm(dbc[..., R + N :], p["C_norm"], cfg.norm_eps)
-    dt = jax.nn.softplus(
-        jnp.dot(dt_r, p["dt_proj"], preferred_element_type=jnp.float32)
-        + p["dt_bias"].astype(jnp.float32)
-    )
-    A = -jnp.exp(p["A_log"].astype(jnp.float32)).T
-    return dt, A, Bm, Cm
-
-
-def _mamba1_mixer(
-    x, p: Params, cfg: MambaConfig, mesh=None, quant="none", *,
-    lengths=None, scan=selective_scan_reference, carry=None,
-):
-    """x (B, S, D) compute dtype -> (out (B, S, D), slab) through a
-    Mamba-1 mixer. With ``lengths`` (B,) a row's state freezes at its
-    length, and ``slab`` is what the recurrent decode step goes on from:
-    {"conv": the last d_conv-1 pre-conv inputs before that position,
-    "ssd": the state there}. ``carry`` is such a slab to go on from (the
-    sequence is then the continuation of the one that left it); without
-    it the scan starts from a zero state and the conv from zero inputs.
-    ``scan`` is the sequence form of ops/selective_scan.py to run: the
-    differentiable ``lax.scan`` one unless the caller (prefill) asks for
-    the one that fits the platform."""
-    B, S, _ = x.shape
-    di, N, K = cfg.d_inner, cfg.d_state, cfg.d_conv
-    before = None if carry is None else carry["conv"]
-    with jax.named_scope("ssm_in_proj"):
-        u_pre, z = (
-            _constrain(
-                qmatmul(x, p["in_proj"][i], quant=quant),
-                P(DATA_AXES, AXIS_CONTEXT, AXIS_TENSOR), mesh,
-            )
-            for i in range(2)
-        )
-    with jax.named_scope("ssm_conv"):
-        u = causal_conv1d(
-            u_pre, p["conv_w"], p["conv_b"], activation="silu", init=before
-        )
-    with jax.named_scope("ssm_params"):
-        dt, A, Bm, Cm = _mamba1_scan_inputs(u, p, cfg)
-        if lengths is not None:
-            dt = freeze_past(dt, lengths)
-    with jax.named_scope("ssm_scan"):
-        y, h = scan(
-            u.astype(jnp.float32), dt, A, Bm.astype(jnp.float32),
-            Cm.astype(jnp.float32), p["D"].astype(jnp.float32),
-            jnp.zeros((B, N, di), jnp.float32) if carry is None
-            else carry["ssd"],
-        )
-    with jax.named_scope("ssm_gate_out"):
-        out = qmatmul(
-            y.astype(x.dtype) * jax.nn.silu(z), p["out_proj"], quant=quant
-        )
-        out = _constrain(out, P(DATA_AXES, AXIS_CONTEXT, None), mesh)
-    if lengths is None:
-        return out, None
-    with jax.named_scope("ssm_conv"):
-        if before is None:
-            before = jnp.zeros((B, K - 1, di), u_pre.dtype)
-        padded = jnp.concatenate([before.astype(u_pre.dtype), u_pre], 1)
-        tail = jax.vmap(
-            lambda row, n: lax.dynamic_slice_in_dim(row, n, K - 1, axis=0)
-        )(padded, lengths)
-    return out, {"conv": tail, "ssd": h}
 
 
 @scoped("attn_mixer")
@@ -484,18 +410,6 @@ def mamba_state_bytes_per_stream(cfg: MambaConfig, compute_dtype=jnp.float32) ->
     )
 
 
-def _conv_step(window, w, b):
-    """Position t of ``causal_conv1d`` from the window of the last d_conv
-    inputs (B, d_conv, C), the current one last: the same ascending-w
-    fp32 FMA sum, bias and silu. Returns fp32."""
-    wf = w.astype(jnp.float32)
-    out = sum(
-        window[:, k].astype(jnp.float32) * wf[None, :, k]
-        for k in range(w.shape[-1])
-    )
-    return jax.nn.silu(out + b.astype(jnp.float32)[None, :])
-
-
 def _mamba_mixer_step(x, st: Params, p: Params, cfg: MambaConfig):
     """One token through a Mamba2 mixer. x (B, D) post-norm hidden in the
     compute dtype; st the layer's {"conv", "ssd"} slab. Returns
@@ -540,28 +454,6 @@ def _mamba_mixer_step(x, st: Params, p: Params, cfg: MambaConfig):
     y = rms_norm(y * jax.nn.silu(z), p["norm"], cfg.norm_eps)
     out = y @ p["out_proj"]
     return out, {"conv": window[:, 1:], "ssd": h_ssd}
-
-
-def _mamba1_mixer_step(x, st: Params, p: Params, cfg: MambaConfig):
-    """One token through a Mamba-1 mixer. x (B, D) post-norm hidden; st
-    the layer's {"conv", "ssd"} slab. Returns (out (B, D), new st): the
-    single-position case of ``_mamba1_mixer``."""
-    di = cfg.d_inner
-    with jax.named_scope("ssm_in_proj"):
-        u_pre, z = x @ p["in_proj"][0], x @ p["in_proj"][1]
-    with jax.named_scope("ssm_conv"):
-        window = jnp.concatenate([st["conv"], u_pre[:, None, :]], axis=1)
-        u = _conv_step(window, p["conv_w"], p["conv_b"]).astype(x.dtype)
-    with jax.named_scope("ssm_params"):
-        dt, A, Bm, Cm = _mamba1_scan_inputs(u, p, cfg)
-    with jax.named_scope("ssm_scan"):
-        y, h = selective_scan_step(
-            u.astype(jnp.float32), dt, A, Bm.astype(jnp.float32),
-            Cm.astype(jnp.float32), p["D"].astype(jnp.float32), st["ssd"],
-        )
-    with jax.named_scope("ssm_gate_out"):
-        out = (y.astype(x.dtype) * jax.nn.silu(z)) @ p["out_proj"]
-    return out, {"conv": window[:, 1:], "ssd": h}
 
 
 def _attn_qkv_step(h, p: Params, a, cos, sin, positions):

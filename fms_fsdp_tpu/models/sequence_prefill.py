@@ -1,6 +1,6 @@
 """A prompt prefilled as a sequence, a chunk at a time: the one loop the
 serving families' prefill programs fill in (models/mamba.py's Mamba-1
-stack, sarvam.py, kexaone.py, minicpm_sala.py, lfm2.py), and what stands
+stack, sarvam.py, kexaone.py, minicpm_sala.py, lfm2.py, phi4flash.py), and what stands
 around it in each. No family and no config class is imported here.
 
 ``chunk_loop`` takes ``chunk_of(S_pad, PREFILL_CHUNK)`` positions at a

@@ -224,6 +224,48 @@ LFM2_SCOPES = (
     "sample",
 )
 
+# the scopes of a phi4flash engine's two programs (models/phi4flash.py,
+# serve/families/phi4flash.py: ``jit__step`` and ``jit__prefill_<tokens>``),
+# in program order. Mamba layers: the five ``ssm_*`` scopes of
+# ``HYBRID_SCOPES`` (the slab's masked update of a decode step lies under
+# ``ssm_scan``). Window layers: ``win_write`` and ``attn_window`` as in
+# ``KEXAONE_SCOPES``. The full layer: ``kv_write`` (the page write; the
+# prefill's buffer write, every position's) and ``attn_full``; the cross
+# layers: ``attn_cross``, their reads of the full layer's pages.
+# ``diff_combine`` (the lambda, the difference of the two softmaxes'
+# outputs, the norm by head) lies inside whichever of ``attn_window``,
+# ``attn_full`` and ``attn_cross`` it follows: a table over these names
+# gives it alone, one over ``PHI4FLASH_SCOPES_COARSE`` leaves it with its
+# attention. ``gmu`` is a gated memory unit, ``qkv`` the projections with
+# their biases and the queries' rows. ``layers`` is around the (unrolled)
+# stack
+PHI4FLASH_SCOPES = (
+    "params_cast",
+    "embed",
+    "norm",
+    "layers",
+    "ssm_in_proj",
+    "ssm_conv",
+    "ssm_params",
+    "ssm_scan",
+    "ssm_gate_out",
+    "qkv",
+    "win_write",
+    "attn_window",
+    "kv_write",
+    "attn_full",
+    "attn_cross",
+    "diff_combine",
+    "attn_out",
+    "gmu",
+    "mlp",
+    "lm_head",
+    "sample",
+)
+PHI4FLASH_SCOPES_COARSE = tuple(
+    s for s in PHI4FLASH_SCOPES if s != "diff_combine"
+)
+
 _COMPUTATION = re.compile(r"^(?:ENTRY\s+)?%?([\w.\-]+)\s.*\{\s*$")
 _INSTRUCTION = re.compile(r"^\s+(?:ROOT\s+)?%?([\w.\-]+) = ")
 _NAME = re.compile(r"%([\w.\-]+)")

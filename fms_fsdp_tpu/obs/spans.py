@@ -57,6 +57,9 @@ FIELDS = (
     # of them, in a family whose positions choose their context (minicpm_sala):
     # the positions that chose and the blocks they chose
     "chose_tokens", "chosen_blocks",
+    # of them, in a family whose prefill stops half way (phi4flash): the
+    # positions the first half of the stack computed and those the second
+    "self_positions", "cross_positions",
     "live", "kv_tokens",  # of the decode step dispatched
     "live_chose",  # its live streams that chose their context
     "tokens",  # decode tokens committed in the step
@@ -97,7 +100,9 @@ TIMED = {
 COUNTED = {
     name: tuple((k, SLOT[k]) for k in keys) for name, keys in {
         "prefill": ("padded_tokens",),
-        "prefill.done": ("computed_tokens", "chose_tokens", "chosen_blocks"),
+        "prefill.done": (
+            "computed_tokens", "chose_tokens", "chosen_blocks",
+            "self_positions", "cross_positions"),
         "prefill.dispatch": ("built",),
         "decode": ("live", "kv_tokens"),
         "decode.dispatch": ("live_chose",),

@@ -25,6 +25,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from fms_fsdp_tpu.obs.scopes import scoped
 from fms_fsdp_tpu.ops import flash_attention as _fa
 from fms_fsdp_tpu.ops.norms import rms_norm
 from fms_fsdp_tpu.ops.rope import rotate_halves
@@ -225,19 +226,20 @@ def band_mask(q_pos, k_pos, window):
     return seen & (back < window) if window else seen
 
 
-def masked_attention(q, k, v, mask):
+def masked_attention(q, k, v, mask, scale=None):
     """q (B, Sq, N, H) over k, v (B, Sk, Nkv, H) where ``mask`` (B or 1,
     Sq, Sk) -> (normalised output (B, Sq, N, H) fp32, log-sum-exp (B, Sq,
     N, 1) fp32): a partial that ``ops/ring_attention.py::merge_partial``
     joins with others. A row that sees nothing gives a log-sum-exp near
-    ``NEG_INF`` and weighs nothing in a merge."""
+    ``NEG_INF`` and weighs nothing in a merge. ``scale``: the scores'
+    factor where it is not ``H ** -0.5``."""
     B, Sq, N, H = q.shape
     nkv = k.shape[2]
     g = N // nkv
     s = jnp.einsum(
         "bqkgh,bskh->bkgqs", q.reshape(B, Sq, nkv, g, H), k,
         preferred_element_type=jnp.float32,
-    ) * (H**-0.5)
+    ) * (H**-0.5 if scale is None else scale)
     s = jnp.where(mask[:, None, None], s, _fa.NEG_INF)
     m = jnp.max(s, axis=-1, keepdims=True)
     p = jnp.exp(s - m)
@@ -248,3 +250,120 @@ def masked_attention(q, k, v, mask):
     ).reshape(B, Sq, N, 1)
     lse = jnp.moveaxis(m + jnp.log(l), 3, 1).reshape(B, Sq, N, 1)
     return o, lse
+
+
+# ---------------------------------------------------------------------------
+# a window layer's prompt, a chunk at a time (kexaone, phi4flash)
+# ---------------------------------------------------------------------------
+
+
+@scoped("attn_window")
+def window_chunk_attention(
+    q, k, v, tail_k, tail_v, start, window, flash, scale=None
+):
+    """A window layer's attention over one chunk: the chunk's queries q
+    (B, c, N, H) at positions ``start`` to ``start + c`` over the chunk's
+    own keys and values k, v (B, c, Nkv, H) and the ``window`` positions
+    before it, tail_k, tail_v (B, window, Nkv, H) (position ``start -
+    window + u`` at ``u``; nothing real when ``start`` is 0). No earlier
+    position is walked. Where ``flash``: the windowed flash kernel over
+    the chunk's band, and the first ``window - 1`` queries' part of the
+    tail as a small einsum merged in through the log-sum-exp; else one
+    masked einsum over tail and chunk. ``scale``: the scores' factor where
+    it is not ``H ** -0.5``. Returns (B, c, N, H)."""
+    from fms_fsdp_tpu.ops.pallas_mode import interpret_default
+    from fms_fsdp_tpu.ops.ring_attention import merge_partial
+
+    B, c, N, H = q.shape
+    q_pos = start + jnp.arange(c, dtype=jnp.int32)
+    t_pos = start - window + jnp.arange(window, dtype=jnp.int32)
+    if not flash:
+        k_pos = jnp.concatenate([t_pos, q_pos])
+        mask = band_mask(q_pos, k_pos, window) & (k_pos >= 0)[None, :]
+        o, _ = masked_attention(
+            q,
+            jnp.concatenate([tail_k, k], axis=1),
+            jnp.concatenate([tail_v, v], axis=1),
+            mask[None],
+            scale=scale,
+        )
+        return o.astype(q.dtype)
+    o, lse = _fa.flash_attention(
+        q, k, v, causal=True, window=window, return_lse=True,
+        interpret=interpret_default(), scale=scale,
+    )
+    rows = min(window, c)  # the queries that can see into the tail
+    mask = band_mask(q_pos[:rows], t_pos, window) & (t_pos >= 0)[None, :]
+    o_t, lse_t = masked_attention(
+        q[:, :rows], tail_k, tail_v, mask[None], scale=scale
+    )
+    head, _ = merge_partial(
+        (o[:, :rows].astype(jnp.float32), lse[:, :rows]), o_t, lse_t
+    )
+    return jnp.concatenate([head.astype(q.dtype), o[:, rows:]], axis=1)
+
+
+@scoped("win_write")
+def as_ring(tail, lengths, window):
+    """The last ``window`` positions of each row's prompt, in order
+    (B, window, ...), as the ring a decode step reads: position ``t`` at
+    ``t mod window``."""
+    return jax.vmap(lambda t, p: jnp.roll(t, p % window, axis=0))(
+        tail, lengths
+    )
+
+
+# ---------------------------------------------------------------------------
+# differential attention (arXiv:2410.05258), as rows of two heads
+# ---------------------------------------------------------------------------
+#
+# Heads in pairs of neighbours: query heads ``(2i, 2i + 1)`` are ``q1_i,
+# q2_i``, key heads ``(2j, 2j + 1)`` are ``k1_j, k2_j``, and the pair's two
+# value heads side by side are its one value head ``V_j``, ``2 H`` wide.
+# ``o_i = RMSNorm((softmax(q1 k1^T) - lambda softmax(q2 k2^T)) V_j) * (1 -
+# lambda_init)``. A pair of key heads side by side is a row of ``2 H``
+# lanes (128 at heads of 64: a whole tile), which is how a cache holds
+# them; a query head that stands in its own half of such a row, zeros in
+# the other (``diff_rows``), scores against its own key head alone, and
+# the value product gives it all ``2 H`` lanes of ``V_j``. So the two
+# softmaxes are one grouped-query attention of ``N`` query heads over
+# ``Nkv / 2`` heads of ``2 H`` (scores' factor ``H ** -0.5``), through
+# whatever runs that: a masked einsum, the windowed flash kernel, the
+# ragged paged kernel. ``diff_combine`` is what follows it.
+
+
+def diff_rows(q):
+    """q (..., N, H) -> (..., N, 2 H): head ``n``'s values in half ``n %
+    2`` of its row, zeros in the other."""
+    N, H = q.shape[-2:]
+    half = jax.nn.one_hot(jnp.arange(N) % 2, 2, dtype=q.dtype)  # (N, 2)
+    return (q[..., None, :] * half[:, :, None]).reshape(
+        q.shape[:-1] + (2 * H,)
+    )
+
+
+def diff_lambda(layer, lambda_init: float):
+    """``exp(lq1 . lk1) - exp(lq2 . lk2) + lambda_init`` in float32, from
+    a layer's four learned vectors."""
+    f32 = jnp.float32
+
+    def dot(a, b):
+        return jnp.sum(layer[a].astype(f32) * layer[b].astype(f32))
+
+    return (
+        jnp.exp(dot("lambda_q1", "lambda_k1"))
+        - jnp.exp(dot("lambda_q2", "lambda_k2")) + lambda_init
+    )
+
+
+@scoped("diff_combine")
+def diff_combine(o, layer, lambda_init: float, eps: float):
+    """o (..., N, 2 H): query head ``2i``'s and ``2i + 1``'s attention
+    outputs over ``V`` -> (..., N / 2 * 2 H) float32: their difference
+    under ``lambda``, taken in float32, RMSNorm by head (``subln``) and
+    the ``1 - lambda_init`` factor."""
+    N, W = o.shape[-2:]
+    o = o.astype(jnp.float32).reshape(o.shape[:-2] + (N // 2, 2, W))
+    d = o[..., 0, :] - diff_lambda(layer, lambda_init) * o[..., 1, :]
+    d = rms_norm(d, layer["subln"], eps) * (1.0 - lambda_init)
+    return d.reshape(d.shape[:-2] + (N // 2 * W,))

@@ -232,13 +232,14 @@ def _paged_decode_kernel(
 def paged_attention_kernel(
     q, k_pages, v_pages, page_table, seq_lens, *,
     k_scales=None, v_scales=None, block_kv=None, interpret=None, scale=None,
-    nkv=None,
+    nkv=None, out_dtype=None,
 ):
     """Pallas ragged paged-attention decode; contract of
     :func:`paged_attention_reference` (same shapes, same masking rule;
     ``scale``: the scores' factor where it is not ``H ** -0.5``; ``nkv``:
     the pages come as the cells read them, (P, page_size * Nkv, H), and
-    this is their ``Nkv``).
+    this is their ``Nkv``; ``out_dtype``: the result's where it is not
+    the queries': float32 hands the cells' own accumulator out unrounded).
 
     Grid (B, ceil(maxp / pages_per_block)); the page table and row
     positions ride as scalar prefetch, and each of a cell's
@@ -323,7 +324,7 @@ def paged_attention_kernel(
             quantized=quantized,
         ),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, nq, hd), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, nq, hd), out_dtype or q.dtype),
         # scratch carries across the block walk; batch rows independent
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")

@@ -1,4 +1,4 @@
-"""Family adapters: one serving engine, seven model families.
+"""Family adapters: one serving engine, eight model families.
 
 The ServingEngine owns admission, continuous batching, eviction and
 metrics — none of which care what a "slot" stores. What differs per
@@ -46,6 +46,16 @@ family          decode-state per stream
                 ``B * x`` a slot, constant bytes whatever the context;
                 the expert layer is the code sarvam runs, every expert
                 held and no shared one
+``phi4flash``   all three kinds of state in one adapter: paged KV pages
+                declared over ONE layer (the full-attention layer's, the
+                only thing that grows) that the seven cross-attention
+                layers behind it read too; a ring of ``sliding_window``
+                keys and values a slot for the window layers; a
+                recurrent slab (conv window + fp32 state) a slot for the
+                Mamba-1 layers; the gated memory units and the cross
+                layers keep nothing. A prefill computes the first half
+                of the stack for every position and the second for the
+                last alone
 ==============  ========================================================
 
 Every adapter is parity-anchored: greedy decode through the engine is
@@ -73,6 +83,7 @@ from fms_fsdp_tpu.models.configs import (
     LlamaConfig,
     MambaConfig,
     MixtralConfig,
+    Phi4FlashConfig,
     SALA_MIXER_KINDS,
     SalaConfig,
     SarvamConfig,
@@ -84,7 +95,7 @@ from fms_fsdp_tpu.obs.spans import done, span
 # "serving"): family = FAMILY_CODES[name]
 FAMILY_CODES = {
     "llama": 0, "mamba": 1, "mixtral": 2, "sarvam": 3, "kexaone": 4,
-    "minicpm_sala": 5, "lfm2": 6,
+    "minicpm_sala": 5, "lfm2": 6, "phi4flash": 7,
 }
 FAMILY_NAMES = {v: k for k, v in FAMILY_CODES.items()}
 
@@ -95,6 +106,7 @@ _CONFIG_FAMILIES = (
     (KExaoneConfig, "kexaone"),
     (SalaConfig, "minicpm_sala"),
     (Lfm2MoeConfig, "lfm2"),
+    (Phi4FlashConfig, "phi4flash"),
     (LlamaConfig, "llama"),
 )
 
@@ -128,7 +140,8 @@ def load_model_config(d: dict):
     "minicpm_sala"`` one (or ``"family": "minicpm_sala"``) to the
     minicpm_sala family through its own, a published ``"model_type":
     "lfm2_moe"`` one (or ``"family": "lfm2"``) to the lfm2 family through
-    its own. This is the single
+    its own, a published ``"model_type": "phi4flash"`` one (or ``"family":
+    "phi4flash"``) to the phi4flash family through its own. This is the single
     resolution point replica.py and the engine share — the two can no
     longer diverge on model construction (the PR-11 bug this replaces:
     replica.py:71 hardwired its own ``init_llama_params`` copy)."""
@@ -156,6 +169,10 @@ def load_model_config(d: dict):
         from fms_fsdp_tpu.models.configs import lfm2_moe_config
 
         return lfm2_moe_config(d)
+    if family == "phi4flash" or d.get("model_type") == "phi4flash":
+        from fms_fsdp_tpu.models.configs import phi4flash_config
+
+        return phi4flash_config(d)
     if family is None:
         if "d_model" in d or "n_layer" in d:
             family = "mamba"
@@ -210,6 +227,10 @@ def check_params_family(params, family: str) -> None:
         "operator_norm" in layers[0]
     ):
         actual = "lfm2"  # a list of layers, each behind its operator's norm
+    elif isinstance(layers, (list, tuple)) and layers and isinstance(
+        layers[0].get("norm"), dict
+    ):
+        actual = "phi4flash"  # a list of layers behind LayerNorms with a bias
     elif isinstance(layers, (list, tuple)):
         actual = "mamba"
     elif isinstance(layers, dict) and "wkv_a" in layers:
@@ -263,6 +284,10 @@ def init_params_for(model_cfg):
         from fms_fsdp_tpu.models.lfm2 import init_lfm2_params
 
         return lambda key: init_lfm2_params(key, model_cfg)
+    if family == "phi4flash":
+        from fms_fsdp_tpu.models.phi4flash import init_phi4flash_params
+
+        return lambda key: init_phi4flash_params(key, model_cfg)
     from fms_fsdp_tpu.models.llama import init_llama_params
 
     return lambda key: init_llama_params(key, model_cfg)
@@ -314,7 +339,7 @@ def block_paged_geometry(
 ):
     """``(page_size, block_kv, max_pages, num_pages)`` of the paged cache
     of a family whose decode kernel walks a stream's pages in cells
-    (kexaone, lfm2, minicpm_sala): ``page_size`` positions a page unless
+    (kexaone, lfm2, minicpm_sala, phi4flash): ``page_size`` positions a page unless
     ``scfg.page_size`` pins it, cells of up to ``DECODE_BLOCK_TOKENS``
     positions in whole pages of the ``longest`` run of pages a row
     attends (all a stream can hold, unless given)."""
@@ -407,6 +432,10 @@ def resolve_adapter(
         )
     elif family == "lfm2":
         from fms_fsdp_tpu.serve.families.lfm2 import Lfm2Adapter as cls
+    elif family == "phi4flash":
+        from fms_fsdp_tpu.serve.families.phi4flash import (
+            Phi4FlashAdapter as cls,
+        )
     else:
         from fms_fsdp_tpu.serve.families.llama import LlamaAdapter as cls
     return cls(params, model_cfg, serve_cfg, compute_dtype, registry)

@@ -608,3 +608,102 @@ def test_kexaone_programs_fit_beside_a_cache_of_each_kind_at_eight_layers(
     assert f"bf16[2,1,{top},8,128]" in ptext
     assert f"bf16[6,1,{top},8,128]" not in ptext
     assert "bf16[6,1,128,8,128]" in ptext  # the rings handed over
+
+
+def test_phi4flash_programs_fit_beside_ring_slab_and_one_layers_pool(
+    v5e, monkeypatch
+):
+    """The decode program (128 slots) and the prefill program of 4096
+    positions of the phi4flash cell at published widths, all 32 layers,
+    bfloat16. Recorded (PR 46): decode peak 13.25 GB with 0.44 GB of
+    temporaries beside 7.71 GB of weights, 2.68 GB of rings, 0.41 GB of
+    slabs and the 2.0 GB pool of **one layer**, all donated and written in
+    place; prefill peak 7.87 GB with 0.15 GB of temporaries, 13.0 GB
+    beside rings, slabs and pool of the 16.9 the compiler has: the pool
+    stands as the issue sized it. With the rings held by head, (8, 128,
+    512, 10, 128), the chip pads a position's ten rows to sixteen and
+    copies both rings whole every step (4.0 GB of temporaries, 16.3 GB in
+    all: refused); as rows of 128 lanes read by the ragged paged kernel
+    they are arguments aliased to results. 16 Mosaic calls a decode step:
+    the paged kernel over a slot's ring a window layer, over the one
+    layer's pages for the full layer and each cross layer; 17 a prefill:
+    the windowed flash kernel a window layer, the scan kernel a Mamba
+    layer."""
+    import json
+    import re
+
+    from fms_fsdp_tpu.models.phi4flash import init_phi4flash_params
+    from fms_fsdp_tpu.serve.engine import ServeConfig
+    from fms_fsdp_tpu.serve.families import load_model_config
+    from fms_fsdp_tpu.serve.families.phi4flash import (
+        cache_bytes,
+        decode_program,
+        page_geometry,
+        pool_row,
+        prefill_program,
+        state_shapes,
+    )
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    here = os.path.dirname(os.path.abspath(__file__))
+    with open(os.path.join(
+            here, "..", "benchmark", "configs",
+            "phi-4-mini-flash.1chip.json")) as f:
+        cfg = load_model_config(json.load(f))
+    with open(os.path.join(
+            here, "..", "benchmark", "workloads",
+            "phi-4-mini-flash.serve-reasoning-over.json")) as f:
+        scfg = ServeConfig(**json.load(f)["engine"])
+    assert (scfg.max_batch, scfg.prefill_bucket) == (128, 256)
+    bf16 = jnp.bfloat16
+    params = jax.tree.map(
+        lambda a: _sds(v5e, a.shape, a.dtype),
+        jax.eval_shape(
+            lambda k: init_phi4flash_params(k, cfg, bf16),
+            jax.random.PRNGKey(0),
+        ),
+    )
+    page, block_kv, max_pages, num_pages = page_geometry(cfg, scfg)
+    assert (page, block_kv) == (128, 512)
+    cost = cache_bytes(cfg, bf16)
+    assert cost["per_token"] == 5120
+    B, top = 128, 4096
+    shapes = state_shapes(cfg, B, bf16)
+    ring = shapes["ring_k"][0]
+    assert ring == (8, 128, 5120, 128)  # rows of 128 lanes, nothing padded
+    pool = (1, num_pages) + pool_row(cfg, page)
+    assert pool == (1, num_pages, 1280, 128)
+    held = B * cost["per_stream"] + num_pages * page * cost["per_token"]
+    decode = decode_program(cfg, scfg, page, block_kv, bf16).lower(
+        params, {k: _sds(v5e, *s) for k, s in shapes.items()},
+        {k: _sds(v5e, pool, bf16) for k in ("k", "v")},
+        _sds(v5e, (B, max_pages), jnp.int32), _sds(v5e, (B,), jnp.int32),
+        _sds(v5e, (B,), jnp.int32), _sds(v5e, (2,), jnp.uint32),
+    ).compile()
+    prefill = prefill_program(cfg, scfg, top, top, bf16).lower(
+        params, _sds(v5e, (1, top), jnp.int32), _sds(v5e, (1,), jnp.int32)
+    ).compile()
+    dm, pm = decode.memory_analysis(), prefill.memory_analysis()
+    hbm = 15.75 * 2**30
+    weights = cfg.n_params() * 2
+    assert dm.temp_size_in_bytes < 0.5e9
+    assert dm.peak_memory_in_bytes < weights + held + 0.6e9 < hbm
+    assert pm.temp_size_in_bytes < 0.2e9
+    assert pm.peak_memory_in_bytes + held < hbm - 2.0e9
+    dtext, ptext = decode.as_text(), prefill.as_text()
+    assert dtext.startswith("HloModule jit__step,")
+    assert ptext.startswith(f"HloModule jit__prefill_{top},")
+    # pool and rings are arguments aliased to results, never copied
+    for shape in (pool, ring):
+        dims = ",".join(map(str, shape))
+        assert f"bf16[{dims}]" in dtext
+        assert not re.search(r"= bf16\[%s\]\S* copy\(" % dims, dtext)
+    assert dtext.count("tpu_custom_call") == 8 + 8
+    assert ptext.count("tpu_custom_call") == 8 + 9
+    # the second half of the stack meets one row a prompt: no product of
+    # a gated memory unit's width over a chunk of rows that is not a
+    # Mamba layer's in_proj (9 layers of two) or its out_proj's operand
+    assert "bf16[1,4096,5120]" not in ptext  # nothing d_inner wide a prompt
+    # the one layer's keys and values for the pages, the state handed over
+    assert f"bf16[1,1,{top * 10},128]" in ptext
+    assert "bf16[8,1,5120,128]" in ptext and "f32[9,1,16,5120]" in ptext

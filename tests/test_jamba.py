@@ -531,3 +531,57 @@ def test_handoff_round_trip_of_a_mamba1_stream(params):
 
     with pytest.raises(HandoffError):
         src.adapter.check_handoff_header({**header, "ssm_layer": "Mamba2"})
+
+
+# sha256 of the programs' StableHLO at the parent of PR 46 (6140358), where
+# the Mamba-1 mixer stood in models/mamba.py and applied its three norms
+# unconditionally
+MIXER_MOVE_DIGESTS = {
+    "decode":
+        "a7aa5a2c4cb2921b8a993f866c91b024fdd5ec34bc930cc5fde50d480bd58ba3",
+    "prefill 16":
+        "ff09ea6bc9c191064ee0df4b6927ec347cd7ccf040100437599b65ed0ef17a72",
+    "prefill 48":
+        "e2e3b5adb7a5fb0e53f99d2071ce8d6beb307ebf96deb341f23a94eca0c4ce8e",
+}
+
+
+def _lowered_text(program):
+    from fms_fsdp_tpu.serve.families import mamba as A
+
+    sd = jax.ShapeDtypeStruct
+    scfg = ServeConfig(
+        max_batch=2, max_seq_len=64, page_size=8, compute_dtype="float32",
+        attn_impl="reference")
+    shapes = jax.eval_shape(_params)
+    if program == "decode":
+        ps, maxp, n = A.page_geometry(CFG, scfg)
+        a = CFG.attn_cfg
+        pool = sd((len(CFG.attn_layer_idx), n, ps, a.num_heads_kv, a.head_dim),
+                  jnp.float32)
+        state = jax.eval_shape(
+            lambda: M.init_mamba_decode_state(CFG, 2, jnp.float32))
+        return A.decode_program(CFG, scfg, ps, jnp.float32).lower(
+            shapes, state, {"k": pool, "v": pool}, sd((2, maxp), jnp.int32),
+            sd((2,), jnp.int32), sd((2,), jnp.int32), sd((2,), jnp.uint32)
+        ).as_text()
+    n = int(program.split()[1])
+    return A.prefill_program(CFG, scfg, n, n, jnp.float32).lower(
+        shapes, sd((1, n), jnp.int32), sd((1,), jnp.int32)).as_text()
+
+
+@pytest.mark.parametrize("program", sorted(MIXER_MOVE_DIGESTS))
+def test_jamba_programs_are_the_text_they_were(program, monkeypatch):
+    """The Mamba-1 mixer moved to models/mamba1.py and took a flag for
+    Jamba's three norms and one for handing its scan output out (the
+    phi4flash family runs it without the first and with the second):
+    jamba's decode program and its prefill programs, of one chunk and of
+    three, lower to the text they had."""
+    import hashlib
+
+    if jax.__version__ != "0.9.0":
+        pytest.skip("digests hold for jax 0.9.0")
+    monkeypatch.setattr(M, "PREFILL_CHUNK", 16)
+    text = _lowered_text(program)
+    assert hashlib.sha256(text.encode()).hexdigest() == MIXER_MOVE_DIGESTS[
+        program]
