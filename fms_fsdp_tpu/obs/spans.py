@@ -61,6 +61,9 @@ FIELDS = (
     # positions the first half of the stack computed and those the second
     "self_positions", "cross_positions",
     "live", "kv_tokens",  # of the decode step dispatched
+    # the blocks the ragged paged kernel's loops walk in it, for one layer
+    # that reads the pages (ops/paged_attention.py); 0 without that kernel
+    "attn_blocks",
     "live_chose",  # its live streams that chose their context
     "tokens",  # decode tokens committed in the step
     "pages_in_use",  # at publish
@@ -104,7 +107,7 @@ COUNTED = {
             "computed_tokens", "chose_tokens", "chosen_blocks",
             "self_positions", "cross_positions"),
         "prefill.dispatch": ("built",),
-        "decode": ("live", "kv_tokens"),
+        "decode": ("live", "kv_tokens", "attn_blocks"),
         "decode.dispatch": ("live_chose",),
         "admit.done": ("admitted",),
         "decode.commit.done": ("tokens",),

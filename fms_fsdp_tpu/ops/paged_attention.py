@@ -20,27 +20,51 @@ Two implementations, one contract:
   (the serve allocator points unwritten table slots at a pristine zero
   page), the reference path is **bit-identical** to dense decode — the
   correctness anchor tier-1 pins on CPU.
-- ``_paged_decode_kernel``: the Pallas kernel — grid (batch, kv block);
-  the page table rides as scalar prefetch so each of a cell's pages is
-  DMA'd straight from its pool page by a BlockSpec index map (no
-  contiguous copy ever materializes), with the FlashAttention-2 online
-  softmax accumulated in VMEM scratch across the block walk. A cell
-  holds every kv head of its pages — a block may not take one head out
-  of the (Nkv, H) minor tile on a TPU — and attends all query heads
-  against them in one pass, masking other heads' columns. Blocks past a
-  row's length run no compute (pl.when) and fetch no data (the index
-  map clamps onto the last live page — a repeat fetch the pipeline
-  elides), which is what makes the ragged batch one kernel call instead
-  of B.
+- ``_paged_decode_kernel``: the Pallas kernel. **A grid cell is a
+  stream** (a row of the batch): the cell itself loops over the row's
+  own live blocks of ``block_kv`` positions, ``0 .. seq_lens[b] //
+  block_kv``, with the FlashAttention-2 online softmax carried in VMEM
+  scratch, so a slot that holds no stream, or a short one, costs one grid
+  step and no more. (Until PR 47 the grid was (batch, every block a slot
+  could hold): a dead cell computed and fetched nothing and still cost
+  0.8 us on a v5e, 1.7 of the 2.3 ms of a call at the phi4flash cell's
+  128 slots of 16 blocks.) **The pools stay in HBM and a block's pages
+  are copied by hand**, straight from their pool pages through the table
+  in scalar memory, into one of two VMEM buffers: the next block's are
+  asked for before this one is multiplied, and behind a row's last block
+  the first block of the next row that walks, so that only the call's
+  first fetch is waited for. Pages past a row's last live one are not
+  fetched at all (the buffers are zeroed once a call: what a buffer held
+  may be no number). No contiguous copy of a sequence ever materializes.
+  **A product takes the heads of one 32-bit word.** A page arrives as
+  its (page_size * Nkv, H) row-major view, row ``t * Nkv + h`` token t
+  of kv head h; one head's rows lie ``Nkv`` rows apart, and the chip
+  reads rows that far apart in words of 32 bits (a block of a BlockSpec
+  or a copy may not take one head out of the (Nkv, H) minor tile; a
+  strided read of the block in VMEM may). A word holds two neighbouring
+  bfloat16 rows, so a product multiplies a *pair* of kv heads' rows of a
+  whole block against that pair's query rows and masks the other head's
+  columns with the out-of-range positions: scores of ``2 * group x 2 *
+  block`` where every head at once made ``Nq x Nkv * page`` (float32
+  pools: one head a product; an odd ``Nkv``, and int8/fp8 pools, whose
+  scales lie a position and head along the lanes: every head at once, a
+  page a product, as before). On the chip, alone, at the phi4flash
+  cell's shapes and a window's lengths (PERF.md section 6, PR 47): the
+  walk with every head at once 1.16 ms a call where the grid took 2.32;
+  a pair a block 1.11; a pair a page 1.84 (twenty small products a block
+  do not overlap); the other routes, a strided copy that lands a head's
+  rows apart or a page whose rows lie head by head, were not built: a
+  copy cannot split the two rows of a word, and no cell asked for a new
+  pool layout.
 
 Tile resolution (page_size at allocator build, block_kv per call) goes
 through the tuning table (fms_fsdp_tpu/tune/lookup.py::
 resolve_paged_decode) like every other kernel. ``block_kv`` may be any
-multiple of ``page_size`` (the cell fetches ``block_kv // page_size``
-pool pages), and int8/fp8-quantized pools are read natively — the
-per-page scale blocks are fetched beside the pages and applied to the
-scores and probabilities in VMEM, so quantized serving does not fall
-back to the reference gather.
+multiple of ``page_size`` (the positions of one hand-fetched block, two
+of which are resident beside the scores), and int8/fp8-quantized pools
+are read natively — the per-page scale rows are fetched beside the pages
+and applied to the scores and probabilities in VMEM, so quantized serving
+does not fall back to the reference gather.
 """
 
 import functools
@@ -131,102 +155,196 @@ def paged_attention_reference(q, k_pages, v_pages, page_table, seq_lens):
 # ---------------------------------------------------------------------------
 
 
+def _heads_per_product(nkv, itemsize, quantized):
+    """How many of a position's ``nkv`` heads one product takes. A page's
+    row ``t * nkv + h`` is position t of kv head h, so one head's rows
+    lie ``nkv`` rows apart: a strided read of the block in VMEM, which
+    the chip makes in words of 32 bits. A word holds one float32 row or
+    two neighbouring bfloat16 rows, so a product takes that many heads
+    where they divide ``nkv``; else (and under scales, which lie a
+    position and head along the lanes) every head at once, the other
+    heads' columns masked."""
+    per_word = 4 // itemsize
+    if quantized or per_word > 2 or nkv % per_word:
+        return nkv
+    return per_word
+
+
+def _positions_per_product(hg, quantized, block_kv, page_size):
+    """The positions whose rows one product takes: a block's, but a page's
+    where scales come a page or every head of more than two is multiplied
+    at once (a page's columns a head are a block's worth already)."""
+    return block_kv if hg <= 2 and not quantized else page_size
+
+
 def _paged_decode_kernel(
     lens_ref,  # scalar prefetch: (B,) int32 query positions
     table_ref,  # scalar prefetch: (B, maxp) int32 page table
-    q_ref,  # (1, Nq, H)
-    *rest,  # ppb k pages, ppb v pages (, ppb k scales, ppb v scales); o; scratch
+    next_ref,  # scalar prefetch: (B + 1,) int32, the next row that walks
+    q_ref,  # (1, G, R, H): G products' query rows, R = hg * group
+    *rest,  # k, v (, k scales, v scales) in HBM; o; their buffers; scratch
     page_size,
     pages_per_block,
     nkv,
+    hg,
+    chunk,
     scale,
     quantized,
 ):
-    """One (batch row, kv block) grid cell: ``pages_per_block`` pool pages,
-    every kv head at once.
+    """One grid cell is one stream (a row of the batch): the cell loops
+    over the row's live blocks of ``pages_per_block`` pages, the online
+    softmax carried in VMEM scratch, and every other row costs a grid
+    step and nothing else.
 
-    A page arrives as its (page_size * Nkv, H) row-major view — row
-    ``t * Nkv + h`` is token t of kv head h — because a cell may not take
-    one head out of the (Nkv, H) minor tile (Mosaic refuses the slice).
-    All Nq query heads multiply against all of those rows in one MXU pass
-    and the columns belonging to another kv head are masked with the
-    out-of-range positions, so each query row's softmax runs over exactly
-    its own head's tokens. No per-head loop, no strided load.
-    """
+    The pools stay in HBM. A block's live pages are copied by hand into
+    one of two VMEM buffers, the next block's asked for before this one
+    is multiplied, and behind a row's last block the first block of the
+    next row that walks (``next_ref``), so no row but the first waits
+    for a fetch. A page arrives as its (page_size * Nkv, H) row-major
+    view, row ``t * Nkv + h`` token t of kv head h. A product takes
+    ``hg`` heads' rows of ``chunk`` positions out of the buffer
+    (``_heads_per_product``: a strided read) against those heads' query
+    rows alone, and masks the columns of the other ``hg - 1`` heads
+    with the positions past the row's."""
     ppb = pages_per_block
-    k_refs, v_refs = rest[:ppb], rest[ppb : 2 * ppb]
-    rest = rest[2 * ppb :]
-    if quantized:
-        ks_refs, vs_refs = rest[:ppb], rest[ppb : 2 * ppb]
-        rest = rest[2 * ppb :]
-    o_ref, acc_ref, m_ref, l_ref = rest
+    n_ops = 4 if quantized else 2
+    hbm, o_ref = rest[:n_ops], rest[n_ops]
+    bufs = rest[n_ops + 1 : 2 * n_ops + 1]
+    sem, slot_ref, acc_ref, m_ref, l_ref = rest[2 * n_ops + 1 :]
 
     b = pl.program_id(0)
-    j = pl.program_id(1)
+    rows_n = pl.num_programs(0)
     pos = lens_ref[b]  # query position; attends to cache idx <= pos
     block = ppb * page_size
-    nq = q_ref.shape[1]
-    group = nq // nkv
-    cols = page_size * nkv
+    page_rows = page_size * nkv
+    G, R, hd = q_ref.shape[1:]
+    group = R // hg
 
-    @pl.when(j == 0)
+    def fetch(row, j, slot, wait):
+        """Start (or wait for) the copies of block ``j`` of ``row``, its
+        pages up to the row's last live one, into ``slot``."""
+        last = lens_ref[row] // page_size
+        for i in range(ppb):
+            page = j * ppb + i
+
+            @pl.when(page <= last)
+            def _():
+                pid = table_ref[row, page]
+                for n, (src, buf) in enumerate(zip(hbm, bufs)):
+                    dst = (
+                        buf.at[slot, pl.ds(i * page_rows, page_rows)]
+                        if n < 2 else buf.at[slot, i]
+                    )
+                    copy = pltpu.make_async_copy(
+                        src.at[pid], dst, sem.at[n, slot]
+                    )
+                    if wait:
+                        copy.wait()
+                    else:
+                        copy.start()
+
+    @pl.when(b == 0)
+    def _():
+        # a page past a row's last is never fetched; what a buffer held
+        # before the call may be no number, and 0 * NaN is NaN
+        for buf in bufs:
+            buf[...] = jnp.zeros(buf.shape, buf.dtype)
+        slot_ref[0] = 0
+
+        @pl.when(next_ref[0] < rows_n)
+        def _():
+            fetch(next_ref[0], 0, 0, wait=False)
+
+    def heads(buf, slot, c, g):
+        """``hg`` heads' rows of chunk ``c`` of the block in ``slot``:
+        (chunk * hg, H), row ``t * hg + h``."""
+        if hg == nkv:
+            return buf[slot, pl.ds(c * chunk * nkv, chunk * nkv), :]
+        per = nkv // hg
+        at = pl.ds(c * chunk * per + g, chunk, stride=per)
+        if buf.dtype.itemsize == 4:
+            return buf[slot, at, :]
+        return pltpu.bitcast(buf.bitcast(jnp.uint32)[slot, at, :], buf.dtype)
+
+    def multiply(j, slot):
+        cols = chunk * hg
+        col = jax.lax.broadcasted_iota(jnp.int32, (R, cols), 1)
+        tok = col // hg
+        if hg > 1:
+            row = jax.lax.broadcasted_iota(jnp.int32, (R, cols), 0)
+            own_head = row // group == col % hg
+        for c in range(block // chunk):
+            first = j * block + c * chunk
+
+            @pl.when(first <= pos)  # else no position of the chunk is seen
+            def _():
+                live = first + tok <= pos
+                if hg > 1:
+                    live &= own_head
+                for g in range(G):
+                    # scale + change of base folded into q; exp2 replaces
+                    # exp in the online softmax (ops/flash_attention.py)
+                    q = (q_ref[0, g] * (scale * LOG2E)).astype(q_ref.dtype)
+                    k = heads(bufs[0], slot, c, g)
+                    v = heads(bufs[1], slot, c, g)
+                    if quantized:
+                        # int8/fp8 values are exact in the compute dtype;
+                        # the per-row absmax scales fold into the score
+                        # columns and the probabilities instead of a
+                        # (ps*Nkv, H) dequantize
+                        k = k.astype(q.dtype)
+                        v = v.astype(q.dtype)
+                    s = jax.lax.dot_general(
+                        q, k, (((1,), (1,)), ((), ())),
+                        preferred_element_type=jnp.float32,
+                    )  # (R, cols), base-2 domain
+                    if quantized:
+                        s = s * bufs[2][slot, c]
+                    s = jnp.where(live, s, NEG_INF)
+                    m = m_ref[g]
+                    m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+                    p = jnp.exp2(s - m_new)
+                    alpha = jnp.exp2(m - m_new)
+                    l_ref[g] = l_ref[g] * alpha + jnp.sum(
+                        p, axis=1, keepdims=True
+                    )
+                    if quantized:
+                        p = p * bufs[3][slot, c]
+                    pv = jax.lax.dot_general(
+                        p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                        preferred_element_type=jnp.float32,
+                    )
+                    acc_ref[g] = acc_ref[g] * alpha + pv
+                    m_ref[g] = m_new
+
+    @pl.when(pos < 0)
+    def _():
+        # a row at a negative position attends nothing: zeros, not 0/0
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    @pl.when(pos >= 0)
     def _():
         acc_ref[...] = jnp.zeros_like(acc_ref)
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
+        blocks = pos // block + 1
+        behind = next_ref[b + 1]
 
-    # blocks holding no position <= pos run no compute (and fetched no
-    # data: the index maps clamped them onto the last live page)
-    @pl.when(j * block <= pos)
-    def _():
-        # scale + change of base folded into q; exp2 replaces exp in the
-        # online softmax (same trick as ops/flash_attention.py)
-        q = (q_ref[0] * (scale * LOG2E)).astype(q_ref.dtype)  # (Nq, H)
-        col = jax.lax.broadcasted_iota(jnp.int32, (nq, cols), 1)
-        row = jax.lax.broadcasted_iota(jnp.int32, (nq, cols), 0)
-        tok = col // nkv
-        first_q = (col % nkv) * group  # first query head of the column's kv head
-        own_head = (row >= first_q) & (row < first_q + group)
-        m, l, acc = m_ref[...], l_ref[...], acc_ref[...]
-        for i in range(ppb):
-            k = k_refs[i][0]  # (ps*Nkv, H), storage dtype
-            v = v_refs[i][0]
-            if quantized:
-                # int8/fp8 values are exact in the compute dtype; the
-                # per-row absmax scales fold into the score columns and
-                # the probabilities instead of a (ps*Nkv, H) dequantize
-                k = k.astype(q.dtype)
-                v = v.astype(q.dtype)
-            s = jax.lax.dot_general(
-                q, k, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )  # (Nq, ps*Nkv), base-2 domain
-            if quantized:
-                s = s * ks_refs[i][0]
-            kpos = (j * ppb + i) * page_size + tok
-            s = jnp.where(own_head & (kpos <= pos), s, NEG_INF)
-            m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
-            p = jnp.exp2(s - m_new)
-            alpha = jnp.exp2(m - m_new)
-            l = l * alpha + jnp.sum(p, axis=1, keepdims=True)
-            if quantized:
-                p = p * vs_refs[i][0]
-            pv = jax.lax.dot_general(
-                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
-            acc = acc * alpha + pv
-            m = m_new
-        m_ref[...], l_ref[...], acc_ref[...] = m, l, acc
+        def walk(j, slot):
+            @pl.when(j + 1 < blocks)
+            def _():
+                fetch(b, j + 1, 1 - slot, wait=False)
 
-    @pl.when(j == pl.num_programs(1) - 1)
-    def _():
-        l = l_ref[...]
-        # a row that attended nothing (a negative position) has l == 0;
-        # emit zeros, not 0/0 NaN — its output is discarded either way
-        # but NaN would trip downstream finiteness guards
-        safe = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0] = (acc_ref[...] / safe).astype(o_ref.dtype)
+            @pl.when((j + 1 == blocks) & (behind < rows_n))
+            def _():
+                fetch(behind, 0, 1 - slot, wait=False)
+
+            fetch(b, j, slot, wait=True)
+            multiply(j, slot)
+            return 1 - slot
+
+        slot_ref[0] = jax.lax.fori_loop(0, blocks, walk, slot_ref[0])
+        o_ref[0] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
 
 
 def paged_attention_kernel(
@@ -241,16 +359,15 @@ def paged_attention_kernel(
     this is their ``Nkv``; ``out_dtype``: the result's where it is not
     the queries': float32 hands the cells' own accumulator out unrounded).
 
-    Grid (B, ceil(maxp / pages_per_block)); the page table and row
-    positions ride as scalar prefetch, and each of a cell's
-    ``block_kv // page_size`` pages is its own BlockSpec operand whose
-    index map reads the pool page out of the table — so every fetch goes
-    through the Pallas pipeline (double-buffered, repeat fetches of a
-    clamped dead page elided) and no contiguous copy of a sequence ever
-    materializes. Quantized pools carry ``k_scales``/``v_scales``
-    (per-row absmax, see ops/quant.py), fetched the same way.
-    Online-softmax state lives in VMEM scratch across the block walk
-    (the ``arbitrary`` grid dim).
+    Grid (B,): a cell is a row, which walks its own live blocks of
+    ``block_kv`` positions (``_paged_decode_kernel``); the page table,
+    the row positions and each row's successor among the rows at a
+    position >= 0 ride as scalar prefetch. The pools are left where they
+    lie and a block's pages copied by hand, so no contiguous copy of a
+    sequence ever materializes. Quantized pools carry
+    ``k_scales``/``v_scales`` (per-row absmax, see ops/quant.py), fetched
+    the same way. The cells run in order on one core (``arbitrary``):
+    each leaves the next one's first fetch in flight.
     """
     b, nq, hd = q.shape
     if nkv is None:
@@ -258,7 +375,6 @@ def paged_attention_kernel(
     else:
         assert k_scales is None, "scales come a position and kv head"
         num_pool_pages, page_size = k_pages.shape[0], k_pages.shape[1] // nkv
-    maxp = page_table.shape[1]
     if interpret is None:
         interpret = interpret_default()
     if block_kv is None:
@@ -270,48 +386,72 @@ def paged_attention_kernel(
         )
     if (k_scales is None) != (v_scales is None):
         raise ValueError("k_scales and v_scales must be passed together")
-    quantized = k_scales is not None
-    ppb = block_kv // page_size
     cols = page_size * nkv
-
-    def page_map(i):
-        def index_map(b_, j_, lens, table):
-            # clamp dead slots onto the row's last live page (repeat fetch)
-            last = jnp.maximum(lens[b_], 0) // page_size
-            return (table[b_, jnp.minimum(j_ * ppb + i, last)], 0, 0)
-
-        return index_map
-
-    def row_map(b_, j_, *_):
-        return (b_, 0, 0)
-
-    def page_specs(rows, width):
-        return [
-            pl.BlockSpec((1, rows, width), page_map(i)) for i in range(ppb)
-        ]
-
-    # (P, ps, Nkv, H) -> (P, ps*Nkv, H): the trailing block dims equal the
-    # array's, which is what the TPU lowering requires of a block that
-    # is not (8, 128)-aligned
+    # (P, ps, Nkv, H) -> (P, ps*Nkv, H): a page as one run of rows
     # (a no-op where ``nkv`` said that the pages come so)
-    operands = [k_pages.reshape(num_pool_pages, cols, hd)] * ppb
-    operands += [v_pages.reshape(num_pool_pages, cols, hd)] * ppb
-    in_specs = [pl.BlockSpec((1, nq, hd), row_map)]
-    in_specs += page_specs(cols, hd) * 2
-    if quantized:
-        operands += [k_scales.reshape(num_pool_pages, 1, cols)] * ppb
-        operands += [v_scales.reshape(num_pool_pages, 1, cols)] * ppb
-        in_specs += page_specs(1, cols) * 2
+    operands = [
+        k_pages.reshape(num_pool_pages, cols, hd),
+        v_pages.reshape(num_pool_pages, cols, hd),
+    ]
+    if k_scales is not None:
+        operands += [
+            k_scales.reshape(num_pool_pages, 1, cols),
+            v_scales.reshape(num_pool_pages, 1, cols),
+        ]
+    return _paged_decode(
+        q, seq_lens.astype(jnp.int32), page_table.astype(jnp.int32),
+        *operands, nkv=nkv, block_kv=block_kv, interpret=interpret,
+        scale=hd**-0.5 if scale is None else scale,
+        out_dtype=jnp.dtype(out_dtype or q.dtype),
+    )
 
+
+# jitted, so that a program whose layers call it with one signature lowers
+# the kernel once
+@functools.partial(
+    jax.jit,
+    static_argnames=(
+        "nkv", "block_kv", "interpret", "scale", "out_dtype"),
+)
+def _paged_decode(
+    q, seq_lens, page_table, k_pages, v_pages, *scales, nkv, block_kv,
+    interpret, scale, out_dtype,
+):
+    b, nq, hd = q.shape
+    cols = k_pages.shape[1]
+    page_size = cols // nkv
+    ppb = block_kv // page_size
+    quantized = bool(scales)
+    store = k_pages.dtype
+    hg = _heads_per_product(nkv, store.itemsize, quantized)
+    chunk = _positions_per_product(hg, quantized, block_kv, page_size)
+    G, R = nkv // hg, hg * (nq // nkv)
+
+    # of each row, the next one that walks (B where none does); [0] the first
+    ids = jnp.where(
+        seq_lens >= 0, jnp.arange(b, dtype=jnp.int32), jnp.int32(b))
+    walks = jnp.concatenate([
+        jax.lax.cummin(ids, reverse=True), jnp.full((1,), b, jnp.int32)])
+
+    def row_map(b_, *_):
+        return (b_, 0, 0, 0)
+
+    buffers = [pltpu.VMEM((2, ppb * cols, hd), store)] * 2
+    if quantized:
+        buffers += [pltpu.VMEM((2, ppb, 1, cols), scales[0].dtype)] * 2
+    resident = 2 * 2 * ppb * cols * hd * store.itemsize
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(b, -(-maxp // ppb)),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, nq, hd), row_map),
-        scratch_shapes=[
-            pltpu.VMEM((nq, hd), jnp.float32),
-            pltpu.VMEM((nq, 1), jnp.float32),
-            pltpu.VMEM((nq, 1), jnp.float32),
+        num_scalar_prefetch=3,
+        grid=(b,),
+        in_specs=[pl.BlockSpec((1, G, R, hd), row_map)]
+        + [pl.BlockSpec(memory_space=pl.ANY)] * (2 + len(scales)),
+        out_specs=pl.BlockSpec((1, G, R, hd), row_map),
+        scratch_shapes=buffers + [
+            pltpu.SemaphoreType.DMA((2 + len(scales), 2)),
+            pltpu.SMEM((1,), jnp.int32),
+            pltpu.VMEM((G, R, hd), jnp.float32),
+            pltpu.VMEM((G, R, 1), jnp.float32),
+            pltpu.VMEM((G, R, 1), jnp.float32),
         ],
     )
     out = pl.pallas_call(
@@ -320,17 +460,22 @@ def paged_attention_kernel(
             page_size=page_size,
             pages_per_block=ppb,
             nkv=nkv,
-            scale=hd**-0.5 if scale is None else scale,
+            hg=hg,
+            chunk=chunk,
+            scale=scale,
             quantized=quantized,
         ),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, nq, hd), out_dtype or q.dtype),
-        # scratch carries across the block walk; batch rows independent
+        out_shape=jax.ShapeDtypeStruct((b, G, R, hd), out_dtype),
+        # a cell leaves the next one's first block in flight
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=resident + 16 * 2**20,
         ),
         interpret=interpret,
-    )(seq_lens.astype(jnp.int32), page_table.astype(jnp.int32), q, *operands)
+        name="paged_attention_decode",
+    )(seq_lens, page_table, walks, q.reshape(b, G, R, hd),
+      k_pages, v_pages, *scales)
     return out.reshape(b, nq * hd)
 
 
@@ -409,9 +554,11 @@ def _latent_decode_kernel(
 ):
     """One (batch row, block of pages) grid cell of absorbed latent
     attention: every query head against the same ``W``-wide rows, whose
-    first ``value_width`` lanes are also the value. ``_paged_decode_kernel``
-    with one kv head, one operand for keys and values, and no columns to
-    mask but the positions past the row's."""
+    first ``value_width`` lanes are also the value: one kv head, one
+    operand for keys and values, and no columns to mask but the positions
+    past the row's. The grid is still every block a slot could hold, the
+    pages BlockSpec operands, as ``_paged_decode_kernel``'s was until
+    PR 47 made its cell a stream."""
     ppb = pages_per_block
     page_refs = rest[:ppb]
     o_ref, acc_ref, m_ref, l_ref = rest[ppb:]

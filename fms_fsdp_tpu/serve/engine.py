@@ -239,6 +239,9 @@ class ServingEngine:
             self.adapter.moe_expert_reads_per_layer
         )
         self.registry.gauge("serve.ssm_layers").set(self.adapter.ssm_layers)
+        self.registry.gauge("serve.decode_attn_grid_blocks").set(
+            self.adapter.attn_grid_blocks
+        )
         self.registry.gauge("serve.ssm_state_bytes_per_stream").set(
             self.adapter.state_bytes_per_stream
         )
@@ -972,12 +975,23 @@ class ServingEngine:
             step=it,
             live=len(active),
             kv_tokens=int(sum(self._lens[slot] for slot, _ in active)),
+            attn_blocks=self._attn_blocks(active),
         ):
             prev, self._inflight = self._inflight, None
             if active:
                 self._inflight = self._dispatch(active, prev is not None)
             if prev is not None:
                 self._commit(prev)
+
+    def _attn_blocks(self, active) -> int:
+        """The blocks the ragged paged kernel walks in a decode step over
+        ``active`` (the adapter's count, 0 without that kernel), counted
+        into ``serve.decode_attn_blocks`` too."""
+        blocks = self.adapter.attn_blocks(
+            [self._lens[slot] for slot, _ in active]
+        )
+        self.registry.counter("serve.decode_attn_blocks").add(blocks)
+        return blocks
 
     def _busy(self) -> int:
         """Slots that hold a stream."""
@@ -1086,6 +1100,7 @@ class ServingEngine:
             step=it,
             live=len(active),
             kv_tokens=int(sum(self._lens[slot] for slot, _ in active)),
+            attn_blocks=self._attn_blocks(active),
         ):
             emit, counts, logits = self.adapter.decode_spec(
                 self._slot_rids(active), self._lens, self._tokens

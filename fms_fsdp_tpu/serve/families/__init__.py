@@ -327,9 +327,10 @@ def paged_geometry(scfg, nheads: int, n_kv_heads: int, head_dim: int,
 
 # positions a page of a family's own pools holds unless ``scfg.page_size``
 # pins it (one page of 8 kv heads of 128 is 256 KB of keys in bfloat16, one
-# fetch of the decode kernel), and the positions one cell of that kernel's
-# grid walks: a cell costs about as much as a page's fetch, so a stream's
-# 132 pages are walked four at a time
+# fetch of the decode kernel), and the positions of one block that kernel
+# fetches by hand and multiplies at once: two blocks are resident in VMEM
+# (5.2 MB at ten rows of 128 lanes a position) while a stream's pages are
+# walked four at a time
 PAGE_SIZE = 128
 DECODE_BLOCK_TOKENS = 512
 
@@ -338,9 +339,9 @@ def block_paged_geometry(
     model_cfg, scfg, page_size: int = PAGE_SIZE, longest: int = 0
 ):
     """``(page_size, block_kv, max_pages, num_pages)`` of the paged cache
-    of a family whose decode kernel walks a stream's pages in cells
+    of a family whose decode kernel walks a stream's pages in blocks
     (kexaone, lfm2, minicpm_sala, phi4flash): ``page_size`` positions a page unless
-    ``scfg.page_size`` pins it, cells of up to ``DECODE_BLOCK_TOKENS``
+    ``scfg.page_size`` pins it, blocks of up to ``DECODE_BLOCK_TOKENS``
     positions in whole pages of the ``longest`` run of pages a row
     attends (all a stream can hold, unless given)."""
     import dataclasses
@@ -544,6 +545,35 @@ class FamilyAdapter:
         self._table_key = None
         self._table_dev = None
         self._setup()
+
+    # -- what the ragged paged decode kernel walks -------------------------
+
+    @property
+    def attn_block(self) -> int:
+        """The positions of one block of the ragged paged decode kernel's
+        walk (ops/paged_attention.py); 0 where the decode program does
+        not run that kernel."""
+        return self.block_kv if self.attn_impl == "kernel" else 0
+
+    def attn_blocks(self, lens) -> int:
+        """The blocks that kernel's loops walk in a step over live streams
+        whose queries sit at positions ``lens``, for one layer that reads
+        the pages: a stream's live blocks, the last one partly filled."""
+        block = self.attn_block
+        return sum(int(n) // block + 1 for n in lens) if block else 0
+
+    @property
+    def attn_grid_blocks(self) -> int:
+        """What a grid of every block a slot could hold walks a call (the
+        kernel's grid before a cell was a stream): ``max_batch x
+        max_pages / pages a block``. Over ``attn_blocks`` of a step: how
+        much of that grid was dead."""
+        block = self.attn_block
+        if not block:
+            return 0
+        return self.scfg.max_batch * -(
+            -self.max_pages // (block // self.page_size)
+        )
 
     def _setup(self) -> None:
         """The family's refusals, state (``_init_pages``, ``_state``) and

@@ -288,6 +288,34 @@ class MiniCPMSalaAdapter(FamilyAdapter):
             context_blocks=context, multiplied_blocks=multiplied,
         )
 
+    # -- what the ragged paged decode kernel walks: the chosen pages ------
+
+    def attn_blocks(self, lens) -> int:
+        """A row of the kernel is a (stream, kv head) and its length the
+        chosen positions (``chosen_pages_attention``): every block up to
+        the query's own while it is dense, ``topk`` of them past that."""
+        block = self.attn_block
+        if not block:
+            return 0
+        sp, ps = self.model_cfg.sparse, self.page_size
+        total = 0
+        for t in map(int, lens):
+            n = t // ps + 1
+            if t + 1 > sp.dense_len:
+                n = min(n, sp.topk)
+            total += ((n - 1) * ps + t % ps) // block + 1
+        return total * self.model_cfg.kvheads
+
+    @property
+    def attn_grid_blocks(self) -> int:
+        block = self.attn_block
+        if not block:
+            return 0
+        width = min(self.model_cfg.sparse.list_blocks, self.max_pages)
+        return self.scfg.max_batch * self.model_cfg.kvheads * -(
+            -width // (block // self.page_size)
+        )
+
     # -- decode: the step's count beside the skeleton's dispatch -----------
 
     def decode_dispatch(

@@ -25,10 +25,10 @@ document:
 - fused CE (ops/fused_ce.py): an XLA scan, not a Pallas kernel — the
   constraint is the fp32 (chunk, V) logits tile (one live in fwd, two in
   bwd: p and d_logits), budgeted against HBM headroom rather than VMEM.
-- paged decode (ops/paged_attention.py): per grid cell one
-  (block_kv * Nkv, H) k and v block (double-buffered), the (Nq, H) q/o
-  blocks, and the fp32 online-softmax scratch — O(block) residency like
-  the kvgrid family, plus the scalar-prefetched page table in SMEM.
+- paged decode (ops/paged_attention.py): one (block_kv * Nkv, H) k and v
+  block in each of the two buffers the kernel fills by hand, the (Nq, H)
+  q/o blocks, and the fp32 online-softmax scratch — O(block) residency
+  like the kvgrid family, plus the scalar-prefetched page table in SMEM.
 """
 
 from typing import Dict, List, Optional
@@ -389,10 +389,10 @@ def paged_decode_vmem_bytes(sig: Dict[str, int], dtype: str,
 def paged_decode_candidates(sig: Dict[str, int], dtype: str,
                             chip: str) -> List[Dict]:
     """Legal (page_size, block_kv) tiles under the VMEM budget. The
-    kernel fetches ``block_kv // page_size`` pool pages per grid step,
-    so enumeration covers block_kv multiples of page_size — more
-    positions per cell amortize the per-step grid overhead at the price
-    of a wider VMEM block."""
+    kernel fetches ``block_kv // page_size`` pool pages a block of a
+    stream's walk, so enumeration covers block_kv multiples of page_size
+    — more positions a block amortize the per-block overhead at the price
+    of a wider VMEM buffer."""
     budget = vmem_budget(chip)
     out = []
     for ps in _PAGE_SIZE_CHOICES:

@@ -707,3 +707,89 @@ def test_phi4flash_programs_fit_beside_ring_slab_and_one_layers_pool(
     # the one layer's keys and values for the pages, the state handed over
     assert f"bf16[1,1,{top * 10},128]" in ptext
     assert "bf16[8,1,5120,128]" in ptext and "f32[9,1,16,5120]" in ptext
+
+
+def test_lfm2_decode_program_walks_its_pages_and_copies_no_pool(
+    v5e, monkeypatch
+):
+    """The decode program of the lfm2 cell (128 slots, layers 0-9, all 64
+    experts, bfloat16) with the ragged paged kernel a cell a stream: the
+    two attention layers' pools, (2, pages, 512, 128), are arguments
+    aliased to results and read where they lie, two Mosaic calls a step.
+    A pool is 1.0 GB: temporaries under 0.1 GB mean that neither the
+    hand-made copies of the kernel nor the view of two layers as one run
+    of pages made the compiler lay one out again."""
+    import json
+    import re
+
+    from fms_fsdp_tpu.models.lfm2 import init_lfm2_params
+    from fms_fsdp_tpu.serve.engine import ServeConfig
+    from fms_fsdp_tpu.serve.families import load_model_config
+    from fms_fsdp_tpu.serve.families.lfm2 import (
+        decode_program,
+        page_geometry,
+        window_shape,
+    )
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    here = os.path.dirname(os.path.abspath(__file__))
+    with open(os.path.join(
+            here, "..", "benchmark", "configs",
+            "lfm2-24b-a2b.1chip.json")) as f:
+        cfg = load_model_config(json.load(f))
+    with open(os.path.join(
+            here, "..", "benchmark", "workloads",
+            "lfm2-24b-a2b.serve-turns-over.json")) as f:
+        scfg = ServeConfig(**json.load(f)["engine"])
+    bf16 = jnp.bfloat16
+    params = jax.tree.map(
+        lambda a: _sds(v5e, a.shape, a.dtype),
+        jax.eval_shape(
+            lambda k: init_lfm2_params(k, cfg, bf16), jax.random.PRNGKey(0)
+        ),
+    )
+    page, block_kv, max_pages, num_pages = page_geometry(cfg, scfg)
+    assert (page, block_kv, scfg.max_batch) == (128, 512, 128)
+    B = scfg.max_batch
+    pool = (2, num_pages, page * 4, 128)
+    decode = decode_program(cfg, scfg, page, block_kv, bf16).lower(
+        params, {"z": _sds(v5e, window_shape(cfg, scfg), bf16)},
+        {k: _sds(v5e, pool, bf16) for k in ("k", "v")},
+        _sds(v5e, (B, max_pages), jnp.int32), _sds(v5e, (B,), jnp.int32),
+        _sds(v5e, (B,), jnp.int32), _sds(v5e, (2,), jnp.uint32),
+    ).compile()
+    m = decode.memory_analysis()
+    assert m.temp_size_in_bytes < 0.1e9
+    text = decode.as_text()
+    assert text.startswith("HloModule jit__step,")
+    dims = ",".join(map(str, pool))
+    assert f"bf16[{dims}]" in text
+    assert not re.search(r"= bf16\[%s\]\S* copy\(" % dims, text)
+
+
+def test_sala_decode_kernel_call_compiles_at_32_slots_of_2_kv_heads(
+    v5e, monkeypatch
+):
+    """The sala decode step's attention at the cell's sizes: 32 slots of 2
+    kv heads are 64 rows of the ragged kernel, 16 query heads a row over
+    pages of 64 positions of one head, each row's table its 128 chosen
+    pages and blocks of 512 positions; the pools of the two sparse
+    layers' four kv heads (1.6 GB each) are read where they lie."""
+    from fms_fsdp_tpu.ops.paged_attention import chosen_pages_attention
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    slots, nkv, g, hd, page, pages = 32, 2, 16, 128, 64, 24576
+    pool = _sds(v5e, (4 * pages, page, 1, hd), jnp.bfloat16)
+
+    def attend(q, k, v, table, lens, blocks, n, first):
+        return chosen_pages_attention(
+            q, k, v, table, lens, blocks, n, first_page=first, block_kv=512)
+
+    compiled = _compile(
+        attend,
+        _sds(v5e, (slots, nkv, g, hd), jnp.bfloat16), pool, pool,
+        _sds(v5e, (slots, 65536 // page), jnp.int32),
+        _sds(v5e, (slots,), jnp.int32),
+        _sds(v5e, (slots, nkv, 128), jnp.int32),
+        _sds(v5e, (slots, nkv), jnp.int32), _sds(v5e, (nkv,), jnp.int32))
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.05e9

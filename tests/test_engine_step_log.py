@@ -437,3 +437,18 @@ def test_no_session_nothing_is_handed(params):
     eng = engine(params)
     serve(eng)
     assert eng.step_log._given is None and len(eng.step_log) == eng.iterations
+
+
+# -- (f) the blocks the ragged paged decode kernel walks ----------------------
+
+
+@pytest.mark.parametrize("attn,rows", [("kernel", 1), ("reference", 0)])
+def test_attn_blocks_of_the_dense_family(params, walked_blocks, attn, rows):
+    """llama's pages through the kernel: blocks of ``block_kv`` positions
+    (the tuning table's; a page at this size), ``seq_len // block + 1`` a
+    live stream; the gauge is the grid of every block a slot could hold.
+    Gathered pages walk no kernel: 0."""
+    eng = engine(params, attn_impl=attn, max_batch=3, max_prefill_per_step=3)
+    assert "attn_blocks" in FIELDS
+    assert eng.adapter.block_kv == 8
+    walked_blocks(eng, (5, 9, 12), 6, 3 * (256 // 8), rows)

@@ -771,3 +771,23 @@ def test_sarvam_programs_are_the_text_they_were(program):
         text = SA.prefill_program(cfg, scfg, 32, 32, jnp.float32).lower(
             params, sd((1, 32), jnp.int32), sd((1,), jnp.int32)).as_text()
     assert hashlib.sha256(text.encode()).hexdigest() == SARVAM_DIGESTS[program]
+
+
+@pytest.mark.parametrize("attn,rows", [("kernel", 1), ("reference", 0)])
+def test_step_record_counts_the_blocks_the_kernel_walks(
+    attn, rows, walked_blocks, monkeypatch
+):
+    """Three streams whose lengths cross a block's edge (16 positions, two
+    pages, at this size): ``attn_blocks`` of each step record is ``seq_len
+    // block + 1`` over the live streams, ``serve.decode_attn_grid_blocks``
+    the grid of every block a slot could hold; both 0 where the pages are
+    gathered."""
+    from fms_fsdp_tpu.serve import families
+
+    monkeypatch.setattr(families, "DECODE_BLOCK_TOKENS", 16)
+    eng = _engine(_tree(TINY), kexaone_config(TINY), max_batch=3,
+                  max_prefill_per_step=3, moe_impl="dense", attn_impl=attn)
+    assert (eng.adapter.page_size, eng.adapter.block_kv) == (8, 16)
+    by_hand = walked_blocks(eng, (5, 14, 28), 6, 3 * (128 // 16), rows)
+    if rows:  # 5..9 one block, 14, 15 | 16..18 two, 28..31 | 32 three
+        assert by_hand[0] == 1 + 1 + 2 and by_hand[-1] == 1 + 2 + 3

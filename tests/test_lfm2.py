@@ -540,3 +540,19 @@ def test_refusals_name_what_is_not_built():
     c = {**TINY, "num_hidden_layers": 2, "layer_types": ["conv"] * 2}
     with pytest.raises(ValueError, match="without one of them"):
         _engine(_tree(c), lfm2_moe_config(c))
+
+
+@pytest.mark.parametrize("attn,rows", [("kernel", 1), ("reference", 0)])
+def test_step_record_counts_the_blocks_the_kernel_walks(
+    attn, rows, walked_blocks, monkeypatch
+):
+    """tests/test_kexaone.py's, over this family's pages of packed rows."""
+    from fms_fsdp_tpu.serve import families
+
+    monkeypatch.setattr(families, "DECODE_BLOCK_TOKENS", 16)
+    eng = _engine(_tree(TINY), lfm2_moe_config(TINY), max_batch=3,
+                  max_prefill_per_step=3, moe_impl="dense", attn_impl=attn)
+    assert (eng.adapter.page_size, eng.adapter.block_kv) == (8, 16)
+    by_hand = walked_blocks(eng, (5, 14, 28), 6, 3 * (128 // 16), rows)
+    if rows:
+        assert by_hand[0] == 1 + 1 + 2 and by_hand[-1] == 1 + 2 + 3

@@ -645,3 +645,30 @@ def test_refusals_name_what_is_not_built():
         _engine(_tree(c), minicpm_sala_config(c))
     with pytest.raises(ValueError, match="minicpm_sala"):
         load_model_config({"family": "nope"})
+
+
+@pytest.mark.parametrize("attn,rows", [("kernel", 2), ("reference", 0)])
+def test_step_record_counts_the_blocks_the_kernel_walks(
+    attn, rows, walked_blocks, monkeypatch
+):
+    """A row of the kernel is a (stream, kv head) and its length the
+    chosen positions: every block up to the query's own while it is dense
+    (``t + 1 <= 64`` here), ``topk`` (6) pages of 16 past that; blocks of
+    two pages. The grid the kernel had: rows x the list's six pages in
+    blocks of two."""
+    from fms_fsdp_tpu.serve import families
+
+    monkeypatch.setattr(families, "DECODE_BLOCK_TOKENS", 32)
+    cfg = minicpm_sala_config(TINY)
+    eng = _engine(_tree(), cfg, max_batch=3, max_prefill_per_step=3,
+                  attn_impl=attn)
+    assert cfg.kvheads == 2 and cfg.sparse.list_blocks == 6
+    assert (eng.adapter.page_size, eng.adapter.block_kv) == (16, 32)
+
+    def chosen(t):
+        n = t // 16 + 1
+        return (min(n, 6) - 1) * 16 + t % 16 if t + 1 > 64 else t
+
+    by_hand = walked_blocks(eng, (5, 60, 130), 6, 3 * 2 * 3, rows, chosen)
+    if rows:  # 5: one block; 60: two; 130: chosen 5 * 16 + 2 = 82, three
+        assert by_hand[0] == 2 * (1 + 2 + 3)

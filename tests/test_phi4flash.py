@@ -531,3 +531,21 @@ def test_handoff_is_refused_by_name():
     assert not adapter.supports_handoff and not adapter.supports_layout
     with pytest.raises(AssertionError, match="phi4flash does not support"):
         adapter.export_handoff(0, 0)
+
+
+@pytest.mark.parametrize("attn,rows", [("kernel", 1), ("reference", 0)])
+def test_step_record_counts_the_blocks_the_kernel_walks(
+    attn, rows, walked_blocks, monkeypatch
+):
+    """tests/test_kexaone.py's: the count is of one reading of the full
+    layer's pages (the cross layers walk the same blocks again; the rings
+    are one block a slot whatever the stream)."""
+    from fms_fsdp_tpu.serve import families
+
+    monkeypatch.setattr(families, "DECODE_BLOCK_TOKENS", 16)
+    eng = _engine(_tree(), phi4flash_config(TINY), max_batch=3,
+                  max_prefill_per_step=3, attn_impl=attn)
+    assert (eng.adapter.page_size, eng.adapter.block_kv) == (8, 16)
+    by_hand = walked_blocks(eng, (5, 14, 28), 6, 3 * (128 // 16), rows)
+    if rows:
+        assert by_hand[0] == 1 + 1 + 2 and by_hand[-1] == 1 + 2 + 3

@@ -416,6 +416,18 @@ def test_counters_read_what_happened(traced):
     assert decodes[-1].stats["live"] == 0
 
 
+def test_a_family_without_the_paged_kernel_walks_no_blocks(traced):
+    """Mixtral's decode step gathers its pages and attends in XLA: the
+    field, the counter and the gauge of the ragged paged kernel's walk
+    (ops/paged_attention.py) read 0."""
+    engine, _, spans = traced
+    decodes = named(spans, "decode")
+    assert decodes and all(s.stats["attn_blocks"] == 0 for s in decodes)
+    assert all(r["attn_blocks"] == 0 for r in engine.step_log)
+    assert engine.registry.counter("serve.decode_attn_blocks").value == 0
+    assert engine.registry.gauge("serve.decode_attn_grid_blocks").value == 0
+
+
 # -- (b) tracing off is no profiler session ----------------------------------
 
 
