@@ -489,16 +489,19 @@ def _add(residual, out):
     return residual + out.astype(jnp.float32)
 
 
-def _stack_step(params: Params, x_t, cfg: MambaConfig, states, attn_cb):
+def _stack_step(params: Params, x_t, cfg: MambaConfig, states, attn_cb,
+                live=None):
     """One token through the whole (heterogeneous) layer stack.
 
     x_t (B, D) embedding row in the compute dtype; ``attn_cb(j, h, mixer)
     -> (B, D)`` runs hybrid attn layer j (qkv + cache interaction + wo)
-    against whatever cache the caller owns. Returns (residual (B, D)
-    fp32, new per-layer states)."""
+    against whatever cache the caller owns; ``live``: ``mamba_decode_step``.
+    Returns (residual (B, D) fp32, new per-layer states)."""
     compute_dtype = x_t.dtype
     residual = x_t.astype(jnp.float32)
-    mixer_step = _mamba1_mixer_step if cfg.mamba1 else _mamba_mixer_step
+    mixer_step = _mamba_mixer_step
+    if cfg.mamba1:
+        mixer_step = functools.partial(_mamba1_mixer_step, live=live)
     new_states = []
     attn_j = 0
     for i, layer in enumerate(params["layers"]):
@@ -745,13 +748,17 @@ def mamba_decode_step(
     *,
     page_size: int = 0,
     compute_dtype=jnp.float32,
+    live=None,
 ):
     """One recurrent decode step for a ragged batch.
 
     tokens (B,) int32 — each row's current token at position
     ``seq_lens[b]``; ``state`` the per-layer slab (all B slots step
     together; an idle slot's slices update with garbage it alone reads —
-    its next prefill overwrites them). Hybrid attn layers scatter k/v
+    its next prefill overwrites them — unless the caller masks them: the
+    serving adapter selects the old rows behind the step, or, where the
+    Mamba-1 scan steps its state in place, gives ``live`` (B,) bool and
+    that state's dead rows stay as they were). Hybrid attn layers scatter k/v
     into ``kv_pools`` (n_attn-layer paged pools) exactly like
     serve/decode.py does for llama; pure-Mamba configs pass ``{}`` /
     ``None`` and touch no cache at all. Returns (logits (B, V), state,
@@ -795,7 +802,7 @@ def mamba_decode_step(
         def attn_cb(j, h, mixer):  # pragma: no cover - unreachable
             raise AssertionError("attn layer in a config without attn_layer_idx")
 
-    residual, state = _stack_step(params, x_t, cfg, state, attn_cb)
+    residual, state = _stack_step(params, x_t, cfg, state, attn_cb, live)
     x = _block_norm(residual, params["norm_f"], cfg, compute_dtype)
     logits = _head(x, params)
     if cfg.attn_layer_idx:

@@ -23,7 +23,12 @@ Two things differ by family and are arguments, not options of a run:
 The sequence form (``mamba1_mixer``) and the one-position form
 (``mamba1_mixer_step``) run the same arithmetic under the same five
 scopes (``ssm_in_proj``, ``ssm_conv``, ``ssm_params``, ``ssm_scan``,
-``ssm_gate_out``).
+``ssm_gate_out``). Each asks ops/selective_scan.py for the scan that fits
+the platform and the shapes: on a TPU a prefill runs the sequence kernel
+and a decode step the one-position kernel, which makes one pass over the
+slab in place (a stacked slab and the layer's index, or one layer's
+state; a dead slot's rows written back as read); elsewhere both run the
+plain forms, and a decode program's text is what it was.
 """
 
 from typing import Any, Dict
@@ -38,7 +43,7 @@ from fms_fsdp_tpu.ops.quant import matmul as qmatmul
 from fms_fsdp_tpu.ops.selective_scan import (
     freeze_past,
     selective_scan_reference,
-    selective_scan_step,
+    selective_scan_slab_step,
 )
 from fms_fsdp_tpu.ops.ssd import causal_conv1d
 from fms_fsdp_tpu.parallel.mesh import AXIS_CONTEXT, AXIS_TENSOR, DATA_AXES
@@ -144,12 +149,20 @@ def conv_step(window, w, b):
 
 def mamba1_mixer_step(
     x, st: Params, p: Params, cfg, *, norms: bool = True,
-    hand_out: bool = False,
+    hand_out: bool = False, layer=None, live=None,
 ):
     """One token through a Mamba-1 mixer. x (B, D) post-norm hidden; st
     the layer's {"conv", "ssd"} slab. Returns (out (B, D), new st) and,
     with ``hand_out``, the scan's output (B, d_inner): the
-    single-position case of ``mamba1_mixer``."""
+    single-position case of ``mamba1_mixer``.
+
+    The scan's state is stepped where it lies
+    (``selective_scan_slab_step``: the in-place kernel where it compiles,
+    else ``jnp``): with ``layer``, ``st["ssd"]`` is the stacked state of
+    every Mamba layer, (layers, B, N, d_inner), of which this layer's is
+    stepped, and the new ``st["ssd"]`` is the whole stack again; with
+    ``live`` (B,) bool a row that is not live keeps its scan state (the
+    conv window is the caller's to keep)."""
     with jax.named_scope("ssm_in_proj"):
         u_pre, z = x @ p["in_proj"][0], x @ p["in_proj"][1]
     with jax.named_scope("ssm_conv"):
@@ -158,9 +171,10 @@ def mamba1_mixer_step(
     with jax.named_scope("ssm_params"):
         dt, A, Bm, Cm = mamba1_scan_inputs(u, p, cfg, norms)
     with jax.named_scope("ssm_scan"):
-        y, h = selective_scan_step(
+        y, h = selective_scan_slab_step(
             u.astype(jnp.float32), dt, A, Bm.astype(jnp.float32),
             Cm.astype(jnp.float32), p["D"].astype(jnp.float32), st["ssd"],
+            layer, live,
         )
     with jax.named_scope("ssm_gate_out"):
         y = y.astype(x.dtype)

@@ -580,7 +580,12 @@ def phi4flash_decode_step(
     ``{"ring_k", "ring_v", "conv", "ssd"}`` as ``phi4flash_prefill``
     returns them with B the slots; pools ``{"k", "v"}`` (1, P, page_size *
     kvheads / 2, 2 head_dim), the adapter's PagedKVCache.pools. A Mamba
-    layer steps its slab (a dead slot's stays as it was); a window layer
+    layer steps its slab; a dead slot's stays as it was: the scan's
+    stacked state goes to the mixer whole with the layer's index and the
+    live rows, and is stepped where it lies (on a TPU one kernel pass
+    that writes a dead row back as read, ops/selective_scan.py; else
+    slice, step, select, write back), and the conv window's 3.9 MB are
+    selected here. A window layer
     writes the position's key and value at ``seq_lens mod
     sliding_window`` of its ring and attends the ring (``"kernel"``: as
     pages of the ragged paged kernel); the full layer
@@ -616,15 +621,13 @@ def phi4flash_decode_step(
             h = _norm(x, layer["norm"], cfg)
             if cfg.kind(i) == "mamba":
                 out, st, y = mamba1_mixer_step(
-                    h, {"conv": conv[mi], "ssd": ssd[mi]}, p, cfg,
-                    norms=False, hand_out=True,
+                    h, {"conv": conv[mi], "ssd": ssd}, p, cfg,
+                    norms=False, hand_out=True, layer=mi, live=live,
                 )
+                ssd = st["ssd"]
                 with jax.named_scope("ssm_scan"):
                     conv = conv.at[mi].set(
                         jnp.where(live[:, None, None], st["conv"], conv[mi])
-                    )
-                    ssd = ssd.at[mi].set(
-                        jnp.where(live[:, None, None], st["ssd"], ssd[mi])
                     )
                 if i == cfg.hand_out_layer:
                     memory = y

@@ -59,6 +59,7 @@ from fms_fsdp_tpu.models.mamba import (
     slab_shapes,
 )
 from fms_fsdp_tpu.obs.scopes import scoped
+from fms_fsdp_tpu.ops.selective_scan import scan_step_form
 from fms_fsdp_tpu.serve.disagg.slab import (
     SLAB_CODEC_VERSION,
     check_slab_header,
@@ -84,17 +85,34 @@ def page_geometry(model_cfg, scfg):
     return page_size, max_pages, num_pages
 
 
+def ssm_form(model_cfg, slots: int) -> str:
+    """How a decode step of ``slots`` slots steps a Mamba-1 layer's scan
+    state (``ops/selective_scan.py::scan_step_form``: ``"kernel"``, in
+    place, or ``"jnp"``); ``"jnp"`` for a Mamba-2 stack, whose step is
+    plain jax."""
+    if not model_cfg.mamba1:
+        return "jnp"
+    return scan_step_form(slots, model_cfg.d_state, model_cfg.d_inner)
+
+
 @scoped("ssm_scan")
-def _mask_state(new, old, live):
+def _mask_state(new, old, live, in_place: bool = False):
     """The slab after a step: the new rows where ``live``, the old rows
-    elsewhere (the end of the state's update, so under its scope)."""
-    return jax.tree.map(
-        lambda n, o: jnp.where(
+    elsewhere (the end of the state's update, so under its scope).
+    ``in_place``: the scan's state was stepped where it lay and its dead
+    rows were never moved, so only the conv windows are selected."""
+
+    def select(n, o):
+        return jnp.where(
             live.reshape((o.shape[0],) + (1,) * (n.ndim - 1)), n, o
-        ),
-        new,
-        old,
-    )
+        )
+
+    if in_place:
+        return [
+            dict(n, conv=select(n["conv"], o["conv"])) if n else n
+            for n, o in zip(new, old)
+        ]
+    return jax.tree.map(select, new, old)
 
 
 def decode_program(model_cfg, scfg, page_size: int, compute_dtype):
@@ -108,6 +126,19 @@ def decode_program(model_cfg, scfg, page_size: int, compute_dtype):
     -> (tokens (B,) int32, logits (B, V), state, pools)``; without
     attention layers the pools and the table drop out of both."""
     cfg = model_cfg
+    in_place = ssm_form(cfg, scfg.max_batch) == "kernel"
+
+    def step(params, state, pools, page_table, seq_lens, tokens):
+        """The model's step and the state with its dead rows (lens 0: a
+        prompt is never empty) as they were: idle rows must not smear
+        garbage into released, zeroed slab slices."""
+        logits, new_state, pools = mamba_decode_step(
+            params, state, pools, page_table, seq_lens, tokens, cfg,
+            page_size=page_size, compute_dtype=compute_dtype,
+            live=seq_lens > 0 if in_place else None,
+        )
+        state = _mask_state(new_state, state, seq_lens > 0, in_place)
+        return logits, state, pools
 
     def sample(logits, key):
         tok = sample_token(
@@ -118,23 +149,15 @@ def decode_program(model_cfg, scfg, page_size: int, compute_dtype):
     if cfg.attn_layer_idx:
 
         def _step(params, state, pools, page_table, seq_lens, tokens, key):
-            logits, new_state, pools = mamba_decode_step(
-                params, state, pools, page_table, seq_lens, tokens,
-                cfg, page_size=page_size, compute_dtype=compute_dtype,
+            logits, state, pools = step(
+                params, state, pools, page_table, seq_lens, tokens
             )
-            # idle rows (lens 0 — a prompt is never empty) must not
-            # smear garbage into released, zeroed slab slices
-            state = _mask_state(new_state, state, seq_lens > 0)
             return sample(logits, key), logits, state, pools
 
         return jax.jit(_step, donate_argnums=(1, 2))
 
     def _step(params, state, seq_lens, tokens, key):
-        logits, new_state, _ = mamba_decode_step(
-            params, state, None, None, seq_lens, tokens,
-            cfg, compute_dtype=compute_dtype,
-        )
-        state = _mask_state(new_state, state, seq_lens > 0)
+        logits, state, _ = step(params, state, None, None, seq_lens, tokens)
         return sample(logits, key), logits, state
 
     return jax.jit(_step, donate_argnums=(1,))
@@ -190,6 +213,9 @@ class MambaAdapter(FamilyAdapter):
                 "speculator_path"
             )
         self.attn_impl = "reference" if self._hybrid else "none"
+        # how the decode program steps a Mamba-1 layer's scan state
+        self.ssm_form = ssm_form(cfg, scfg.max_batch)
+        self._dispatch_fields = {"ssm_form": self.ssm_form}
 
         if self._hybrid:
             a = cfg.attn_cfg

@@ -35,7 +35,9 @@ written yet and leaves a dead slot's slab as it was.
 
 Decode: one ragged step over ``max_batch`` slots; ``attn_form`` on every
 ``serve/decode.dispatch`` span says how the full layer's pages are read
-(the ragged paged kernel, or ``reference``).
+(the ragged paged kernel, or ``reference``) and ``ssm_form`` how a Mamba
+layer's scan state is stepped (``kernel``: one pass over the slab in
+place, ops/selective_scan.py; or ``jnp``).
 
 Prefill: the prompt's positions through the first half of the stack,
 ``PREFILL_CHUNK`` at a time in a loop inside its program that stops at
@@ -69,6 +71,7 @@ from fms_fsdp_tpu.models.phi4flash import (
     prefill_attn_form,
     prefill_positions,
 )
+from fms_fsdp_tpu.ops.selective_scan import scan_step_form
 from fms_fsdp_tpu.serve.families import (
     FamilyAdapter,
     block_paged_geometry as page_geometry,  # the full layer's pages
@@ -176,7 +179,13 @@ class Phi4FlashAdapter(FamilyAdapter):
              "a prompt's chunks between decode steps are not built"),
         )
         self.attn_impl = resolve_attn_impl(scfg)
-        self._dispatch_fields = {"attn_form": self.attn_impl}
+        # how the Mamba layers step their scan state, beside ``attn_form``
+        self.ssm_form = scan_step_form(
+            scfg.max_batch, cfg.d_state, cfg.d_inner
+        )
+        self._dispatch_fields = {
+            "attn_form": self.attn_impl, "ssm_form": self.ssm_form,
+        }
 
         from fms_fsdp_tpu.serve.kv_cache import PagedKVCache
 

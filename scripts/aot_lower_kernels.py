@@ -146,6 +146,49 @@ def _compile_ssd_fused():
     ).compile()
 
 
+def _compile_scan_step(layers, slots, states, channels):
+    """The one-position selective scan over a donated stacked slab, every
+    layer through the one lowering (the layer's index an operand): Mosaic
+    takes the strided reads of a (slots * states, 128) block, and the
+    slab comes back as the buffer it went in as, whole: no temporary of a
+    layer's size beside the kernel's calls."""
+    import jax
+    import jax.numpy as jnp
+
+    from fms_fsdp_tpu.ops.selective_scan import (
+        selective_scan_slab_step,
+        scan_step_form,
+    )
+
+    mesh, _ = _topology_mesh()
+    r = _repl(mesh)
+    f32 = jnp.float32
+    assert scan_step_form(slots, states, channels) == "kernel"
+
+    def steps(u, dt, A, B, C, D, slab, live):
+        y = 0.0
+        for layer in range(layers):
+            out, slab = selective_scan_slab_step(
+                u, dt, A, B, C, D, slab, layer, live
+            )
+            y = y + out
+        return y, slab
+
+    row = _sds((slots, channels), f32, r)
+    col = _sds((slots, states), f32, r)
+    shape = (layers, slots, states, channels)
+    compiled = jax.jit(steps, donate_argnums=(6,)).lower(
+        row, row, _sds((states, channels), f32, r), col, col,
+        _sds((channels,), f32, r), _sds(shape, f32, r),
+        _sds((slots,), jnp.bool_, r),
+    ).compile()
+    m = compiled.memory_analysis()
+    slab_bytes = 4 * layers * slots * states * channels
+    assert m.alias_size_in_bytes >= slab_bytes, m.alias_size_in_bytes
+    assert m.temp_size_in_bytes < slab_bytes // layers, m.temp_size_in_bytes
+    assert compiled.as_text().count("tpu_custom_call") == layers
+
+
 def _compile_ring(cp):
     import jax
     import jax.numpy as jnp
@@ -279,6 +322,11 @@ TARGETS = [
     ("flash_kvgrid_32k", lambda: _compile_flash("kvgrid", 1, 32768, 8, 2, 128)),
     # fused whole-sequence SSD kernel (the win-or-delete candidate)
     ("ssd_fused_fwd_bwd", _compile_ssd_fused),
+    # the one-position Mamba-1 scan over the slab in place: the phi4flash
+    # cell's stacked slab (9 layers, 128 slots) and one Jamba layer's
+    # state (16 slots)
+    ("scan_step_phi4flash_slab", lambda: _compile_scan_step(9, 128, 16, 5120)),
+    ("scan_step_jamba_layer", lambda: _compile_scan_step(1, 16, 16, 5120)),
     # kernel + collective compositions a pod actually runs
     ("ring_attention_cp4", lambda: _compile_ring(4)),
     ("cp_ssd_cp4", lambda: _compile_cp_ssd(4)),

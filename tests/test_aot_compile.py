@@ -180,6 +180,41 @@ def test_gathered_blocks_attention_compiles(v5e, kv_len, segment):
     assert compiled.as_text().count("tpu_custom_call") == 1
 
 
+@pytest.mark.parametrize(
+    "layers,slots", [(9, 128), (1, 16)], ids=["phi4flash", "jamba"])
+def test_scan_step_kernel_compiles_in_place(v5e, layers, slots):
+    """The one-position selective scan over the phi4flash cell's stacked
+    slab and over one Jamba layer's state, donated: Mosaic takes the
+    strided reads of a (slots * 16, 128) block, nine layers are one
+    lowering, the slab comes back as the buffer it went in as and nothing
+    of a layer's size stands beside it."""
+    from fms_fsdp_tpu.ops.selective_scan import selective_scan_step_kernel
+
+    N, C = 16, 5120
+
+    def steps(u, dt, A, B, Cm, D, slab, live):
+        y = 0.0
+        for layer in range(layers):
+            out, slab = selective_scan_step_kernel(
+                u, dt, A, B, Cm, D, slab, layer, live)
+            y = y + out
+        return y, slab
+
+    f32 = jnp.float32
+    row, col = _sds(v5e, (slots, C), f32), _sds(v5e, (slots, N), f32)
+    lowered = jax.jit(steps, donate_argnums=(6,)).lower(
+        row, row, _sds(v5e, (N, C), f32), col, col, _sds(v5e, (C,), f32),
+        _sds(v5e, (layers, slots, N, C), f32),
+        _sds(v5e, (slots,), jnp.bool_))
+    # the layer is an operand: every layer's call is the one lowering
+    assert lowered.as_text().count("tpu_custom_call") == 1
+    compiled = lowered.compile()
+    m = compiled.memory_analysis()
+    assert m.alias_size_in_bytes >= 4 * layers * slots * N * C
+    assert m.temp_size_in_bytes < 4 * slots * N * C
+    assert compiled.as_text().count("tpu_custom_call") == layers
+
+
 def test_ssd_fused_fwd_bwd_compiles(v5e, monkeypatch):
     from fms_fsdp_tpu.ops import pallas_mode
     from fms_fsdp_tpu.ops.ssd import ssd_scan
@@ -626,9 +661,12 @@ def test_phi4flash_programs_fit_beside_ring_slab_and_one_layers_pool(
     all: refused); as rows of 128 lanes read by the ragged paged kernel
     they are arguments aliased to results. 16 Mosaic calls a decode step:
     the paged kernel over a slot's ring a window layer, over the one
-    layer's pages for the full layer and each cross layer; 17 a prefill:
-    the windowed flash kernel a window layer, the scan kernel a Mamba
-    layer."""
+    layer's pages for the full layer and each cross layer; since PR 48 nine
+    more, the one-position scan kernel a Mamba layer over the stacked slab
+    in place (decode peak 12.97 GB with 0.043 GB of temporaries: the stack's
+    copy and a layer's new state have no buffer of their own); 17 a
+    prefill: the windowed flash kernel a window
+    layer, the scan kernel a Mamba layer."""
     import json
     import re
 
@@ -686,8 +724,8 @@ def test_phi4flash_programs_fit_beside_ring_slab_and_one_layers_pool(
     dm, pm = decode.memory_analysis(), prefill.memory_analysis()
     hbm = 15.75 * 2**30
     weights = cfg.n_params() * 2
-    assert dm.temp_size_in_bytes < 0.5e9
-    assert dm.peak_memory_in_bytes < weights + held + 0.6e9 < hbm
+    assert dm.temp_size_in_bytes < 0.1e9
+    assert dm.peak_memory_in_bytes < weights + held + 0.2e9 < hbm
     assert pm.temp_size_in_bytes < 0.2e9
     assert pm.peak_memory_in_bytes + held < hbm - 2.0e9
     dtext, ptext = decode.as_text(), prefill.as_text()
@@ -698,7 +736,15 @@ def test_phi4flash_programs_fit_beside_ring_slab_and_one_layers_pool(
         dims = ",".join(map(str, shape))
         assert f"bf16[{dims}]" in dtext
         assert not re.search(r"= bf16\[%s\]\S* copy\(" % dims, dtext)
-    assert dtext.count("tpu_custom_call") == 8 + 8
+    # the nine scan states are stepped where they lie in the stacked slab
+    # (PR 48): no copy, slice or write-back of a layer's 42 MB or of the
+    # stack beside the kernel's nine calls
+    slab = ",".join(map(str, shapes["ssd"][0]))
+    assert f"f32[{slab}]" in dtext
+    assert not re.search(
+        r"= f32\[(%s|%s)\]\S* (copy|dynamic-update-slice|select)\("
+        % (slab, slab.split(",", 1)[1]), dtext)
+    assert dtext.count("tpu_custom_call") == 8 + 8 + 9
     assert ptext.count("tpu_custom_call") == 8 + 9
     # the second half of the stack meets one row a prompt: no product of
     # a gated memory unit's width over a chunk of rows that is not a

@@ -154,7 +154,7 @@ def test_attention_has_no_rope(params):
 
 
 # ---------------------------------------------------------------------------
-# the three forms of the scan
+# the four forms of the scan
 # ---------------------------------------------------------------------------
 
 
@@ -192,6 +192,47 @@ def test_scan_forms_agree_with_a_carried_state_and_ragged_rows(rows, S, C, N):
         u[1:2, : S - 5], dt[1:2, : S - 5], A, B[1:2, : S - 5],
         Cm[1:2, : S - 5], D, h0[1:2])
     np.testing.assert_array_equal(h_ref[1:2], h_cut)
+
+
+@pytest.mark.parametrize(
+    "layers,slots,C", [(1, 2, 128), (3, 16, 1024), (2, 24, 5120)])
+def test_the_one_position_kernel_steps_a_slab_where_it_lies(layers, slots, C):
+    """The fourth form against ``selective_scan_step`` on a stacked slab
+    with some rows dead: ``y`` and the stepped layer's live rows agree,
+    dead rows and every other layer are the bits they were, and the slab
+    that comes back is the buffer that went in where the backend
+    donates."""
+    N = 16
+    u, dt, A, B, Cm, D, _ = (
+        x[:, 0] if x.ndim == 3 else x
+        for x in _scan_inputs(slots, 1, C, N, seed=slots))
+    slab = jax.random.normal(jax.random.PRNGKey(C), (layers, slots, N, C))
+    live = jnp.arange(slots) % 3 != 1
+    layer = layers - 1
+    assert ss.step_kernel_supports(slots, N, C)
+    y_ref, h_ref = ss.selective_scan_step(u, dt, A, B, Cm, D, slab[layer])
+    before = np.asarray(slab)
+    step = jax.jit(
+        lambda slab, layer: ss.selective_scan_step_kernel(
+            u, dt, A, B, Cm, D, slab, layer, live, interpret=True),
+        donate_argnums=0)
+    held = slab.unsafe_buffer_pointer()
+    y, out = step(slab, jnp.int32(layer))
+    if slab.is_deleted():  # the backend donates
+        assert out.unsafe_buffer_pointer() == held
+    np.testing.assert_allclose(y, y_ref, rtol=1e-5, atol=1e-5)
+    out, alive = np.asarray(out), np.asarray(live)
+    np.testing.assert_allclose(
+        out[layer][alive], np.asarray(h_ref)[alive], rtol=1e-5, atol=1e-5)
+    np.testing.assert_array_equal(out[layer][~alive], before[layer][~alive])
+    np.testing.assert_array_equal(out[:layer], before[:layer])
+    # one layer's state alone is the same call with one layer, and
+    # without ``live`` every row is stepped
+    y1, h1 = ss.selective_scan_step_kernel(
+        u, dt, A, B, Cm, D, jnp.asarray(before[layer]), interpret=True)
+    assert h1.shape == (slots, N, C)
+    np.testing.assert_allclose(y1, y_ref, rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(h1, h_ref, rtol=1e-5, atol=1e-5)
 
 
 def test_prefill_through_the_kernel_equals_the_scan_form(params, monkeypatch):
@@ -435,6 +476,37 @@ def test_engine_agrees_with_the_reference_on_logits_float32(params, lengths):
             params, params, "float32", lengths, seed=sum(lengths)):
         # float32 on both sides: reduction order only
         assert _gaps(got, want) < 1e-4
+
+
+def test_engine_through_the_step_kernel_agrees_and_keeps_an_idle_slot_zero(
+        params, monkeypatch):
+    """Where the one-position kernel compiles, the hybrid's decode step
+    steps each layer's scan state in place and the adapter selects the
+    conv windows alone; here, in interpret mode: a stream's logits are
+    the reference's and the idle slot beside it stays zero."""
+    from fms_fsdp_tpu.serve.families import mamba as A
+
+    kernel = ss.selective_scan_step_kernel
+    monkeypatch.setattr(
+        ss, "selective_scan_step_kernel",
+        lambda *args: kernel(*args, interpret=True))
+    monkeypatch.setattr(ss, "scan_step_form", lambda *shape: "kernel")
+    monkeypatch.setattr(A, "scan_step_form", ss.scan_step_form)
+    eng = _engine(params)
+    assert eng.adapter._dispatch_fields == {"ssm_form": "kernel"}
+    prompt = np.random.default_rng(3).integers(1, 512, size=11).tolist()
+    req = eng.submit(prompt, NEW)
+    for _ in range(5):
+        eng.step()
+    assert 0 < len(req.generated) < NEW
+    for x in jax.tree.leaves(eng.adapter.slab_slice(0)):
+        assert float(jnp.abs(x).max()) > 0
+    for x in jax.tree.leaves(eng.adapter.slab_slice(1)):
+        assert float(jnp.abs(x).max()) == 0.0
+    eng = _engine(params)
+    (req,), (got,) = _serve_capturing(eng, [prompt], NEW)
+    want = _ref_logits(params, prompt + list(req.generated[:-1]))
+    assert _gaps(got, want[len(prompt) - 1:]) < 1e-4
 
 
 def _bf16_gap(control):

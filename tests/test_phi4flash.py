@@ -399,7 +399,8 @@ def test_engine_agrees_with_the_reference_on_logits_float32(slots, attn):
     cfg, tree = phi4flash_config(TINY), _tree()
     eng = _engine(tree, cfg, max_batch=slots, attn_impl=attn)
     assert eng.adapter.attn_impl == attn
-    assert eng.adapter._dispatch_fields == {"attn_form": attn}
+    assert eng.adapter._dispatch_fields == {
+        "attn_form": attn, "ssm_form": "jnp"}
     rng = np.random.default_rng(7)
     lengths, outputs = (37, 32, 1, 1, 5), (6, 20, 20, 12, 12)
     prompts = [rng.integers(1, 256, size=n).tolist() for n in lengths]
@@ -433,6 +434,59 @@ def test_engine_agrees_with_the_reference_on_logits_float32(slots, attn):
         "ring_v": (3, slots, WINDOW * 2, 16),
         "conv": (4, slots, 3, 128), "ssd": (4, slots, 16, 128)}
     assert eng.adapter._state["ssd"].dtype == jnp.float32
+
+
+def _through_the_step_kernel(monkeypatch):
+    """Make the decode step take the one-position scan kernel, as it does
+    on a TPU, in interpret mode. -> the slab shapes it was called with."""
+    from fms_fsdp_tpu.ops import selective_scan as ss
+
+    calls, kernel = [], ss.selective_scan_step_kernel
+
+    def interpreted(*args):
+        calls.append(args[6].shape)
+        return kernel(*args, interpret=True)
+
+    monkeypatch.setattr(ss, "selective_scan_step_kernel", interpreted)
+    monkeypatch.setattr(ss, "scan_step_form", lambda *shape: "kernel")
+    monkeypatch.setattr(A, "scan_step_form", ss.scan_step_form)
+    return calls
+
+
+def test_engine_through_the_step_kernel_agrees_with_the_reference(monkeypatch):
+    """On a TPU a decode step steps the nine scan states through the
+    Pallas kernel, in place in the stacked slab; here, in interpret mode,
+    every served position's logits are the reference's, with more
+    requests than slots so that a slot lies dead for a while, and every
+    ``decode.dispatch`` span says ``ssm_form`` ``kernel``."""
+    from fms_fsdp_tpu.serve import families
+
+    calls = _through_the_step_kernel(monkeypatch)
+    seen, span = [], families.span
+
+    def noted(name, **fields):
+        seen.append((name, fields))
+        return span(name, **fields)
+
+    monkeypatch.setattr(families, "span", noted)
+    cfg, tree = phi4flash_config(TINY), _tree()
+    eng = _engine(tree, cfg, max_batch=2)
+    assert eng.adapter.ssm_form == "kernel"
+    rng = np.random.default_rng(11)
+    lengths, outputs = (37, 5, 1), (6, 20, 12)
+    prompts = [rng.integers(1, 256, size=n).tolist() for n in lengths]
+    reqs, rows = _serve_capturing(eng, prompts, outputs)
+    for prompt, req, got, n in zip(prompts, reqs, rows, outputs):
+        assert req.state == "finished" and len(req.generated) == n
+        want = _ref_logits(tree, prompt + req.generated[:-1])
+        assert _gap(got, want[len(prompt) - 1:]) < TOL
+    # one trace of the decode program: four Mamba layers, each handed the
+    # whole stacked slab
+    assert calls == [(4, 2, 16, 128)] * 4
+    dispatched = [f for name, f in seen if name == "decode.dispatch"]
+    assert dispatched and all(
+        f["ssm_form"] == "kernel" and f["attn_form"] == "reference"
+        for f in dispatched)
 
 
 def test_every_cross_layer_reads_the_full_layers_pages(monkeypatch):
@@ -477,10 +531,15 @@ def test_every_cross_layer_reads_the_full_layers_pages(monkeypatch):
     assert np.abs(again - base).max() > 1e-4
 
 
-def test_a_dead_slots_state_stays_as_it_was():
-    """A decode step steps the slabs of the live slots alone."""
+@pytest.mark.parametrize("form", ["jnp", "kernel"])
+def test_a_dead_slots_state_stays_as_it_was(form, monkeypatch):
+    """A decode step steps the slabs of the live slots alone, whichever
+    form steps the scan's state."""
+    if form == "kernel":
+        _through_the_step_kernel(monkeypatch)
     cfg, tree = phi4flash_config(TINY), _tree()
     eng = _engine(tree, cfg, max_batch=2)
+    assert eng.adapter.ssm_form == form
     eng.submit(list(range(1, 20)), 6)
     eng.run()
     ssd, conv = (np.asarray(eng.adapter._state[n]) for n in ("ssd", "conv"))
